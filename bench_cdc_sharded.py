@@ -59,6 +59,10 @@ elif "xla_force_host_platform_device_count" not in os.environ.get(
                                + os.environ.get("XLA_FLAGS", ""))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+from dfs_tpu.utils.device import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()      # workers re-run this file: they share it
+
 import argparse          # noqa: E402
 import asyncio           # noqa: E402
 import json              # noqa: E402

@@ -30,6 +30,10 @@ def main() -> int:
     import jax.numpy as jnp
 
     from dfs_tpu.ops import cdc_anchored as A
+    from dfs_tpu.utils.device import bench_device
+
+    print(bench_device("bench_profile.py"), file=sys.stderr)
+
     from dfs_tpu.ops.cdc_anchored import (AnchoredCdcParams, region_buffer,
                                           region_dispatch)
     from dfs_tpu.ops.layout import bswap_transpose

@@ -64,6 +64,10 @@ if "--sketch-worker" in sys.argv:
         + os.environ.get("XLA_FLAGS", ""))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+from dfs_tpu.utils.device import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()      # workers re-run this file: they share it
+
 import argparse          # noqa: E402
 import json              # noqa: E402
 import subprocess        # noqa: E402

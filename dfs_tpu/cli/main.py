@@ -16,7 +16,7 @@ import asyncio
 import sys
 from pathlib import Path
 
-from dfs_tpu.cli.client import NodeClient
+from dfs_tpu.cli.client import DEFAULT_TIMEOUT_S, NodeClient
 from dfs_tpu.config import (CDCParams, CensusConfig, ChaosConfig,
                             ClusterConfig, DurabilityConfig,
                             FragmenterConfig, IndexConfig, IngestConfig,
@@ -24,8 +24,16 @@ from dfs_tpu.config import (CDCParams, CensusConfig, ChaosConfig,
                             ServeConfig, SimConfig, TierConfig)
 
 
-def _client(args) -> NodeClient:
-    return NodeClient(host=args.host, port=args.port)
+# Bulk transfers and cluster-wide sweeps take as long as the data held,
+# not a round trip: the reference's 5 s client timeout (cli/client.py)
+# fits status/list, and failed `census` over 2 GiB on the first chip run.
+BULK_TIMEOUT_S = 600.0
+
+
+def _client(args, bulk: bool = False) -> NodeClient:
+    return NodeClient(host=args.host, port=args.port,
+                      timeout_s=BULK_TIMEOUT_S if bulk
+                      else DEFAULT_TIMEOUT_S)
 
 
 def _smart_client(args):
@@ -199,23 +207,48 @@ def cmd_serve(args) -> int:
         periodic(args.scrub_interval, "scrub", do_scrub)
         await asyncio.Event().wait()  # serve forever
 
+    from dfs_tpu.utils.device import DeviceError
+
+    if not args.sidecar_port:       # a delegating node compiles nothing
+        _place_compile_cache(args.fragmenter, args.cdc_devices)
     try:
         asyncio.run(run())
     except KeyboardInterrupt:
         pass
+    except DeviceError as e:
+        return _device_refused(e)
     return 0
+
+
+def _place_compile_cache(fragmenter: str, devices: int = 0) -> None:
+    """Place the persistent compile cache before an engine that compiles
+    is built; the host-only engines compile nothing."""
+    if fragmenter == "auto" or fragmenter.endswith("-tpu") or devices > 1:
+        from dfs_tpu.utils.device import enable_compile_cache
+
+        enable_compile_cache()
+
+
+def _device_refused(e: Exception) -> int:
+    print(f"error: {e}", file=sys.stderr, flush=True)
+    return 1
 
 
 def cmd_sidecar(args) -> int:
     import time
 
     from dfs_tpu.sidecar.service import SidecarServer
+    from dfs_tpu.utils.device import DeviceError
 
-    srv = SidecarServer(
-        port=args.sidecar_port, fragmenter=args.fragmenter,
-        cdc_params=CDCParams(min_size=args.min_chunk,
-                             avg_size=args.avg_chunk,
-                             max_size=args.max_chunk))
+    _place_compile_cache(args.fragmenter)
+    try:
+        srv = SidecarServer(
+            port=args.sidecar_port, fragmenter=args.fragmenter,
+            cdc_params=CDCParams(min_size=args.min_chunk,
+                                 avg_size=args.avg_chunk,
+                                 max_size=args.max_chunk))
+    except DeviceError as e:
+        return _device_refused(e)
     srv.start()
     print(f"sidecar listening on 127.0.0.1:{srv.port} "
           f"(fragmenter={srv.fragmenter.name})", flush=True)
@@ -275,15 +308,15 @@ def cmd_upload(args) -> int:
                   file=sys.stderr)
             return 2
         # chunk locally, probe, send only missing payloads (SURVEY §5.4)
-        info = _client(args).upload_resume(data, name=path.name,
-                                           trace_id=trace_id)
+        info = _client(args, bulk=True).upload_resume(
+            data, name=path.name, trace_id=trace_id)
         tr = f" traceId={trace_id}" if trace_id else ""
         print(f"Uploaded (resume): fileId={info['fileId']} "
               f"chunks={info['chunks']} "
               f"clientSent={info['clientBytesSent']}B of {len(data)}B{tr}")
         return 0
-    info = _client(args).upload(data, name=path.name, ec=ec,
-                                trace_id=trace_id)
+    info = _client(args, bulk=True).upload(data, name=path.name, ec=ec,
+                                           trace_id=trace_id)
     extra = (f" ecParity={info['ecParityBytes']}B"
              if "ecParityBytes" in info else "")
     if trace_id:
@@ -295,7 +328,7 @@ def cmd_upload(args) -> int:
 
 
 def cmd_download(args) -> int:
-    c = _client(args)
+    c = _client(args, bulk=True)
     file_id = args.file_id
     trace_id = _maybe_trace_id(args)
     if getattr(args, "smart", False):
@@ -368,7 +401,7 @@ def cmd_doctor(args) -> int:
     pathologies with their evidence (GET /doctor)."""
     from dfs_tpu.obs.doctor import render_report
 
-    report = _client(args).doctor(cluster=not args.local)
+    report = _client(args, bulk=True).doctor(cluster=not args.local)
     print(render_report(report))
     if args.json:
         import json
@@ -390,7 +423,7 @@ def cmd_census(args) -> int:
     a data-health gate: exit 1 on findings or unreachable peers."""
     from dfs_tpu.obs.census import render_census
 
-    report = _client(args).census(cluster=not args.local)
+    report = _client(args, bulk=True).census(cluster=not args.local)
     print(render_census(report))
     if args.json:
         import json
@@ -408,7 +441,7 @@ def cmd_df(args) -> int:
     section of GET /census."""
     from dfs_tpu.obs.census import render_df
 
-    report = _client(args).census(cluster=True)
+    report = _client(args, bulk=True).census(cluster=True)
     print(render_df(report))
     if report.get("peersFailed"):
         print(f"(warning: {report['peersFailed']} peer(s) unreachable "

@@ -625,19 +625,30 @@ class InternalClient:
             retries=retries, timeout_s=self._bulk_timeout(expect_bytes))
         return unpack_chunks(resp.get("chunks", []), body)
 
+    # readdir+stat budget of a peer's CAS walk: a census over 2 GiB on
+    # the chip machine's disk ran past the flat request timeout and read
+    # as "2 peer(s) unreachable" (PERF.md, PR 21)
+    _CENSUS_CHUNKS_PER_S = 1000
+
     async def get_census(self, peer: PeerAddr,
                          prefixes: list[str] | None = None,
-                         retries: int | None = None) -> dict | None:
+                         retries: int | None = None,
+                         expect_chunks: int = 0) -> dict | None:
         """Census inventory of one peer (docs/observability.md): the
         bucketed CAS summary, or — with ``prefixes`` — member digest
         lists for exactly those buckets (the census drill-down; the
         receiver caps each list). Callers pass ``retries=1``: the
         census is partial-on-dead by contract, so a dead peer must cost
-        one fast probe, not the full retry envelope."""
+        one fast probe, not the full retry envelope. ``expect_chunks``
+        (how many chunks the peer may have to walk) raises the
+        per-attempt budget like a bulk transfer's byte count does."""
         header: dict = {"op": "get_census"}
         if prefixes:
             header["prefixes"] = list(prefixes)
-        resp, _ = await self.call(peer, header, retries=retries)
+        resp, _ = await self.call(
+            peer, header, retries=retries,
+            timeout_s=self.request_timeout_s
+            + expect_chunks / self._CENSUS_CHUNKS_PER_S)
         census = resp.get("census")
         return census if isinstance(census, dict) else None
 

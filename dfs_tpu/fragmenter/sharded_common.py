@@ -11,13 +11,15 @@ same two pieces, and they must not drift apart:
   spans tile it evenly) and at least a strategy-specific floor (the
   rolling halo source span / the anchored two-segment window).
 
-- **one degraded-fallback predicate** (:class:`ShardedSteps`): building
-  the mesh + steps is LAZY (jax untouched until the first stream) and
-  any failure — jax missing, fewer devices visible than configured, a
-  backend that refuses the mesh — degrades to the single-device kernel
-  with one logged warning. A degraded environment must never fail
-  ingest; output is identical either way (the sharded steps compute the
-  same boundaries, which tests pin byte-identical).
+- **one fallback predicate** (:class:`ShardedSteps`): building the mesh
+  + steps is LAZY (jax untouched until the first stream). Where the
+  devices were asked for — any process not started with
+  ``JAX_PLATFORMS=cpu`` — a failure is an ERROR: no TPU, fewer chips
+  than configured, a kernel the compiler refuses. Only the CPU-on-purpose
+  rehearsal (virtual devices, tests) degrades to the single-device
+  kernel with one logged warning; output is identical either way (the
+  sharded steps compute the same boundaries, which tests pin
+  byte-identical).
 """
 
 from __future__ import annotations
@@ -39,10 +41,11 @@ def fixed_region_bytes(requested: int, default: int, granule: int) -> int:
 class ShardedSteps:
     """Lazy mesh + step construction behind the single fallback
     predicate. ``build(mesh)`` runs at most once, on the first
-    :meth:`get`; it may return any strategy-specific step bundle.
-    Failure of any kind marks the instance unavailable, logs one
-    warning, and every later ``get()`` returns None — callers fall back
-    to their single-device kernel."""
+    :meth:`get`; it may return any strategy-specific step bundle. On the
+    device (anything but ``JAX_PLATFORMS=cpu``) a failure raises. On the
+    CPU on purpose it marks the instance unavailable, logs one warning,
+    and every later ``get()`` returns None — callers fall back to their
+    single-device kernel."""
 
     def __init__(self, devices: int, build: Callable, dp: int = 1) -> None:
         self.devices = int(devices)
@@ -52,26 +55,35 @@ class ShardedSteps:
         self.mesh = None
         self.unavailable = False
 
+    def _make(self) -> None:
+        import jax
+
+        from dfs_tpu.parallel.mesh import make_mesh
+
+        if len(jax.devices()) < self.devices:
+            raise RuntimeError(
+                f"{self.devices} devices configured, "
+                f"{len(jax.devices())} visible")
+        # dp=1: one stream, its byte axis tiled over every device
+        # (the rolling halo ring); dp=devices: windows ride the dp
+        # axis, one whole window per device (the anchored walk)
+        mesh = make_mesh(self.devices, dp=self._dp)
+        self._steps = self._build(mesh)
+        self.mesh = mesh
+
     def get(self):
         if self._steps is not None or self.unavailable:
             return self._steps
+        from dfs_tpu.utils.device import cpu_on_purpose, require_tpu
+
+        if not cpu_on_purpose():
+            require_tpu(f"a sharded walk over {self.devices} devices")
+            self._make()
+            return self._steps
         try:
-            import jax
-
-            from dfs_tpu.parallel.mesh import make_mesh
-
-            if len(jax.devices()) < self.devices:
-                raise RuntimeError(
-                    f"{self.devices} devices configured, "
-                    f"{len(jax.devices())} visible")
-            # dp=1: one stream, its byte axis tiled over every device
-            # (the rolling halo ring); dp=devices: windows ride the dp
-            # axis, one whole window per device (the anchored walk)
-            self.mesh = make_mesh(self.devices, dp=self._dp)
-            self._steps = self._build(self.mesh)
-        except Exception as e:  # noqa: BLE001 - degrade, don't fail ingest
+            self._make()
+        except Exception as e:  # noqa: BLE001 - CPU rehearsal: degrade
             self.unavailable = True
-            self.mesh = None
             logging.getLogger("dfs_tpu.fragmenter").warning(
                 "sharded CDC unavailable (%s); running single-device", e)
         return self._steps

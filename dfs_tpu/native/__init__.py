@@ -1,15 +1,21 @@
 """ctypes loader for the native CPU core (cdc_core.cpp).
 
-Compiles on first use with g++ (cached as cdc_core.so next to the source; no
-pybind11 in the image, so the binding is plain ctypes over an extern-C ABI).
-Every entry point degrades gracefully to pure Python/NumPy when the toolchain
-is unavailable — the framework never *requires* the native library.
+Compiles on first use with g++ into ``_build/`` next to the source (no
+pybind11 in the image, so the binding is plain ctypes over an extern-C
+ABI). A built object is reused only if it was built from THIS source
+with THESE flags on THIS CPU: its file name carries a hash of all three
+(``-march=native`` code loaded on another CPU is a SIGILL, and a tree
+copied between machines carries its build directory along). Every entry
+point degrades to pure Python/NumPy when the toolchain is unavailable —
+the framework never *requires* the native library; :func:`engine` says
+which one a process got.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import platform
 import subprocess
 import threading
 from pathlib import Path
@@ -18,20 +24,51 @@ import numpy as np
 
 _DIR = Path(__file__).resolve().parent
 _SRC = _DIR / "cdc_core.cpp"
-_SO = _DIR / "cdc_core.so"
+_BUILD_DIR = _DIR / "_build"
+_LIB_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _compile(args: list[str], tmp: Path, dst: Path) -> bool:
-    # compile to a temp path and rename over the target: rebuilding in
-    # place would truncate an inode this (or another) process may have
-    # dlopen'd/mmapped — SIGBUS territory; rename swaps a fresh inode in
-    # atomically for concurrent loaders too
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolved against: the first CPU's model and
+    feature flags."""
+    keep = []
     try:
-        subprocess.run(args, check=True, capture_output=True, timeout=120)
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if not line.strip():
+                    break                       # first processor only
+                if line.split(":")[0].strip() in (
+                        "vendor_id", "model name", "flags", "Features"):
+                    keep.append(line.strip())
+    except OSError:
+        pass
+    return platform.machine() + "\n" + "\n".join(keep)
+
+
+def _artifact(src: Path, flags: tuple[str, ...], suffix: str) -> Path:
+    """Build-cache path keyed on source bytes, flags and CPU."""
+    from dfs_tpu.utils.hashing import sha256_new
+
+    h = sha256_new()
+    h.update(src.read_bytes())
+    h.update("\0".join(flags).encode())
+    h.update(_cpu_identity().encode())
+    return _BUILD_DIR / f"{src.stem}-{h.hexdigest()[:16]}{suffix}"
+
+
+def _compile(src: Path, flags: tuple[str, ...], dst: Path) -> bool:
+    # compile to a temp path and rename over the target: a half-written
+    # object must never be visible under the keyed name, and rename swaps
+    # a fresh inode in atomically for concurrent builders/loaders
+    tmp = dst.with_name(f"{dst.name}.tmp{os.getpid()}")
+    try:
+        _BUILD_DIR.mkdir(exist_ok=True)
+        subprocess.run(["g++", *flags, str(src), "-o", str(tmp)],
+                       check=True, capture_output=True, timeout=120)
         os.replace(tmp, dst)
         return True
     except (OSError, subprocess.SubprocessError):
@@ -39,11 +76,13 @@ def _compile(args: list[str], tmp: Path, dst: Path) -> bool:
         return False
 
 
-def _build() -> bool:
-    tmp = _SO.with_suffix(f".tmp{os.getpid()}.so")
-    return _compile(
-        ["g++", "-O3", "-march=native", "-shared", "-fPIC",
-         str(_SRC), "-o", str(tmp)], tmp, _SO)
+def _built(src: Path, flags: tuple[str, ...], suffix: str) -> Path | None:
+    """The keyed artifact, building it if absent; None without a
+    toolchain."""
+    dst = _artifact(src, flags, suffix)
+    if dst.is_file() or _compile(src, flags, dst):
+        return dst
+    return None
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -53,33 +92,26 @@ def get_lib() -> ctypes.CDLL | None:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not _SO.exists() or _SO.stat().st_mtime < _SRC.stat().st_mtime:
-            if not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(str(_SO))
-        except OSError:
+        so = _built(_SRC, _LIB_FLAGS, ".so")
+        if so is None:
             return None
         try:
+            lib = ctypes.CDLL(str(so))
             _bind(lib)
-        except AttributeError:
-            # stale prebuilt .so missing newer symbols (e.g. shipped in an
-            # image layer with a fresh mtime): rebuild once, else degrade
-            # to the Python fallbacks rather than crash the first caller
-            if not _build():
-                return None
-            try:
-                lib = ctypes.CDLL(str(_SO))
-                _bind(lib)
-            except (OSError, AttributeError):
-                return None
+        except (OSError, AttributeError):
+            return None
         _lib = lib
         return _lib
 
 
+def engine() -> str:
+    """``"native"`` when the C++ core is loaded, ``"numpy"`` when every
+    entry point is answering from its NumPy fallback."""
+    return "native" if get_lib() is not None else "numpy"
+
+
 def _bind(lib: ctypes.CDLL) -> None:
-    """Declare the extern-C signatures (raises AttributeError on a stale
-    library missing newer symbols — get_lib handles that)."""
+    """Declare the extern-C signatures."""
     lib.dfs_sha256_batch.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p]
     lib.dfs_sha256_batch.restype = None
@@ -213,21 +245,12 @@ def native_gear_cuts(data: bytes | np.ndarray, table: np.ndarray, mask: int,
 
 
 _SIDECAR_SRC = _DIR / "sidecar_client.cpp"
-_SIDECAR_BIN = _DIR / "sidecar_client"
 
 
 def build_sidecar_client() -> Path | None:
-    """Build (once, cached) the dependency-free C++ sidecar conformance
-    client — POSIX sockets + hand-rolled HTTP/2, no gRPC library (see
-    sidecar_client.cpp and docs/sidecar_wire.md). Returns the binary
-    path, or None when the toolchain is unavailable."""
-    if not _SIDECAR_SRC.is_file():
-        return _SIDECAR_BIN if _SIDECAR_BIN.is_file() else None
-    if _SIDECAR_BIN.is_file() \
-            and _SIDECAR_BIN.stat().st_mtime >= _SIDECAR_SRC.stat().st_mtime:
-        return _SIDECAR_BIN
-    tmp = _SIDECAR_BIN.with_suffix(f".tmp{os.getpid()}")
-    if _compile(["g++", "-O2", "-o", str(tmp), str(_SIDECAR_SRC)],
-                tmp, _SIDECAR_BIN):
-        return _SIDECAR_BIN
-    return None
+    """Build (once, cached like the library) the dependency-free C++
+    sidecar conformance client — POSIX sockets + hand-rolled HTTP/2, no
+    gRPC library (see sidecar_client.cpp and docs/sidecar_wire.md).
+    Returns the binary path, or None when the toolchain is
+    unavailable."""
+    return _built(_SIDECAR_SRC, ("-O2",), "")

@@ -549,12 +549,21 @@ class StorageNodeServer:
         # Server.wait_closed() (3.12+) waits for every live handler, so
         # idle inbound connections must be torn down explicitly or stop()
         # deadlocks on a peer that simply hasn't spoken lately.
-        for w in list(self._inbound):
-            w.close()
         for srv in (self._internal_server, self._http_server):
-            if srv is not None:
-                srv.close()
-                await srv.wait_closed()
+            if srv is None:
+                continue
+            srv.close()
+            closed = asyncio.ensure_future(srv.wait_closed())
+            while not closed.done():
+                # sweep AFTER close(), and again until the wait returns:
+                # a connection the listener accepted just before close()
+                # attaches to the server (and lands in _inbound) a loop
+                # turn later — invisible to one sweep taken up front,
+                # and then wait_closed() never returns
+                for w in list(self._inbound):
+                    w.close()
+                await asyncio.wait({closed}, timeout=0.2)
+            await closed
         if self.obs.journal is not None:
             # last: every subsystem above may still emit during teardown;
             # close() drains the bounded queue on the writer thread and
@@ -3720,9 +3729,11 @@ class StorageNodeServer:
     def frag_stats(self) -> dict:
         """Fragmenter execution knobs for /metrics "frag" (DFS005: every
         FragmenterConfig field surfaces here) plus what is ACTUALLY
-        running: the live engine name (the auto fragmenter can flip
-        CPU<->TPU mid-life) and ``degraded`` — True once a sharded walk
-        has fallen back to its single-device kernel (thin environment).
+        running: the engine name ('auto' resolved once, at start;
+        'sidecar:<engine>' when a chip owner does the work) and
+        ``degraded`` — True once a sharded walk has fallen back to its
+        single-device kernel (CPU rehearsal only: where the device was
+        asked for, that is an error instead).
         The sharded fragmenters share the host engine's ``name`` on
         purpose (same strategy, same manifests), so the name alone
         cannot reveal that fallback — this flag is the operator's
@@ -4062,7 +4073,8 @@ class StorageNodeServer:
 
         async def one(peer) -> tuple[int, dict | None]:
             try:
-                inv = await self.client.get_census(peer, retries=1)
+                inv = await self.client.get_census(
+                    peer, retries=1, expect_chunks=len(lengths))
                 return peer.node_id, inv if isinstance(inv, dict) else None
             # not silent: a None inventory IS the partial-result signal
             # (peersFailed + unknown copies in the report)
@@ -4094,7 +4106,8 @@ class StorageNodeServer:
                 return nid, inv.get("listed") or {}
             try:
                 inv = await self.client.get_census(
-                    self.cfg.cluster.peer(nid), prefixes=want, retries=1)
+                    self.cfg.cluster.peer(nid), prefixes=want, retries=1,
+                    expect_chunks=len(lengths))
                 return nid, (inv or {}).get("listed") or {}
             # not silent: an unanswered drill leaves its buckets in the
             # report's uncheckedBuckets count (build_report)

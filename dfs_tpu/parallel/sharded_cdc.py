@@ -33,23 +33,6 @@ from dfs_tpu.ops.gear_jax import HALO, WINDOW
 from dfs_tpu.ops.sha256_jax import _sha256_blocks_impl
 
 
-def _shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across the API move: newer releases export the
-    stable top-level name, older ones only ``jax.experimental``'s; the
-    replication-check flag was renamed check_rep -> check_vma along the
-    way (and some releases have the top-level name but the OLD flag
-    spelling, so the flag is chosen by signature, not by location)."""
-    import inspect
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    flag = "check_vma" \
-        if "check_vma" in inspect.signature(fn).parameters else "check_rep"
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{flag: check_vma})
-
-
 def _rowwise_gear_bitmap(data: jax.Array, prev_g: jax.Array,
                          table: jax.Array, mask: jax.Array) -> jax.Array:
     """data: [B, S] uint8; prev_g: [B, 31] uint32 (halo per row)."""
@@ -88,7 +71,7 @@ def make_sharded_step(mesh: Mesh, table: np.ndarray, mask: int):
             jax.lax.psum(jnp.sum(bitmap.astype(jnp.int32)), "sp"), "dp")
         return bitmap, state, n_cand
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", "sp"), P(("dp", "sp")), P(("dp", "sp"))),
         out_specs=(P("dp", "sp"), P(("dp", "sp")), P()),
@@ -129,7 +112,7 @@ def make_sharded_bitmap_step(mesh: Mesh, table: np.ndarray, mask: int):
         prev_g = jnp.where(jax.lax.axis_index("sp") == 0, head, prev_g)
         return _rowwise_gear_bitmap(data, prev_g, table_j, mask_j)
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", "sp"), P("dp", None)),
         out_specs=P("dp", "sp"),
@@ -193,7 +176,7 @@ def make_aligned_step(mesh: Mesh, params):
             jax.lax.psum(jnp.sum(cf32), "sp"), "dp")
         return cf32, states, n
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(("dp", "sp")), P(("dp", "sp"))),
         out_specs=(P(None, ("dp", "sp")), P(None, ("dp", "sp")), P()),
@@ -242,7 +225,7 @@ def make_anchored_anchor_step(mesh: Mesh, params, m_local: int):
                                   dev * jnp.int32(m_local * 4),
                                   0))[None, :, :]
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(("dp", "sp"), None),),
         out_specs=P(("dp", "sp"), None, None),
@@ -307,7 +290,7 @@ def make_anchored_step(mesh: Mesh, params):
         n = jax.lax.psum(jax.lax.psum(jnp.sum(cf32), "sp"), "dp")
         return cf32, since, states, n
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), P(("dp", "sp")), P(("dp", "sp")), P(("dp", "sp")),),
         out_specs=(P(None, ("dp", "sp")), P(None, ("dp", "sp")),
@@ -370,7 +353,7 @@ def make_anchored_window_anchor_step(mesh: Mesh, params, m_words: int):
     def local_step(words):
         return local_fn(words[0])[None]
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None),),
         out_specs=P("dp", None, None),
@@ -427,7 +410,7 @@ def make_anchored_window_step(mesh: Mesh, params, total_words: int,
         return (count[None], q[None], offs[None], lens[None], dig[None])
 
     row = P("dp", None)
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(row, row, row, row, row, row, row),
         out_specs=(P("dp"), row, row, row, P("dp", None, None)),
@@ -709,7 +692,7 @@ def make_sketch_step(mesh: Mesh, lanes_a: np.ndarray, lanes_b: np.ndarray,
     def local_step(blocks, lns):
         return jax.vmap(one)(blocks, lns)
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("dp", None), P("dp")),
         out_specs=P("dp", None),
@@ -745,7 +728,7 @@ def make_ec_step(mesh: Mesh, k: int):
             "sp"), "dp")
         return p, q, nbytes
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(("dp", "sp")),),
         out_specs=(P(("dp", "sp")), P(("dp", "sp")), P()),

@@ -20,11 +20,15 @@ nothing, buy nothing). Methods (all under service ``dfs.Sidecar``):
   whole stream.
 - ``ChunkHash``  unary-unary compatibility path (whole payload in one
   message, 1 GiB gRPC message cap applies).
-- ``Health``     unary-unary. Request: empty. Response: JSON status.
+- ``Health``     unary-unary. Request: empty. Response: JSON status,
+  including ``device`` — what the engine computes on, as JAX reports it,
+  and how many regions it has dispatched there (null for host engines).
 
-The sidecar accepts a ``fragmenter`` name at startup — default ``auto``
-(the anchored flagship: TPU device path when a TPU is present, CPU oracle
-otherwise, fragmenter/base.py). ``SidecarFragmenter`` is the node-side
+The sidecar is the deployment's CHIP OWNER (utils/device.py): a chip
+belongs to one process, so N nodes on a host share it through this one.
+It accepts a ``fragmenter`` name at startup — default ``auto`` (the
+anchored flagship: TPU device engine iff the machine has a TPU platform,
+decided once, fragmenter/base.py). ``SidecarFragmenter`` is the node-side
 adapter: a drop-in Fragmenter that delegates chunk+hash to a sidecar
 process (NodeConfig.sidecar_port wires it into the node runtime).
 """
@@ -121,7 +125,9 @@ class SidecarServer:
             return json.dumps({"ok": True,
                                "fragmenter": self.fragmenter.name,
                                "window": span or 0,
-                               "describe": desc}).encode()
+                               "describe": desc,
+                               "device": self.fragmenter.device_stats(),
+                               }).encode()
 
         methods = {
             f"/{_SERVICE}/ChunkHash": grpc.unary_unary_rpc_method_handler(
@@ -154,10 +160,10 @@ class SidecarServer:
 
 
 class SidecarClient:
-    """Deadlines are mandatory: the sidecar's fragmenter can wedge in
-    device init (the stale-tunnel JAX hang tpu_available() guards
-    against), and an un-deadlined blocking call from the node would freeze
-    its entire event loop."""
+    """Deadlines are mandatory: an un-deadlined blocking call from the
+    node would freeze its entire event loop if the sidecar wedged (a cold
+    compile inside a stream runs against ``timeout_s``; warm the owner
+    before serving, as chip_smoke.py does)."""
 
     def __init__(self, port: int, host: str = "127.0.0.1",
                  timeout_s: float = 600.0,

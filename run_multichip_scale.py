@@ -1,12 +1,13 @@
 """Produce MULTICHIP_SCALE_r{N}.json: the sharded anchored step at
 PRODUCTION geometry (full 64 MiB region, default params,
-lane_multiple=128) over an 8-device virtual CPU mesh, oracle-checked
-end to end (VERDICT r4 #4 — the toy-shape dryrun leaves lane
-provisioning and halo correctness at real tile counts unverified).
+lane_multiple=128) over an n-device mesh, oracle-checked end to end (the
+toy-shape dryrun leaves lane provisioning and halo correctness at real
+tile counts unverified).
 
 Usage: python run_multichip_scale.py [out.json] [n_devices]
-Must run in a fresh process (forces the virtual-CPU platform before
-any JAX backend initializes, same as __graft_entry__.dryrun_multichip).
+The mesh is the machine's real devices; ``JAX_PLATFORMS=cpu`` asks for a
+virtual CPU mesh by name (fresh process — the split must precede backend
+init, same as __graft_entry__.dryrun_multichip).
 """
 
 from __future__ import annotations
@@ -19,8 +20,11 @@ def main() -> int:
     out = sys.argv[1] if len(sys.argv) > 1 else "MULTICHIP_SCALE_r05.json"
     n_devices = int(sys.argv[2]) if len(sys.argv) > 2 else 8
 
-    from __graft_entry__ import _force_virtual_cpu_devices
-    _force_virtual_cpu_devices(n_devices)
+    from __graft_entry__ import ensure_devices
+    from dfs_tpu.utils.device import device_info, enable_compile_cache
+
+    ensure_devices(n_devices)
+    enable_compile_cache()
 
     from dfs_tpu.parallel.mesh import make_mesh
     from dfs_tpu.parallel.sharded_cdc import (
@@ -28,9 +32,10 @@ def main() -> int:
 
     rec = anchored_sharded_production_check(make_mesh(n_devices), n_devices)
     rec["ok"] = True
-    rec["scope"] = ("virtual CPU mesh (xla_force-style device split): "
-                    "oracle parity at production shapes is the claim; "
-                    "wall times are host-bound, not ICI-bound")
+    rec["device"] = device_info()
+    rec["scope"] = ("oracle parity at production shapes is the claim; on "
+                    "a virtual CPU mesh (device.platform == 'cpu') wall "
+                    "times are host-bound and say nothing about ICI")
     with open(out, "w") as f:
         json.dump(rec, f, indent=1)
     print(json.dumps(rec))

@@ -267,23 +267,29 @@ def test_factory_anchored_kinds():
 
 
 def test_factory_auto_resolves_by_device(monkeypatch):
-    """'auto' (the serve default) must pick the anchored TPU pipeline on
-    TPU hosts and the anchored CPU oracle elsewhere."""
+    """'auto' (the serve default) resolves ONCE, at construction, to the
+    engine itself: the anchored TPU pipeline iff the machine has a TPU
+    platform, the anchored CPU engine elsewhere — no wrapper left to
+    flip engines under a running node."""
     import dfs_tpu.fragmenter.base as base
+    import dfs_tpu.utils.device as device
+    from dfs_tpu.fragmenter.cdc_anchored import (AnchoredCpuFragmenter,
+                                                 AnchoredTpuFragmenter)
 
-    monkeypatch.setattr(base, "tpu_available", lambda: True)
-    assert base.get_fragmenter("auto").name == "cdc-anchored-tpu"
-    monkeypatch.setattr(base, "tpu_available", lambda: False)
-    assert base.get_fragmenter("auto").name == "cdc-anchored"
+    monkeypatch.setattr(device, "wants_tpu", lambda: True)
+    assert type(base.get_fragmenter("auto")) is AnchoredTpuFragmenter
+    monkeypatch.setattr(device, "wants_tpu", lambda: False)
+    assert type(base.get_fragmenter("auto")) is AnchoredCpuFragmenter
 
 
 def test_factory_auto_honors_chunk_params(monkeypatch):
     """Operator chunk sizing flows through auto into the nested grid
     (ADVICE round 1: the anchored branch silently dropped CDCParams)."""
     import dfs_tpu.fragmenter.base as base
+    import dfs_tpu.utils.device as device
     from dfs_tpu.config import CDCParams
 
-    monkeypatch.setattr(base, "tpu_available", lambda: False)
+    monkeypatch.setattr(device, "wants_tpu", lambda: False)
     f = base.get_fragmenter(
         "auto", cdc_params=CDCParams(min_size=1024, avg_size=4096,
                                      max_size=32768))
@@ -353,68 +359,6 @@ def test_region_buffer_size_is_dma_tiled():
     p = AnchoredCdcParams()
     for n in (1, 4096, 64 * 2**20, 64 * 2**20 - 5):
         assert region_buffer_size(n, p) % 4096 == 0
-
-
-def test_factory_auto_reprobes_and_flips_both_ways(monkeypatch):
-    """'auto' must not pin the boot-time engine forever: the shared
-    harness link swings ~1.5 GB/s <-> ~10 MB/s hour to hour (round-3
-    finding), so the wrapper re-probes and flips engines in BOTH
-    directions, logging the flip."""
-    import logging
-
-    import dfs_tpu.fragmenter.base as base
-
-    link_ok = {"v": False}
-    monkeypatch.setattr(base, "tpu_available", lambda: link_ok["v"])
-    f = base.get_fragmenter("auto")
-    assert f.name == "cdc-anchored"
-
-    # link comes good -> flip up
-    link_ok["v"] = True
-    with_caplog = []
-    handler = logging.Handler()
-    handler.emit = lambda rec: with_caplog.append(rec.getMessage())
-    logging.getLogger("dfs_tpu.fragmenter").addHandler(handler)
-    try:
-        f.reprobe_now()
-        assert f.name == "cdc-anchored-tpu"
-        # link collapses -> flip back down
-        link_ok["v"] = False
-        f.reprobe_now()
-        assert f.name == "cdc-anchored"
-        assert sum("auto engine flip" in m for m in with_caplog) == 2
-    finally:
-        logging.getLogger("dfs_tpu.fragmenter").removeHandler(handler)
-    # chunking still works across flips (same params, same boundaries)
-    data = b"x" * 300_000
-    assert [c.digest for c in f.chunk(data)] \
-        == [c.digest for c in base.get_fragmenter("cdc-anchored").chunk(data)]
-
-
-def test_factory_auto_background_reprobe_is_throttled(monkeypatch):
-    """Data-plane calls trigger at most one background probe per
-    interval; an elapsed interval flips the engine without blocking the
-    caller."""
-    import time
-
-    import dfs_tpu.fragmenter.base as base
-
-    calls = {"n": 0}
-
-    def probe():
-        calls["n"] += 1
-        return calls["n"] > 1       # first probe: CPU; later: TPU
-
-    f = base.AutoAnchoredFragmenter(
-        base._anchored_params(None), probe=probe, reprobe_s=0.0)
-    assert f.name == "cdc-anchored" and calls["n"] == 1
-    f.chunk(b"y" * 200_000)          # kicks a background re-probe
-    for _ in range(100):
-        if f.name == "cdc-anchored-tpu":
-            break
-        time.sleep(0.05)
-    assert f.name == "cdc-anchored-tpu"
-    assert calls["n"] == 2
 
 
 def test_tight_segment_lane_overflow_redispatches(monkeypatch):

@@ -611,6 +611,27 @@ def test_cache_temperature_reaches_metrics_and_census(tmp_path, rng):
     asyncio.run(run())
 
 
+def test_census_rpc_budget_scales_with_the_walk():
+    """A peer's inventory is a readdir+stat pass over everything it
+    holds: the per-attempt budget grows with the chunks it may have to
+    walk (a flat 10 s read a healthy 2 GiB cluster as "2 peer(s)
+    unreachable" on the first chip run, PERF.md PR 21)."""
+    from dfs_tpu.comm.rpc import InternalClient
+
+    client = InternalClient(2.0, 10.0, 3)
+    seen = {}
+
+    async def call(peer, header, body=b"", retries=None, timeout_s=None):
+        seen["timeout_s"] = timeout_s
+        return {"census": {}}, memoryview(b"")
+
+    client.call = call
+    asyncio.run(client.get_census(None, retries=1))
+    assert seen["timeout_s"] == 10.0
+    asyncio.run(client.get_census(None, retries=1, expect_chunks=65000))
+    assert seen["timeout_s"] == 10.0 + 65.0
+
+
 def test_serve_cli_exposes_census_flags():
     """DFS005 satellite: every CensusConfig field is CLI-reachable and
     the census/df subcommands parse."""

@@ -27,6 +27,33 @@ def test_health(sidecar):
     h = sidecar.health()
     assert h["ok"] and h["fragmenter"] == "cdc" and h["window"] == 0
     assert h["describe"]["kind"] == "cdc"
+    assert h["device"] is None          # host engine: no backend to name
+
+
+def test_health_names_the_device_and_counts_regions(rng):
+    """The chip owner's Health says what the engine computes on, as JAX
+    reports it, and how many regions went there since start — what
+    chip_smoke.py cannot see from outside."""
+    from dfs_tpu.fragmenter.cdc_anchored import AnchoredCpuFragmenter
+
+    srv = SidecarServer(port=0, fragmenter="cdc-anchored-tpu")
+    srv.start()
+    client = SidecarClient(srv.port)
+    try:
+        dev = client.health()["device"]
+        assert dev == {"platform": "cpu", "device_kind": "cpu",
+                       "count": dev["count"], "regions": 0,
+                       "overflow_redos": 0}
+        # above the 2 MiB host cut-off, so the chain really dispatches
+        data = rng.integers(0, 256, size=3 * 2**20 + 17,
+                            dtype=np.uint8).tobytes()
+        resp = client.chunk_hash_stream([data])
+        assert [c["digest"] for c in resp["chunks"]] \
+            == [c.digest for c in AnchoredCpuFragmenter().chunk(data)]
+        assert client.health()["device"]["regions"] == 1
+    finally:
+        client.close()
+        srv.stop()
 
 
 def test_chunk_hash_matches_inprocess(sidecar, rng):

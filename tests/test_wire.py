@@ -145,6 +145,10 @@ def test_frame_connection_rejects_malformed_reply():
             await read_msg(reader)
             writer.write(crafted)
             await writer.drain()
+            # explicit: a handler that returns with its writer open keeps
+            # the connection attached until the writer happens to be
+            # collected, and Server.wait_closed() (3.12+) waits for it
+            writer.close()
 
         srv = await asyncio.start_server(bad_server, "127.0.0.1", 0)
         port = srv.sockets[0].getsockname()[1]
@@ -226,6 +230,7 @@ def test_vectored_body_is_wire_identical_to_joined():
 
         async def sink(reader, writer):
             got.append(await reader.read())
+            writer.close()      # see bad_server: wait_closed() needs it
             done.set()
 
         srv = await asyncio.start_server(sink, "127.0.0.1", 0)

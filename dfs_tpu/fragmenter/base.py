@@ -24,6 +24,10 @@ class Fragmenter(abc.ABC):
     """Splits a byte stream into content-addressed chunks."""
 
     name: str = "abstract"
+    # the Observability of the process that serves this engine, set by a
+    # chip owner (sidecar/service.py); an engine with a window walk
+    # opens its per-window spans through it
+    obs = None
 
     @abc.abstractmethod
     def chunk(self, data: bytes) -> list[ChunkRef]:
@@ -83,9 +87,10 @@ class Fragmenter(abc.ABC):
 
     def device_stats(self) -> dict | None:
         """What this engine computes on, for the chip owner's Health
-        answer: ``{platform, device_kind, count, regions,
-        overflow_redos}`` as JAX reports it, or None for an engine that
-        runs on the host (asking must not initialise a backend there)."""
+        answer: ``{platform, device_kind, count}`` as JAX reports it,
+        ``regions`` / ``overflow_redos`` and the streams' phase clock
+        (docs/sidecar_wire.md), or None for an engine that runs on the
+        host (asking must not initialise a backend there)."""
         return None
 
     def stream_span(self) -> int | None:

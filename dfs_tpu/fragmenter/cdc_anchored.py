@@ -28,7 +28,9 @@ AnchoredTpuFragmenter.__init__).
 
 from __future__ import annotations
 
+import contextlib
 import threading
+import time
 
 import numpy as np
 
@@ -258,6 +260,81 @@ class AnchoredCpuFragmenter(_AnchoredBase):
         return self._manifest_via_chunks_stream(blocks, name, store)
 
 
+# What a streamed walk can be doing, as exclusive phases of its wall
+# time: blocked taking the next block from its caller, staging and
+# dispatching a window (or, under the CPU cutoff, chunking on the
+# host), collecting one, suspended at ``yield`` while the caller takes
+# the batch.
+_PHASES = ("inputWaitS", "dispatchS", "collectS", "replyS")
+
+
+class _StreamPhases:
+    """Where the wall time of an engine's streamed walks went — the
+    chip owner's answer to "why was the device idle while a stream was
+    open" (``Health.device``). Concurrent streams share one engine,
+    hence the lock."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._s = dict.fromkeys(
+            (*_PHASES, "deviceWaitS", "streamS", "openS"), 0.0)
+        self._streams = self._bytes = self._open = 0
+        self._open_since = 0.0
+
+    def add(self, key: str, seconds: float) -> None:
+        with self._lock:
+            self._s[key] += seconds
+            if key in _PHASES:
+                self._s["streamS"] += seconds
+
+    def opened(self, now: float) -> None:
+        with self._lock:
+            self._streams += 1
+            if not self._open:
+                self._open_since = now
+            self._open += 1
+
+    def closed(self, now: float, nbytes: int) -> None:
+        with self._lock:
+            self._bytes += nbytes
+            self._open -= 1
+            if not self._open:
+                self._s["openS"] += now - self._open_since
+
+    def snapshot(self) -> dict:
+        """Counters only: ``streamS`` sums the streams' wall time (the
+        four phases sum to it), ``openS`` is the wall time with at
+        least one stream open, ``deviceWaitS`` the part of ``collectS``
+        blocked on the device's result."""
+        with self._lock:
+            s = dict(self._s)
+            if self._open:      # openS runs on while a stream is open
+                s["openS"] += time.monotonic() - self._open_since
+            return {**{k: round(v, 6) for k, v in s.items()},
+                    "streams": self._streams, "bytes": self._bytes}
+
+
+class _StreamClock:
+    """One stream's phase clock: every instant between construction and
+    ``close`` is added to exactly one phase at the next switch, so the
+    four phases sum to ``streamS`` by construction."""
+
+    def __init__(self, phases: _StreamPhases) -> None:
+        self._phases = phases
+        self._phase = "inputWaitS"
+        self._t = time.monotonic()
+        phases.opened(self._t)
+
+    def to(self, phase: str) -> None:
+        now = time.monotonic()
+        self._phases.add(self._phase, now - self._t)
+        self._phase, self._t = phase, now
+
+    def close(self, nbytes: int) -> None:
+        self.to(self._phase)
+        self._phases.closed(self._t, nbytes)
+
+
 class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
     """Device pipeline, region-batched; output is batching-independent."""
 
@@ -304,6 +381,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         self._stats_lock = threading.Lock()
         self.regions_dispatched = 0
         self.overflow_redos = 0
+        self._phases = _StreamPhases()
         # warm the _touch jit once at construction (trace + a trivial
         # 1-element compile): the readiness probe's one-time cost must
         # never be billed to the first staging-bandwidth sample
@@ -476,7 +554,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
 
         return {**device_info(),
                 "regions": self.regions_dispatched,
-                "overflow_redos": self.overflow_redos}
+                "overflow_redos": self.overflow_redos,
+                **self._phases.snapshot()}
 
     def chunks_stream(self, blocks, store=None):
         """Bounded-memory PIPELINED streaming: same fixed-stride window
@@ -488,6 +567,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         ~(max_inflight + 1) windows regardless of stream length. Yields
         each collected window's ChunkRefs as a batch (the sidecar's
         incremental stream-stream surface)."""
+        import jax
+
         chunks: list[ChunkRef] = []
         buf = bytearray()
         buf_base = 0                   # absolute offset of buf[0]
@@ -497,6 +578,9 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         base = 0
         done = False
         self._since_measure = _REMEASURE_EVERY  # see _walk
+        span = self.obs.span if self.obs is not None \
+            else lambda name: contextlib.nullcontext()
+        clock = _StreamClock(self._phases)
 
         def fetch(off: int, ln: int) -> np.ndarray:
             if off < buf_base:
@@ -513,6 +597,24 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 del buf[:keep_from - buf_base]
                 buf_base = keep_from
 
+        def collect():
+            """Collect the oldest window; yields its batch, with the
+            time suspended there on the clock as the reply."""
+            clock.to("collectS")
+            n0 = len(chunks)
+            win = pending.pop(0)
+            with span("owner.collect"):
+                t0 = time.monotonic()
+                jax.block_until_ready(win[3])   # the device's part of it
+                self._phases.add("deviceWaitS", time.monotonic() - t0)
+                bound = self._collect_window(*win, fetch, chunks, store)
+            trim()
+            if len(chunks) > n0:
+                clock.to("replyS")
+                yield chunks[n0:]
+            clock.to("dispatchS")
+            return bound
+
         def advance(n_known: int, final_ok: bool):
             """Dispatch every window whose bytes are fully buffered;
             yields a batch per collected window."""
@@ -523,13 +625,10 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 if not (full or final):
                     return
                 if len(pending) >= self.max_inflight:
-                    n0 = len(chunks)
-                    self._collect_window(*pending.pop(0), fetch, chunks,
-                                         store)
-                    if len(chunks) > n0:
-                        yield chunks[n0:]
-                win = self._dispatch_window(fetch, base, n_known, start0,
-                                            final)
+                    yield from collect()
+                with span("owner.dispatch"):
+                    win = self._dispatch_window(fetch, base, n_known,
+                                                start0, final)
                 pending.append(win)
                 trim()
                 if final:
@@ -538,31 +637,36 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 start0 = win[3][0] - self.stride
                 base += self.stride
 
-        for blk in blocks:
-            buf += blk
-            total += len(blk)
-            yield from advance(total, final_ok=False)
-        if total == 0:
-            return
-        if total <= self.cpu_cutoff and not pending and base == 0:
-            # small streams take chunk()'s oracle fast path (identical
-            # output either way; this skips device dispatch entirely)
-            cl = self._walk(np.frombuffer(buf, np.uint8), store=store)
-            if cl:
-                yield cl
-            return
-        yield from advance(total, final_ok=True)
-        bound = 0
-        while pending:
-            n0 = len(chunks)
-            bound = self._collect_window(*pending.pop(0), fetch, chunks,
-                                         store)
-            trim()
-            if len(chunks) > n0:
-                yield chunks[n0:]
-        if bound != total:
-            raise AssertionError(
-                f"anchored stream ended at {bound} != {total}")
+        try:
+            blocks = iter(blocks)
+            while True:
+                clock.to("inputWaitS")
+                blk = next(blocks, None)
+                clock.to("dispatchS")
+                if blk is None:
+                    break
+                buf += blk
+                total += len(blk)
+                yield from advance(total, final_ok=False)
+            if total == 0:
+                return
+            if total <= self.cpu_cutoff and not pending and base == 0:
+                # small streams take chunk()'s oracle fast path (identical
+                # output either way; this skips device dispatch entirely)
+                cl = self._walk(np.frombuffer(buf, np.uint8), store=store)
+                if cl:
+                    clock.to("replyS")
+                    yield cl
+                return
+            yield from advance(total, final_ok=True)
+            bound = 0
+            while pending:
+                bound = yield from collect()
+            if bound != total:
+                raise AssertionError(
+                    f"anchored stream ended at {bound} != {total}")
+        finally:
+            clock.close(total)
 
     def manifest_stream(self, blocks, name: str, store=None) -> Manifest:
         return self._manifest_via_chunks_stream(blocks, name, store)

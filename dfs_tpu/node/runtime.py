@@ -28,6 +28,7 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import errno
+import functools
 import math
 import threading
 import time
@@ -56,6 +57,19 @@ from dfs_tpu.utils.hashing import (is_hex_digest, sha256_hex,
 from dfs_tpu.utils.aio import create_logged_task, gather_abort_siblings
 from dfs_tpu.utils.logging import Counters, Stopwatches, get_logger
 from dfs_tpu.utils.trace import LatencyRecorder
+
+
+def _spanned(name: str):
+    """Run an async method of the node inside ``self.obs.span(name)``:
+    one span per call under the caller's trace (a no-op when the caller
+    is untraced), whichever upload path makes the call."""
+    def deco(fn):
+        @functools.wraps(fn)
+        async def wrapper(self, *args, **kwargs):
+            with self.obs.span(name):
+                return await fn(self, *args, **kwargs)
+        return wrapper
+    return deco
 
 
 class UploadError(RuntimeError):
@@ -1243,7 +1257,7 @@ class StorageNodeServer:
         if op == "get_trace":
             # span query for cross-node stitching (trace_spans below):
             # cheap metadata (bounded ring scan), ungated like health
-            return {"ok": True, "spans": self.obs.spans_for(
+            return {"ok": True, "spans": await self._own_spans(
                 str(header.get("traceId", "")))}, b""
         if op == "get_doctor":
             # per-node diagnosis snapshot for the cluster doctor fan-out
@@ -1470,8 +1484,13 @@ class StorageNodeServer:
 
         def run_fragmenter():
             try:
-                m = self.fragmenter.manifest_stream(
-                    feed_iter(), name=name or "stream", store=on_chunk)
+                # to_thread copied the request's context: the span (the
+                # owner seam as this node sees it, same name as the
+                # whole-payload path's) parents to the request, and a
+                # chip owner's spans hang under it
+                with self.obs.span("upload.fragment", latency=True):
+                    m = self.fragmenter.manifest_stream(
+                        feed_iter(), name=name or "stream", store=on_chunk)
                 loop.call_soon_threadsafe(outq.put_nowait, ("done", m))
             # not silent: surfaced to the async consumer via the
             # ("error", e) queue item, which re-raises on the loop
@@ -1495,16 +1514,32 @@ class StorageNodeServer:
 
         async def feeder() -> int:
             total = 0
-            try:
-                async for b in blocks:
-                    if aborted.is_set():
-                        break        # placement failed: stop reading, do
-                        # NOT drain the rest of the body into memory
-                    total += len(b)
-                    hasher.update(b)
-                    await asyncio.to_thread(put_block, b)
-            finally:
-                await asyncio.to_thread(put_block, None)
+            # the body's two waits, told apart: for the next block from
+            # the socket (the client, or TCP backpressure) and for
+            # put_block (the fragmenter side is not draining inq)
+            body_wait = feed_wait = 0.0
+            with self.obs.span("upload.body") as sp:
+                try:
+                    t = time.perf_counter()
+                    async for b in blocks:
+                        body_wait += time.perf_counter() - t
+                        if aborted.is_set():
+                            break    # placement failed: stop reading, do
+                            # NOT drain the rest of the body into memory
+                        total += len(b)
+                        hasher.update(b)
+                        t = time.perf_counter()
+                        await asyncio.to_thread(put_block, b)
+                        now = time.perf_counter()
+                        feed_wait += now - t
+                        t = now
+                    else:       # the wait that found the body's end
+                        body_wait += time.perf_counter() - t
+                finally:
+                    await asyncio.to_thread(put_block, None)
+                    sp.bytes = total
+                    self.ingest_stalls.add("bodyWaitS", body_wait)
+                    self.ingest_stalls.add("feedWaitS", feed_wait)
             return total
 
         feed_task = asyncio.create_task(feeder())
@@ -2020,6 +2055,7 @@ class StorageNodeServer:
         raise UploadError("Insufficient storage: local CAS put failed "
                           "(ENOSPC)", status=507) from e
 
+    @_spanned("upload.place")
     async def _place_batch(self, file_id: str,
                            batch: list[tuple[str, bytes]],
                            stats: dict, rf: int | None = None,
@@ -2614,6 +2650,7 @@ class StorageNodeServer:
         await self._place_batch(file_id, items, stats, rf=rf,
                                 placement=placement)
 
+    @_spanned("upload.commit")
     async def _finalize_upload(self, manifest: Manifest) -> None:
         # Manifest-last ordering (SURVEY.md §5.4), then best-effort announce
         # (reference: announce failure only logged, StorageNode.java:338-346).
@@ -3746,17 +3783,35 @@ class StorageNodeServer:
                 "degraded": bool(getattr(self.fragmenter,
                                          "_unavailable", False))}
 
+    async def _own_spans(self, trace_id: str) -> list[dict]:
+        """This node's spans of one trace, and its chip owner's when it
+        delegates to one (the owner's ``Trace`` method): the owner is a
+        node of the trace like any other, reached through the node it
+        serves. An owner that does not answer leaves the node's own
+        spans (``owner.*`` then simply miss from the tree)."""
+        spans = self.obs.spans_for(trace_id)
+        if self.cfg.sidecar_port:
+            import grpc
+
+            try:
+                spans = spans + await asyncio.to_thread(
+                    self.fragmenter.client.trace, traceId=trace_id)
+            except grpc.RpcError as e:
+                self.log.warning("owner did not answer Trace: %s", e)
+                self.counters.inc("owner_trace_failures")
+        return spans
+
     async def trace_spans(self, trace_id: str,
                           cluster: bool = True) -> dict:
-        """Spans of one trace — local ring, plus (``cluster=True``) every
-        peer's ring via the ``get_trace`` op, merged for the stitcher
-        (GET /trace, CLI ``trace <id>``). Unreachable peers degrade the
-        result to a partial trace (reported in ``peersFailed``), never
-        an error: a stitch query must work exactly when something is
-        wrong."""
+        """Spans of one trace — local ring and this node's chip owner,
+        plus (``cluster=True``) every peer's via the ``get_trace`` op,
+        merged for the stitcher (GET /trace, CLI ``trace <id>``).
+        Unreachable peers degrade the result to a partial trace
+        (reported in ``peersFailed``), never an error: a stitch query
+        must work exactly when something is wrong."""
         from dfs_tpu.obs.stitch import merge_spans
 
-        lists: list[list[dict]] = [self.obs.spans_for(trace_id)]
+        lists: list[list[dict]] = [await self._own_spans(trace_id)]
         failed = 0
         peers = self._peers() if cluster else []
 

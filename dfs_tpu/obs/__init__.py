@@ -19,11 +19,20 @@ Three pieces:
   JSON wire header (comm/rpc.py) — old peers ignore the field, new peers
   tolerate its absence (backward compatible by construction).
 - **Span collection** — :meth:`Observability.span` records finished
-  spans (name, ids, wall start, duration, peer, bytes, error) into a
-  bounded ring (``ObsConfig.trace_ring`` entries; 0 disables tracing
-  entirely and the context var is never even read). Served at
+  spans (name, ids, wall start, ``m0`` = CLOCK_MONOTONIC ns at open,
+  duration, peer, bytes, error) into a bounded ring
+  (``ObsConfig.trace_ring`` entries; 0 disables tracing entirely and
+  the context var is never even read). Served at
   ``GET /trace?traceId=…`` and stitched cluster-wide by
   :mod:`dfs_tpu.obs.stitch` + the ``trace <id>`` CLI subcommand.
+  ``m0`` is one clock for every process of a host, the clock a device
+  profile's session start is stamped with (benchmarks/owner.py), so a
+  span can be laid beside a device op.
+- **Span totals** — every finished span also lands in a bounded
+  per-name table (count, seconds, self seconds), served as
+  ``/metrics`` ``obs.spans``: where a layer's time goes, summed where
+  the work happens. Self time is the span's duration minus the union
+  of its direct children's intervals in the same process.
 - **Unified metrics** — :class:`RpcStats` (per-peer per-op RPC
   count/latency/bytes/errors/retries, client and server side) and the
   Prometheus text exposition (:mod:`dfs_tpu.obs.prom`) flattening every
@@ -44,7 +53,6 @@ import threading
 import time
 from collections import deque
 
-from dfs_tpu.utils import trace as _trace_mod
 from dfs_tpu.utils.logging import capped_key
 from dfs_tpu.utils.trace import LatencyRecorder
 
@@ -268,9 +276,9 @@ class RpcStats:
 
 
 def _span_dict(r: tuple) -> dict:
-    tid, sid, parent, name, node, t_wall, dur, peer, nbytes, err = r
+    tid, sid, parent, name, node, t_wall, dur, peer, nbytes, err, m0 = r
     d = {"t": tid, "s": sid, "p": parent, "name": name, "node": node,
-         "t0": round(t_wall, 6), "d": round(dur, 6)}
+         "t0": round(t_wall, 6), "d": round(dur, 6), "m0": m0}
     if peer is not None:
         d["peer"] = peer
     if nbytes:
@@ -278,6 +286,52 @@ def _span_dict(r: tuple) -> dict:
     if err:
         d["err"] = err
     return d
+
+
+class _Open:
+    """What one OPEN span knows of its direct children in this process,
+    for its self time: each child registers ``[start, end]`` (clock ns,
+    ``end`` None while it runs) when it opens. ``covered`` is the part
+    of the children's union that is already final — below ``floor`` —
+    so a span with thousands of short children (a per-chunk read under
+    a streamed download) holds its open children and a number, not
+    every interval it ever had. Every access is under the owning
+    Observability's lock."""
+
+    __slots__ = ("floor", "covered", "kids", "fold_at")
+    _FOLD_AT = 256
+
+    def __init__(self, start: int) -> None:
+        self.floor = start      # no interval counts below this
+        self.covered = 0        # ns of [span start, floor) under a child
+        self.kids: list[list] = []
+        self.fold_at = self._FOLD_AT
+
+    def add(self, kid: list) -> None:
+        if len(self.kids) >= self.fold_at:
+            self.fold(kid[0], final=False)
+            self.fold_at = max(self._FOLD_AT, 2 * len(self.kids))
+        self.kids.append(kid)
+
+    def fold(self, now: int, final: bool) -> None:
+        """Union the children's intervals into ``covered`` up to the
+        earliest start of a child still open (``now`` when none is, or
+        when the span itself closes: a child that outlives its parent
+        is clipped to the parent's end). A later child starts at
+        ``now`` or after and the open ones reach at least ``now``, so
+        nothing below that cut can change, and the closed intervals
+        above it lie inside an open child's."""
+        live = [] if final else [k for k in self.kids if k[1] is None]
+        cut = min([k[0] for k in live], default=now)
+        hi = self.floor
+        for s, e in sorted((k[0], now if k[1] is None else k[1])
+                           for k in self.kids):
+            s, e = max(s, hi), min(e, cut)
+            if e > s:
+                self.covered += e - s
+                hi = e
+        self.floor = cut
+        self.kids = live
 
 
 class Observability:
@@ -293,15 +347,28 @@ class Observability:
     # traces the tail store tracks at once; oldest forgotten first (its
     # already-pinned spans stay until the span-count bound evicts them)
     _MAX_INTERESTING = 128
+    # distinct span names the totals table holds; further names fold
+    # into "_overflow" (span names are code literals plus allowlisted
+    # routes and wire ops, so the cap is a guard, not a budget)
+    _MAX_SPAN_NAMES = 256
 
     def __init__(self, cfg, node_id: int,
                  latency: LatencyRecorder | None = None,
-                 journal=None) -> None:
+                 journal=None, clock_ns=time.monotonic_ns) -> None:
         self.cfg = cfg
         self.node_id = node_id
         self.latency = latency if latency is not None else LatencyRecorder()
         self._ring: deque | None = deque(maxlen=cfg.trace_ring) \
             if cfg.trace_ring > 0 else None
+        # the one clock of every span: CLOCK_MONOTONIC in ns, shared by
+        # every process of the host (tests inject their own)
+        self._now = clock_ns
+        # span id -> _Open for every span open in this process, and the
+        # per-name totals [count, seconds, self seconds]; both live and
+        # die with the ring (never touched when tracing is off)
+        self._open: dict[str, _Open] = {}
+        self._totals: dict[str, list] = {}
+        self._overflow_warned = False
         # tail retention (Dapper's tail-sampling lesson): spans of
         # slow/errored traces are COPIED here and survive main-ring
         # eviction — bounded by span count, FIFO. None = feature off.
@@ -348,26 +415,17 @@ class Observability:
 
     # ---- span recording ---------------------------------------------- #
 
-    @staticmethod
-    def _annotate(name):
-        """When a jax.profiler device trace is being captured
-        (utils.trace.device_trace set the flag), annotate it like the
-        pre-r09 utils.trace.span did — device timelines keep lining up
-        with framework phases. Returns the entered annotation or None."""
-        if not _trace_mod._PROFILING:
-            return None
-        import jax.profiler  # device_trace already imported it
-
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-        return ann
-
     def _traced(self, name, tid, sid, parent, peer, latency_name):
         tok = _ctx.set((tid, sid))
-        ann = self._annotate(name)
         sp = Span()
         t_wall = time.time()
-        t0 = time.perf_counter()
+        m0 = self._now()
+        kid = [m0, None]
+        with self._lock:
+            mine = self._open[sid] = _Open(m0)
+            up = self._open.get(parent)
+            if up is not None:      # the parent is open in this process
+                up.add(kid)
         err = None
         try:
             yield sp
@@ -376,22 +434,30 @@ class Observability:
             raise
         finally:
             _ctx.reset(tok)
-            dur = time.perf_counter() - t0
+            m1 = self._now()
+            dur = (m1 - m0) / 1e9
             if latency_name is not None:
                 # traced observations carry their trace id as the
                 # bucket's OpenMetrics exemplar (/metrics?format=prom)
                 self.latency.record(latency_name, dur, exemplar=tid)
+            rec = (tid, sid, parent, name, self.node_id,
+                   t_wall, dur, peer, sp.bytes, err or sp.err, m0)
             ring = self._ring
-            if ring is not None:
-                rec = (tid, sid, parent, name, self.node_id,
-                       t_wall, dur, peer, sp.bytes, err or sp.err)
-                with self._lock:
-                    ring.append(rec)
-                    if self._tail is not None:
-                        self._tail_note(rec)
-            if ann is not None:
-                with contextlib.suppress(Exception):
-                    ann.__exit__(None, None, None)
+            with self._lock:
+                kid[1] = m1
+                del self._open[sid]
+                mine.fold(m1, final=True)
+                key = capped_key(self._totals, name, self._MAX_SPAN_NAMES,
+                                 self, "span totals", "_overflow")
+                row = self._totals.get(key)
+                if row is None:
+                    row = self._totals[key] = [0, 0.0, 0.0]
+                row[0] += 1
+                row[1] += dur
+                row[2] += (m1 - m0 - mine.covered) / 1e9
+                ring.append(rec)
+                if self._tail is not None:
+                    self._tail_note(rec)
 
     # ---- tail retention (lock held by caller) ------------------------- #
 
@@ -436,15 +502,11 @@ class Observability:
             if not latency:
                 yield _NULL_SPAN
                 return
-            ann = self._annotate(name)
             t0 = time.perf_counter()
             try:
                 yield _NULL_SPAN
             finally:
                 self.latency.record(name, time.perf_counter() - t0)
-                if ann is not None:
-                    with contextlib.suppress(Exception):
-                        ann.__exit__(None, None, None)
             return
         yield from self._traced(name, cur[0], new_span_id(), cur[1],
                                 peer, name if latency else None)
@@ -500,20 +562,42 @@ class Observability:
 
     # ---- query ------------------------------------------------------- #
 
-    def spans_for(self, trace_id: str) -> list[dict]:
-        """Finished spans of one trace still resident — main ring plus
-        the tail-retention store (outlier traces outlive ring churn
-        there), deduped by span id, ordered by wall start."""
+    def _select(self, want) -> list[dict]:
+        """Finished spans still resident that ``want(record)`` — main
+        ring plus the tail-retention store (outlier traces outlive ring
+        churn there), deduped by span id, ordered by wall start."""
         if self._ring is None:
             return []
         with self._lock:
-            rows = [r for r in self._ring if r[0] == trace_id]
+            rows = [r for r in self._ring if want(r)]
             if self._tail is not None:
                 have = {r[1] for r in rows}
                 rows.extend(r for r in self._tail
-                            if r[0] == trace_id and r[1] not in have)
+                            if want(r) and r[1] not in have)
         rows.sort(key=lambda r: r[5])
         return [_span_dict(r) for r in rows]
+
+    def spans_for(self, trace_id: str) -> list[dict]:
+        """Finished spans of one trace still resident."""
+        return self._select(lambda r: r[0] == trace_id)
+
+    def spans_between(self, since_ns: int, until_ns: int) -> list[dict]:
+        """Finished spans still resident that were open at some moment
+        of ``[since_ns, until_ns]`` on the spans' clock (``m0``): what
+        this process was doing while, say, a device sat idle."""
+        return self._select(
+            lambda r: r[10] <= until_ns
+            and r[10] + int(r[6] * 1e9) >= since_ns)
+
+    def span_totals(self) -> dict[str, dict]:
+        """name -> {count, seconds, selfSeconds} over every span this
+        process has finished (``/metrics`` ``obs.spans``; a reader takes
+        deltas). Spans of one name may overlap (slices of one batch in
+        flight together), so ``seconds`` is span-seconds, not wall."""
+        with self._lock:
+            return {name: {"count": r[0], "seconds": round(r[1], 6),
+                           "selfSeconds": round(r[2], 6)}
+                    for name, r in sorted(self._totals.items())}
 
     def stats(self) -> dict:
         """JSON ``/metrics`` ``obs`` section. The ``traceRing`` /
@@ -525,8 +609,12 @@ class Observability:
         return {"traceRing": self.cfg.trace_ring,
                 "slowSpanS": self.cfg.slow_span_s,
                 "tailKeep": self.cfg.tail_keep,
-                "spans": len(self._ring) if self._ring is not None else 0,
+                "ringSpans": len(self._ring)
+                if self._ring is not None else 0,
                 "tailSpans": tail_spans,
+                # the per-name totals exist only with the ring
+                **({"spans": self.span_totals()}
+                   if self._ring is not None else {}),
                 "journal": self.journal.stats()
                 if self.journal is not None else {"enabled": False},
                 "sentinel": self.sentinel.stats()

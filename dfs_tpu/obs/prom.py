@@ -22,6 +22,8 @@ Flattens every metric registry the node owns into one scrapeable page:
   which pre-digested p50/p90/p99 cannot do.
 - ``RpcStats``            -> ``dfs_rpc_{client,server}_*_total{peer=…,op=…}``
   per-peer per-op calls/errors/retries/bytes/seconds.
+- span totals (``obs.spans``) -> ``dfs_span_total{name=…}``,
+  ``dfs_span_seconds_total`` and ``dfs_span_self_seconds_total``.
 - node gauges             -> ``dfs_under_replicated``, ``dfs_trace_spans``.
 
 Label values are escaped per the exposition format (backslash, quote,
@@ -147,8 +149,19 @@ def render_node_metrics(node) -> str:
     fam("dfs_under_replicated", "gauge")
     lines.append(f"dfs_under_replicated {len(node.under_replicated)}")
     obs = node.obs.stats()
+    # per-name span totals (absent with tracing off): where each layer's
+    # time went, self time beside the total
+    totals = obs.get("spans")
+    if totals:
+        for suffix, key in (("total", "count"),
+                            ("seconds_total", "seconds"),
+                            ("self_seconds_total", "selfSeconds")):
+            fam(f"dfs_span_{suffix}", "counter")
+            for name, row in totals.items():
+                lines.append(f'dfs_span_{suffix}{{name="{_esc(name)}"}} '
+                             f'{_fmt(row[key])}')
     fam("dfs_trace_spans", "gauge")
-    lines.append(f'dfs_trace_spans {obs["spans"]}')
+    lines.append(f'dfs_trace_spans {obs["ringSpans"]}')
     fam("dfs_trace_ring_capacity", "gauge")
     lines.append(f'dfs_trace_ring_capacity {obs["traceRing"]}')
     fam("dfs_trace_tail_spans", "gauge")

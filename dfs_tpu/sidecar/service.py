@@ -22,7 +22,15 @@ nothing, buy nothing). Methods (all under service ``dfs.Sidecar``):
   message, 1 GiB gRPC message cap applies).
 - ``Health``     unary-unary. Request: empty. Response: JSON status,
   including ``device`` — what the engine computes on, as JAX reports it,
-  and how many regions it has dispatched there (null for host engines).
+  how many regions it has dispatched there and where its streams' wall
+  time went (null for host engines) — and ``spans``, the owner's span
+  totals. Counters only: it is polled.
+- ``Trace``      unary-unary. Request: JSON ``{"traceId"}`` or
+  ``{"sinceMonoNs", "untilMonoNs"}``. Response: ``{"spans": [...]}``
+  from the owner's span ring (dfs_tpu/obs): the owner is a node of the
+  trace like any other. A caller names its current span in the gRPC
+  metadata key ``x-dfs-trace`` (the ``X-Dfs-Trace`` header's value
+  format); every chunking handler opens ``owner.stream`` under it.
 
 The sidecar is the deployment's CHIP OWNER (utils/device.py): a chip
 belongs to one process, so N nodes on a host share it through this one.
@@ -40,10 +48,19 @@ from concurrent import futures
 
 import grpc
 
+from dfs_tpu import obs as obs_mod
 from dfs_tpu.fragmenter.base import Fragmenter
 
 _SERVICE = "dfs.Sidecar"
 STREAM_BLOCK = 4 * 1024 * 1024
+_TRACE_KEY = "x-dfs-trace"      # gRPC metadata keys are lower case
+
+
+def _trace_metadata() -> tuple | None:
+    """The calling thread's current span as call metadata, or None when
+    it is untraced (or tracing is off: the context is then never set)."""
+    cur = obs_mod.current()
+    return ((_TRACE_KEY, f"{cur[0]}-{cur[1]}"),) if cur else None
 
 
 def _identity(x: bytes) -> bytes:
@@ -53,9 +70,15 @@ def _identity(x: bytes) -> bytes:
 class SidecarServer:
     def __init__(self, port: int = 0, fragmenter: str = "auto",
                  cdc_params=None, max_workers: int = 4) -> None:
+        from dfs_tpu.config import ObsConfig
         from dfs_tpu.fragmenter.base import get_fragmenter
 
         self.fragmenter = get_fragmenter(fragmenter, cdc_params=cdc_params)
+        # ring and totals only (no journal, no sentinel): node id 0 is
+        # the owner in a stitched tree. The engine opens its per-window
+        # spans through it.
+        self.obs = obs_mod.Observability(ObsConfig(), node_id=0)
+        self.fragmenter.obs = self.obs
         self._server = grpc.server(
             futures.ThreadPoolExecutor(max_workers=max_workers),
             options=[("grpc.max_receive_message_length", 1 << 30),
@@ -78,15 +101,26 @@ class SidecarServer:
                        for c in chunks],
         }).encode()
 
+    def _stream_span(self, ctx):
+        """``owner.stream`` under the span the caller named in its
+        metadata (a fresh root when it named none)."""
+        carrier = dict(ctx.invocation_metadata()).get(_TRACE_KEY)
+        return self.obs.request_span(
+            "owner.stream", obs_mod.parse_http_trace(carrier))
+
     def _handlers(self) -> grpc.GenericRpcHandler:
         def chunk_hash(request: bytes, ctx) -> bytes:
-            return self._chunk_table(self.fragmenter.chunk(request),
-                                     len(request))
+            with self._stream_span(ctx) as sp:
+                sp.bytes = len(request)
+                return self._chunk_table(self.fragmenter.chunk(request),
+                                         len(request))
 
         def chunk_hash_stream(request_iterator, ctx) -> bytes:
-            m = self.fragmenter.manifest_stream(request_iterator,
-                                                name="stream")
-            return self._chunk_table(list(m.chunks), m.size)
+            with self._stream_span(ctx) as sp:
+                m = self.fragmenter.manifest_stream(request_iterator,
+                                                    name="stream")
+                sp.bytes = m.size
+                return self._chunk_table(list(m.chunks), m.size)
 
         def chunk_hash_duplex(request_iterator, ctx):
             """stream-stream: chunk batches flow back AS the fragmenter's
@@ -99,15 +133,20 @@ class SidecarServer:
 
             digests: list[str] = []
             size = 0
-            for batch in self.fragmenter.chunks_stream(request_iterator):
-                if not batch:
-                    continue
-                size = batch[-1].offset + batch[-1].length
-                digests.extend(c.digest for c in batch)
-                yield json.dumps({
-                    "chunks": [{"index": c.index, "offset": c.offset,
-                                "length": c.length, "digest": c.digest}
-                               for c in batch]}).encode()
+            # the span stays open across the yields: gRPC runs one call
+            # in one thread and one context from first reply to last
+            with self._stream_span(ctx) as sp:
+                for batch in self.fragmenter.chunks_stream(
+                        request_iterator):
+                    if not batch:
+                        continue
+                    size = batch[-1].offset + batch[-1].length
+                    digests.extend(c.digest for c in batch)
+                    yield json.dumps({
+                        "chunks": [{"index": c.index, "offset": c.offset,
+                                    "length": c.length, "digest": c.digest}
+                                   for c in batch]}).encode()
+                sp.bytes = size
             yield json.dumps({
                 "done": True, "size": size,
                 "fileId": file_id_from_digests(digests),
@@ -127,7 +166,22 @@ class SidecarServer:
                                "window": span or 0,
                                "describe": desc,
                                "device": self.fragmenter.device_stats(),
+                               "spans": self.obs.span_totals(),
                                }).encode()
+
+        def trace(request: bytes, ctx) -> bytes:
+            try:
+                q = json.loads(request or b"{}")
+                if "traceId" in q:
+                    spans = self.obs.spans_for(str(q["traceId"]))
+                else:
+                    spans = self.obs.spans_between(
+                        int(q["sinceMonoNs"]), int(q["untilMonoNs"]))
+            except (ValueError, KeyError, TypeError) as e:
+                ctx.abort(grpc.StatusCode.INVALID_ARGUMENT,
+                          f"Trace wants traceId or sinceMonoNs and "
+                          f"untilMonoNs: {e!r}")
+            return json.dumps({"spans": spans}).encode()
 
         methods = {
             f"/{_SERVICE}/ChunkHash": grpc.unary_unary_rpc_method_handler(
@@ -143,6 +197,9 @@ class SidecarServer:
                     response_serializer=_identity),
             f"/{_SERVICE}/Health": grpc.unary_unary_rpc_method_handler(
                 health, request_deserializer=_identity,
+                response_serializer=_identity),
+            f"/{_SERVICE}/Trace": grpc.unary_unary_rpc_method_handler(
+                trace, request_deserializer=_identity,
                 response_serializer=_identity),
         }
 
@@ -186,24 +243,40 @@ class SidecarClient:
         self._health = self._channel.unary_unary(
             f"/{_SERVICE}/Health", request_serializer=_identity,
             response_deserializer=_identity)
+        self._trace = self._channel.unary_unary(
+            f"/{_SERVICE}/Trace", request_serializer=_identity,
+            response_deserializer=_identity)
+
+    # every chunking call names the caller's current span (if it has
+    # one) in its metadata: the owner's spans hang under it
 
     def chunk_hash(self, data: bytes) -> dict:
-        return json.loads(self._chunk_hash(data, timeout=self.timeout_s))
+        return json.loads(self._chunk_hash(
+            data, timeout=self.timeout_s, metadata=_trace_metadata()))
 
     def chunk_hash_stream(self, blocks) -> dict:
         """Stream byte blocks (any iterable of bytes) — no size ceiling."""
         return json.loads(self._chunk_hash_stream(
-            iter(blocks), timeout=self.timeout_s))
+            iter(blocks), timeout=self.timeout_s,
+            metadata=_trace_metadata()))
 
     def chunk_hash_duplex(self, blocks):
         """Stream blocks in, iterate chunk-batch dicts out as the sidecar
         finalizes them; the last message is {'done': True, ...}."""
         for msg in self._chunk_hash_duplex(iter(blocks),
-                                           timeout=self.timeout_s):
+                                           timeout=self.timeout_s,
+                                           metadata=_trace_metadata()):
             yield json.loads(msg)
 
     def health(self) -> dict:
         return json.loads(self._health(b"", timeout=self.health_timeout_s))
+
+    def trace(self, **query) -> list[dict]:
+        """The owner's ring spans for ``traceId=`` or for
+        ``sinceMonoNs=, untilMonoNs=`` (CLOCK_MONOTONIC ns)."""
+        return json.loads(self._trace(
+            json.dumps(query).encode(),
+            timeout=self.health_timeout_s))["spans"]
 
     def close(self) -> None:
         self._channel.close()

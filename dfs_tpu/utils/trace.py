@@ -1,20 +1,15 @@
-"""Tracing / profiling (SURVEY.md §5.1 — absent in the reference, which has
+"""Latency histograms (SURVEY.md §5.1 — absent in the reference, which has
 only printf logging).
 
-Two layers:
-- :class:`LatencyRecorder` — lock-protected streaming histograms (log2
-  buckets) for request/phase latencies; snapshots expose count/p50/p90/p99/max
-  per name, served by the node's ``/metrics`` endpoint.
-- :func:`span` — context manager that records into a recorder and, when a
-  ``jax.profiler`` trace session is active (``start_trace``), also emits a
-  ``TraceAnnotation`` so device timelines in TensorBoard/XProf line up with
-  framework phases. The jax import is deferred and optional.
+:class:`LatencyRecorder` — lock-protected streaming histograms (log2
+buckets) for request/phase latencies; snapshots expose count/p50/p90/p99/max
+per name, served by the node's ``/metrics`` endpoint. Spans live in
+:mod:`dfs_tpu.obs`, which records into one of these.
 """
 
 from __future__ import annotations
 
 import bisect
-import contextlib
 import math
 import threading
 import time
@@ -119,47 +114,3 @@ class LatencyRecorder:
         exposition (indices align with histogram_snapshot buckets)."""
         with self._lock:
             return {name: dict(ex) for name, ex in self._ex.items()}
-
-
-# Set only while device_trace() is active. span() consults this flag instead
-# of importing jax per call: importing jax inside a request span would block
-# the node's event loop for seconds (and on jax-less hosts a failed import is
-# retried every call — failed imports aren't cached in sys.modules).
-_PROFILING = False
-
-
-@contextlib.contextmanager
-def span(name: str, recorder: LatencyRecorder | None = None):
-    """Time a phase; annotate the device trace when one is being captured."""
-    ann = None
-    if _PROFILING:
-        import jax.profiler  # device_trace already imported it
-
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        if recorder is not None:
-            recorder.record(name, dt)
-        if ann is not None:
-            with contextlib.suppress(Exception):
-                ann.__exit__(None, None, None)
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str):
-    """Capture a jax.profiler device trace around a block (TensorBoard/XProf
-    readable). Usage: ``with device_trace('/tmp/trace'): frag.chunk(data)``."""
-    global _PROFILING
-    import jax.profiler
-
-    jax.profiler.start_trace(log_dir)
-    _PROFILING = True
-    try:
-        yield
-    finally:
-        _PROFILING = False
-        jax.profiler.stop_trace()

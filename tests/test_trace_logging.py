@@ -1,21 +1,17 @@
 """Unit coverage for utils/trace.py and utils/logging.py (none existed
 before round 9): histogram bucket edges, quantile correctness against a
 reference implementation, concurrent record() safety, the cardinality
-guards, span() with and without an active profiler flag, and
-device_trace flag restore on exception."""
+guards, and the logger / Counters / Stopwatches plumbing. Spans are
+dfs_tpu.obs's (tests/test_obs.py, tests/test_span_totals.py)."""
 
 import logging
 import math
-import sys
 import threading
-import types
 
 import pytest
 
-from dfs_tpu.utils import trace as trace_mod
 from dfs_tpu.utils.logging import Counters, Stopwatches, get_logger
-from dfs_tpu.utils.trace import (BUCKET_BOUNDS, LatencyRecorder,
-                                 device_trace, span)
+from dfs_tpu.utils.trace import BUCKET_BOUNDS, LatencyRecorder
 
 
 # --------------------------------------------------------------------- #
@@ -122,102 +118,6 @@ def test_latency_cardinality_guard():
     # an EXISTING name keeps recording normally after the cap is hit
     r.record("n0", 0.001)
     assert r.snapshot()["n0"]["count"] == 2
-
-
-# --------------------------------------------------------------------- #
-# span() / device_trace(): profiler-flag interplay
-# --------------------------------------------------------------------- #
-
-class _FakeAnnotation:
-    entered = exited = 0
-
-    def __init__(self, name):
-        self.name = name
-
-    def __enter__(self):
-        _FakeAnnotation.entered += 1
-        return self
-
-    def __exit__(self, *exc):
-        _FakeAnnotation.exited += 1
-        return False
-
-
-def _fake_profiler(monkeypatch, calls):
-    prof = types.ModuleType("jax.profiler")
-    prof.TraceAnnotation = _FakeAnnotation
-    prof.start_trace = lambda d: calls.append(("start", d))
-    prof.stop_trace = lambda: calls.append(("stop",))
-    jax_mod = types.ModuleType("jax")
-    jax_mod.profiler = prof
-    monkeypatch.setitem(sys.modules, "jax", jax_mod)
-    monkeypatch.setitem(sys.modules, "jax.profiler", prof)
-    return prof
-
-
-def test_span_without_profiler_flag_records_only_latency(monkeypatch):
-    monkeypatch.setattr(trace_mod, "_PROFILING", False)
-    _FakeAnnotation.entered = _FakeAnnotation.exited = 0
-    r = LatencyRecorder()
-    with span("phase", r):
-        pass
-    assert r.snapshot()["phase"]["count"] == 1
-    assert _FakeAnnotation.entered == 0   # no profiler touch at all
-
-
-def test_span_with_profiler_flag_annotates(monkeypatch):
-    _fake_profiler(monkeypatch, [])
-    monkeypatch.setattr(trace_mod, "_PROFILING", True)
-    _FakeAnnotation.entered = _FakeAnnotation.exited = 0
-    r = LatencyRecorder()
-    with span("phase", r):
-        pass
-    assert _FakeAnnotation.entered == 1 and _FakeAnnotation.exited == 1
-    assert r.snapshot()["phase"]["count"] == 1
-
-
-def test_span_exits_annotation_on_exception(monkeypatch):
-    _fake_profiler(monkeypatch, [])
-    monkeypatch.setattr(trace_mod, "_PROFILING", True)
-    _FakeAnnotation.entered = _FakeAnnotation.exited = 0
-    with pytest.raises(RuntimeError):
-        with span("phase"):
-            raise RuntimeError("boom")
-    assert _FakeAnnotation.exited == 1
-
-
-def test_obs_span_annotates_under_profiler_flag(monkeypatch):
-    """Observability spans keep the pre-r09 device-trace annotation
-    contract: with a jax.profiler capture active, every span (ringed or
-    latency-only) opens a TraceAnnotation."""
-    from dfs_tpu.config import ObsConfig
-    from dfs_tpu.obs import Observability
-
-    _fake_profiler(monkeypatch, [])
-    monkeypatch.setattr(trace_mod, "_PROFILING", True)
-    _FakeAnnotation.entered = _FakeAnnotation.exited = 0
-    obs = Observability(ObsConfig(trace_ring=8), node_id=1)
-    with obs.request_span("http./x"):
-        with obs.span("upload.replicate", latency=True):
-            pass
-    assert _FakeAnnotation.entered == 2 and _FakeAnnotation.exited == 2
-    # tracing OFF but latency on: the annotation path still runs
-    obs_off = Observability(ObsConfig(trace_ring=0), node_id=1)
-    with obs_off.span("download.gather", latency=True):
-        pass
-    assert _FakeAnnotation.entered == 3 and _FakeAnnotation.exited == 3
-
-
-def test_device_trace_restores_flag_on_exception(monkeypatch):
-    calls = []
-    _fake_profiler(monkeypatch, calls)
-    monkeypatch.setattr(trace_mod, "_PROFILING", False)
-    with pytest.raises(ValueError):
-        with device_trace("/tmp/ignored"):
-            assert trace_mod._PROFILING is True
-            raise ValueError("inside trace")
-    assert trace_mod._PROFILING is False      # flag restored
-    assert calls == [("start", "/tmp/ignored"), ("stop",)]
 
 
 # --------------------------------------------------------------------- #

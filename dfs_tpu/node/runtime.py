@@ -1058,29 +1058,26 @@ class StorageNodeServer:
         if op == "store_chunks":
             # Hash echo: recompute every digest from the received bytes
             # (reference receiver contract, StorageNode.java:279-292).
-            # The hash + thousands of file writes run OFF the event loop:
-            # inline they occupied it for seconds under writeback
+            # The hash runs OFF the event loop and the file writes in
+            # the bounded CAS write pool, as a batch (cas.put_many —
+            # the coordinator's own copy takes the same road): inline
+            # they occupied the loop for seconds under writeback
             # pressure (observed on a 2 GiB-corpus ingest), so the node
-            # answered NOTHING and every peer cascaded into "unreachable"
-            # — the same rule upload/download/scrub already follow.
+            # answered NOTHING and every peer cascaded into
+            # "unreachable" — the same rule upload/download/scrub
+            # already follow. A pair whose echo differs is left out.
             pairs = unpack_chunks(header.get("chunks", []), body)
-
-            def store_all():
-                echoed = sha256_many_hex([b for _, b in pairs])
-                stored = dedup = 0
-                nbytes = 0
-                for (claimed, data), actual in zip(pairs, echoed):
-                    if claimed == actual:
-                        if self.store.chunks.put(actual, data,
-                                                 verify=False):
-                            stored += 1
-                            nbytes += len(data)
-                        else:
-                            dedup += 1
-                return echoed, stored, dedup, nbytes
-
-            echoed, stored, dedup, nbytes = await asyncio.to_thread(
-                store_all)
+            echoed = await asyncio.to_thread(
+                sha256_many_hex, [b for _, b in pairs])
+            sound = [(actual, data)
+                     for (claimed, data), actual in zip(pairs, echoed)
+                     if claimed == actual]
+            results = await self.cas.put_many(sound, verify=False)
+            stored = sum(results)
+            dedup = len(sound) - stored
+            nbytes = sum(len(data)
+                         for (_, data), newly in zip(sound, results)
+                         if newly)
             if stored:
                 self.counters.inc("chunks_stored", stored)
                 self.counters.inc("bytes_stored", nbytes)
@@ -3003,6 +3000,7 @@ class StorageNodeServer:
         return got, other_id
 
     _FETCH_BATCH_BYTES = 32 * 1024 * 1024
+    _PROBE_SLICE_DIGESTS = 2048   # digests per repair has_chunks call
 
     async def _gather_chunks(self, manifest: Manifest | None,
                              chunks=None, strict: bool = True,
@@ -4055,9 +4053,13 @@ class StorageNodeServer:
     def durability_stats(self) -> dict:
         """``/metrics`` ``durability`` section. The ``mode`` key mirrors
         DurabilityConfig.mode (dfslint DFS005 checks the mapping);
-        ``fsyncs`` counts barriers the chunk store actually issued."""
+        ``fsyncs`` counts the chunk files the store made durable (payload
+        fsync'd, linked, directory fsync'd — one per file, before its put
+        returned), ``dirBarriers`` the directory fsyncs that took: one
+        per distinct directory of a batch, not one per file."""
         return {"mode": self.cfg.durability.mode,
-                "fsyncs": self.store.chunks.fsync_count()}
+                "fsyncs": self.store.chunks.fsync_count(),
+                "dirBarriers": self.store.chunks.dir_barrier_count()}
 
     def chaos_stats(self) -> dict:
         """``/metrics`` ``chaos`` section: active knobs + per-kind
@@ -4625,10 +4627,21 @@ class StorageNodeServer:
             try:
                 have: set[str] = set()
                 if probe_digests:
-                    resp, _ = await self.client.call(
-                        peer, {"op": "has_chunks",
-                               "digests": probe_digests})
-                    have = set(resp.get("have", []))
+                    # bounded, serial slices like the push below: the
+                    # peer serves a probe as ONE cas.has_many job on its
+                    # 2-worker latency lane, and a whole-store list is
+                    # tens of thousands of stats — past the request
+                    # timeout on a slow file system, so the call was
+                    # RETRIED (a second such job) while live uploads'
+                    # probes waited behind both until they timed out and
+                    # a healthy peer was marked dead (PERF.md §6, PR 25)
+                    for i in range(0, len(probe_digests),
+                                   self._PROBE_SLICE_DIGESTS):
+                        resp, _ = await self.client.call(
+                            peer, {"op": "has_chunks",
+                                   "digests": probe_digests[
+                                       i:i + self._PROBE_SLICE_DIGESTS]})
+                        have.update(resp.get("have", []))
                     if filter_known:
                         for d in probe_digests:
                             if d not in have:

@@ -13,10 +13,12 @@ put/get through a small dedicated thread pool so
   let a burst of concurrent reads stack arbitrary many file descriptors
   and seeks.
 
-Batch variants (:meth:`put_many` / :meth:`get_many`) run a whole list in
-ONE worker job — per-chunk executor dispatch costs a lock+wakeup per
-item, which at CDC chunk sizes (thousands of chunks per batch) is real
-time on the 1-core CI host.
+Batch variants (:meth:`put_many` / :meth:`get_many`) never dispatch per
+chunk — a lock+wakeup per item is real time at CDC chunk sizes
+(thousands of chunks per batch) on the 1-core CI host. ``get_many`` is
+ONE worker job; ``put_many`` cuts a large batch by directory into at
+most ``workers`` jobs, so the batch's barriers are in flight on every
+worker the pool has instead of in series on one.
 
 The wrapper also attributes time: ``queue_s`` (submitted jobs waiting
 for a free worker — the disk tier is saturated) vs ``busy_s`` (actual
@@ -26,6 +28,7 @@ stall breakdown (docs/ingest.md).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -35,12 +38,16 @@ from dfs_tpu.store.cas import ChunkStore
 
 T = TypeVar("T")
 
+# a batch smaller than this stays one job: its directories are met once
+# each anyway, and a handful of files is not worth four dispatches
+_SPLIT_MIN_ITEMS = 64
+
 
 class AsyncChunkStore:
     """Bounded-thread-pool async wrapper over one node's :class:`ChunkStore`.
 
     Three lanes, because a batch job pins a worker for its whole list
-    (thousands of chunk files — multi-second under writeback pressure)
+    or part (hundreds of chunk files — seconds under writeback pressure)
     and FIFO queueing behind one would blow a peer RPC's budget, making
     a merely BUSY node look dead to its callers — the same
     probe-starvation failure the internal admission gate exempts health
@@ -155,17 +162,62 @@ class AsyncChunkStore:
     async def put_many(self, items: Sequence[tuple[str, bytes]],
                        verify: bool = False) -> list[bool]:
         """Store a batch; per-item True = newly stored (False = dedup
-        hit), same contract as :meth:`ChunkStore.put`, one worker job."""
+        hit), same contract as :meth:`ChunkStore.put`, in the items'
+        order.
+
+        A batch is written as a batch (:meth:`ChunkStore.put_batch`:
+        payload barriers, links, one directory barrier per directory)
+        and spread over the write pool's workers: cut by directory
+        (``digest[:2]``, contiguous ranges) into at most ``workers``
+        parts, one ``cas-w`` job each, awaited together inside the one
+        ``cas.put_many`` span. A directory belongs to one part, so a
+        batch still pays one barrier per directory. A batch under
+        ``_SPLIT_MIN_ITEMS``, and any batch when the similarity plane is
+        attached (its sketch pass is one launch per batch), is one job.
+        An exception in any part fails the call once every part has
+        ended (no job is left writing behind a failed call)."""
         if not items:
             return []
+        import asyncio
+
         its = list(items)
-        # put_batch, not a put loop: with the similarity plane attached
-        # the store sketches the whole batch through the mesh in one
-        # launch; without it, put_batch IS the per-item loop
-        return await self._run(
-            self._wpool,
-            lambda: self.store.put_batch(its, verify=verify),
-            "cas.put_many")
+        parts = self._split(its)
+
+        def job(idx: list[int]) -> Callable[[], list[bool]]:
+            return lambda: self.store.put_batch(
+                [its[i] for i in idx], verify=verify)
+
+        with (self._obs.span("cas.put_many") if self._obs is not None
+              else contextlib.nullcontext()):
+            done = await asyncio.gather(
+                *(self._run(self._wpool, job(idx)) for idx in parts),
+                return_exceptions=True)
+        results = [False] * len(its)
+        for idx, got in zip(parts, done):
+            if isinstance(got, BaseException):
+                raise got
+            for i, newly in zip(idx, got):
+                results[i] = newly
+        return results
+
+    def _split(self, its: list[tuple[str, bytes]]) -> list[list[int]]:
+        """Indexes of ``its`` cut into at most ``workers`` contiguous
+        ranges of its directory order, a directory never in two."""
+        if (self._workers == 1 or len(its) < _SPLIT_MIN_ITEMS
+                or self.store.sim is not None):
+            return [list(range(len(its)))]
+        order = sorted(range(len(its)), key=lambda i: its[i][0][:2])
+        parts: list[list[int]] = []
+        start = 0
+        for k in range(1, self._workers + 1):
+            end = max(start, len(order) * k // self._workers)
+            while 0 < end < len(order) and \
+                    its[order[end]][0][:2] == its[order[end - 1]][0][:2]:
+                end += 1
+            if end > start:
+                parts.append(order[start:end])
+            start = end
+        return parts
 
     async def inventory(self, list_prefixes=None,
                         list_cap: int = 4096) -> dict:

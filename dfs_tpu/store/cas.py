@@ -103,11 +103,14 @@ class ChunkStore:
     """Flat content-addressed blob store.
 
     ``fsync=True`` (DurabilityConfig mode "fsync", routed down by the
-    node runtime) makes every put crash-durable before it returns: the
-    payload file is fsync'd before the link makes it visible, and the
+    node runtime) makes every put crash-durable before it returns: each
+    payload file is fsync'd before the link makes it visible, and its
     parent directory is fsync'd after — so an acked upload's chunks
-    survive kill -9 / power loss, not just process death. Default False
-    here: standalone/library users opt in; the node defaults on.
+    survive kill -9 / power loss, not just process death. A batch
+    (:meth:`put_batch`) keeps that order per chunk and pays the
+    directory barrier once per distinct directory, after the last link
+    into it. Default False here: standalone/library users opt in; the
+    node defaults on.
 
     ``fault`` is the chaos seam (dfs_tpu.chaos): when set, every
     put/get calls ``fault(op, digest)`` first — on the CALLING thread
@@ -129,8 +132,17 @@ class ChunkStore:
         self.index = None
         self._count: int | None = None     # lazy; maintained by put/delete
         self._bytes: int | None = None     # lazy; maintained by put/delete
-        self._fsyncs = 0                   # barriers issued (durability_stats)
-        self._count_lock = threading.Lock()   # puts run in to_thread pools
+        # chunk files made durable (payload fsync'd, linked, directory
+        # fsync'd) — one per file, counted before the put returns
+        self._fsyncs = 0
+        self._dir_barriers = 0             # directory fsyncs issued
+        # digests whose name this store linked (or is about to) and whose
+        # directory barrier has not been issued yet: whoever meets such a
+        # name as a dedup hit fsyncs the directory itself before answering
+        # (_settle). Entered BEFORE the link, so a visible name is always
+        # found here until a barrier covers it.
+        self._unbarriered: set[str] = set()
+        self._count_lock = threading.Lock()   # puts run on CAS pool workers
         # orders the visible link/unlink against its index record: a
         # put racing a delete of the SAME digest could otherwise
         # interleave (link, note_delete, unlink, note_put) and leave a
@@ -309,20 +321,26 @@ class ChunkStore:
         so a crash-lost record costs one stat, not one per probe
         forever — and the first post-restart repair probe sweep
         re-indexes everything it touches."""
+        p = self._path_str(digest)
         if self.index is None:
-            return os.path.isfile(self._path_str(digest)) \
+            present = os.path.isfile(p) \
                 or (self._deltas_possible()
                     and self._chain_resolves(digest))
-        if self.index.lookup(digest):
-            return True
-        with self._index_mu:
-            present = os.path.isfile(self._path_str(digest)) \
-                or (self._deltas_possible()
-                    and self._chain_resolves(digest))
+        elif self.index.lookup(digest):
+            present = True
+        else:
+            with self._index_mu:
+                present = os.path.isfile(p) \
+                    or (self._deltas_possible()
+                        and self._chain_resolves(digest))
+                if present:
+                    self.index.note_put(digest, defer_flush=True)
             if present:
-                self.index.note_put(digest, defer_flush=True)
+                self.index.maybe_flush()   # outside the ordering mutex
         if present:
-            self.index.maybe_flush()       # outside the ordering mutex
+            # "present" is what a coordinator counts as a copy before it
+            # acks: a name still owed its directory barrier gets it first
+            self._settle(digest, p)
         return present
 
     def has_many(self, digests) -> list[bool]:
@@ -343,137 +361,238 @@ class ChunkStore:
 
         With ``fsync`` on, the payload file is fsync'd before the link
         and the directory after it — the put is crash-durable when it
-        returns (the fsync-before-ack contract, docs/chaos.md).
+        returns (the fsync-before-ack contract, docs/chaos.md): payload
+        durable → name visible → name durable → return. A dedup hit
+        keeps the contract too: a name whose first writer has not issued
+        its directory barrier yet is barriered by whoever meets it
+        (``_settle``) before the answer.
+
+        This is :meth:`put_batch` of one item — one write path.
 
         With the similarity plane attached (``self.sim``), an eligible
         new chunk may be stored as a DELTA against a resident similar
         base instead of raw — transparent to every reader via get().
-        ``sketch`` optionally carries a precomputed min-hash from the
-        batched path (``put_batch``) so the plane need not re-sketch."""
-        if self.fault is not None:
-            self.fault("put", digest)
-        p = self._path_str(digest)
-        if os.path.isfile(p):
-            if self.index is not None and not self.index.lookup(digest):
-                # dedup hit on a chunk the index forgot (crash-lost WAL
-                # buffer): heal here too — a repair push re-sending a
-                # restarted node its own chunks is exactly how that
-                # node's catalog re-enters the index (same ordering
-                # mutex discipline as has()'s backstop)
-                with self._index_mu:
-                    if os.path.isfile(p):
-                        self.index.note_put(digest, defer_flush=True)
-                self.index.maybe_flush()
-            return False
-        if self._deltas_possible():
-            with self._delta_mu:
-                if digest in self._delta_base:
-                    return False   # present (as a delta): dedup hit
-        if verify and sha256_hex(data) != digest:
-            raise ValueError(f"data does not match digest {digest[:12]}…")
-        if self.sim is not None:
-            enc = self.sim.encode_for_put(self, digest, data,
-                                          sketch=sketch)
-            if enc is not None:
-                stored = self._put_delta(digest, enc[0], enc[1],
-                                         raw_len=len(data))
-                if stored is not None:
-                    return stored
-                # rolled back (base vanished mid-write): store raw below
-        return self._put_raw(digest, p, data)
+        ``sketch`` optionally carries a precomputed min-hash so the
+        plane need not re-sketch."""
+        return self._put_items(
+            [(digest, data)], verify,
+            None if sketch is None else {digest: sketch})[0]
 
     def put_batch(self, items, verify: bool = True) -> list[bool]:
-        """Batched puts — the seam ``AsyncChunkStore.put_many`` rides so
-        the similarity plane can sketch a whole batch through the mesh
-        in one launch instead of per-chunk on the host. Without the
-        plane this is exactly the per-item loop."""
-        if self.sim is None:
-            return [self.put(d, b, verify=verify) for d, b in items]
-        sketches = self.sim.sketch_for_batch(self, items)
-        return [self.put(d, b, verify=verify, sketch=sketches.get(d))
-                for d, b in items]
+        """Store a batch of ``(digest, data)``; per item True = newly
+        stored, False = dedup hit — the results of a :meth:`put` loop,
+        written as a batch:
 
-    def _put_raw(self, digest: str, p: str, data: bytes) -> bool:
-        """The raw-file write mechanics (tmp + O_EXCL + link + fsync) —
-        shared by put() and re-materialization, which must bypass the
-        sim seam (re-encoding what it just reconstructed would loop)."""
-        parent = os.path.dirname(p)
-        if parent not in self._dirs:       # one mkdir per subdir lifetime
-            os.makedirs(parent, exist_ok=True)
-            self._dirs.add(parent)
-        # pid+sequence tmp names instead of mkstemp: uniqueness within
-        # this store is all that is needed, and mkstemp's random-name
-        # search measured real time at thousands of puts per upload.
-        # O_EXCL collisions (a crash-leaked temp from a previous run of
-        # the same pid — routine for PID-1 containers) just advance the
-        # sequence; the loop touches nothing it did not create, so a
-        # concurrent writer's live temp is never deleted.
-        while True:
-            tmp = f"{parent}/.tmp-{os.getpid()}-{next(self._tmp_seq)}"
-            try:
-                fd = os.open(tmp,
-                             os.O_WRONLY | os.O_CREAT | os.O_EXCL, 0o600)
-                break
-            except FileExistsError:
+        a. per item, on the calling thread: fault hook, dedup hit on an
+           existing file or delta, ``verify``, the similarity plane's
+           delta path (one sketch launch for the whole batch); a digest
+           met twice is written once;
+        b. per new file: temp (``O_EXCL``), write, **payload fsync**,
+           close — one descriptor at a time;
+        c. every link temp → final name (a lost race is a dedup hit);
+        d. **one fsync per distinct parent directory**, after the last
+           link into it;
+        e. the temps unlinked (on every error path too), the gauges.
+
+        It returns only after (d): per chunk the order is ``put``'s —
+        payload durable → name visible → name durable → return — and the
+        directory barrier is shared by the files of one directory
+        instead of issued per file. With durability off the same phases
+        run without barriers. An exception fails the whole call; names
+        already linked stay (content-addressed, fully written) and are
+        barriered by the next put that meets them.
+
+        With the similarity plane attached each raw write is a batch of
+        its own: a later item may encode against an earlier one, which
+        has to be resident by then."""
+        items = list(items)
+        sketches = self.sim.sketch_for_batch(self, items) \
+            if self.sim is not None else None
+        return self._put_items(items, verify, sketches)
+
+    def _put_items(self, items, verify: bool, sketches) -> list[bool]:
+        results = [False] * len(items)
+        fresh: list[tuple[str, str, bytes]] = []   # raw writes owed
+        fresh_at: list[int] = []                   # their place in items
+        queued: set[str] = set()
+        for i, (digest, data) in enumerate(items):
+            if self.fault is not None:
+                self.fault("put", digest)
+            p = self._path_str(digest)
+            if digest in queued:
+                continue           # twice in one batch: written once
+            if os.path.isfile(p):
+                self._settle(digest, p)
+                if self.index is not None \
+                        and not self.index.lookup(digest):
+                    # dedup hit on a chunk the index forgot (crash-lost
+                    # WAL buffer): heal here too — a repair push
+                    # re-sending a restarted node its own chunks is
+                    # exactly how that node's catalog re-enters the
+                    # index (same ordering mutex discipline as has()'s
+                    # backstop)
+                    with self._index_mu:
+                        if os.path.isfile(p):
+                            self.index.note_put(digest, defer_flush=True)
+                    self.index.maybe_flush()
                 continue
-        try:
-            with os.fdopen(fd, "wb") as f:
-                f.write(data)
-                if self._fsync:
-                    f.flush()
-                    os.fsync(f.fileno())
-            with self._index_mu:
-                try:
-                    os.link(tmp, p)
-                except FileExistsError:
-                    return False
-                except OSError as e:
-                    # filesystem without hard links: fall back to atomic
-                    # rename. Loses the exactly-one-True race guarantee
-                    # (both racers see True, count drifts by one until
-                    # restart) but never loses data — rename is still
-                    # atomic and content-addressed names make the
-                    # overwrite idempotent. Only the no-hardlink errnos
-                    # take the fallback; anything else (vanished tmp,
-                    # EIO, and EXDEV — tmp is created in the target's
-                    # OWN directory, so a cross-device link error means
-                    # something anomalous that os.replace would also
-                    # fail on, just with a less accurate traceback)
-                    # stays loud with its real cause.
-                    if e.errno not in (errno.EPERM, errno.EOPNOTSUPP,
-                                       errno.ENOTSUP, errno.EMLINK):
-                        raise
-                    os.replace(tmp, p)
-                if self.index is not None:
-                    # recorded AFTER the link is visible (inside the
-                    # ordering lock): a crash between the two leaves a
-                    # false NEGATIVE — has()'s stat backstop covers
-                    # it. The flush/compaction threshold runs AFTER
-                    # the mutex drops (below) — a multi-second merge
-                    # inside it would freeze every CAS worker.
-                    self.index.note_put(digest, defer_flush=True)
-            if self._fsync:
-                # the NAME is durable only once the directory block is:
-                # link/rename ordered the visible state, the dirfd fsync
-                # makes it survive power loss (payload fsync'd above)
-                _fsync_path(parent)
-                with self._count_lock:
-                    self._fsyncs += 1
-        finally:
-            try:
-                os.unlink(tmp)       # ours: the O_EXCL open succeeded
-            # already consumed by os.replace on the no-hardlink path, or
-            # re-leaked to the aged sweep — either way non-fatal cleanup
-            except OSError:  # dfslint: ignore[DFS007]
-                pass
+            if self._deltas_possible():
+                with self._delta_mu:
+                    if digest in self._delta_base:
+                        continue   # present (as a delta): dedup hit
+            if verify and sha256_hex(data) != digest:
+                raise ValueError(
+                    f"data does not match digest {digest[:12]}…")
+            if self.sim is not None:
+                enc = self.sim.encode_for_put(
+                    self, digest, data,
+                    sketch=sketches.get(digest) if sketches else None)
+                stored = None
+                if enc is not None:
+                    stored = self._put_delta(digest, enc[0], enc[1],
+                                             raw_len=len(data))
+                if stored is None:
+                    # no delta, or rolled back (base vanished
+                    # mid-write): raw, and now — the next item may
+                    # encode against this one
+                    stored = self._write_raw([(digest, p, data)])[0]
+                results[i] = stored
+                continue
+            queued.add(digest)
+            fresh.append((digest, p, data))
+            fresh_at.append(i)
+        if fresh:
+            for i, new in zip(fresh_at, self._write_raw(fresh)):
+                results[i] = new
+        return results
+
+    def _settle(self, digest: str, p: str) -> None:
+        """Before a dedup hit on ``p`` is answered: if the name was
+        linked by a put whose directory barrier is still owed (the
+        window between phases (c) and (d) of another thread's batch),
+        issue that barrier here. No waiting on the other thread; the
+        fsync runs outside every lock."""
+        if not self._fsync:
+            return
         with self._count_lock:
-            if self._count is not None:
-                self._count += 1
-            if self._bytes is not None:
-                self._bytes += len(data)
+            if digest not in self._unbarriered:
+                return
+        _fsync_path(os.path.dirname(p))
+        with self._count_lock:
+            self._dir_barriers += 1
+            self._unbarriered.discard(digest)
+
+    def _write_raw(self, batch) -> list[bool]:
+        """The raw-file write mechanics for ``(digest, path, data)``
+        items, phases (b)–(e) of :meth:`put_batch` — shared by every
+        put and by re-materialization, which must bypass the sim seam
+        (re-encoding what it just reconstructed would loop)."""
+        new = [False] * len(batch)
+        temps: list[str] = []
+        parents: dict[str, None] = {}   # owed a barrier, in link order
+        nlinked = nbytes = barriers = 0
+        try:
+            for _, p, data in batch:
+                parent = os.path.dirname(p)
+                if parent not in self._dirs:   # one mkdir per subdir lifetime
+                    os.makedirs(parent, exist_ok=True)
+                    self._dirs.add(parent)
+                # pid+sequence tmp names instead of mkstemp: uniqueness
+                # within this store is all that is needed, and mkstemp's
+                # random-name search measured real time at thousands of
+                # puts per upload. O_EXCL collisions (a crash-leaked temp
+                # from a previous run of the same pid — routine for PID-1
+                # containers) just advance the sequence; the loop touches
+                # nothing it did not create, so a concurrent writer's live
+                # temp is never deleted.
+                while True:
+                    tmp = f"{parent}/.tmp-{os.getpid()}-{next(self._tmp_seq)}"
+                    try:
+                        fd = os.open(
+                            tmp, os.O_WRONLY | os.O_CREAT | os.O_EXCL, 0o600)
+                        break
+                    except FileExistsError:
+                        continue
+                temps.append(tmp)
+                try:
+                    view = memoryview(data)
+                    done = os.write(fd, view)
+                    while done < len(view):    # short write: disk nearly full
+                        done += os.write(fd, view[done:])
+                    if self._fsync:
+                        os.fsync(fd)           # payload durable BEFORE the name
+                finally:
+                    os.close(fd)
+            for k, (digest, p, data) in enumerate(batch):
+                if self._fsync:
+                    # entered BEFORE the link: whoever sees the name finds
+                    # it owed a barrier (_settle). A lost race below is
+                    # owed one as well — the winner's may not be out yet.
+                    with self._count_lock:
+                        self._unbarriered.add(digest)
+                    parents[os.path.dirname(p)] = None
+                with self._index_mu:
+                    try:
+                        os.link(temps[k], p)
+                    except FileExistsError:
+                        continue               # dedup hit
+                    except OSError as e:
+                        # filesystem without hard links: fall back to
+                        # atomic rename. Loses the exactly-one-True race
+                        # guarantee (both racers see True, count drifts by
+                        # one until restart) but never loses data — rename
+                        # is still atomic and content-addressed names make
+                        # the overwrite idempotent. Only the no-hardlink
+                        # errnos take the fallback; anything else (vanished
+                        # tmp, EIO, and EXDEV — tmp is created in the
+                        # target's OWN directory, so a cross-device link
+                        # error means something anomalous that os.replace
+                        # would also fail on, just with a less accurate
+                        # traceback) stays loud with its real cause.
+                        if e.errno not in (errno.EPERM, errno.EOPNOTSUPP,
+                                           errno.ENOTSUP, errno.EMLINK):
+                            raise
+                        os.replace(temps[k], p)
+                    if self.index is not None:
+                        # recorded AFTER the link is visible (inside the
+                        # ordering lock): a crash between the two leaves a
+                        # false NEGATIVE — has()'s stat backstop covers
+                        # it. The flush/compaction threshold runs AFTER
+                        # the mutex drops (below) — a multi-second merge
+                        # inside it would freeze every CAS worker.
+                        self.index.note_put(digest, defer_flush=True)
+                new[k] = True
+                nlinked += 1
+                nbytes += len(data)
+            if self._fsync:
+                # a NAME is durable only once its directory block is:
+                # link/rename ordered the visible state, the dirfd fsync
+                # makes it survive power loss (payloads fsync'd above).
+                # Once per directory, after the last link into it.
+                for parent in parents:
+                    _fsync_path(parent)
+                    barriers += 1
+                with self._count_lock:
+                    self._fsyncs += nlinked
+                    self._unbarriered.difference_update(
+                        d for d, _, _ in batch)
+        finally:
+            for tmp in temps:
+                try:
+                    os.unlink(tmp)       # ours: the O_EXCL open succeeded
+                # already consumed by os.replace on the no-hardlink path,
+                # or re-leaked to the aged sweep — non-fatal cleanup
+                except OSError:  # dfslint: ignore[DFS007]
+                    pass
+            with self._count_lock:
+                self._dir_barriers += barriers
+                if self._count is not None:
+                    self._count += nlinked
+                if self._bytes is not None:
+                    self._bytes += nbytes
         if self.index is not None:
             self.index.maybe_flush()   # outside the ordering mutex
-        return True
+        return new
 
     def _put_delta(self, digest: str, base_digest: str, blob: bytes,
                    raw_len: int) -> bool | None:
@@ -511,7 +630,7 @@ class ChunkStore:
                 except FileExistsError:
                     return False   # racing identical delta: present
                 except OSError as e:
-                    # same no-hardlink fallback story as _put_raw
+                    # same no-hardlink fallback story as _write_raw
                     if e.errno not in (errno.EPERM, errno.EOPNOTSUPP,
                                        errno.ENOTSUP, errno.EMLINK):
                         raise
@@ -527,6 +646,7 @@ class ChunkStore:
                 _fsync_path(parent)
                 with self._count_lock:
                     self._fsyncs += 1
+                    self._dir_barriers += 1
         finally:
             try:
                 os.unlink(tmp)       # ours: the O_EXCL open succeeded
@@ -601,15 +721,42 @@ class ChunkStore:
         on read, and _prime_delta_maps completes the unlink next boot."""
         p = self._path_str(digest)
         if not os.path.isfile(p):
-            self._put_raw(digest, p, data)
+            self._write_raw([(digest, p, data)])
         if self.sim is not None:
             self.sim.maybe_crash("sim.after_rematerialize")
         self._drop_delta(digest)
 
     def fsync_count(self) -> int:
-        """Durability barriers issued so far (``/metrics`` durability)."""
+        """Chunk files made durable so far — payload fsync'd, linked,
+        directory fsync'd; one per file, counted before its put returns
+        (``/metrics`` ``durability.fsyncs``)."""
         with self._count_lock:
             return self._fsyncs
+
+    def barrier_dirs(self) -> int:
+        """fsync every chunk directory once; returns how many. The BOOT
+        barrier (``NodeStore.boot_sweep``): ``_unbarriered`` dies with
+        the process, so a life killed between phases (c) and (d) of a
+        batch leaves names this life would answer as dedup hits with no
+        directory barrier ever issued. Whatever names survived to this
+        life are made durable here, before the servers listen. A no-op
+        with durability off."""
+        if not self._fsync:
+            return 0
+        dirs = [os.fspath(sub) for sub in self.root.iterdir()
+                if sub.is_dir() and len(sub.name) == 2]
+        for d in dirs:
+            _fsync_path(d)
+        with self._count_lock:
+            self._dir_barriers += len(dirs)
+        return len(dirs)
+
+    def dir_barrier_count(self) -> int:
+        """Directory fsyncs issued so far (``durability.dirBarriers``):
+        one per distinct directory per batch, plus the few a dedup hit
+        issues for a name whose first writer has not yet."""
+        with self._count_lock:
+            return self._dir_barriers
 
     def get(self, digest: str) -> bytes | None:
         if self.fault is not None:
@@ -1103,11 +1250,20 @@ class NodeStore:
         aged path already reclaims. The 1h age is kept even at boot:
         a young orphan may belong to a manifest announced while this
         node was down, which manifest anti-entropy adopts on the first
-        repair cycle — deleting it here would force a re-fetch."""
+        repair cycle — deleting it here would force a re-fetch.
+
+        With durability on, every chunk directory is fsync'd once
+        (``ChunkStore.barrier_dirs``): a previous life killed between a
+        batch's links and its directory barriers left names that no
+        barrier covers and that this life cannot tell from durable
+        ones — after this sweep every name on disk is durable, so a
+        dedup hit on it may be acked."""
         tmps = self.chunks.sweep_tmp(max_age_s=0.0) \
             + self.manifests.sweep_tmp(max_age_s=0.0)
+        barriers = self.chunks.barrier_dirs()
         orphans = self.gc(min_age_s=3600.0)
-        return {"tmps": tmps, "orphans": len(orphans)}
+        return {"tmps": tmps, "orphans": len(orphans),
+                "dirBarriers": barriers}
 
     def gc(self, min_age_s: float = 0.0) -> list[str]:
         """Delete chunks referenced by no manifest (the reference has no

@@ -201,7 +201,7 @@ def scope_nodes(fn: ast.AST) -> Iterator[ast.AST]:
     """Nodes lexically inside ``fn``'s body, NOT descending into nested
     function/lambda scopes — 'lexically inside an async def' must stop
     at a nested ``def`` (which may legitimately run in a worker thread,
-    e.g. the store_all closure runtime._dispatch hands to to_thread)."""
+    e.g. a closure an async handler hands to to_thread)."""
     todo = list(getattr(fn, "body", []))
     while todo:
         n = todo.pop()

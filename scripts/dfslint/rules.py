@@ -1114,8 +1114,8 @@ def _wire_handlers(runtime: SourceFile) -> dict[str, dict] | None:
         h = out.setdefault(op, {"reads": set(), "produces": set(),
                                 "open_reply": False,
                                 "line": stmt.lineno})
-        # scope-limited walk: a nested def's returns (store_chunks'
-        # store_all worker closure) are NOT the op's reply
+        # scope-limited walk: a nested def's returns (a worker closure
+        # inside the op's branch) are NOT the op's reply
         todo = list(stmt.body)
         while todo:
             n = todo.pop()

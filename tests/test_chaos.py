@@ -43,6 +43,7 @@ from dfs_tpu.config import (CDCParams, CensusConfig, ChaosConfig,
                             PeerAddr)
 from dfs_tpu.meta.manifest import Manifest
 from dfs_tpu.node.runtime import StorageNodeServer, UploadError
+from dfs_tpu.store import cas
 from dfs_tpu.store.cas import NodeStore
 from dfs_tpu.utils.hashing import sha256_hex
 
@@ -201,6 +202,69 @@ def test_fsync_mode_counts_barriers(tmp_path):
     assert on.chunks.fsync_count() == 1
     assert off.chunks.fsync_count() == 0
     assert on.chunks.get(d) == data
+
+
+_KILL_BETWEEN_LINKS_AND_BARRIERS = """
+import os, random, signal, sys
+from dfs_tpu.store import cas
+from dfs_tpu.utils.hashing import sha256_hex
+
+def kill(path):                     # the first directory barrier of (d)
+    os.kill(os.getpid(), signal.SIGKILL)
+
+cas._fsync_path = kill
+rng = random.Random(7)
+items = []
+for _ in range(300):
+    b = rng.randbytes(rng.randrange(100, 9000))
+    items.append((sha256_hex(b), b))
+store = cas.ChunkStore(sys.argv[1], fsync=True)
+store.put_batch(items)
+print("RETURNED", flush=True)       # an ack would follow: must not happen
+"""
+
+
+def test_kill_between_the_batch_links_and_directory_barriers(
+        tmp_path, monkeypatch):
+    """kill -9 after phase (c) of a batch put, before the first directory
+    barrier of (d): the put never returned (nothing was acked), and
+    every name present holds its full payload — the payload barrier
+    preceded the link. The temps it leaves are the boot sweep's, and so
+    are the directory barriers the dead life owed."""
+    root = tmp_path / "node-1"
+    proc = subprocess.run(
+        [sys.executable, "-c", _KILL_BETWEEN_LINKS_AND_BARRIERS,
+         str(root / "chunks")],
+        capture_output=True, text=True, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        cwd=str(Path(__file__).resolve().parent.parent))
+    assert proc.returncode == -signal.SIGKILL, proc.stderr[-2000:]
+    assert "RETURNED" not in proc.stdout
+    store = NodeStore(tmp_path, 1, fsync=True)
+    names = store.chunks.digests()
+    assert len(names) == 300                # every link of (c) had landed
+    for d in names:
+        assert sha256_hex(store.chunks.get(d)) == d
+    assert store.chunks.fsync_count() == 0  # a new life counts from zero
+    leaked = list(store.chunks.root.rglob(".tmp-*"))
+    assert len(leaked) == 300               # (e) never ran
+    # this life cannot know which names the dead one still owed a
+    # directory barrier (that set died with it): the boot sweep fsyncs
+    # every chunk directory, BEFORE a dedup hit on such a name is answered
+    barriered = []
+    monkeypatch.setattr(cas, "_fsync_path", barriered.append)
+    swept = store.boot_sweep()
+    assert swept["tmps"] == 300
+    assert list(store.chunks.root.rglob(".tmp-*")) == []
+    parents = {str(store.chunks.root / d[:2]) for d in names}
+    assert set(barriered) == parents and len(barriered) == len(parents)
+    assert swept["dirBarriers"] == len(parents) \
+        == store.chunks.dir_barrier_count()
+    # the names stay: a repeat (the client's retry) is a dedup hit, acked
+    # on names the sweep above made durable
+    again = store.chunks.put_batch([(d, store.chunks.get(d))
+                                    for d in names[:10]])
+    assert again == [False] * 10
 
 
 # ------------------------------------------------------------------ #

@@ -102,6 +102,134 @@ def test_async_chunk_store_roundtrip(tmp_path, rng):
     aio.close()
 
 
+def _items(n, seed=0, size=48):
+    import random
+    r = random.Random(seed)
+    out = []
+    for _ in range(n):
+        b = r.randbytes(size)
+        out.append((sha256_hex(b), b))
+    return out
+
+
+class _Jobs:
+    """Which items each put_batch call (= one cas-w job) was handed."""
+
+    def __init__(self, store, monkeypatch):
+        self.calls = []
+        real = store.put_batch
+
+        def put_batch(items, verify=True):
+            items = list(items)
+            self.calls.append([d for d, _ in items])
+            return real(items, verify=verify)
+
+        monkeypatch.setattr(store, "put_batch", put_batch)
+
+
+@pytest.mark.parametrize("workers", [4, 3])
+def test_put_many_splits_a_batch_by_directory(tmp_path, monkeypatch,
+                                              workers):
+    """A large batch: at most ``workers`` jobs, every directory's files
+    in one of them (one barrier per directory per batch, read from the
+    store's counter), results in the items' order."""
+    store = ChunkStore(tmp_path / "chunks", fsync=True)
+    aio = AsyncChunkStore(store, workers=workers)
+    old = _items(40, seed=1)
+    store.put_batch(old)
+    base_dirs = store.dir_barrier_count()
+    jobs = _Jobs(store, monkeypatch)
+    fresh = _items(600, seed=2)
+    batch = fresh[:300] + old[:20] + fresh[300:] + fresh[:5]
+    want_new = [True] * 300 + [False] * 20 + [True] * 300 + [False] * 5
+
+    async def run():
+        return await aio.put_many(batch)
+
+    assert asyncio.run(run()) == want_new
+    assert 2 <= len(jobs.calls) <= workers
+    assert sorted(d for c in jobs.calls for d in c) \
+        == sorted(d for d, _ in batch)
+    where = {}
+    for k, call in enumerate(jobs.calls):
+        for d in call:
+            assert where.setdefault(d[:2], k) == k    # a directory: one part
+    sizes = [len(c) for c in jobs.calls]
+    assert max(sizes) <= 2 * min(sizes)               # near-even ranges
+    assert store.fsync_count() == 40 + 600
+    assert store.dir_barrier_count() - base_dirs \
+        == len({d[:2] for d, _ in fresh})
+    st = aio.stats()
+    assert st["pending"] == 0 and st["ops"] == len(jobs.calls)
+    for d, b in batch:
+        assert store.get(d) == b
+    aio.close()
+
+
+def test_put_many_small_batch_and_sim_stay_one_job(tmp_path, monkeypatch):
+    store = ChunkStore(tmp_path / "chunks")
+    aio = AsyncChunkStore(store, workers=4)
+    jobs = _Jobs(store, monkeypatch)
+
+    class _NoSim:                       # a plane that never deltas
+        def sketch_for_batch(self, store, items):
+            return {}
+
+        def encode_for_put(self, store, digest, data, sketch=None):
+            return None
+
+    async def run():
+        small = _items(40, seed=3)
+        assert await aio.put_many(small) == [True] * 40
+        assert len(jobs.calls) == 1
+        store.sim = _NoSim()
+        big = _items(400, seed=4)
+        assert await aio.put_many(big) == [True] * 400
+        assert len(jobs.calls) == 2     # one more job, not four
+        store.sim = None
+        assert await aio.put_many(big + small) == [False] * 440
+        assert len(jobs.calls) > 2
+
+    asyncio.run(run())
+    assert aio.stats()["pending"] == 0
+    aio.close()
+
+
+def test_put_many_part_failure_fails_the_call(tmp_path, monkeypatch):
+    """An exception in one part fails the call — after every part has
+    ended — and leaves no temp; the backlog gauge returns to 0."""
+    store = ChunkStore(tmp_path / "chunks", fsync=True)
+    aio = AsyncChunkStore(store, workers=4)
+    batch = _items(400, seed=5)
+    victim = sorted(d for d, _ in batch)[-1]          # in the last part
+
+    def fault(op, digest):
+        if op == "put" and digest == victim:
+            raise OSError(28, "No space left on device (injected)")
+
+    store.fault = fault
+
+    async def run():
+        with pytest.raises(OSError, match="injected"):
+            await aio.put_many(batch)
+
+    asyncio.run(run())
+    assert aio.stats()["pending"] == 0
+    assert list(store.root.rglob(".tmp-*")) == []
+    on_disk = store.digests()
+    assert victim not in on_disk and 0 < len(on_disk) < 400
+    assert store.fsync_count() == len(on_disk)        # the other parts ended
+    store.fault = None
+
+    async def again():
+        return await aio.put_many(batch)
+
+    got = asyncio.run(again())
+    assert got.count(True) == 400 - len(on_disk)
+    assert len(store.digests()) == 400
+    aio.close()
+
+
 # ---------------------------------------------------------------------- #
 # cluster helpers (same in-process idiom as test_node_cluster)
 # ---------------------------------------------------------------------- #
@@ -173,6 +301,52 @@ def test_store_chunks_windowed_delivers_and_reports_peak(tmp_path, rng):
     asyncio.run(run())
 
 
+def test_store_chunks_writes_through_the_cas_pool(tmp_path, rng):
+    """A peer stores what it receives through the bounded CAS write pool
+    (``ingest.cas.ops`` rises; ``cas.put_many`` is a child span of
+    ``peer.store_chunks``), as a batch, and leaves out a chunk whose
+    echo differs from the digest claimed for it."""
+    async def run():
+        cluster = _cluster_cfg(1, rf=1)
+        nodes = await _start(cluster, tmp_path)
+        node = nodes[1]
+        try:
+            peer = cluster.peer(1)
+            client = InternalClient()
+            good = _items(200, seed=7, size=300)
+            liar = ("f" * 64, b"not what the digest says")
+            ops0 = node.ingest_stats()["cas"]["ops"]
+            dirs0 = node.durability_stats()["dirBarriers"]
+            files0 = node.durability_stats()["fsyncs"]
+            echoed = await client.store_chunks(
+                peer, "", good[:100] + [liar] + good[100:])
+            assert len(echoed) == 201
+            assert echoed[100] == sha256_hex(liar[1]) != liar[0]
+            assert [e for i, e in enumerate(echoed) if i != 100] \
+                == [d for d, _ in good]
+            for d, b in good:
+                assert node.store.chunks.get(d) == b
+            assert not node.store.chunks.has(liar[0])
+            assert not node.store.chunks.has(sha256_hex(liar[1]))
+            assert node.ingest_stats()["cas"]["ops"] > ops0
+            assert node.ingest_stats()["cas"]["pending"] == 0
+            dur = node.durability_stats()
+            assert dur["fsyncs"] - files0 == 200
+            assert dur["dirBarriers"] - dirs0 \
+                == len({d[:2] for d, _ in good}) < 200
+            spans = node.obs.spans_between(0, 2 ** 63 - 1)
+            by_id = {s["s"]: s for s in spans}
+            puts = [s for s in spans if s["name"] == "cas.put_many"]
+            assert len(puts) == 1
+            assert by_id[puts[0]["p"]]["name"] == "peer.store_chunks"
+            client.close()
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
 def test_store_chunks_windowed_callback_error_propagates(tmp_path, rng):
     """An on_slice exception (the caller's hash-echo verdict) must cancel
     the remaining in-flight slices and propagate — the serial path's
@@ -195,6 +369,53 @@ def test_store_chunks_windowed_callback_error_propagates(tmp_path, rng):
                 await client.store_chunks_windowed(
                     peer, "", slices, window=2, on_slice=on_slice)
             client.close()
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
+def test_repair_probes_a_peer_in_bounded_slices(tmp_path, rng, monkeypatch):
+    """The repair cycle asks a peer what it holds in bounded has_chunks
+    calls, never one list of every digest (one cas.has_many job of the
+    whole store on the peer's latency lane outlasts the request timeout
+    on a slow file system and starves live uploads' probes), and still
+    finds and repairs exactly what is missing."""
+    data = rng.integers(0, 256, size=300_000, dtype=np.uint8).tobytes()
+    monkeypatch.setattr(StorageNodeServer, "_PROBE_SLICE_DIGESTS", 100)
+
+    async def run():
+        cluster = _cluster_cfg(2, rf=2)
+        nodes = await _start(cluster, tmp_path)
+        try:
+            async def blocks():
+                yield data
+
+            manifest, _ = await nodes[1].upload_stream(blocks(), "r.bin")
+            digests = sorted({c.digest for c in manifest.chunks})
+            assert len(digests) > 300
+            victims = digests[::97]                 # lost on the peer
+            for d in victims:
+                assert nodes[2].store.chunks.delete(d)
+            asked = []
+            real = nodes[2]._dispatch
+
+            async def dispatch(header, body):
+                if header.get("op") == "has_chunks":
+                    asked.append(list(header.get("digests", [])))
+                return await real(header, body)
+
+            monkeypatch.setattr(nodes[2], "_dispatch", dispatch)
+            repaired = await nodes[1].repair_once()
+            assert len(asked) >= 4 and max(map(len, asked)) <= 100
+            assert sorted(d for part in asked for d in part) == digests
+            assert repaired == len(victims)
+            for d in victims:
+                assert nodes[2].store.chunks.has(d)
+            asked.clear()
+            assert await nodes[1].repair_once() == 0     # and a clean cycle
+            assert sorted(d for part in asked for d in part) == digests
         finally:
             for n in nodes.values():
                 await n.stop()

@@ -194,3 +194,440 @@ def test_count_gauge_primes_once_and_tracks_put_delete(tmp_path):
     assert store.count() == 3 + 32
     assert store.bytes_total() == 20 + 30 + 5 + 32 * 8
     assert all(c >= 3 and b >= 55 for c, b in seen)
+
+
+# ---------------------------------------------------------------------- #
+# the batch put: payload barriers, links, one directory barrier per
+# directory (PR 25) — put is put_batch of one item
+# ---------------------------------------------------------------------- #
+
+class _Calls:
+    """Record the file-system calls the store issues (name, path), in
+    order, and how many descriptors it holds at once."""
+
+    NAMES = ("stat", "fstat", "open", "write", "fsync", "close", "link",
+             "replace", "unlink")
+
+    def __init__(self, monkeypatch, root):
+        import os
+        import threading
+        self.root = str(root)
+        self.events = []        # (call, path or None)
+        self.paths = {}         # fd -> path
+        self.max_open = 0
+        self.fail = None        # (call, predicate(path)) -> OSError once
+        self.on_call = None     # hook(call, path), before the real call
+        self.mu = threading.Lock()
+        real = {n: getattr(os, n) for n in self.NAMES}
+        self.real = real
+
+        def path_of(call, args):
+            if call in ("fstat", "write", "fsync", "close"):
+                return self.paths.get(args[0])
+            if call in ("link", "replace"):
+                return str(args[1])
+            return str(args[0]) if isinstance(args[0], (str, bytes)) \
+                or hasattr(args[0], "__fspath__") else self.paths.get(args[0])
+
+        def wrap(call):
+            def fn(*args, **kw):
+                path = path_of(call, args)
+                mine = path is not None and path.startswith(self.root)
+                if mine:
+                    with self.mu:
+                        self.events.append((call, path))
+                    if self.on_call is not None:
+                        self.on_call(call, path)
+                    if self.fail is not None and self.fail[0] == call \
+                            and self.fail[1](path):
+                        self.fail = None
+                        raise OSError(5, f"injected {call} failure", path)
+                out = real[call](*args, **kw)
+                if call == "open" and mine:
+                    with self.mu:
+                        self.paths[out] = path
+                        self.max_open = max(self.max_open, len(self.paths))
+                if call == "close":
+                    with self.mu:
+                        self.paths.pop(args[0], None)
+                return out
+            return fn
+
+        for n in self.NAMES:
+            monkeypatch.setattr(os, n, wrap(n))
+
+    def of(self, *calls):
+        return [(c, p) for c, p in self.events if c in calls]
+
+
+def _batch(n, seed=0, size=64):
+    import random
+    rng = random.Random(seed)
+    out = []
+    for _ in range(n):
+        data = rng.randbytes(size)
+        out.append((sha256_hex(data), data))
+    return out
+
+
+def _temps(root):
+    return sorted(p.name for p in root.rglob(".tmp-*"))
+
+
+def _is_temp(path):
+    return "/.tmp-" in path
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_put_batch_barriers_once_per_directory(tmp_path, monkeypatch, fsync):
+    """A batch of 600: one payload fsync per new file before that file's
+    link, one directory fsync per distinct parent after the last link
+    into it and before put_batch returns, never more than two
+    descriptors, and the counters say so."""
+    import os
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    items = _batch(600)
+    for d, _ in items:                      # directories made beforehand
+        os.makedirs(cs.root / d[:2], exist_ok=True)
+    calls = _Calls(monkeypatch, cs.root)
+    assert cs.put_batch(items, verify=True) == [True] * 600
+    ev = calls.events
+    assert calls.max_open <= 2
+    assert calls.of("fstat") == []
+    parents = {os.path.dirname(str(cs._path(d))) for d, _ in items}
+    assert 1 < len(parents) <= 256
+    payload_syncs = [p for c, p in calls.of("fsync") if _is_temp(p)]
+    dir_syncs = [p for c, p in calls.of("fsync") if p in parents]
+    links = calls.of("link")
+    assert len(links) == 600
+    if not fsync:
+        assert calls.of("fsync") == []
+        assert cs.fsync_count() == 0 and cs.dir_barrier_count() == 0
+    else:
+        assert len(payload_syncs) == 600 and len(set(payload_syncs)) == 600
+        assert sorted(dir_syncs) == sorted(parents)     # once each
+        assert len(calls.of("fsync")) == 600 + len(parents)
+        # payload durable before its name: link k publishes the k-th temp
+        # (same order), whose fsync lies before the link
+        link_at = [i for i, (c, _) in enumerate(ev) if c == "link"]
+        sync_at = {p: i for i, (c, p) in enumerate(ev)
+                   if c == "fsync" and _is_temp(p)}
+        opened = [p for c, p in calls.of("open") if _is_temp(p)]
+        assert len(opened) == 600
+        for tmp, at in zip(opened, link_at):
+            assert sync_at[tmp] < at
+        # name durable after the LAST link into its directory
+        last_link = {}
+        for i, (c, p) in enumerate(ev):
+            if c == "link":
+                last_link[os.path.dirname(p)] = i
+        for i, (c, p) in enumerate(ev):
+            if c == "fsync" and p in parents:
+                assert i > last_link[p]
+        assert cs.fsync_count() == 600
+        assert cs.dir_barrier_count() == len(parents)
+    assert _temps(cs.root) == []
+    assert len(calls.of("unlink")) == 600
+    # a second, overlapping batch: only the new files are written
+    more = items[:50] + _batch(25, seed=1)
+    before = len(calls.of("link"))
+    assert cs.put_batch(more) == [False] * 50 + [True] * 25
+    assert len(calls.of("link")) - before == 25
+    assert cs.fsync_count() == (625 if fsync else 0)
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_put_batch_equals_the_put_loop(tmp_path, fsync):
+    """Dedup hits, a digest twice in the batch, and the gauges: what a
+    loop of put answers, put_batch answers."""
+    a = ChunkStore(tmp_path / "a", fsync=fsync)
+    b = ChunkStore(tmp_path / "b", fsync=fsync)
+    old = _batch(8, seed=2)
+    for cs in (a, b):
+        for d, data in old[:5]:
+            cs.put(d, data)
+        assert cs.count() == 5            # primes the gauges
+    fresh = _batch(40, seed=3)
+    batch = old[3:8] + fresh[:20] + fresh[5:10] + fresh[20:] + old[:2]
+    want = [a.put(d, data) for d, data in batch]
+    got = b.put_batch(batch)
+    assert got == want
+    assert want.count(True) == 3 + 40
+    assert sorted(a.digests()) == sorted(b.digests())
+    assert (a.count(), a.bytes_total()) == (b.count(), b.bytes_total()) \
+        == (48, 48 * 64)
+    assert a.fsync_count() == b.fsync_count() == (48 if fsync else 0)
+    for d, data in batch:
+        assert b.get(d) == data
+    assert _temps(b.root) == []
+    assert b.put_batch([]) == []
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_put_batch_verify_mismatch_leaves_nothing(tmp_path, fsync):
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    items = _batch(30, seed=4)
+    bad = ("e" * 64, b"not what the digest says")
+    with pytest.raises(ValueError):
+        cs.put_batch(items[:20] + [bad] + items[20:], verify=True)
+    assert not cs.has(bad[0])
+    assert not (cs.root / "ee").exists() or \
+        list((cs.root / "ee").iterdir()) == []
+    assert _temps(cs.root) == []
+    # the call failed as a whole; what it may have left is sound, and a
+    # repeat stores the rest
+    for d in cs.digests():
+        assert sha256_hex(cs.get(d)) == d
+    left = len(cs.digests())
+    again = cs.put_batch(items)
+    assert again.count(True) == 30 - left
+    assert len(cs.digests()) == 30 and cs.count() == 30
+
+
+@pytest.mark.parametrize("fsync,phase", [
+    (True, "open"), (True, "write"), (True, "payload-fsync"),
+    (True, "link"), (True, "dir-fsync"),
+    # with durability off no barrier is issued: three phases can fail
+    (False, "open"), (False, "write"), (False, "link")])
+def test_put_batch_oserror_in_each_phase(tmp_path, monkeypatch, fsync,
+                                         phase):
+    """An OSError in any phase fails the call, leaves no temp, keeps the
+    gauges exact, and leaves no name that a later dedup hit would answer
+    for without a directory barrier."""
+    import os
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    first = _batch(4, seed=5)
+    cs.put_batch(first)
+    assert cs.count() == 4
+    items = _batch(120, seed=6)
+    calls = _Calls(monkeypatch, cs.root)
+    seen = {"n": 0}
+
+    def nth(k, want):
+        def pred(path):
+            if not want(path):
+                return False
+            seen["n"] += 1
+            return seen["n"] == k
+        return pred
+
+    parents = {os.path.dirname(str(cs._path(d))) for d, _ in items}
+    calls.fail = {
+        "open": ("open", nth(60, _is_temp)),
+        "write": ("write", nth(60, _is_temp)),
+        "payload-fsync": ("fsync", nth(60, _is_temp)),
+        "link": ("link", nth(60, lambda p: True)),
+        "dir-fsync": ("fsync", nth(len(parents) // 2,
+                                   lambda p: p in parents)),
+    }[phase]
+    with pytest.raises(OSError):
+        cs.put_batch(items)
+    assert calls.fail is None               # the fault fired
+    assert _temps(cs.root) == []
+    on_disk = cs.digests()
+    assert cs.count() == len(on_disk)
+    assert cs.bytes_total() == sum(len(cs.get(d)) for d in on_disk)
+    for d in on_disk:                       # every name holds its payload
+        assert sha256_hex(cs.get(d)) == d
+    linked = len(on_disk) - 4
+    assert linked == {"open": 0, "write": 0, "payload-fsync": 0,
+                      "link": 59, "dir-fsync": 120}[phase]
+    # the failed call counted no file as made durable ...
+    assert cs.fsync_count() == (4 if fsync else 0)
+    # ... and a repeat answers a dedup hit for what is linked only after
+    # a directory barrier covering it was issued
+    before = len([1 for c, p in calls.of("fsync") if p in parents])
+    got = cs.put_batch(items)
+    assert got.count(False) == linked
+    assert len(cs.digests()) == 124 and cs.count() == 124
+    if fsync:
+        owed = {os.path.dirname(str(cs._path(d)))
+                for (d, _), new in zip(items, got) if not new}
+        synced = [p for c, p in calls.of("fsync") if p in parents][before:]
+        assert owed <= set(synced)
+        assert cs.fsync_count() == 4 + got.count(True)
+    assert _temps(cs.root) == []
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("race", ["precheck", "link"])
+def test_same_digest_from_two_threads(tmp_path, monkeypatch, fsync, race):
+    """Exactly one True; and the loser — whether it meets the name at the
+    dedup pre-check or loses the link — does not return before a
+    directory barrier covering the name was issued, although the
+    winner's own is still held back."""
+    import os
+    import threading
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    data = b"raced chunk"
+    d = sha256_hex(data)
+    parent = os.path.dirname(str(cs._path(d)))
+    os.makedirs(parent)
+    calls = _Calls(monkeypatch, cs.root)
+    winner = {}
+    linked = threading.Event()
+    release = threading.Event()
+    both = threading.Barrier(2)
+    out = {}
+
+    def on_call(call, path):
+        me = threading.get_ident()
+        if call == "write" and race == "link":
+            both.wait(timeout=10)       # both are past the pre-check
+        if call == "fsync" and path == parent and winner.get("id") == me:
+            release.wait(timeout=10)    # the winner's barrier is held back
+
+    real_link = os.link
+
+    def link(src, dst, **kw):
+        real_link(src, dst, **kw)       # raises FileExistsError for the loser
+        winner["id"] = threading.get_ident()
+        linked.set()
+
+    monkeypatch.setattr(os, "link", link)
+    calls.on_call = on_call
+
+    def run(name, wait_for_link):
+        if wait_for_link:
+            assert linked.wait(timeout=10)
+        out[name] = cs.put(d, data)
+        out[name + "_syncs"] = len(
+            [1 for c, p in calls.of("fsync") if p == parent])
+
+    ta = threading.Thread(target=run, args=("a", False))
+    tb = threading.Thread(target=run, args=("b", race == "precheck"))
+    ta.start()
+    tb.start()
+    # the loser returns while the winner's directory barrier is held back
+    deadline = 10.0
+    loser = None
+    import time as _time
+    t0 = _time.time()
+    while _time.time() - t0 < deadline:
+        done = [n for n in ("a", "b") if n in out]
+        if fsync and done:
+            loser = done[0]
+            break
+        if not fsync and len(done) == 2:
+            break
+        _time.sleep(0.005)
+    if fsync:
+        assert loser is not None and out[loser] is False
+        # a directory barrier was issued — by the loser — before it returned
+        assert out[loser + "_syncs"] >= 1
+    release.set()
+    ta.join(10)
+    tb.join(10)
+    assert sorted([out["a"], out["b"]]) == [False, True]
+    assert cs.get(d) == data and _temps(cs.root) == []
+    assert cs.fsync_count() == (1 if fsync else 0)
+    if fsync:
+        assert cs.dir_barrier_count() == 2
+        # settled: a third put answers without another barrier
+        assert cs.put(d, data) is False
+        assert cs.dir_barrier_count() == 2
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_put_of_one_item_call_sequence(tmp_path, monkeypatch, fsync):
+    """put is put_batch of one: the old sequence less the fstat."""
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    seen = {}
+    pair = None
+    for i in range(100000):                 # two chunks of one directory
+        data = b"chunk-%d" % i
+        d = sha256_hex(data)
+        if d[:2] in seen:
+            pair = (seen[d[:2]], (d, data))
+            break
+        seen[d[:2]] = (d, data)
+    (d0, data0), (d1, data1) = pair
+    assert cs.put(d0, data0) is True        # makes the directory
+    calls = _Calls(monkeypatch, cs.root)
+    assert cs.put(d1, data1) is True
+    final = str(cs._path(d1))
+    parent = str(cs._path(d1).parent)
+    tmp = [p for c, p in calls.events if c == "open" and _is_temp(p)][0]
+    want = [("stat", final), ("open", tmp), ("write", tmp)]
+    if fsync:
+        want.append(("fsync", tmp))
+    want += [("close", tmp), ("link", final)]
+    if fsync:
+        want += [("open", parent), ("fsync", parent), ("close", parent)]
+    want.append(("unlink", tmp))
+    assert calls.events == want
+    calls.events.clear()
+    assert cs.put(d1, data1) is False       # a dedup hit: one stat
+    assert calls.events == [("stat", final)]
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_overlapping_batches_from_many_threads(tmp_path, fsync):
+    """More writers than cores, a short switch interval, batches that
+    overlap: every digest is newly stored by exactly one of them, the
+    gauges and counters are exact, and no name is left owed a barrier."""
+    import os
+    import sys
+    import threading
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    assert cs.count() == 0
+    pool = _batch(240, seed=9, size=32)
+    n_threads = 2 * (os.cpu_count() or 4)
+    wins = [None] * n_threads
+    errors = []
+
+    def writer(k):
+        try:
+            mine = pool[(k * 17) % 120:][:120] + pool[:40]
+            wins[k] = [d for (d, _), new in zip(mine, cs.put_batch(mine))
+                       if new]
+        except BaseException as e:      # reported by the assert below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=writer, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and not any(t.is_alive() for t in threads)
+    won = [d for w in wins for d in w]
+    assert len(won) == len(set(won)) == len(cs.digests())
+    assert cs.count() == len(won) and cs.bytes_total() == 32 * len(won)
+    assert cs.fsync_count() == (len(won) if fsync else 0)
+    assert cs._unbarriered == set()
+    assert _temps(cs.root) == []
+    for d, data in pool[:40]:
+        assert cs.get(d) == data
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_barrier_dirs_covers_what_a_dead_life_left(tmp_path, monkeypatch,
+                                                    fsync):
+    """Names linked by a life that never reached its directory barriers
+    (``_unbarriered`` died with it): ``barrier_dirs`` — the boot sweep's
+    — fsyncs every chunk directory once, and nothing with durability
+    off; the next put of such a name is a plain dedup hit."""
+    dead = ChunkStore(tmp_path / "chunks", fsync=False)   # no barrier issued
+    items = _batch(80, seed=3)
+    assert all(dead.put_batch(items))
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    calls = _Calls(monkeypatch, cs.root)
+    n = cs.barrier_dirs()
+    parents = {str(cs.root / d[:2]) for d, _ in items}
+    if fsync:
+        assert n == len(parents) == cs.dir_barrier_count()
+        assert {p for _, p in calls.of("fsync")} == parents
+        assert len(calls.of("fsync")) == len(parents)
+    else:
+        assert n == 0 == cs.dir_barrier_count()
+        assert calls.of("fsync") == []
+    assert calls.max_open <= 1
+    before = len(calls.of("fsync"))
+    assert cs.put_batch(items[:5]) == [False] * 5
+    assert len(calls.of("fsync")) == before and cs.fsync_count() == 0

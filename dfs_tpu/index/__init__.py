@@ -20,6 +20,7 @@ tests/test_index.py).
 
 from __future__ import annotations
 
+import threading
 from collections import OrderedDict
 from pathlib import Path
 
@@ -127,6 +128,15 @@ class IndexPlane:
         self.trusted = 0              # filter-positive copies credited
         self.echo_trusted = 0         # echo-cache copies credited
                                       # (skip ledger AND verify round)
+        self.place_considered = 0     # digests _place_batch weighed for
+        self.place_skipped = 0        # a peer, and those it never put
+                                      # to that peer in a has_chunks
+        # what the ChunkStore seam saw (CAS worker threads, so locked)
+        self._seam_mu = threading.Lock()
+        self._stat_fallbacks = 0      # has(): index said no -> stat
+        self._stat_fallback_hits = 0  # ... and the stat found the file
+        self._put_dedup_hits = 0      # put: isfile found the chunk
+        self._put_dedup_known = 0     # ... which the index also knew
 
     # ---- ChunkStore seam (CAS worker threads) ------------------------ #
 
@@ -156,6 +166,22 @@ class IndexPlane:
     def lookup(self, digest: str) -> bool:
         return self.lsi.lookup(digest)
 
+    def note_stat_fallback(self, found: bool) -> None:
+        """``ChunkStore.has``: a negative index answer went to the stat
+        backstop; ``found`` when the stat contradicted the index (the
+        caller re-records the digest, so it counts once)."""
+        with self._seam_mu:
+            self._stat_fallbacks += 1
+            self._stat_fallback_hits += found
+
+    def note_put_dedup(self, hits: int, known: int) -> None:
+        """A put batch's dedup hits found by ``isfile``, and how many
+        of them a lookup would have answered: whether that pre-check
+        is worth routing through the index."""
+        with self._seam_mu:
+            self._put_dedup_hits += hits
+            self._put_dedup_known += known
+
     # ---- lifecycle --------------------------------------------------- #
 
     def open_or_rebuild(self, cas_digests) -> dict:
@@ -178,7 +204,14 @@ class IndexPlane:
                "probeRpcsSkipped": self.probe_rpcs_skipped,
                "filterTrusted": self.trusted,
                "filterFp": self.peer_filters.fp_observed,
-               "echoTrusted": self.echo_trusted}
+               "echoTrusted": self.echo_trusted,
+               "placementConsidered": self.place_considered,
+               "placementSkipped": self.place_skipped}
+        with self._seam_mu:
+            out.update(statFallbacks=self._stat_fallbacks,
+                       statFallbackHits=self._stat_fallback_hits,
+                       putDedupHits=self._put_dedup_hits,
+                       putDedupIndexKnown=self._put_dedup_known)
         if self.local_filter is not None:
             out["filter"] = self.local_filter.stats()
             out["peerFilters"] = self.peer_filters.stats()

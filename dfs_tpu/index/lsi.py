@@ -222,6 +222,10 @@ class DigestIndex:
         self._closed = False
         self._compact_stall_s = 0.0   # merge seconds paid by callers
         self._bg_compact_s = 0.0      # merge seconds on the thread
+        self._lookups = 0             # lookup() calls answered
+        self._lookup_hits = 0         # ... with "present"
+        self._lookup_s = 0.0          # seconds inside them, summed
+                                      # over the calling threads
 
     # ---------------------------------------------------------------- #
     # open / rebuild
@@ -679,24 +683,34 @@ class DigestIndex:
         reader."""
         if not is_hex_digest(digest):
             return False
+        t0 = time.perf_counter()
         raw = bytes.fromhex(digest)
         prefix = int.from_bytes(raw[:8], "big")
         with self._lock:
             state = self._memtable.get(raw)
             if state is not None:
-                return state != _DELETED
+                return self._count_lookup_locked(state != _DELETED, t0)
             runs = list(reversed(self._runs))   # newest first
             for r in runs:
                 r.refs += 1
+        found = False
         try:
             for run in runs:
                 state = run.get(raw, prefix)
                 if state is not None:
-                    return state != _DELETED
-            return False
+                    found = state != _DELETED
+                    break
+            return found
         finally:
             with self._lock:
                 self._unpin_locked(runs)
+                self._count_lookup_locked(found, t0)
+
+    def _count_lookup_locked(self, found: bool, t0: float) -> bool:
+        self._lookups += 1
+        self._lookup_hits += found
+        self._lookup_s += time.perf_counter() - t0
+        return found
 
     def _unpin_locked(self, runs) -> None:
         for r in runs:
@@ -781,4 +795,10 @@ class DigestIndex:
                 # second (and ``compactions``) grows
                 "compactStallS": round(self._compact_stall_s, 6),
                 "bgCompactS": round(self._bg_compact_s, 6),
+                # what the index answered: every lookup() is a hit
+                # ("present") or a miss, and lookupS the seconds spent
+                # inside them on the calling (CAS worker) threads
+                "lookups": self._lookups,
+                "lookupHits": self._lookup_hits,
+                "lookupS": round(self._lookup_s, 6),
             }

@@ -34,7 +34,7 @@ import threading
 import time
 import types
 from collections import deque
-from typing import Mapping, Sequence
+from typing import Collection, Mapping, Sequence
 
 from dfs_tpu.comm.rpc import (DeadlineExpired, InternalClient, RpcError,
                               RpcRemoteError, RpcUnreachable)
@@ -2075,8 +2075,10 @@ class StorageNodeServer:
         filter RULES OUT skip the probe and transfer directly; filter
         POSITIVES are — when ``ledger`` is given — credited as trusted
         copies (probe and transfer both skipped; the caller MUST run
-        :meth:`_verify_trusted` on the ledger before acking) or, with
-        no ledger, probed as before minus the ruled-out payload."""
+        :meth:`_verify_trusted` on the ledger before acking), except
+        that a chunk nothing else would vouch for is put to one of its
+        peers (``filter_credits``) or, with no ledger, probed as before
+        minus the ruled-out payload."""
         if self.chaos is not None:
             self.chaos.maybe_crash("place.before_local_put")
         # placement snapshot: ONE ring map for the whole batch — a
@@ -2143,72 +2145,110 @@ class StorageNodeServer:
         # repeated handoff probes cannot double-count one transfer
         counted: set[tuple[int, str]] = set()
 
+        def filter_credits() -> dict[int, set[str]]:
+            """The filter positives each primary leg may credit
+            unasked (docs/index.md §3), settled before a leg starts.
+            A chunk this node does not own, which no leg would be sent
+            or asked about, would be credited by every filter and
+            stored nowhere this node can vouch for — and its payload
+            leaves with the batch, so the pre-ack verify round could
+            name two false positives but heal neither. The first leg
+            that would credit such a chunk asks its peer instead, side
+            by side with the other legs; the rest may credit it."""
+            plane = self.index
+            maybe: dict[int, set[str]] = {}
+            if ledger is None or plane is None \
+                    or plane.local_filter is None:
+                return maybe
+            cache = plane.echo_cache
+            real = {d for d, _ in local_puts}
+            for nid, wanted in per_node.items():
+                if not self.health.is_alive(nid):
+                    continue             # a corpse backs nothing
+                if plane.peer_filters.state(nid) is None:
+                    real.update(d for d, _ in wanted)    # all probed
+                    continue
+                for d, _ in wanted:
+                    if cache is not None and cache.confirmed(nid, d) \
+                            or plane.peer_filters.contains(nid, d) is False:
+                        real.add(d)      # echo on record, or to be sent
+                    else:
+                        maybe.setdefault(nid, set()).add(d)
+            for ds in maybe.values():
+                asks = ds - real
+                ds -= asks
+                real |= asks
+            return maybe
+
         async def replicate(node_id: int,
-                            wanted: list[tuple[str, bytes]]) -> None:
+                            wanted: list[tuple[str, bytes]],
+                            credit: Collection[str] = ()) -> None:
+            # ``credit``: the filter positives this leg may take on
+            # trust; a handoff leg is given none and asks about each
             peer = self.cfg.cluster.peer(node_id)
             # Known-dead peers get one fast probe instead of the full retry
             # envelope (health registry, SURVEY.md §5.3).
             retries = None if self.health.is_alive(node_id) else 1
-            # peer-filter consultation (docs/index.md): split this
-            # peer's list into ruled-out (definitely absent — transfer
-            # without probing), trusted (filter-positive under a
-            # ledger — probe AND transfer skipped, verified pre-ack),
-            # and to-probe. A dead peer's filter is never trusted (a
-            # stale summary crediting copies on a corpse is exactly
-            # the phantom the health registry exists to prevent); no
-            # replica of the peer's filter = the pre-index path.
             plane = self.index
             cache = plane.echo_cache if plane is not None else None
             trusted: set[str] = set()
-            # echo-cache consult first (ISSUE 16 satellite): a digest
-            # this peer hash-echo-confirmed THIS SESSION under the
-            # current epoch is first-party evidence, stronger than a
-            # bloom positive — credit the copy with NO ledger entry,
-            # skipping the probe AND the pre-ack verify round. Dead
-            # peers never qualify (same rule as filter trust).
-            remaining = wanted
-            if cache is not None and retries is None:
-                echoed_skip = 0
-                remaining = []
-                for d, b in wanted:
-                    if cache.confirmed(node_id, d):
-                        echoed_skip += 1
-                        copies[d] += 1
-                        if (node_id, d) not in counted:
-                            counted.add((node_id, d))
-                            stats["dedupSkippedBytes"] += len(b)
-                    else:
-                        remaining.append((d, b))
-                if echoed_skip:
-                    plane.echo_trusted += echoed_skip
-                    plane.probes_skipped += echoed_skip
-            filtered = False
-            to_probe = remaining
-            if plane is not None and plane.local_filter is not None \
-                    and retries is None \
-                    and plane.peer_filters.state(node_id) is not None:
-                filtered = True
-                ruled_out = 0
-                to_probe = []
-                for d, b in remaining:
-                    verdict = plane.peer_filters.contains(node_id, d)
-                    if verdict is False:
-                        ruled_out += 1       # straight to transfer
-                    elif ledger is not None:
-                        trusted.add(d)
-                        copies[d] += 1
-                        ledger.credit(node_id, d, len(b))
-                        if (node_id, d) not in counted:
-                            counted.add((node_id, d))
-                            stats["dedupSkippedBytes"] += len(b)
-                    else:
-                        to_probe.append((d, b))
-                plane.probes_skipped += ruled_out + len(trusted)
-                plane.trusted += len(trusted)
-                if not to_probe and remaining:
-                    plane.probe_rpcs_skipped += 1
-            digests = [d for d, _ in to_probe]
-            try:
+
+            async def probe() -> tuple[list, set[str], list | None]:
+                """This leg's existence check: what is still to be
+                weighed after the echo cache, what the peer answered it
+                has, and the payload slices staged meanwhile."""
+                # echo-cache consult first (ISSUE 16 satellite): a
+                # digest this peer hash-echo-confirmed THIS SESSION
+                # under the current epoch is first-party evidence,
+                # stronger than a bloom positive — credit the copy with
+                # NO ledger entry, skipping the probe AND the pre-ack
+                # verify round. Dead peers never qualify (same rule as
+                # filter trust).
+                remaining = wanted
+                if cache is not None and retries is None:
+                    echoed_skip = 0
+                    remaining = []
+                    for d, b in wanted:
+                        if cache.confirmed(node_id, d):
+                            echoed_skip += 1
+                            copies[d] += 1
+                            if (node_id, d) not in counted:
+                                counted.add((node_id, d))
+                                stats["dedupSkippedBytes"] += len(b)
+                        else:
+                            remaining.append((d, b))
+                    if echoed_skip:
+                        plane.echo_trusted += echoed_skip
+                        plane.probes_skipped += echoed_skip
+                filtered = False
+                to_probe = remaining
+                if plane is not None and plane.local_filter is not None \
+                        and retries is None \
+                        and plane.peer_filters.state(node_id) is not None:
+                    filtered = True
+                    ruled_out = 0
+                    to_probe = []
+                    for d, b in remaining:
+                        if d in credit:
+                            trusted.add(d)
+                            copies[d] += 1
+                            ledger.credit(node_id, d, len(b))
+                            if (node_id, d) not in counted:
+                                counted.add((node_id, d))
+                                stats["dedupSkippedBytes"] += len(b)
+                        elif plane.peer_filters.contains(
+                                node_id, d) is False:
+                            ruled_out += 1       # straight to transfer
+                        else:
+                            to_probe.append((d, b))
+                    plane.probes_skipped += ruled_out + len(trusted)
+                    plane.trusted += len(trusted)
+                    if not to_probe and remaining:
+                        plane.probe_rpcs_skipped += 1
+                digests = [d for d, _ in to_probe]
+                if plane is not None:
+                    plane.place_considered += len(wanted)
+                    plane.place_skipped += len(wanted) - len(digests)
                 staged = None
                 have: set[str] = set()
                 if to_probe and not filtered:
@@ -2217,7 +2257,7 @@ class StorageNodeServer:
                     # dedups, so the optimistic staging is usually
                     # final; a dedup hit restages only the missing
                     # remainder
-                    probe = asyncio.create_task(self.client.call(
+                    call = asyncio.create_task(self.client.call(
                         peer, {"op": "has_chunks", "digests": digests},
                         retries=retries))
                     try:
@@ -2231,9 +2271,9 @@ class StorageNodeServer:
                         staged = await asyncio.to_thread(
                             self._slice_payloads, remaining,
                             self._REPLICA_SLICE_BYTES)
-                        resp, _ = await probe
+                        resp, _ = await call
                     except BaseException:
-                        probe.cancel()   # replicate cancelled/failed
+                        call.cancel()    # replicate cancelled/failed
                         raise            # first: don't orphan the probe
                     have = set(resp.get("have", []))
                 elif to_probe:
@@ -2249,6 +2289,20 @@ class StorageNodeServer:
                             # an OBSERVED false positive — counted, and
                             # overridden so a retry stops re-trusting
                             plane.peer_filters.note_fp(node_id, d)
+                return remaining, have, staged
+
+            try:
+                # peer-filter consultation (docs/index.md): split this
+                # peer's list into ruled-out (definitely absent —
+                # transfer without probing), trusted (a filter
+                # positive this leg was given to credit — probe AND
+                # transfer skipped, verified pre-ack), and to-probe. A
+                # dead peer's filter is never trusted (a stale summary
+                # crediting copies on a corpse is exactly the phantom
+                # the health registry exists to prevent); no replica of
+                # the peer's filter = the pre-index path.
+                with self.obs.span("upload.probe"):
+                    remaining, have, staged = await probe()
                 missing = [(d, b) for d, b in remaining
                            if d not in have and d not in trusted]
                 for d, b in remaining:
@@ -2361,9 +2415,11 @@ class StorageNodeServer:
 
         with self.obs.span("upload.replicate", latency=True):
             try:
+                credits = filter_credits()
                 await gather_abort_siblings(
                     put_local(local_puts),
-                    *(replicate(nid, w) for nid, w in per_node.items()))
+                    *(replicate(nid, w, credits.get(nid, ()))
+                      for nid, w in per_node.items()))
             except OSError as e:
                 self._raise_if_disk_full(e)
                 raise

@@ -335,6 +335,7 @@ class ChunkStore:
                         and self._chain_resolves(digest))
                 if present:
                     self.index.note_put(digest, defer_flush=True)
+            self.index.note_stat_fallback(present)
             if present:
                 self.index.maybe_flush()   # outside the ordering mutex
         if present:
@@ -415,6 +416,7 @@ class ChunkStore:
         fresh: list[tuple[str, str, bytes]] = []   # raw writes owed
         fresh_at: list[int] = []                   # their place in items
         queued: set[str] = set()
+        hits = known = 0    # dedup hits by isfile; known to the index
         for i, (digest, data) in enumerate(items):
             if self.fault is not None:
                 self.fault("put", digest)
@@ -423,8 +425,12 @@ class ChunkStore:
                 continue           # twice in one batch: written once
             if os.path.isfile(p):
                 self._settle(digest, p)
-                if self.index is not None \
-                        and not self.index.lookup(digest):
+                hits += 1
+                if self.index is None:
+                    continue
+                if self.index.lookup(digest):
+                    known += 1
+                else:
                     # dedup hit on a chunk the index forgot (crash-lost
                     # WAL buffer): heal here too — a repair push
                     # re-sending a restarted node its own chunks is
@@ -461,6 +467,8 @@ class ChunkStore:
             queued.add(digest)
             fresh.append((digest, p, data))
             fresh_at.append(i)
+        if hits and self.index is not None:
+            self.index.note_put_dedup(hits, known)
         if fresh:
             for i, new in zip(fresh_at, self._write_raw(fresh)):
                 results[i] = new

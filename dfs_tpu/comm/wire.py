@@ -107,8 +107,12 @@ OP_SPECS = {
     "store_chunks": {"request": ["fileId", "chunks"],
                      "reply": ["digests"],
                      "body": "request: chunk payloads (scatter-gather)"},
-    "has_chunks": {"request": ["digests"], "reply": ["have"],
-                   "body": None},
+    # residentOk (optional, placement's probes and pre-ack rounds only):
+    # the store's resident set may answer instead of a stat a digest
+    # (store/cas.py has); without it — the repair cycle, who_has, an
+    # older caller — the answer is a look at the disk
+    "has_chunks": {"request": ["digests", "residentOk"],
+                   "reply": ["have"], "body": None},
     "get_chunk": {"request": ["digest"], "reply": [],
                   "body": "reply: chunk payload"},
     "get_chunks": {"request": ["digests"], "reply": ["chunks"],

@@ -1086,12 +1086,18 @@ class StorageNodeServer:
             return {"ok": True, "digests": echoed}, b""
         if op == "has_chunks":
             digests = header.get("digests", [])
-            # ONE bounded read-pool job for the whole probe list (this
-            # used to ride the unbounded to_thread executor); with the
-            # index plane on, each answer is a memtable/run hit instead
-            # of a stat syscall — the hot probe service stops paying
-            # one filesystem touch per probed digest (docs/index.md)
-            mask = await self.cas.has_many(digests)
+            # ONE job of the CAS latency lane for the whole probe list
+            # (this used to ride the unbounded to_thread executor); with
+            # the index plane on, each answer is a memtable/run hit
+            # instead of a stat syscall (docs/index.md). With it off the
+            # answer is a look at the disk, a stat a digest — unless
+            # the CALLER sets `residentOk` (placement's probes and
+            # pre-ack rounds only): then the store's resident set may
+            # answer (store/cas.py has). A caller that does not send
+            # the key — the repair cycle, who_has, an older peer — is
+            # answered from the disk, and that look heals the set.
+            mask = await self.cas.has_many(
+                digests, resident_ok=bool(header.get("residentOk")))
             return {"ok": True,
                     "have": [d for d, h in zip(digests, mask) if h]}, b""
         if op == "get_filter":
@@ -1684,17 +1690,20 @@ class StorageNodeServer:
         """Which of ``digests`` the cluster holds NOwhere reachable —
         the resumable-upload probe (SURVEY §5.4: chunk-level resume falls
         out of the dedup index). Local CAS first — ONE batched
-        ``has_many`` job on the bounded read pool (this loop used to
+        ``has_many`` job of the CAS latency lane (this loop used to
         stat inline ON the event loop, one syscall per digest); the
         remainder is asked of each digest's replica set via batched
         has_chunks, with peer-filter-ruled-out digests never probed at
-        all. Filter POSITIVES are still probed here on purpose: a
+        all. Both take a resident answer (``residentOk``): this is
+        placement asking, and what it is told is present is re-counted
+        before any ack (``upload_resume`` fetches or 409s). Filter
+        POSITIVES are still probed here on purpose: a
         bloom false positive answered as "cluster has it" would tell
         the client to skip bytes, and at bloom FP rates every large
         resume would then trip upload_resume's 409 fallback — the
         probe is cheaper than the fallback (docs/index.md)."""
         cand = [d for d in dict.fromkeys(digests) if is_hex_digest(d)]
-        mask = await self.cas.has_many(cand)
+        mask = await self.cas.has_many(cand, resident_ok=True)
         missing = [d for d, h in zip(cand, mask) if not h]
         if not missing:
             return []
@@ -1728,7 +1737,8 @@ class StorageNodeServer:
             try:
                 resp, _ = await self.client.call(
                     self.cfg.cluster.peer(nid),
-                    {"op": "has_chunks", "digests": ds}, retries=1)
+                    {"op": "has_chunks", "digests": ds,
+                     "residentOk": True}, retries=1)
                 found.update(resp.get("have", []))
             except RpcError:
                 # best-effort: an unanswered probe only makes the client
@@ -1883,7 +1893,7 @@ class StorageNodeServer:
         digests = list(dict.fromkeys(dg for _, _, dg in table))
         copies = {d: 0 for d in digests}
         # local holdings first (this node is an owner for its arc)
-        mask = await self.cas.has_many(digests)
+        mask = await self.cas.has_many(digests, resident_ok=True)
         for d, h in zip(digests, mask):
             if h:
                 copies[d] += 1
@@ -1899,7 +1909,8 @@ class StorageNodeServer:
             try:
                 resp, _ = await self.client.call(
                     self.cfg.cluster.peer(nid),
-                    {"op": "has_chunks", "digests": ds},
+                    {"op": "has_chunks", "digests": ds,
+                     "residentOk": True},
                     retries=None if self.health.is_alive(nid) else 1)
                 self.health.mark_alive(nid)
                 return set(resp.get("have", []))
@@ -2258,7 +2269,8 @@ class StorageNodeServer:
                     # final; a dedup hit restages only the missing
                     # remainder
                     call = asyncio.create_task(self.client.call(
-                        peer, {"op": "has_chunks", "digests": digests},
+                        peer, {"op": "has_chunks", "digests": digests,
+                               "residentOk": True},
                         retries=retries))
                     try:
                         # staging runs on a worker thread so it is
@@ -2280,7 +2292,8 @@ class StorageNodeServer:
                     # filter-trimmed probe: only what the filter could
                     # not rule out goes over the wire
                     resp, _ = await self.client.call(
-                        peer, {"op": "has_chunks", "digests": digests},
+                        peer, {"op": "has_chunks", "digests": digests,
+                               "residentOk": True},
                         retries=retries)
                     have = set(resp.get("have", []))
                     for d in digests:
@@ -2646,7 +2659,8 @@ class StorageNodeServer:
                 digests = sorted(entries)
                 try:
                     resp, _ = await self.client.call(
-                        peer, {"op": "has_chunks", "digests": digests})
+                        peer, {"op": "has_chunks", "digests": digests,
+                               "residentOk": True})
                     self.health.mark_alive(node_id)
                 except RpcError as e:
                     # the peer answered the filter sync but not the
@@ -4112,10 +4126,13 @@ class StorageNodeServer:
         ``fsyncs`` counts the chunk files the store made durable (payload
         fsync'd, linked, directory fsync'd — one per file, before its put
         returned), ``dirBarriers`` the directory fsyncs that took: one
-        per distinct directory of a batch, not one per file."""
+        per distinct directory of a batch, not one per file;
+        ``resident*`` how often the store's resident set answered an
+        existence check in place of a ``stat`` (index off)."""
         return {"mode": self.cfg.durability.mode,
                 "fsyncs": self.store.chunks.fsync_count(),
-                "dirBarriers": self.store.chunks.dir_barrier_count()}
+                "dirBarriers": self.store.chunks.dir_barrier_count(),
+                **self.store.chunks.resident_stats()}
 
     def chaos_stats(self) -> dict:
         """``/metrics`` ``chaos`` section: active knobs + per-kind

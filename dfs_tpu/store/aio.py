@@ -129,22 +129,29 @@ class AsyncChunkStore:
             self._wpool,
             lambda: self.store.put(digest, data, verify=verify), "cas.put")
 
-    async def has_many(self, digests: Sequence[str]) -> list[bool]:
+    async def has_many(self, digests: Sequence[str],
+                       resident_ok: bool = False) -> list[bool]:
         """Batched local existence — ONE worker job for the whole
         probe list. The ``has_chunks`` server path and the resume
         probe used to pay a per-digest job (or, worse, inline loop
         stats); a hot probe service must cost one worker dispatch per
         LIST. Each ``has`` rides the index fast path when the dedup
-        plane is on (store/cas.py) and a stat otherwise. On the
-        LATENCY lane (``cas-g``), not the batch-read lane: a probe is
-        stats/index hits — microseconds — and peers time budget it
-        like a metadata op, so it must never queue behind a
-        multi-second ``get_many`` gather."""
+        plane is on (store/cas.py). With it off, a caller that says a
+        resident answer will do (``resident_ok``: placement's probes
+        and pre-ack rounds) is answered from the store's resident set —
+        microseconds a list once the store has linked or seen the
+        names — and everyone else by a ``stat`` a digest, which on a
+        busy file system measured 0.67 ms each (PERF.md §6, PR 28): a
+        repair slice of 2 048 digests is over a second of one worker.
+        On the LATENCY lane (``cas-g``), not the batch-read lane:
+        peers time budget a probe like a metadata op, so it must never
+        queue behind a multi-second ``get_many`` gather."""
         if not digests:
             return []
         ds = list(digests)
         return await self._run(
-            self._gpool, lambda: self.store.has_many(ds), "cas.has_many")
+            self._gpool, lambda: self.store.has_many(ds, resident_ok),
+            "cas.has_many")
 
     async def get_many(self, digests: Sequence[str]
                        ) -> list[tuple[str, bytes]]:

@@ -78,6 +78,11 @@ def _atomic_write(path: Path | str, data: bytes,
 
 _TMP_SWEEP_AGE_S = 3600.0
 
+# bound of ChunkStore's resident set: the source's deployment holds
+# ~870 000 digests a node (PERF.md §4) -> 2**20 entries, ~100 MB at the
+# worst; past it the set is emptied and refills from stats
+_RESIDENT_MAX = 1 << 20
+
 
 def _sweep_tmp_files(dirs, max_age_s: float = _TMP_SWEEP_AGE_S) -> int:
     """Unlink ``.tmp-*`` entries older than ``max_age_s`` in the given
@@ -142,6 +147,17 @@ class ChunkStore:
         # (_settle). Entered BEFORE the link, so a visible name is always
         # found here until a barrier covers it.
         self._unbarriered: set[str] = set()
+        # what is on the disk, remembered (index off only): raw 32-byte
+        # digests whose raw file this process linked, or found by a stat,
+        # and has not unlinked since. Positives only — an absent name is
+        # never cached. The put pre-check and has(resident_ok=True)
+        # answer from it in place of the stat; every other look at the
+        # disk heals it. Entered and discarded-before-the-unlink under
+        # _index_mu; dies with the process.
+        self._resident: set[bytes] = set()
+        self._unlinks = 0                  # chunk unlinks ended
+        # resident answers / went on to a stat / entries found stale
+        self._res_hits = self._res_misses = self._res_drops = 0
         self._count_lock = threading.Lock()   # puts run on CAS pool workers
         # orders the visible link/unlink against its index record: a
         # put racing a delete of the SAME digest could otherwise
@@ -304,8 +320,77 @@ class ChunkStore:
             cur = base
         return False
 
-    def has(self, digest: str) -> bool:
-        """Local existence. With the index plane attached, a positive
+    # -- the resident set (index off) ----------------------------------
+
+    def _remember(self, key: bytes, seen: int | None = None) -> None:
+        """The raw name of ``key`` was just linked or seen. The caller
+        holds ``_index_mu`` — the lock ``delete`` holds from its discard
+        to the end of its unlink — so no entry outlives its file. A name
+        seen by a ``stat`` outside that lock passes ``seen``, the count
+        of unlinks when its look began: if one ended since, it may have
+        been this name's, and nothing is entered (the next look does)."""
+        if self.index is not None:
+            return
+        with self._count_lock:
+            if seen is not None and seen != self._unlinks:
+                return
+            if len(self._resident) >= _RESIDENT_MAX:
+                self._resident.clear()
+            self._resident.add(key)
+
+    def _forget(self, key: bytes) -> None:
+        """The disk says the raw file of ``key`` is not there."""
+        with self._count_lock:
+            if key in self._resident:
+                self._resident.remove(key)
+                self._res_drops += 1
+
+    def _raw_present(self, digest: str, p: str, resident_ok: bool) -> bool:
+        """Index off: is the raw file of ``digest`` there? With
+        ``resident_ok`` a resident entry is the answer and no ``stat``
+        is issued. Otherwise one ``stat``, which heals the set both
+        ways: "absent" drops the entry, "present" enters it."""
+        key = bytes.fromhex(digest)
+        with self._count_lock:
+            seen = self._unlinks
+            known = key in self._resident
+            if resident_ok:
+                if known:
+                    self._res_hits += 1
+                    return True
+                self._res_misses += 1
+        if not os.path.isfile(p):
+            if known:
+                self._forget(key)
+            return False
+        if not known:
+            with self._index_mu:
+                self._remember(key, seen)
+        return True
+
+    def resident_stats(self) -> dict:
+        """``/metrics`` ``durability.resident*``: existence checks the
+        resident set answered, those that went on to a ``stat``, entries
+        held now, entries dropped because the disk disagreed."""
+        with self._count_lock:
+            return {"residentHits": self._res_hits,
+                    "residentMisses": self._res_misses,
+                    "residentEntries": len(self._resident),
+                    "residentDrops": self._res_drops}
+
+    def has(self, digest: str, resident_ok: bool = False) -> bool:
+        """Local existence. With the index plane off the answer is a look
+        at the disk — unless the caller says a resident answer will do
+        (``resident_ok``: placement's probes and pre-ack rounds), and the
+        raw name is in the resident set: this process linked or saw it
+        and has not begun to unlink it since (``delete`` discards before
+        the unlink, under the mutex that orders both). The argument is
+        the index's below, minus persistence — so minus its crash cases;
+        the one caveat is the same external directory mutation, and a
+        look without ``resident_ok`` (the repair cycle's, every cycle)
+        drops what the disk no longer has.
+
+        With the index plane attached, a positive
         index answer is final — puts are recorded only AFTER the link
         is visible and deletes BEFORE the unlink (see ``put`` /
         ``delete``), so "present" in the index implies the file was
@@ -323,7 +408,7 @@ class ChunkStore:
         re-indexes everything it touches."""
         p = self._path_str(digest)
         if self.index is None:
-            present = os.path.isfile(p) \
+            present = self._raw_present(digest, p, resident_ok) \
                 or (self._deltas_possible()
                     and self._chain_resolves(digest))
         elif self.index.lookup(digest):
@@ -344,11 +429,11 @@ class ChunkStore:
             self._settle(digest, p)
         return present
 
-    def has_many(self, digests) -> list[bool]:
+    def has_many(self, digests, resident_ok: bool = False) -> list[bool]:
         """Batched :meth:`has` — one call for a whole probe list, so
         async callers pay one thread-pool job instead of one per
         digest (:meth:`AsyncChunkStore.has_many`)."""
-        return [self.has(d) for d in digests]
+        return [self.has(d, resident_ok) for d in digests]
 
     def put(self, digest: str, data: bytes, verify: bool = True,
             sketch=None) -> bool:
@@ -423,11 +508,14 @@ class ChunkStore:
             p = self._path_str(digest)
             if digest in queued:
                 continue           # twice in one batch: written once
-            if os.path.isfile(p):
+            if self.index is None:
+                # resident, or one stat: a dedup hit either way
+                if self._raw_present(digest, p, True):
+                    self._settle(digest, p)
+                    continue
+            elif os.path.isfile(p):
                 self._settle(digest, p)
                 hits += 1
-                if self.index is None:
-                    continue
                 if self.index.lookup(digest):
                     known += 1
                 else:
@@ -467,7 +555,7 @@ class ChunkStore:
             queued.add(digest)
             fresh.append((digest, p, data))
             fresh_at.append(i)
-        if hits and self.index is not None:
+        if hits:
             self.index.note_put_dedup(hits, known)
         if fresh:
             for i, new in zip(fresh_at, self._write_raw(fresh)):
@@ -543,7 +631,9 @@ class ChunkStore:
                     try:
                         os.link(temps[k], p)
                     except FileExistsError:
-                        continue               # dedup hit
+                        # dedup hit: the name is there, as a stat would say
+                        self._remember(bytes.fromhex(digest))
+                        continue
                     except OSError as e:
                         # filesystem without hard links: fall back to
                         # atomic rename. Loses the exactly-one-True race
@@ -569,6 +659,7 @@ class ChunkStore:
                         # the mutex drops (below) — a multi-second merge
                         # inside it would freeze every CAS worker.
                         self.index.note_put(digest, defer_flush=True)
+                    self._remember(bytes.fromhex(digest))
                 new[k] = True
                 nlinked += 1
                 nbytes += len(data)
@@ -773,6 +864,7 @@ class ChunkStore:
             with open(self._path_str(digest), "rb") as f:
                 return f.read()
         except FileNotFoundError:
+            self._forget(bytes.fromhex(digest))    # no raw file: no entry
             if not self._deltas_possible():
                 return None
             return self._get_delta(digest, 0)
@@ -846,7 +938,16 @@ class ChunkStore:
                     # direction; the reverse order could persist a
                     # stale "present" for vanished bytes
                     self.index.note_delete(digest, defer_flush=True)
-                os.unlink(p)
+                # out of the resident set BEFORE the unlink, and the
+                # unlink counted once it ended: a stat that saw the name
+                # just before enters nothing after this (_raw_present)
+                with self._count_lock:
+                    self._resident.discard(bytes.fromhex(digest))
+                try:
+                    os.unlink(p)
+                finally:
+                    with self._count_lock:
+                        self._unlinks += 1
             with self._count_lock:
                 if self._count is not None:
                     self._count -= 1
@@ -856,6 +957,7 @@ class ChunkStore:
                 self.index.maybe_flush()   # outside the ordering mutex
             return True
         except FileNotFoundError:
+            self._forget(bytes.fromhex(digest))    # no raw file: no entry
             if self._deltas_possible():
                 return self._drop_delta(digest)
             return False

@@ -424,6 +424,206 @@ def test_repair_probes_a_peer_in_bounded_slices(tmp_path, rng, monkeypatch):
 
 
 # ---------------------------------------------------------------------- #
+# the resident set on the wire (PR 28): placement's has_chunks carries
+# `residentOk`, every other caller is answered from the disk
+# ---------------------------------------------------------------------- #
+
+def _chunk_stats(monkeypatch, nodes):
+    """Record every ``stat`` of a chunk file name under a node's chunk
+    store: (node id, digest), in order."""
+    real = os.stat
+    roots = {os.fspath(n.store.chunks.root): nid
+             for nid, n in nodes.items()}
+    seen = []
+
+    def stat(path, *a, **kw):
+        if isinstance(path, (str, os.PathLike)):
+            head, name = os.path.split(os.fspath(path))
+            nid = roots.get(os.path.dirname(head))
+            if nid is not None and len(name) == 64:
+                seen.append((nid, name))
+        return real(path, *a, **kw)
+
+    monkeypatch.setattr(os, "stat", stat)
+    return seen
+
+
+def test_reupload_through_another_coordinator_stats_no_known_chunk(
+        tmp_path, rng, monkeypatch):
+    """What every node holds it linked itself: a re-upload through
+    another coordinator — its own pre-check, both peers' has_chunks —
+    issues no ``stat`` for a chunk name on any node, where the first
+    upload paid one a digest a holder; /metrics says so."""
+    data = rng.integers(0, 256, size=300_000, dtype=np.uint8).tobytes()
+
+    async def run():
+        cluster = _cluster_cfg(3, rf=2)
+        nodes = await _start(cluster, tmp_path)
+        try:
+            seen = _chunk_stats(monkeypatch, nodes)
+            manifest, _ = await nodes[1].upload(data, "first.bin")
+            digests = {c.digest for c in manifest.chunks}
+            assert len(digests) > 300
+            # first sight: the holders' put pre-checks and the probes
+            assert {d for _, d in seen} == digests
+            assert all(n.durability_stats()["residentHits"] == 0
+                       for n in nodes.values())
+            held = {nid: set(n.store.chunks.digests())
+                    for nid, n in nodes.items()}
+            seen.clear()
+            again, stats = await nodes[2].upload(data, "first.bin")
+            assert again.file_id == manifest.file_id
+            assert seen == []
+            assert stats["transferredBytes"] == 0
+            for nid, n in nodes.items():
+                dur = n.durability_stats()
+                assert dur["residentEntries"] == len(held[nid]) > 0
+                assert dur["residentHits"] >= len(held[nid])
+                assert dur["residentDrops"] == 0
+                assert set(n.store.chunks.digests()) == held[nid]
+            assert sum(n.durability_stats()["residentHits"]
+                       for n in nodes.values()) >= 2 * len(digests)
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
+def test_repair_restores_a_file_removed_behind_a_store_that_remembers_it(
+        tmp_path, rng, monkeypatch):
+    """The one caveat and its bound: a chunk file unlinked behind the
+    peer's store, after the store memoised it, is still "present" to a
+    caller that takes a resident answer — and ONE repair cycle, which
+    looks at the disk, drops the entry, pushes, and the push's pre-check
+    misses and writes: exactly that chunk, byte-identical."""
+    data = rng.integers(0, 256, size=200_000, dtype=np.uint8).tobytes()
+
+    async def run():
+        cluster = _cluster_cfg(2, rf=2)
+        nodes = await _start(cluster, tmp_path)
+        try:
+            manifest, _ = await nodes[1].upload(data, "r.bin")
+            digests = sorted({c.digest for c in manifest.chunks})
+            victim = digests[len(digests) // 2]
+            ch = nodes[2].store.chunks
+            payload = ch.get(victim)
+            assert ch.has(victim, resident_ok=True)
+            os.unlink(ch._path_str(victim))         # behind its back
+            assert ch.has(victim, resident_ok=True)  # the caveat
+            asked = []
+            real = nodes[2]._dispatch
+
+            async def dispatch(header, body):
+                if header.get("op") == "has_chunks":
+                    asked.append(header)
+                return await real(header, body)
+
+            monkeypatch.setattr(nodes[2], "_dispatch", dispatch)
+            seen = _chunk_stats(monkeypatch, nodes)
+            assert await nodes[1].repair_once() == 1
+            assert asked and not any("residentOk" in h for h in asked)
+            assert sorted(d for h in asked for d in h["digests"]) \
+                == digests
+            # the cycle looked at every name on the peer's disk
+            assert {d for nid, d in seen if nid == 2} == set(digests)
+            assert os.path.isfile(ch._path_str(victim))
+            assert ch.get(victim) == payload
+            assert ch.resident_stats()["residentDrops"] == 1
+            assert sorted(ch.digests()) == digests
+            assert await nodes[1].repair_once() == 0
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
+def test_has_chunks_without_the_key_is_answered_from_the_disk(
+        tmp_path, rng):
+    """An older caller — and the repair cycle, who_has, relocation, the
+    smart client — sends no `residentOk`: its answer is a look at the
+    disk, which heals the set for the callers that do."""
+    async def run():
+        cluster = _cluster_cfg(1, rf=1)
+        nodes = await _start(cluster, tmp_path)
+        node = nodes[1]
+        try:
+            peer = cluster.peer(1)
+            client = InternalClient()
+            items = _items(40, seed=3, size=200)
+            await client.store_chunks(peer, "", items)
+            digests = [d for d, _ in items]
+            lost = digests[7]
+            os.unlink(node.store.chunks._path_str(lost))
+
+            async def have(**extra):
+                resp, _ = await client.call(
+                    peer, {"op": "has_chunks", "digests": digests,
+                           **extra})
+                return resp["have"]
+
+            assert await have(residentOk=True) == digests   # remembered
+            assert await have(residentOk=False) \
+                == [d for d in digests if d != lost]        # the disk
+            assert await have(residentOk=True) \
+                == [d for d in digests if d != lost]        # healed
+            os.unlink(node.store.chunks._path_str(digests[8]))
+            assert await have() \
+                == [d for d in digests if d not in (lost, digests[8])]
+            assert node.durability_stats()["residentDrops"] == 2
+            assert node.durability_stats()["residentEntries"] == 38
+            client.close()
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
+def test_a_handler_that_does_not_know_the_key_ignores_it(
+        tmp_path, rng, monkeypatch):
+    """A peer running the parent's handler reads `digests` alone: an
+    upload placed through it succeeds, dedups, and is repaired as
+    before; the key rides placement's calls only."""
+    data = rng.integers(0, 256, size=200_000, dtype=np.uint8).tobytes()
+
+    async def run():
+        cluster = _cluster_cfg(2, rf=2)
+        nodes = await _start(cluster, tmp_path)
+        try:
+            old = nodes[2]
+            real = old._dispatch
+            asked = []
+
+            async def parents_dispatch(header, body):
+                if header.get("op") != "has_chunks":
+                    return await real(header, body)
+                asked.append("residentOk" in header)
+                digests = header.get("digests", [])
+                mask = await old.cas.has_many(digests)
+                return {"ok": True, "have": [
+                    d for d, h in zip(digests, mask) if h]}, b""
+
+            monkeypatch.setattr(old, "_dispatch", parents_dispatch)
+            manifest, stats = await nodes[1].upload(data, "o.bin")
+            assert stats["minCopies"] == 2 and asked and all(asked)
+            _, stats2 = await nodes[1].upload(data, "o.bin")
+            assert stats2["transferredBytes"] == 0
+            assert old.durability_stats()["residentHits"] == 0
+            n_placed = len(asked)
+            assert await nodes[1].repair_once() == 0
+            assert len(asked) > n_placed and not any(asked[n_placed:])
+            _, body = await old.download(manifest.file_id)
+            assert bytes(body) == data
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
+# ---------------------------------------------------------------------- #
 # transfer accounting: bytes counted at most once per peer, per-slice
 # crediting across primary + handoff passes
 # ---------------------------------------------------------------------- #

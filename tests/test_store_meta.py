@@ -557,7 +557,12 @@ def test_put_of_one_item_call_sequence(tmp_path, monkeypatch, fsync):
     want.append(("unlink", tmp))
     assert calls.events == want
     calls.events.clear()
-    assert cs.put(d1, data1) is False       # a dedup hit: one stat
+    assert cs.put(d1, data1) is False       # a dedup hit on a name this
+    assert calls.events == []               # store linked: resident (PR 28)
+    other = ChunkStore(cs.root, fsync=fsync)    # another life: one stat,
+    calls.events.clear()                        # and then none
+    assert other.put(d1, data1) is False
+    assert other.put(d1, data1) is False
     assert calls.events == [("stat", final)]
 
 
@@ -631,3 +636,353 @@ def test_barrier_dirs_covers_what_a_dead_life_left(tmp_path, monkeypatch,
     before = len(calls.of("fsync"))
     assert cs.put_batch(items[:5]) == [False] * 5
     assert len(calls.of("fsync")) == before and cs.fsync_count() == 0
+
+
+# ---------------------------------------------------------------------- #
+# the resident set: what is on the disk, remembered (PR 28) — index off;
+# with the index plane attached, the parent's calls to the letter
+# ---------------------------------------------------------------------- #
+
+def _key(d):
+    return bytes.fromhex(d)
+
+
+def _stale(cs):
+    """Resident entries whose raw file is not on the disk."""
+    import os
+    return [k.hex() for k in cs._resident
+            if not os.path.isfile(cs._path_str(k.hex()))]
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_resident_after_put_and_after_a_positive_look(tmp_path, fsync):
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    items = _batch(30)
+    assert all(cs.put_batch(items))
+    assert cs._resident == {_key(d) for d, _ in items}
+    assert cs.resident_stats() == {
+        "residentHits": 0, "residentMisses": 30, "residentEntries": 30,
+        "residentDrops": 0}                 # the pre-check's 30 stats
+    # another life knows nothing; each positive look at the disk enters
+    # one, with the caller's leave to answer from the set or without it
+    other = ChunkStore(cs.root, fsync=fsync)
+    assert other._resident == set()
+    assert other.has(items[0][0]) is True
+    assert other.has(items[1][0], resident_ok=True) is True
+    assert other._resident == {_key(items[0][0]), _key(items[1][0])}
+    assert other.has_many([d for d, _ in items], resident_ok=True) \
+        == [True] * 30
+    assert other._resident == cs._resident
+    assert other.resident_stats() == {
+        "residentHits": 2, "residentMisses": 29, "residentEntries": 30,
+        "residentDrops": 0}
+    # a dedup hit that loses the link enters the name as a stat would
+    late = ChunkStore(cs.root, fsync=fsync)
+    d, data = items[2]
+    assert late._write_raw([(d, late._path_str(d), data)]) == [False]
+    assert late._resident == {_key(d)}
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_an_absent_answer_is_never_cached(tmp_path, fsync):
+    import threading
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    (d, data), = _batch(1, seed=4)
+    for ok in (True, False, True):
+        assert cs.has(d, resident_ok=ok) is False
+    assert cs._resident == set()
+    t = threading.Thread(target=cs.put, args=(d, data))
+    t.start()
+    t.join(10)
+    assert cs.has(d, resident_ok=True) is True      # the very next call
+    assert cs.delete(d) is True
+    assert cs.has(d, resident_ok=True) is False and cs._resident == set()
+    assert cs.put(d, data) is True                  # written again
+    assert cs.has(d, resident_ok=True) is True and cs.get(d) == data
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("via", ["delete", "gc"])
+def test_entries_leave_before_the_unlink(tmp_path, monkeypatch, fsync, via):
+    ns = NodeStore(tmp_path, 1, fsync=fsync)
+    cs = ns.chunks
+    items = _batch(12, seed=5)
+    assert all(cs.put_batch(items))
+    calls = _Calls(monkeypatch, cs.root)
+    seen = []
+
+    def on_call(call, path):
+        if call == "unlink" and not _is_temp(path):
+            d = path.rsplit("/", 1)[1]
+            seen.append((d, _key(d) in cs._resident,
+                         cs._index_mu.locked()))
+
+    calls.on_call = on_call
+    if via == "delete":
+        assert all(cs.delete(d) for d, _ in items)
+    else:
+        assert sorted(ns.gc()) == sorted(d for d, _ in items)
+    assert sorted(d for d, _, _ in seen) == sorted(d for d, _ in items)
+    assert all(not resident and locked for _, resident, locked in seen)
+    assert cs._resident == set() and cs._unlinks == 12
+    assert cs.has_many([d for d, _ in items], resident_ok=True) \
+        == [False] * 12
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("when", ["after_the_stat", "inside_the_delete"])
+def test_a_stat_positive_racing_a_delete_enters_nothing(
+        tmp_path, monkeypatch, fsync, when):
+    """The look saw the name; the unlink ended before the look could
+    enter it: nothing is entered, whichever side of the delete's discard
+    the stat fell on."""
+    import os
+    import threading
+    first = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    (d, data), = _batch(1, seed=6)
+    assert first.put(d, data)
+    cs = ChunkStore(first.root, fsync=fsync)    # knows nothing yet
+    final = cs._path_str(d)
+    real_stat = os.stat
+    if when == "after_the_stat":
+        once = []
+
+        def stat(path, *a, **kw):
+            out = real_stat(path, *a, **kw)
+            if str(path) == final and not once:
+                once.append(1)
+                assert cs.delete(d) is True     # between stat and entry
+            return out
+        monkeypatch.setattr(os, "stat", stat)
+        assert cs.has(d) is True                # true when it looked
+    else:
+        statted = threading.Event()
+        go = threading.Event()
+        looker = threading.Thread(
+            target=lambda: (go.wait(10), cs.has(d, resident_ok=True)))
+
+        def stat(path, *a, **kw):
+            out = real_stat(path, *a, **kw)
+            if str(path) == final \
+                    and threading.current_thread() is looker:
+                statted.set()
+            return out
+
+        real_unlink = os.unlink
+
+        def unlink(path, *a, **kw):
+            if str(path) == final:              # inside delete's mutex,
+                go.set()                        # past the discard
+                assert statted.wait(10)
+            return real_unlink(path, *a, **kw)
+
+        monkeypatch.setattr(os, "stat", stat)
+        monkeypatch.setattr(os, "unlink", unlink)
+        looker.start()
+        assert cs.delete(d) is True
+        looker.join(10)
+        assert not looker.is_alive()
+    monkeypatch.undo()
+    assert cs._resident == set() and cs._unlinks == 1
+    assert cs.has(d, resident_ok=True) is False
+    assert cs.put(d, data) is True              # the pre-check missed
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_deletes_racing_looks_from_many_threads_leave_no_stale_entry(
+        tmp_path, fsync):
+    """More threads than cores at a 10 µs switch interval: lookers enter
+    names from stats while deleters unlink and writers put them back —
+    an entry never outlives its file, and every answer that says
+    "present" from the set is backed by the disk at the end."""
+    import os
+    import sys
+    import threading
+    pool = _batch(96, seed=7, size=32)
+    ChunkStore(tmp_path / "chunks", fsync=fsync).put_batch(pool)
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)   # learns by looking
+    digests = [d for d, _ in pool]
+    n_threads = 2 * (os.cpu_count() or 4)
+    errors = []
+    stop = threading.Event()
+
+    def looker(k):
+        try:
+            while not stop.is_set():
+                cs.has_many(digests[k % 7::3], resident_ok=bool(k & 1))
+        except BaseException as e:
+            errors.append(e)
+
+    def churner(k):
+        try:
+            for _ in range(3):
+                for d, data in pool[k % 5::5]:
+                    cs.delete(d)
+                    if (int(d[:2], 16) + k) & 1:
+                        cs.put(d, data)
+        except BaseException as e:
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        lookers = [threading.Thread(target=looker, args=(k,))
+                   for k in range(n_threads)]
+        churners = [threading.Thread(target=churner, args=(k,))
+                    for k in range(n_threads)]
+        for t in lookers + churners:
+            t.start()
+        for t in churners:
+            t.join(120)
+        stop.set()
+        for t in lookers:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors
+    assert not any(t.is_alive() for t in lookers + churners)
+    assert _stale(cs) == []
+    on_disk = set(cs.digests())
+    assert 0 < len(on_disk) < 96
+    assert cs.has_many(digests, resident_ok=True) \
+        == [d in on_disk for d in digests]
+    assert cs._unbarriered == set()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("index", [False, True], ids=["index-off", "index-on"])
+def test_known_names_cost_no_stat_index_off_and_the_parents_calls_index_on(
+        tmp_path, monkeypatch, fsync, index):
+    """A leg's list of 1 700 digests the store already holds: with the
+    index off the second ``has_many`` and a ``put_batch`` of 1 700 dedup
+    hits issue no file-system call at all; with the index plane attached
+    the calls are the parent's — no stat for ``has`` (the index
+    answers), one ``isfile`` a digest for the put pre-check — and the
+    set stays empty."""
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    plane = None
+    if index:
+        from dfs_tpu.config import IndexConfig
+        from dfs_tpu.index import IndexPlane
+        plane = IndexPlane(IndexConfig(enabled=True), tmp_path / "plane")
+        plane.open_or_rebuild(cs.digests)
+        cs.index = plane
+    items = _batch(1700, seed=8)
+    digests = [d for d, _ in items]
+    assert all(cs.put_batch(items))
+    life = ChunkStore(cs.root, fsync=fsync)     # a peer that never wrote
+    life.index = plane
+    calls = _Calls(monkeypatch, cs.root)
+    finals = [("stat", life._path_str(d)) for d in digests]
+    try:
+        for store in (cs, life):
+            calls.events.clear()
+            assert store.has_many(digests, resident_ok=True) \
+                == [True] * 1700                    # the first list
+            first = list(calls.events)
+            calls.events.clear()
+            assert store.has_many(digests, resident_ok=True) \
+                == [True] * 1700                    # the second
+            assert calls.events == []
+            assert store.put_batch(items) == [False] * 1700
+            if index:
+                assert first == []                  # index positives
+                assert calls.events == finals       # the parent's isfile
+                assert store._resident == set()
+                assert store.resident_stats()["residentHits"] == 0
+            else:
+                assert first == ([] if store is cs else finals)
+                assert calls.events == []
+                assert len(store._resident) == 1700
+            # without the caller's leave: a look at the disk (index off)
+            calls.events.clear()
+            assert store.has_many(digests) == [True] * 1700
+            assert calls.events == ([] if index else finals)
+        if not index:
+            assert life.resident_stats() == {
+                "residentHits": 2 * 1700, "residentMisses": 1700,
+                "residentEntries": 1700, "residentDrops": 0}
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+def test_a_resident_hit_still_settles_a_name_owed_its_barrier(
+        tmp_path, monkeypatch):
+    """Between another thread's link and its directory barrier the name
+    is resident AND owed a barrier: ``has`` and the put pre-check issue
+    it before they answer, as a stat-positive did at the parent."""
+    import os
+    cs = ChunkStore(tmp_path / "chunks", fsync=True)
+    (d, data), = _batch(1, seed=10)
+    parent = os.path.dirname(cs._path_str(d))
+    os.makedirs(parent)
+    calls = _Calls(monkeypatch, cs.root)
+    answers = []
+
+    def on_call(call, path):
+        # the writer's own directory barrier is about to be issued: the
+        # name is linked, resident, and still owed
+        if call == "fsync" and path == parent and not answers:
+            assert _key(d) in cs._resident and d in cs._unbarriered
+            answers.append(None)                # re-entrancy guard
+            before = cs.dir_barrier_count()
+            answers[:] = [cs.has(d, resident_ok=True),
+                          cs.dir_barrier_count() - before]
+            assert d not in cs._unbarriered
+            assert cs.put(d, data) is False     # settled: no second one
+            answers.append(cs.dir_barrier_count() - before)
+
+    calls.on_call = on_call
+    assert cs.put(d, data) is True
+    assert answers == [True, 1, 1]
+    assert calls.of("stat").count(("stat", cs._path_str(d))) == 1   # the
+    #                                             writer's own pre-check
+    assert cs.dir_barrier_count() == 2 and cs.fsync_count() == 1
+    assert cs.resident_stats()["residentHits"] == 2
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_overflow_empties_the_set_and_answers_stay_right(
+        tmp_path, monkeypatch, fsync):
+    import dfs_tpu.store.cas as cas
+    monkeypatch.setattr(cas, "_RESIDENT_MAX", 64)
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    items = _batch(150, seed=11)
+    digests = [d for d, _ in items]
+    assert all(cs.put_batch(items))
+    assert len(cs._resident) == 150 - 128       # emptied twice on the way
+    assert cs._resident == {_key(d) for d in digests[128:]}
+    assert cs.has_many(digests, resident_ok=True) == [True] * 150
+    assert len(cs._resident) <= 64 and _stale(cs) == []
+    assert cs.put_batch(items) == [False] * 150
+    assert cs.delete(digests[0]) and cs.delete(digests[149])
+    assert cs.has_many(digests, resident_ok=True) \
+        == [False] + [True] * 148 + [False]
+    assert cs.count() == 148 and _stale(cs) == []
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_a_look_at_the_disk_and_a_get_miss_heal_the_set(tmp_path, fsync):
+    """A file removed behind the store's back: a resident answer still
+    says present (the one caveat); the audit look, a ``get`` and a
+    ``delete`` each drop the entry, and the next put writes the file."""
+    import os
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    items = _batch(4, seed=12)
+    assert all(cs.put_batch(items))
+    for d, _ in items:
+        os.unlink(cs._path_str(d))              # behind its back
+    (a, da), (b, db), (c, dc), (e, de) = items
+    assert cs.has_many([a, b, c, e], resident_ok=True) == [True] * 4
+    assert cs.has(a) is False                   # the repair cycle's look
+    assert cs.get(b) is None
+    assert cs.delete(c) is False
+    assert cs._resident == {_key(e)}
+    assert cs.resident_stats()["residentDrops"] == 3
+    assert cs.has_many([a, b, c], resident_ok=True) == [False] * 3
+    assert cs.put_batch([(a, da), (b, db), (c, dc)]) == [True] * 3
+    assert cs.get(a) == da and cs.get(b) == db and cs.get(c) == dc
+    assert cs.put(e, de) is False               # still believed: the caveat
+    assert cs.has(e) is False and cs.put(e, de) is True
+    assert cs.get(e) == de and _stale(cs) == []

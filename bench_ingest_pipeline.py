@@ -119,7 +119,7 @@ async def _start_cluster(root: Path, p: dict, ingest: IngestConfig
                          fragmenter="cdc", cdc=p["cdc"],
                          health_probe_s=0, ingest=ingest)
         node = StorageNodeServer(cfg)
-        node._REPLICA_SLICE_BYTES = p["slice_bytes"]
+        node.placement.slice_bytes = p["slice_bytes"]
         await node.start()
         nodes[i] = node
     for i in (2, 3):   # the uploader's replica peers are the slow ones

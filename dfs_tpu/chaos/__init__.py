@@ -60,14 +60,14 @@ from dfs_tpu.config import ChaosConfig
 # upload, ``demote.*`` points fire only during a tiering demotion
 # (exercised by tests/test_tiering.py).
 CRASH_POINTS = frozenset({
-    # _place_batch: before any local CAS put of the batch
+    # Placement.place: before any local CAS put of the batch
     "place.before_local_put",
-    # _place_batch: local puts + replication done, before quorum check
+    # BatchPlacement.run: local puts + replication done, before quorum check
     "place.after_replicate",
-    # _finalize_upload: chunks durable, manifest NOT yet written — the
+    # Ingest._finalize: chunks durable, manifest NOT yet written — the
     # classic "after CAS put, before manifest" torn-upload window
     "upload.before_manifest",
-    # _finalize_upload: manifest written (upload is durable), before
+    # Ingest._finalize: manifest written (upload is durable), before
     # the announce fan-out / HTTP ack
     "upload.after_manifest",
     # _demote_file: parity durable at its stripe holders, the cold

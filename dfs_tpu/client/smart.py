@@ -40,7 +40,8 @@ import time
 import urllib.parse
 
 from dfs_tpu.cli.client import NodeClient
-from dfs_tpu.comm.rpc import (InternalClient, RpcError, RpcRemoteError)
+from dfs_tpu.comm.rpc import (InternalClient, RpcError, RpcRemoteError,
+                              slice_payloads)
 from dfs_tpu.config import ClientConfig, PeerAddr
 from dfs_tpu.fragmenter.base import fragmenter_from_description
 from dfs_tpu.index import EchoCache
@@ -176,11 +177,6 @@ class SmartClient:
                     chunking["describe"])
             except (ValueError, KeyError):
                 self._frag = None   # unknown engine: legacy path
-
-    def _refresh_boot(self) -> None:
-        """Re-fetch /dataplane (epoch churn): adopt the newer view."""
-        self._boot = None
-        self._bootstrap()
 
     def _smart_ready(self) -> bool:
         self._bootstrap()
@@ -358,9 +354,7 @@ class SmartClient:
                         to_probe.append(d)
                 if to_probe:
                     self.counters["probeRpcs"] += 1
-                    resp, _ = await rpc.call(
-                        peer, {"op": "has_chunks", "digests": to_probe})
-                    have = set(resp.get("have", []))
+                    have = await rpc.has_chunks(peer, to_probe)
                     for d in to_probe:
                         if d in have:
                             landed.add(d)
@@ -382,9 +376,7 @@ class SmartClient:
                 # + a real send, never to a committed phantom
                 if trusted:
                     self.counters["verifyRpcs"] += 1
-                    resp, _ = await rpc.call(
-                        peer, {"op": "has_chunks", "digests": trusted})
-                    have = set(resp.get("have", []))
+                    have = await rpc.has_chunks(peer, trusted)
                     heal = [d for d in trusted if d not in have]
                     for d in trusted:
                         if d in have:
@@ -426,7 +418,7 @@ class SmartClient:
         if not digests:
             return 0
         items = [(d, payload_of[d]) for d in digests]
-        slices = _slice_items(items, _READ_BATCH_BYTES)
+        slices = slice_payloads(items, _READ_BATCH_BYTES)
         sent = 0
 
         def on_slice(part: list[tuple[str, bytes]],
@@ -534,8 +526,8 @@ class SmartClient:
             sem = asyncio.Semaphore(self.cfg.stripe)
 
             async def fetch_group(nid: int, digests: list[str]) -> None:
-                for batch in _batch_digests(digests, need,
-                                            _READ_BATCH_BYTES):
+                for batch in slice_payloads(digests, _READ_BATCH_BYTES,
+                                            size=need.__getitem__):
                     expect = sum(need[d] for d in batch)
                     async with sem:
                         try:
@@ -601,73 +593,25 @@ class SmartClient:
         """Client-side budget-capped hedging (the serve/hedge.py
         shapes): race the batch to the next owner when the primary
         outlives the configured floor and the token bucket allows."""
-        peer = self._peers[nid]
         hedge = self._hedge
         backup = None
         if hedge is not None:
             ring = self._ringview.current
             backup = next(
-                (self._peers[n] for n in
-                 ring.owners(digests[0], len(ring.active_ids()))
+                (n for n in ring.owners(digests[0], len(ring.active_ids()))
                  if n != nid and n in self._peers), None)
-        if hedge is None or backup is None:
-            return await rpc.get_chunks(peer, digests,
+
+        async def issue(n: int):
+            return await rpc.get_chunks(self._peers[n], digests,
                                         expect_bytes=expect)
-        task = asyncio.create_task(
-            rpc.get_chunks(peer, digests, expect_bytes=expect))
-        btask: asyncio.Task | None = None
 
-        async def reap() -> None:
-            task.cancel()
-            if btask is not None:
-                btask.cancel()
-            await asyncio.gather(
-                task, *([btask] if btask is not None else []),
-                return_exceptions=True)
-
+        if backup is None:
+            return await issue(nid)
         # no client-side latency history: the floor IS the delay (the
         # conservative end of the serve-side clamp)
-        delay = hedge.delay_s(None)
-        try:
-            return await asyncio.wait_for(asyncio.shield(task), delay)
-        except asyncio.TimeoutError:
-            pass                        # primary in flight: hedge below
-        except asyncio.CancelledError:
-            await reap()
-            raise
-        if not hedge.take():
-            try:
-                return await task
-            except asyncio.CancelledError:
-                await reap()
-                raise
-        hedge.note_fired()
-        btask = asyncio.create_task(
-            rpc.get_chunks(backup, digests, expect_bytes=expect))
-        try:
-            done, _ = await asyncio.wait(
-                {task, btask}, return_when=asyncio.FIRST_COMPLETED)
-        except asyncio.CancelledError:
-            await reap()
-            raise
-        first, other = (task, btask) if task in done else (btask, task)
-        if first.exception() is None:
-            other.cancel()
-            try:
-                await other
-            except (asyncio.CancelledError, RpcError):
-                pass
-            if first is btask:
-                hedge.note_won()
-            return first.result()
-        try:
-            got = await other
-        except asyncio.CancelledError:
-            await reap()
-            raise
-        if other is btask:
-            hedge.note_won()
-        return got
+        pairs, _ = await hedge.race(issue, nid, backup, op="get_chunks",
+                                    delay_s=hedge.delay_s(None))
+        return pairs
 
     # ------------------------------------------------------------------ #
     # introspection / lifecycle
@@ -701,35 +645,3 @@ class SmartClient:
     def close(self) -> None:
         """Nothing pooled survives an operation (see module docstring);
         close() exists for symmetry and future connection reuse."""
-
-
-def _slice_items(items: list[tuple[str, bytes]],
-                 max_bytes: int) -> list[list[tuple[str, bytes]]]:
-    out: list[list[tuple[str, bytes]]] = []
-    cur: list[tuple[str, bytes]] = []
-    size = 0
-    for d, b in items:
-        if cur and size + len(b) > max_bytes:
-            out.append(cur)
-            cur, size = [], 0
-        cur.append((d, b))
-        size += len(b)
-    if cur:
-        out.append(cur)
-    return out
-
-
-def _batch_digests(digests: list[str], length_of: dict[str, int],
-                   max_bytes: int) -> list[list[str]]:
-    out: list[list[str]] = []
-    cur: list[str] = []
-    size = 0
-    for d in digests:
-        if cur and size + length_of[d] > max_bytes:
-            out.append(cur)
-            cur, size = [], 0
-        cur.append(d)
-        size += length_of[d]
-    if cur:
-        out.append(cur)
-    return out

@@ -23,7 +23,7 @@ from __future__ import annotations
 import asyncio
 import random
 import time
-from typing import Any
+from typing import Any, Callable
 
 from dfs_tpu.comm.wire import (Buffer, FrameConnection, WireError,
                                buffers_nbytes, pack_chunks, unpack_chunks)
@@ -131,6 +131,31 @@ class RetryBudget:
                 "exhausted": {str(p): n for p, n in
                               sorted(self._exhausted.items(),
                                      key=lambda kv: str(kv[0]))}}
+
+
+def slice_payloads(items: list, max_bytes: int,
+                   size: Callable[..., int] = lambda it: len(it[1])
+                   ) -> list[list]:
+    """Split (digest, payload) lists into <= max_bytes slices (always
+    at least one item per slice) so no single RPC carries unbounded
+    bytes — the receiver hash-echoes a whole call before replying.
+    ``max_bytes`` is required: placement passes its ``slice_bytes``
+    (instance-scalable) so a default here cannot silently drift.
+    ``size`` weighs an item that is not such a pair (the smart client
+    batches bare digests by their chunks' lengths)."""
+    out: list[list] = []
+    cur: list = []
+    total = 0
+    for it in items:
+        n = size(it)
+        if cur and total + n > max_bytes:
+            out.append(cur)
+            cur, total = [], 0
+        cur.append(it)
+        total += n
+    if cur:
+        out.append(cur)
+    return out
 
 
 class InternalClient:
@@ -566,6 +591,21 @@ class InternalClient:
 
         await gather_abort_siblings(*(one(p) for p in slices))
         return peak
+
+    async def has_chunks(self, peer: PeerAddr, digests: list[str], *,
+                         resident_ok: bool = False,
+                         retries: int | None = None) -> set[str]:
+        """Which of ``digests`` the peer says it holds. ``resident_ok``
+        lets the peer's resident set answer instead of a look at the
+        disk (store/cas.py ``has``): placement's probes and pre-ack
+        rounds pass it, because what they are told is re-counted before
+        any ack; the repair cycle and the read path's who-has sweep do
+        not, and their look at the disk heals the set."""
+        header = {"op": "has_chunks", "digests": digests}
+        if resident_ok:
+            header["residentOk"] = True
+        resp, _ = await self.call(peer, header, retries=retries)
+        return set(resp.get("have", []))
 
     async def announce(self, peer: PeerAddr, manifest_json: str,
                        fresh: bool = False) -> None:

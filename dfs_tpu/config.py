@@ -745,7 +745,7 @@ class IngestConfig:
     ingest 2.66x serial under injected peer latency).
     """
 
-    window: int = 2             # _place_batch calls in flight during
+    window: int = 2             # placement batches in flight during
                                 # streaming ingest; 1 = serial placement
     flush_bytes: int = 32 * 1024 * 1024   # batch size streaming ingest
                                 # accumulates before placing

@@ -128,7 +128,7 @@ class IndexPlane:
         self.trusted = 0              # filter-positive copies credited
         self.echo_trusted = 0         # echo-cache copies credited
                                       # (skip ledger AND verify round)
-        self.place_considered = 0     # digests _place_batch weighed for
+        self.place_considered = 0     # digests placement weighed for
         self.place_skipped = 0        # a peer, and those it never put
                                       # to that peer in a has_chunks
         # what the ChunkStore seam saw (CAS worker threads, so locked)

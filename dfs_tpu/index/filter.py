@@ -21,7 +21,7 @@ Semantics the callers rely on:
 - **maybe present** (filter positive) carries the bloom false-positive
   rate (~0.8% at the default 10 bits/key). Callers that act on a
   positive must either verify it (the placement trust ledger's
-  pre-ack ``has_chunks`` verification, runtime ``_verify_trusted``)
+  pre-ack ``has_chunks`` verification, ``Placement.verify_trusted``)
   or be harmless when wrong (repair's probe simply finds out).
 - filters only ever ADD bits: deletes cannot be unlearned, so the
   owner rebuilds its filter (fresh bloom over the live digest set)

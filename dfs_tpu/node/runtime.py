@@ -27,26 +27,26 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
-import errno
-import functools
+import dataclasses
 import math
-import threading
 import time
-import types
-from collections import deque
-from typing import Collection, Mapping, Sequence
+from typing import Sequence
 
 from dfs_tpu.comm.rpc import (DeadlineExpired, InternalClient, RpcError,
-                              RpcRemoteError, RpcUnreachable)
+                              RpcRemoteError, RpcUnreachable, slice_payloads)
 from dfs_tpu.comm.wire import (FrameServerProtocol, WireError, encode_frame,
                                pack_chunks, unpack_chunks)
 from dfs_tpu.config import NodeConfig
 from dfs_tpu.fragmenter.base import get_fragmenter
-from dfs_tpu.meta.manifest import (ChunkRef, EcInfo, Manifest, StripeRef,
-                                   ec_stripe_groups, stripe_shard_len)
+from dfs_tpu.meta.manifest import ChunkRef, Manifest, ec_stripe_groups
+from dfs_tpu.node.errors import (DeadlineExceeded, DownloadError,
+                                 NotFoundError, RangeNotSatisfiable,
+                                 UploadError)
 from dfs_tpu.node.health import HealthMonitor
+from dfs_tpu.node.ingest import Ingest
+from dfs_tpu.node.placement import (Placement, ec_placement_map,
+                                    ec_shard_items, new_upload_stats)
 from dfs_tpu.obs import Observability, Span, parse_wire_trace
-from dfs_tpu.ring import RingMap
 from dfs_tpu.ring.manager import RingManager
 from dfs_tpu.serve import BatchPrefetcher, ServingTier
 from dfs_tpu.store.aio import AsyncChunkStore
@@ -54,128 +54,9 @@ from dfs_tpu.store.cas import NodeStore
 from dfs_tpu.utils import deadline
 from dfs_tpu.utils.hashing import (is_hex_digest, sha256_hex,
                                    sha256_many_hex, sha256_new)
-from dfs_tpu.utils.aio import create_logged_task, gather_abort_siblings
+from dfs_tpu.utils.aio import create_logged_task
 from dfs_tpu.utils.logging import Counters, Stopwatches, get_logger
 from dfs_tpu.utils.trace import LatencyRecorder
-
-
-def _spanned(name: str):
-    """Run an async method of the node inside ``self.obs.span(name)``:
-    one span per call under the caller's trace (a no-op when the caller
-    is untraced), whichever upload path makes the call."""
-    def deco(fn):
-        @functools.wraps(fn)
-        async def wrapper(self, *args, **kwargs):
-            with self.obs.span(name):
-                return await fn(self, *args, **kwargs)
-        return wrapper
-    return deco
-
-
-class UploadError(RuntimeError):
-    """Maps to HTTP 500 'Replication failed' (StorageNode.java:176) by
-    default; raisers may pin a different code via ``status`` (resume
-    validation -> 400, resume-missing-chunks -> 409) so the HTTP layer
-    never classifies by matching message text."""
-
-    def __init__(self, msg: str, status: int = 500) -> None:
-        super().__init__(msg)
-        self.status = status
-
-
-class NotFoundError(KeyError):
-    """Maps to HTTP 404 (StorageNode.java:408-411)."""
-
-
-class DownloadError(RuntimeError):
-    """Maps to HTTP 500 'Could not retrieve fragment…' / 'File corrupted'
-    (StorageNode.java:443-446, 453-458)."""
-
-
-class RangeNotSatisfiable(DownloadError):
-    """A byte range past EOF — maps to HTTP 416 with the file size."""
-
-    def __init__(self, size: int) -> None:
-        super().__init__(f"range not satisfiable (size {size})")
-        self.size = size
-
-
-class DeadlineExceeded(DownloadError):
-    """The caller's end-to-end deadline expired during a read — maps to
-    HTTP 503 + Retry-After (the same answer the admission gate gives an
-    expired arrival), never a 500: the cluster is healthy, the budget
-    is gone, and a 500 would invite the immediate no-backoff retry the
-    Retry-After discipline exists to prevent. Also distinct so the
-    fetch walks can STOP at expiry instead of touring every remaining
-    candidate and counting each refusal as a remote miss."""
-
-
-def ec_placement_map(manifest: Manifest, ring) -> Mapping[str, tuple[int, ...]]:
-    """digest -> candidate holder nodes for every shard (data + parity)
-    of an erasure-coded manifest. Derived from the manifest plus the
-    membership ring alone, so any node can locate any shard. ``ring``
-    is a :class:`~dfs_tpu.ring.RingMap` — or a plain node-id list,
-    which compiles to the static epoch-0 map (the pre-r14 call shape;
-    tests and benches still use it). A digest appearing in several
-    stripes (dedup within the file) gets the union of its slots'
-    holders. Memoized per (manifest layout, ring identity): rebuilding
-    measured ~30 ms per gather on a 32 MiB manifest, and a degraded
-    read runs two gathers. The key is a cheap layout fingerprint, not
-    the manifest object — hashing a frozen dataclass walks every
-    ChunkRef, which would cost as much as the rebuild; stripe endpoints
-    pin the ec_k re-upload case where the same file_id maps to a
-    different stripe layout."""
-    if not isinstance(ring, RingMap):
-        ring = RingMap.static(list(ring))
-    ec = manifest.ec
-    assert ec is not None
-    key = (manifest.file_id, ec.k, len(manifest.chunks), len(ec.stripes),
-           ec.stripes[0].p if ec.stripes else "",
-           ec.stripes[-1].q if ec.stripes else "", ring.key)
-    hit = _EC_PLACEMENT_CACHE.get(key)
-    if hit is None:
-        hit = _ec_placement_build(manifest, ring)
-        if len(_EC_PLACEMENT_CACHE) >= 64:
-            _EC_PLACEMENT_CACHE.pop(next(iter(_EC_PLACEMENT_CACHE)))
-        _EC_PLACEMENT_CACHE[key] = hit
-    return hit
-
-
-_EC_PLACEMENT_CACHE: dict = {}
-
-
-def _ec_placement_build(manifest: Manifest, ring: RingMap
-                        ) -> Mapping[str, tuple[int, ...]]:
-    ec = manifest.ec
-    assert ec is not None
-    pl: dict[str, list[int]] = {}
-    groups = ec_stripe_groups(manifest.chunks, ec.k)
-    for s, (st, grp) in enumerate(zip(ec.stripes, groups)):
-        # one ring walk per stripe: holders for all k data shards + P/Q
-        holders = ring.ec_stripe_nodes(manifest.file_id, s, len(grp) + 2)
-        for j, c in enumerate(grp):
-            pl.setdefault(c.digest, []).append(holders[j])
-        pl.setdefault(st.p, []).append(holders[len(grp)])
-        pl.setdefault(st.q, []).append(holders[len(grp) + 1])
-    # read-only view over tuple values: the map is cached and shared by
-    # every reader of this (manifest, membership) pair — a caller
-    # mutating it would corrupt placement for all subsequent reads, so
-    # violations fail loudly instead of silently.
-    return types.MappingProxyType(
-        {d: tuple(dict.fromkeys(v)) for d, v in pl.items()})
-
-
-def ec_shard_items(manifest: Manifest) -> list[tuple[str, int]]:
-    """(digest, byte length) of every shard an EC manifest references —
-    data chunks at their true length, parity at the stripe's padded
-    shard length."""
-    ec = manifest.ec
-    assert ec is not None
-    out = [(c.digest, c.length) for c in manifest.chunks]
-    for st in ec.stripes:
-        out.append((st.p, st.shard_len))
-        out.append((st.q, st.shard_len))
-    return out
 
 
 # storage-plane ops the internal admission gate bounds: the ones that
@@ -192,81 +73,14 @@ _HEAVY_OPS = frozenset({"store_chunks", "get_chunk", "get_chunks"})
 _NULL_OBS_SPAN = Span()
 
 
-class ByteBudget:
-    """Counting BYTE semaphore for cross-thread ingest backpressure.
-
-    The streaming-upload credit gate originally bounded chunk COUNT
-    (256), which bounds memory only as well as the chunk-size config
-    does: a stream of max-size chunks under a large ``max_chunk`` could
-    buffer ~1 GiB of produced-but-unconsumed payloads, silently breaking
-    the bounded-memory ingest contract. This gate charges actual payload
-    bytes instead.
-
-    A single chunk larger than the whole budget is admitted when nothing
-    else is outstanding (otherwise it could never proceed — the classic
-    byte-semaphore deadlock); the budget is then simply oversubscribed
-    by that one chunk until it is consumed.
-    """
-
-    def __init__(self, budget: int) -> None:
-        self.budget = max(1, int(budget))
-        self._out = 0
-        self._cv = threading.Condition()
-
-    def acquire(self, n: int, timeout: float | None = None) -> bool:
-        """Block until ``n`` bytes fit under the budget (or the gate is
-        empty); False on timeout. Called from the fragmenter thread."""
-        with self._cv:
-            ok = self._cv.wait_for(
-                lambda: self._out + n <= self.budget or self._out == 0,
-                timeout)
-            if ok:
-                self._out += n
-            return ok
-
-    def release(self, n: int) -> None:
-        with self._cv:
-            self._out = max(0, self._out - n)
-            self._cv.notify_all()
-
-    @property
-    def outstanding(self) -> int:
-        with self._cv:
-            return self._out
-
-
-class _TrustLedger:
-    """Filter-credited replica copies awaiting pre-ack verification.
-
-    When placement trusts a peer-filter POSITIVE (skipping both the
-    has_chunks probe and the transfer — the re-upload fast path,
-    docs/index.md), the copy it credited is a bloom ``maybe``, not a
-    fact. Every trusted (peer, digest, length) lands here, and
-    ``StorageNodeServer._verify_trusted`` confirms the whole ledger
-    with ONE has_chunks round per peer BEFORE the manifest write acks
-    the upload — so a false positive can delay an ack (it gets healed
-    by a real transfer first), never weaken one. Event-loop-only, like
-    the placement bookkeeping it extends."""
-
-    def __init__(self) -> None:
-        self.by_peer: dict[int, dict[str, int]] = {}
-
-    def credit(self, peer: int, digest: str, length: int) -> None:
-        self.by_peer.setdefault(peer, {})[digest] = length
-
-    def __bool__(self) -> bool:
-        return bool(self.by_peer)
-
-
 def _config_fingerprint(cfg: NodeConfig) -> str:
     """sha256 over the SHARED config surface — everything that should be
     identical across a healthy cluster. Node-local identity fields
     (node_id, data_root, sidecar_port) are excluded so the doctor's
     config_drift rule compares policy, not identity."""
-    import dataclasses as _dc
     import json as _json
 
-    d = _dc.asdict(cfg)
+    d = dataclasses.asdict(cfg)
     for local in ("node_id", "data_root", "sidecar_port"):
         d.pop(local, None)
     return sha256_hex(_json.dumps(d, sort_keys=True,
@@ -352,17 +166,14 @@ class StorageNodeServer:
         self.cas = AsyncChunkStore(self.store.chunks,
                                    workers=cfg.ingest.cas_io_threads,
                                    obs=self.obs)
-        # streaming-ingest flush size: config-driven, kept as an instance
-        # attribute so tests/benches can still scale it per node
-        self._STREAM_FLUSH_BYTES = cfg.ingest.flush_bytes
         if cfg.sidecar_port:
             # delegate chunk+hash to a sidecar process (north-star shape:
             # device init/compiles never block the serving loop)
             from dfs_tpu.sidecar.service import SidecarFragmenter
 
-            self.fragmenter = SidecarFragmenter(cfg.sidecar_port)
+            fragmenter = SidecarFragmenter(cfg.sidecar_port)
         else:
-            self.fragmenter = get_fragmenter(
+            fragmenter = get_fragmenter(
                 cfg.fragmenter, cdc_params=cfg.cdc,
                 fixed_parts=cfg.fixed_parts, frag=cfg.frag)
         self.client = InternalClient(cfg.connect_timeout_s,
@@ -442,9 +253,34 @@ class StorageNodeServer:
         self._disk_pressure = False
         self.log = get_logger("node", cfg.node_id)
         self.under_replicated: set[str] = set()  # digests needing repair
+        # the write path's two layers (docs/ingest.md), each handed its
+        # collaborators by name: batch placement below, the upload verb
+        # above it. The read path lends each its fetch.
+        self.placement = Placement(
+            cfg, self.ring, self.cas, self.client, self.health,
+            index=self.index, hedge=self.serve.hedge, obs=self.obs,
+            counters=self.counters, stalls=self.ingest_stalls,
+            chaos=self.chaos, under_replicated=self.under_replicated,
+            fetch_chunk=self._fetch_chunk)
+        self.ingest = Ingest(
+            cfg, fragmenter, self.placement, self.cas, self.client,
+            self.health, self.ring, self.store.manifests,
+            index=self.index, obs=self.obs, counters=self.counters,
+            stalls=self.ingest_stalls, chaos=self.chaos,
+            fetch_verified=self._fetch_verified)
         self._internal_server: asyncio.AbstractServer | None = None
         self._http_server: asyncio.AbstractServer | None = None
         self._inbound: set[FrameServerProtocol] = set()  # live peer conns
+
+    @property
+    def fragmenter(self):
+        """The chunk+hash engine: ingest's, shown here for the stats,
+        the lifecycle and whoever swaps it on a running node."""
+        return self.ingest.fragmenter
+
+    @fragmenter.setter
+    def fragmenter(self, engine) -> None:
+        self.ingest.fragmenter = engine
 
     # ------------------------------------------------------------------ #
     # lifecycle
@@ -1305,1453 +1141,26 @@ class StorageNodeServer:
 
     async def upload(self, data: bytes, name: str,
                      ec_k: int = 0) -> tuple[Manifest, dict]:
-        # hashing + fragmentation run off the event loop: a multi-hundred-
-        # MiB body would otherwise stall every concurrent request for the
-        # full CPU pass (the reference is thread-per-connection so it
-        # never noticed; an asyncio node must not block its loop)
-        with self.obs.span("upload.hash_file", latency=True):
-            file_id = await asyncio.to_thread(sha256_hex, data)
-        if not name:
-            name = f"file-{file_id[:8]}"  # reference default, StorageNode.java:133-135
-        with self.obs.span("upload.fragment", latency=True):
-            manifest = await asyncio.to_thread(
-                self.fragmenter.manifest, data, name=name, file_id=file_id)
-
-        stats = self._new_upload_stats()
-        stats["bytes"] = len(data)
-        seen: set[str] = set()
-        batch: list[tuple[str, bytes]] = []
-        view = memoryview(data).toreadonly()
-        for c in manifest.chunks:
-            if c.digest in seen:
-                continue  # duplicate content within the file: place once
-            seen.add(c.digest)
-            # read-only VIEW per chunk, shared across every target —
-            # pre-r10 this was a bytes slice per chunk (a full-corpus
-            # copy before a byte hit the wire); views flow untouched
-            # through CAS puts and scatter-gather peer sends
-            batch.append((c.digest, view[c.offset:c.offset + c.length]))
-        stats["uniqueChunks"] = len(seen)
-        placement = None
-        rf = None
-        if ec_k:
-            ids = self.ring.node_ids()
-            if ec_k + 2 > len(ids):
-                raise UploadError(
-                    f"ec={ec_k} needs {ec_k + 2} nodes, ring has "
-                    f"{len(ids)} active (shards of a stripe must land "
-                    "on distinct nodes)", status=400)
-            if ec_k > 255:
-                # the Q coefficients live in GF(256)*'s order-255 group:
-                # beyond k=255 they repeat and some double erasures
-                # become uncorrectable — the any-2-lost guarantee fails
-                raise UploadError("ec must be <= 255", status=400)
-            with self.obs.span("upload.ec_encode", latency=True):
-                manifest, parity = await asyncio.to_thread(
-                    self._ec_extend, manifest, data, ec_k)
-            for d, b in parity:
-                # per-item seen check: P and Q can share a digest
-                # (k=1 makes Q == P), and a lazy bulk-extend would
-                # place it twice
-                if d not in seen:
-                    seen.add(d)
-                    batch.append((d, b))
-            stats["ecParityBytes"] = sum(len(b) for _, b in parity)
-            placement = ec_placement_map(manifest, self.ring.current)
-            rf = 1   # the parity IS the redundancy (any 2 shards may die)
-        ledger = self._new_trust_ledger()
-        await self._place_batch(file_id, batch, stats, rf=rf,
-                                placement=placement, ledger=ledger)
-        if ledger:
-            # filter-credited copies confirmed BEFORE the ack
-            await self._verify_trusted(file_id, ledger, stats, rf=rf,
-                                       placement=placement)
-        await self._finalize_upload(manifest)
-        self.counters.inc("upload_bytes", len(data))
-        return manifest, stats
-
-    def _ec_extend(self, manifest: Manifest, data: bytes, k: int
-                   ) -> tuple[Manifest, list[tuple[str, bytes]]]:
-        """Compute P+Q parity per stripe of ``k`` data chunks (ops.ec;
-        device encode when the node's fragmenter already runs on one) and
-        return the EC manifest plus the parity (digest, payload) list.
-        Runs in a worker thread — NumPy/encode work."""
-        view = memoryview(data)
-        src = {c.digest: view[c.offset:c.offset + c.length]
-               for c in manifest.chunks}
-        return self._ec_extend_from(manifest, src, k)
-
-    def _ec_extend_from(self, manifest: Manifest,
-                        chunk_bytes: Mapping[str, bytes], k: int
-                        ) -> tuple[Manifest, list[tuple[str, bytes]]]:
-        """:meth:`_ec_extend` with per-chunk payloads sourced from a
-        digest map instead of one contiguous buffer — the shape tier
-        demotion has (its bytes come from a ``_gather_chunks`` dict,
-        never a whole-file assembly). Worker-thread code."""
-        import dataclasses as _dc
-
-        import numpy as np
-
-        from dfs_tpu.ops import ec as ec_ops
-
-        device = "tpu" in self.fragmenter.name
-        stripes: list[StripeRef] = []
-        parity: list[tuple[str, bytes]] = []
-        for grp in ec_stripe_groups(manifest.chunks, k):
-            pad = stripe_shard_len(grp)
-            sh = np.zeros((len(grp), pad), dtype=np.uint8)
-            for j, c in enumerate(grp):
-                sh[j, :c.length] = np.frombuffer(
-                    chunk_bytes[c.digest], dtype=np.uint8,
-                    count=c.length)
-            p, q = ec_ops.encode_pq(sh, device=device)
-            pb, qb = p.tobytes(), q.tobytes()
-            pd, qd = sha256_hex(pb), sha256_hex(qb)
-            stripes.append(StripeRef(p=pd, q=qd, shard_len=pad))
-            parity.append((pd, pb))
-            parity.append((qd, qb))
-        ec = EcInfo(k=k, stripes=tuple(stripes))
-        return _dc.replace(manifest, ec=ec), parity
-
-    # per-RPC payload cap for replication slices (see replicate() in
-    # _place_batch); class-level so tests/benches can scale it per node
-    _REPLICA_SLICE_BYTES = 8 * 1024 * 1024
+        return await self.ingest.upload(data, name, ec_k)
 
     async def upload_stream(self, blocks, name: str) -> tuple[Manifest, dict]:
-        """Bounded-memory PIPELINED ingest: ``blocks`` is an async
-        iterator of byte blocks (e.g. an HTTP chunked-transfer body).
-        The fragmenter's streaming walk runs in a worker thread
-        consuming the blocks; finished chunks flow back and are
-        placed/replicated in ~``ingest.flush_bytes`` batches as the
-        stream arrives — at no point does the whole payload exist in
-        node memory (the reference reads the entire body into one array,
-        StorageNode.java:124). file_id stays sha256(whole stream),
-        computed incrementally.
+        return await self.ingest.upload_stream(blocks, name)
 
-        Up to ``ingest.window`` placement batches stay in flight at once
-        (docs/ingest.md): while batch N replicates over the network the
-        fragmenter keeps chunking batch N+1 instead of stalling on its
-        credits — replication latency was the dominant ingest cost the
-        serial schedule paid in full (INGEST_r07.json: 2.66x). The first
-        placement failure aborts the stream exactly like the serial
-        path: reading stops, no manifest commits, already-placed chunks
-        age out via GC. Per-batch stats are kept separately and merged
-        in batch order, so the windowed schedule reports byte-identical
-        stats to the serial one."""
-        import queue as _queue
+    async def upload_resume(self, table, name: str, file_id: str,
+                            size: int, provided: dict[str, bytes]
+                            ) -> tuple[Manifest, dict]:
+        return await self.ingest.upload_resume(table, name, file_id, size,
+                                               provided)
 
-        loop = asyncio.get_running_loop()
-        inq: _queue.Queue = _queue.Queue(maxsize=4)
-        outq: asyncio.Queue = asyncio.Queue()
-        hasher = sha256_new()
-        frag_dead = threading.Event()
-        aborted = threading.Event()
-        # byte credits: the fragmenter thread blocks once this many
-        # produced-but-unconsumed payload BYTES are outstanding, which
-        # stops it draining inq, which blocks the feeder, which stops
-        # reading the socket — TCP backpressure end to end. Without it a
-        # fast client outruns slow replication and the 'bounded-memory'
-        # contract silently fails. (Counting chunks instead of bytes —
-        # the gate until round 7 — let max-size chunks oversubscribe the
-        # budget by orders of magnitude.)
-        credits = ByteBudget(self.cfg.ingest.credit_bytes)
-
-        def feed_iter():
-            while True:
-                try:
-                    b = inq.get(timeout=0.5)
-                except _queue.Empty:
-                    # abort must not depend on the end-of-stream sentinel
-                    # arriving: the feeder's cancelled finally submits it
-                    # through the shared to_thread pool, which can be
-                    # saturated — a fragmenter parked in a bare get()
-                    # would deadlock the abort path's gather forever
-                    if aborted.is_set():
-                        return
-                    continue
-                if b is None:
-                    return
-                yield b
-
-        def on_chunk(digest: str, payload: bytes) -> None:
-            t0 = time.perf_counter()
-            while not credits.acquire(len(payload), timeout=0.5):
-                if aborted.is_set():
-                    raise RuntimeError("upload aborted")
-            waited = time.perf_counter() - t0
-            if waited > 0.001:   # stall attribution: chunking blocked on
-                # unconsumed output (downstream placement is the
-                # bottleneck); sub-ms lock noise is not a stall
-                self.ingest_stalls.add("creditS", waited)
-            loop.call_soon_threadsafe(outq.put_nowait, (digest, payload))
-
-        def run_fragmenter():
-            try:
-                # to_thread copied the request's context: the span (the
-                # owner seam as this node sees it, same name as the
-                # whole-payload path's) parents to the request, and a
-                # chip owner's spans hang under it
-                with self.obs.span("upload.fragment", latency=True):
-                    m = self.fragmenter.manifest_stream(
-                        feed_iter(), name=name or "stream", store=on_chunk)
-                loop.call_soon_threadsafe(outq.put_nowait, ("done", m))
-            # not silent: surfaced to the async consumer via the
-            # ("error", e) queue item, which re-raises on the loop
-            except BaseException as e:  # dfslint: ignore[DFS007]
-                loop.call_soon_threadsafe(outq.put_nowait, ("error", e))
-            finally:
-                frag_dead.set()
-
-        def put_block(b) -> None:
-            # bounded put that cannot deadlock: if the fragmenter thread
-            # died it stopped draining inq, so give up instead of blocking
-            # a worker thread (and the feeder await) forever
-            while not frag_dead.is_set():
-                try:
-                    inq.put(b, timeout=0.5)
-                    return
-                except _queue.Full:
-                    continue
-
-        frag_task = asyncio.create_task(asyncio.to_thread(run_fragmenter))
-
-        async def feeder() -> int:
-            total = 0
-            # the body's two waits, told apart: for the next block from
-            # the socket (the client, or TCP backpressure) and for
-            # put_block (the fragmenter side is not draining inq)
-            body_wait = feed_wait = 0.0
-            with self.obs.span("upload.body") as sp:
-                try:
-                    t = time.perf_counter()
-                    async for b in blocks:
-                        body_wait += time.perf_counter() - t
-                        if aborted.is_set():
-                            break    # placement failed: stop reading, do
-                            # NOT drain the rest of the body into memory
-                        total += len(b)
-                        hasher.update(b)
-                        t = time.perf_counter()
-                        await asyncio.to_thread(put_block, b)
-                        now = time.perf_counter()
-                        feed_wait += now - t
-                        t = now
-                    else:       # the wait that found the body's end
-                        body_wait += time.perf_counter() - t
-                finally:
-                    await asyncio.to_thread(put_block, None)
-                    sp.bytes = total
-                    self.ingest_stalls.add("bodyWaitS", body_wait)
-                    self.ingest_stalls.add("feedWaitS", feed_wait)
-            return total
-
-        feed_task = asyncio.create_task(feeder())
-
-        stats = self._new_upload_stats()
-        seen: set[str] = set()
-        batch: list[tuple[str, bytes]] = []
-        pending = 0
-        manifest: Manifest | None = None
-        window = max(1, self.cfg.ingest.window)
-        # (task, per-batch stats) in submission order — awaited FIFO so
-        # stats merge deterministically and the FIRST failing batch is
-        # the one that aborts the stream
-        inflight: deque[tuple[asyncio.Task, dict]] = deque()
-
-        async def drain_one() -> None:
-            task, bstats = inflight[0]
-            # removed only AFTER the await resolves: if THIS coroutine
-            # is cancelled mid-await (client hung up), the still-running
-            # placement must remain in `inflight` so the abort path
-            # below cancels and reaps it — popping first leaked it
-            await task
-            inflight.popleft()
-            self._merge_upload_stats(stats, bstats)
-
-        ledger = self._new_trust_ledger()
-
-        async def submit(b: list[tuple[str, bytes]]) -> None:
-            if window == 1:     # serial placement: the historical
-                # schedule, byte-identical behavior
-                await self._place_batch("", b, stats, ledger=ledger)
-                return
-            while len(inflight) >= window:
-                # stall attribution: the window is full — ingest is
-                # blocked on placement (replication/disk), not chunking
-                t0 = time.perf_counter()
-                # surface a failure from ANY in-flight batch before
-                # blocking: awaiting only the head would ride out a
-                # slow batch A (dead-peer retries run tens of seconds)
-                # while batch C's failure is already known — and then
-                # replicate one more doomed batch
-                for task, _ in inflight:
-                    if task.done() and not task.cancelled() \
-                            and task.exception() is not None:
-                        await task          # re-raise: abort the stream
-                if inflight[0][0].done():
-                    await drain_one()       # FIFO merge
-                else:
-                    await asyncio.wait(
-                        [t for t, _ in inflight if not t.done()],
-                        return_when=asyncio.FIRST_COMPLETED)
-                self.ingest_stalls.add("placementS",
-                                       time.perf_counter() - t0)
-            bstats = self._new_upload_stats()
-            task = asyncio.create_task(
-                self._place_batch("", b, bstats, ledger=ledger))
-            # completion wakes the consume loop below via a sentinel: a
-            # FAILED placement must abort the stream even while the
-            # consumer is parked on outq behind a slow client — without
-            # the wakeup, abort latency was coupled to body progress
-            task.add_done_callback(
-                lambda t: outq.put_nowait(("placed", t)))
-            inflight.append((task, bstats))
-            self.ingest_stalls.peak("placeWindow", len(inflight))
-
-        # file_id is only known at stream end; batches placed before that
-        # tag transfers with a placeholder (store_chunks ignores it)
-        try:
-            while manifest is None:
-                # merge (and surface failures of) any placements that
-                # already resolved, oldest first
-                while inflight and inflight[0][0].done():
-                    await drain_one()
-                item = await outq.get()
-                if item[0] == "placed":
-                    task = item[1]
-                    if not task.cancelled() and task.exception() \
-                            is not None:
-                        await task   # re-raise the placement failure
-                        # NOW — reading the body stops immediately
-                    continue         # success: head drain above merges
-                if item[0] == "error" and isinstance(item[1], BaseException):
-                    raise UploadError(f"fragmenter failed: {item[1]}")
-                if item[0] == "done" and isinstance(item[1], Manifest):
-                    manifest = item[1]
-                    break
-                digest, payload = item
-                credits.release(len(payload))
-                if digest in seen:
-                    continue
-                seen.add(digest)
-                batch.append((digest, payload))
-                pending += len(payload)
-                if pending >= self._STREAM_FLUSH_BYTES:
-                    await submit(batch)
-                    batch, pending = [], 0
-            if batch:
-                await submit(batch)
-            while inflight:        # tail drain: the stream is chunked,
-                t0 = time.perf_counter()   # only placement remains
-                await drain_one()
-                self.ingest_stalls.add("placementS",
-                                       time.perf_counter() - t0)
-        except BaseException:
-            aborted.set()                  # unblock fragmenter + feeder
-            # the feeder may be parked in a socket read with no timeout
-            # (a stalled client mid-body) — cancel it rather than wait
-            # for the next block that may never come; its finally still
-            # hands the fragmenter the end-of-stream sentinel
-            feed_task.cancel()
-            for task, _ in inflight:       # first failure aborts: stop
-                task.cancel()              # sibling placements too
-            await asyncio.gather(feed_task, frag_task,
-                                 *(t for t, _ in inflight),
-                                 return_exceptions=True)
-            raise
-        try:
-            # re-raises body errors (malformed chunked framing -> 400);
-            # nothing was finalized, so a truncated stream commits NO
-            # manifest — its already-placed chunks are unreferenced and
-            # the aged GC in the repair loop reclaims them
-            total = await feed_task
-        finally:
-            await frag_task
-        if stats["minCopies"] is None:     # zero-chunk (empty) stream
-            stats["minCopies"] = self.cfg.cluster.replication_factor
-        file_id = hasher.hexdigest()
-        if not name:
-            name = f"file-{file_id[:8]}"
-        manifest = Manifest(file_id=file_id, name=name, size=total,
-                            fragmenter=manifest.fragmenter,
-                            chunks=manifest.chunks)
-        stats["bytes"] = total
-        stats["uniqueChunks"] = len(seen)
-        if ledger:
-            # every filter-credited copy across every placed batch is
-            # confirmed in ONE has_chunks round per peer — before the
-            # manifest write acks the stream (docs/index.md)
-            await self._verify_trusted(file_id, ledger, stats)
-        await self._finalize_upload(manifest)
-        self.counters.inc("upload_bytes", total)
-        return manifest, stats
+    async def commit_manifest(self, table, name: str, file_id: str,
+                              size: int) -> tuple[Manifest, dict]:
+        return await self.ingest.commit_manifest(table, name, file_id, size)
 
     async def missing_digests(self, digests: list[str]) -> list[str]:
-        """Which of ``digests`` the cluster holds NOwhere reachable —
-        the resumable-upload probe (SURVEY §5.4: chunk-level resume falls
-        out of the dedup index). Local CAS first — ONE batched
-        ``has_many`` job of the CAS latency lane (this loop used to
-        stat inline ON the event loop, one syscall per digest); the
-        remainder is asked of each digest's replica set via batched
-        has_chunks, with peer-filter-ruled-out digests never probed at
-        all. Both take a resident answer (``residentOk``): this is
-        placement asking, and what it is told is present is re-counted
-        before any ack (``upload_resume`` fetches or 409s). Filter
-        POSITIVES are still probed here on purpose: a
-        bloom false positive answered as "cluster has it" would tell
-        the client to skip bytes, and at bloom FP rates every large
-        resume would then trip upload_resume's 409 fallback — the
-        probe is cheaper than the fallback (docs/index.md)."""
-        cand = [d for d in dict.fromkeys(digests) if is_hex_digest(d)]
-        mask = await self.cas.has_many(cand, resident_ok=True)
-        missing = [d for d, h in zip(cand, mask) if not h]
-        if not missing:
-            return []
-        rf = self.cfg.cluster.replication_factor
-        found: set[str] = set()
-        by_peer: dict[int, list[str]] = {}
-        for d in missing:
-            # dual-read candidates: mid-rebalance the bytes may still
-            # sit at previous-epoch owners only
-            for t in self.ring.read_candidates(d, rf):
-                if t != self.cfg.node_id:
-                    by_peer.setdefault(t, []).append(d)
-        plane = self.index
-        if plane is not None and plane.local_filter is not None:
-            trimmed: dict[int, list[str]] = {}
-            for nid, ds in by_peer.items():
-                if plane.peer_filters.state(nid) is None:
-                    trimmed[nid] = ds       # no replica: probe as-is
-                    continue
-                keep = [d for d in ds
-                        if plane.peer_filters.contains(nid, d)
-                        is not False]
-                plane.probes_skipped += len(ds) - len(keep)
-                if keep:
-                    trimmed[nid] = keep
-                elif ds:
-                    plane.probe_rpcs_skipped += 1
-            by_peer = trimmed
-
-        async def probe(nid: int, ds: list[str]) -> None:
-            try:
-                resp, _ = await self.client.call(
-                    self.cfg.cluster.peer(nid),
-                    {"op": "has_chunks", "digests": ds,
-                     "residentOk": True}, retries=1)
-                found.update(resp.get("have", []))
-            except RpcError:
-                # best-effort: an unanswered probe only makes the client
-                # resend bytes the cluster already has — but count it
-                # (DFS007): habitual probe failures silently erase the
-                # resume/dedup win
-                self.counters.inc("probe_failures")
-
-        await asyncio.gather(*(probe(n, ds) for n, ds in by_peer.items()))
-        return [d for d in missing if d not in found]
-
-    async def upload_resume(self, table: list[tuple[int, int, str]],
-                            name: str, file_id: str, size: int,
-                            provided: dict[str, bytes]
-                            ) -> tuple[Manifest, dict]:
-        """Finalize an upload from a client-supplied chunk table plus
-        ONLY the payloads the cluster lacked (client flow: GET /chunking
-        -> chunk locally -> POST /missing -> POST /upload_resume). The
-        interrupted-upload bytes already placed are never re-sent — the
-        resume SURVEY §5.4 says should fall out of the dedup index.
-
-        Integrity: every provided payload is hash-verified; chunks NOT
-        provided must be locally present or fetchable from replicas
-        (else UploadError lists them — client falls back to a full
-        upload); the assembled stream must hash to ``file_id`` exactly
-        like a regular upload's fileId = sha256(body)."""
-        if not name:
-            name = f"file-{file_id[:8]}"   # reference default naming
-        # table sanity: contiguous tiling of [0, size)
-        expect = 0
-        for off, ln, dg in table:
-            if off != expect or ln < 0 or not is_hex_digest(dg):
-                raise UploadError("malformed chunk table", status=400)
-            expect = off + ln
-        if expect != size:
-            raise UploadError("chunk table does not tile the stream",
-                              status=400)
-
-        hexes = await asyncio.to_thread(
-            sha256_many_hex, list(provided.values()))
-        for d, h in zip(provided, hexes):
-            if d != h:
-                raise UploadError(f"provided chunk {d[:12]}… hash mismatch",
-                                  status=400)
-
-        refs = [ChunkRef(index=i, offset=off, length=ln, digest=dg)
-                for i, (off, ln, dg) in enumerate(table)]
-        manifest = Manifest(file_id=file_id, name=name, size=size,
-                            fragmenter=self.fragmenter.name,
-                            chunks=tuple(refs))
-
-        # assemble incrementally (batches) to verify the whole-stream
-        # hash AND place everything; bytes come from `provided`, the
-        # local CAS, or replicas
-        stats = self._new_upload_stats()
-        stats["bytes"] = sum(len(b) for b in provided.values())
-        hasher = sha256_new()
-        seen: set[str] = set()
-        ledger = self._new_trust_ledger()
-        batch: list = []
-        bsize = 0
-        for c in refs:
-            batch.append(c)
-            bsize += c.length
-            if bsize >= self._FETCH_BATCH_BYTES or c is refs[-1]:
-                got = dict(provided)
-                need = [x for x in batch if x.digest not in got]
-                if need:
-                    # digest-verified like every read path: a rotten
-                    # local copy of an interrupted upload's chunk heals
-                    # from a replica instead of failing the resume with
-                    # a client-blaming hash error forever
-                    fetched = await self._fetch_verified(
-                        manifest, need, strict=False)
-                    got.update(fetched)
-                absent = [x.digest for x in batch if x.digest not in got]
-                if absent:
-                    raise UploadError(
-                        "resume missing chunks: "
-                        + ",".join(d[:12] for d in absent), status=409)
-                payloads = [got[x.digest] for x in batch]
-                await asyncio.to_thread(
-                    lambda ps=payloads: [hasher.update(p) for p in ps])
-                place = [(x.digest, got[x.digest]) for x in batch
-                         if x.digest not in seen]
-                seen.update(d for d, _ in place)
-                await self._place_batch(file_id, place, stats,
-                                        ledger=ledger)
-                batch, bsize = [], 0
-        if hasher.hexdigest() != file_id:
-            raise UploadError("resumed stream does not hash to fileId",
-                              status=400)
-        stats["uniqueChunks"] = len(seen)
-        if stats["minCopies"] is None:
-            stats["minCopies"] = self.cfg.cluster.replication_factor
-        if ledger:
-            await self._verify_trusted(file_id, ledger, stats)
-        await self._finalize_upload(manifest)
-        self.counters.inc("uploads_resumed")
-        self.counters.inc("upload_bytes", size)
-        return manifest, stats
-
-    async def commit_manifest(self, table: list[tuple[int, int, str]],
-                              name: str, file_id: str, size: int
-                              ) -> tuple[Manifest, dict]:
-        """Single-hop ingest commit (docs/client.md): the smart client
-        already striped every payload directly to its ring owners with
-        per-slice hash-echo verification; this ONE coordinator call
-        turns that pre-staged state into an acked file. Ack semantics
-        are unchanged from a regular upload — the manifest write is
-        fsync-before-ack and nothing is acked until every chunk in the
-        table is confirmed AT WRITE QUORUM by real ``has_chunks``
-        rounds (a stale filter or a lying client cannot manufacture a
-        phantom copy: the coordinator re-counts durable copies itself,
-        and re-places anything below quorum through the normal batch
-        path). Chunks held nowhere reachable raise a 409-class
-        UploadError — the client falls back to a legacy full upload.
-
-        ``file_id`` on this path is the client's claim of
-        sha256(stream): the coordinator never saw the assembled bytes.
-        Per-chunk digests WERE verified at store time (the owners
-        hash-echo what they durably hold), and every read re-verifies
-        each chunk against the manifest — so a wrong claim can only
-        mis-name the file, never corrupt bytes (same trust model as
-        the chunk table itself; documented in docs/client.md)."""
-        if not name:
-            name = f"file-{file_id[:8]}"   # reference default naming
-        # table sanity: contiguous tiling of [0, size) — the same
-        # contract as upload_resume
-        expect = 0
-        for off, ln, dg in table:
-            if off != expect or ln < 0 or not is_hex_digest(dg):
-                raise UploadError("malformed chunk table", status=400)
-            expect = off + ln
-        if expect != size:
-            raise UploadError("chunk table does not tile the stream",
-                              status=400)
-        refs = [ChunkRef(index=i, offset=off, length=ln, digest=dg)
-                for i, (off, ln, dg) in enumerate(table)]
-        manifest = Manifest(file_id=file_id, name=name, size=size,
-                            fragmenter=self.fragmenter.name,
-                            chunks=tuple(refs))
-        stats = self._new_upload_stats()
-        stats["bytes"] = size
-
-        ring = self.ring.current
-        ids = ring.active_ids()
-        rf = self.cfg.cluster.replication_factor
-        quorum = min(self.cfg.write_quorum, rf, len(ids))
-        plane = self.index
-        cache = plane.echo_cache if plane is not None else None
-        digests = list(dict.fromkeys(dg for _, _, dg in table))
-        copies = {d: 0 for d in digests}
-        # local holdings first (this node is an owner for its arc)
-        mask = await self.cas.has_many(digests, resident_ok=True)
-        for d, h in zip(digests, mask):
-            if h:
-                copies[d] += 1
-        # one real has_chunks round per owner peer — first-party
-        # evidence, the same pre-ack discipline as _verify_trusted
-        by_peer: dict[int, list[str]] = {}
-        for d in digests:
-            for t in ring.owners(d, rf):
-                if t != self.cfg.node_id:
-                    by_peer.setdefault(t, []).append(d)
-
-        async def probe(nid: int, ds: list[str]) -> set[str]:
-            try:
-                resp, _ = await self.client.call(
-                    self.cfg.cluster.peer(nid),
-                    {"op": "has_chunks", "digests": ds,
-                     "residentOk": True},
-                    retries=None if self.health.is_alive(nid) else 1)
-                self.health.mark_alive(nid)
-                return set(resp.get("have", []))
-            except DeadlineExpired:
-                raise
-            except RpcError as e:
-                if isinstance(e, RpcUnreachable):
-                    self.health.mark_dead(nid)
-                self.counters.inc("commit_probe_failures")
-                return set()
-
-        with self.obs.span("upload.commit_verify", latency=True):
-            peers = sorted(by_peer)
-            results = await asyncio.gather(
-                *(probe(n, by_peer[n]) for n in peers))
-        for nid, have in zip(peers, results):
-            for d in by_peer[nid]:
-                if d in have:
-                    copies[d] += 1
-                    if cache is not None:
-                        cache.confirm(nid, d)
-        confirmed = {d: n for d, n in copies.items() if n >= quorum}
-        stats["dedupSkippedBytes"] = sum(
-            ln for _, ln, dg in table if dg in confirmed)
-        below = [d for d in digests if d not in confirmed]
-        if below:
-            # heal below-quorum chunks pre-ack: fetch the bytes (local
-            # CAS, then any replica — the client may have reached SOME
-            # owners) and re-place through the normal batch path, which
-            # re-probes, transfers, and falls to handoff as needed.
-            # Chunks absent everywhere 409 — the ack was never given.
-            self.obs.event("commit_replace", chunks=len(below))
-            need = [c for c in refs if c.digest in set(below)]
-            dedup: set[str] = set()
-            need = [c for c in need
-                    if not (c.digest in dedup or dedup.add(c.digest))]
-            fetched = await self._fetch_verified(manifest, need,
-                                                 strict=False)
-            absent = [c.digest for c in need if c.digest not in fetched]
-            if absent:
-                raise UploadError(
-                    "commit missing chunks: "
-                    + ",".join(d[:12] for d in absent), status=409)
-            await self._place_batch(
-                file_id, [(c.digest, fetched[c.digest]) for c in need],
-                stats)
-        stats["uniqueChunks"] = len(digests)
-        batch_min = min((confirmed[d] for d in confirmed), default=rf)
-        stats["minCopies"] = batch_min if stats["minCopies"] is None \
-            else min(stats["minCopies"], batch_min)
-        stats["degraded"] = stats["degraded"] or batch_min < rf
-        await self._finalize_upload(manifest)
-        self.counters.inc("uploads_committed")
-        self.counters.inc("upload_bytes", size)
-        return manifest, stats
+        return await self.ingest.missing_digests(digests)
 
     def dataplane_info(self) -> dict:
-        """GET /dataplane (docs/client.md): one bootstrap call telling
-        an external smart client everything it needs to run the data
-        plane itself — the ring map (so it can compute owners), the
-        peer address book (so it can dial their storage-plane ports),
-        the replication policy (rf / write quorum), the fragmenter
-        description (so its chunk boundaries match the cluster's
-        bit-exactly), and the existence-filter state. Old servers 404
-        this route; the client falls back to the coordinator path."""
-        out = {"nodeId": self.cfg.node_id,
-               "epoch": self.ring.epoch,
-               "fingerprint": self.ring.current.fingerprint,
-               "ring": self.ring.current.to_dict(),
-               "migrating": self.ring.migrating,
-               "replicationFactor": self.cfg.cluster.replication_factor,
-               "writeQuorum": self.cfg.write_quorum,
-               "peers": [{"nodeId": p.node_id, "host": p.host,
-                          "port": p.port,
-                          "internalPort": p.internal_port}
-                         for p in self.cfg.cluster.peers],
-               "filters": {"enabled": False}}
-        try:
-            out["chunking"] = {"fragmenter": self.fragmenter.name,
-                               "describe": self.fragmenter.describe()}
-        except NotImplementedError:
-            out["chunking"] = None   # engine not resume-describable:
-            # the client cannot reproduce boundaries — legacy path only
-        if self.index is not None and self.index.local_filter is not None:
-            fstats = self.index.local_filter.stats()
-            out["filters"] = {
-                "enabled": True,
-                "generation": fstats["generation"],
-                "version": fstats["version"],
-                "peerAges": {str(p): round(a, 3) for p, a in
-                             self.index.peer_filters.ages().items()}}
-        return out
-
-    @staticmethod
-    def _new_upload_stats() -> dict:
-        return {"bytes": 0, "uniqueChunks": 0, "transferredBytes": 0,
-                "dedupSkippedBytes": 0, "minCopies": None,
-                "handoffChunks": 0, "degraded": False}
-
-    @staticmethod
-    def _merge_upload_stats(into: dict, part: dict) -> None:
-        """Fold one batch's placement stats into the stream totals.
-        Every field is commutative (sum / min / or), so the windowed
-        schedule reports exactly what the serial one would; merging in
-        batch order anyway keeps the trace reproducible. ``bytes`` and
-        ``uniqueChunks`` are stream-level — set by the caller at stream
-        end, never by a batch."""
-        into["transferredBytes"] += part["transferredBytes"]
-        into["dedupSkippedBytes"] += part["dedupSkippedBytes"]
-        into["handoffChunks"] += part["handoffChunks"]
-        into["degraded"] = into["degraded"] or part["degraded"]
-        if part["minCopies"] is not None:
-            into["minCopies"] = part["minCopies"] \
-                if into["minCopies"] is None \
-                else min(into["minCopies"], part["minCopies"])
-
-    @staticmethod
-    def _slice_payloads(items: list[tuple[str, bytes]], max_bytes: int
-                        ) -> list[list[tuple[str, bytes]]]:
-        """Split (digest, payload) lists into <= max_bytes slices (always
-        at least one item per slice) so no single RPC carries unbounded
-        bytes — the receiver hash-echoes a whole call before replying.
-        ``max_bytes`` is required: callers pass ``_REPLICA_SLICE_BYTES``
-        (instance-scalable) so a default here cannot silently drift."""
-        out: list[list[tuple[str, bytes]]] = []
-        cur: list[tuple[str, bytes]] = []
-        size = 0
-        for d, b in items:
-            if cur and size + len(b) > max_bytes:
-                out.append(cur)
-                cur, size = [], 0
-            cur.append((d, b))
-            size += len(b)
-        if cur:
-            out.append(cur)
-        return out
-
-    def _raise_if_disk_full(self, e: OSError) -> None:
-        """ENOSPC graceful degradation (docs/chaos.md): a full local
-        disk during placement is a capacity condition, not a crash —
-        surface it as HTTP 507 (Insufficient Storage) with a journaled
-        ``disk_pressure`` event instead of a 500 traceback. Reads and
-        internal gets keep working (they never put); replication TO a
-        full node already degrades via handoff. Anything that is not
-        ENOSPC re-raises in the caller unchanged."""
-        if e.errno != errno.ENOSPC:
-            return
-        self.counters.inc("disk_full_rejects")
-        self.obs.event("disk_pressure", cause="enospc_put")
-        raise UploadError("Insufficient storage: local CAS put failed "
-                          "(ENOSPC)", status=507) from e
-
-    @_spanned("upload.place")
-    async def _place_batch(self, file_id: str,
-                           batch: list[tuple[str, bytes]],
-                           stats: dict, rf: int | None = None,
-                           placement: Mapping[str, tuple[int, ...]] | None = None,
-                           ledger: _TrustLedger | None = None
-                           ) -> None:
-        """Place one batch of unique (digest, payload) chunks: local puts
-        for canonical ownership, concurrent replication with hash-echo
-        verification, then sloppy-quorum handoff — failing loudly if any
-        chunk ends below quorum. Shared by whole-payload upload (one
-        batch) and streaming upload (a batch per ~32 MiB). ``rf``
-        overrides the cluster replication factor (erasure-coded files
-        place single copies — the parity is the redundancy) and
-        ``placement`` pins digests to explicit holders (EC stripe
-        placement) instead of the digest-derived replica set; the
-        handoff ring then continues cyclically from the pinned holder.
-
-        With the index plane on, each peer's replication pass consults
-        that peer's existence filter first (docs/index.md): digests the
-        filter RULES OUT skip the probe and transfer directly; filter
-        POSITIVES are — when ``ledger`` is given — credited as trusted
-        copies (probe and transfer both skipped; the caller MUST run
-        :meth:`_verify_trusted` on the ledger before acking), except
-        that a chunk nothing else would vouch for is put to one of its
-        peers (``filter_credits``) or, with no ledger, probed as before
-        minus the ruled-out payload."""
-        if self.chaos is not None:
-            self.chaos.maybe_crash("place.before_local_put")
-        # placement snapshot: ONE ring map for the whole batch — a
-        # concurrent epoch adoption must not split a batch between two
-        # maps (the rebalancer reconciles whole batches placed under
-        # either epoch; a half-and-half batch would satisfy neither)
-        ring = self.ring.current
-        ids = ring.active_ids()
-        if self.index is not None and self.index.echo_cache is not None:
-            # pin the echo cache to this batch's epoch: an adoption
-            # since the last batch clears every session confirmation
-            # (ownership moved — docs/client.md §filter freshness)
-            self.index.echo_cache.note_epoch(ring.epoch)
-        if rf is None:
-            rf = self.cfg.cluster.replication_factor
-        placement = placement or {}
-
-        def primary_targets(digest: str) -> Sequence[int]:
-            return placement.get(digest) \
-                or ring.owners(digest, rf)
-
-        def handoff_ring(digest: str) -> list[int]:
-            pinned = placement.get(digest)
-            if not pinned:
-                return ring.owners(digest, len(ids))
-            return ring.handoff_order(pinned)
-
-        per_node: dict[int, list[tuple[str, bytes]]] = {}
-        copies: dict[str, int] = {}
-        payload_of: dict[str, bytes] = {}
-        local_puts: list[tuple[str, bytes]] = []
-        for digest, payload in batch:
-            copies[digest] = 0
-            payload_of[digest] = payload
-            for target in primary_targets(digest):
-                if target == self.cfg.node_id:
-                    local_puts.append((digest, payload))
-                    copies[digest] += 1
-                else:
-                    per_node.setdefault(target, []).append((digest, payload))
-
-        async def put_local(items: list[tuple[str, bytes]],
-                            count_dedup: bool = True) -> None:
-            # local canonical copies through the async CAS tier: one
-            # bounded-pool job for the whole list, OFF the event loop
-            # (inline puts occupied it for the full writeback pass) and
-            # overlapping peer replication instead of preceding it. A
-            # failed put still fails the batch via the gather below.
-            results = await self.cas.put_many(items, verify=False)
-            nstored = nbytes = 0
-            for (d, b), newly in zip(items, results):
-                if newly:
-                    nstored += 1
-                    nbytes += len(b)
-            if nstored:
-                self.counters.inc("chunks_stored", nstored)
-                self.counters.inc("bytes_stored", nbytes)
-            if count_dedup and len(items) > nstored:
-                self.counters.inc("dedup_hits", len(items) - nstored)
-
-        # (peer, digest) pairs whose bytes are already accounted in
-        # transferredBytes/dedupSkippedBytes: a chunk's bytes count at
-        # most ONCE per peer across the primary and handoff passes, so
-        # repeated handoff probes cannot double-count one transfer
-        counted: set[tuple[int, str]] = set()
-
-        def filter_credits() -> dict[int, set[str]]:
-            """The filter positives each primary leg may credit
-            unasked (docs/index.md §3), settled before a leg starts.
-            A chunk this node does not own, which no leg would be sent
-            or asked about, would be credited by every filter and
-            stored nowhere this node can vouch for — and its payload
-            leaves with the batch, so the pre-ack verify round could
-            name two false positives but heal neither. The first leg
-            that would credit such a chunk asks its peer instead, side
-            by side with the other legs; the rest may credit it."""
-            plane = self.index
-            maybe: dict[int, set[str]] = {}
-            if ledger is None or plane is None \
-                    or plane.local_filter is None:
-                return maybe
-            cache = plane.echo_cache
-            real = {d for d, _ in local_puts}
-            for nid, wanted in per_node.items():
-                if not self.health.is_alive(nid):
-                    continue             # a corpse backs nothing
-                if plane.peer_filters.state(nid) is None:
-                    real.update(d for d, _ in wanted)    # all probed
-                    continue
-                for d, _ in wanted:
-                    if cache is not None and cache.confirmed(nid, d) \
-                            or plane.peer_filters.contains(nid, d) is False:
-                        real.add(d)      # echo on record, or to be sent
-                    else:
-                        maybe.setdefault(nid, set()).add(d)
-            for ds in maybe.values():
-                asks = ds - real
-                ds -= asks
-                real |= asks
-            return maybe
-
-        async def replicate(node_id: int,
-                            wanted: list[tuple[str, bytes]],
-                            credit: Collection[str] = ()) -> None:
-            # ``credit``: the filter positives this leg may take on
-            # trust; a handoff leg is given none and asks about each
-            peer = self.cfg.cluster.peer(node_id)
-            # Known-dead peers get one fast probe instead of the full retry
-            # envelope (health registry, SURVEY.md §5.3).
-            retries = None if self.health.is_alive(node_id) else 1
-            plane = self.index
-            cache = plane.echo_cache if plane is not None else None
-            trusted: set[str] = set()
-
-            async def probe() -> tuple[list, set[str], list | None]:
-                """This leg's existence check: what is still to be
-                weighed after the echo cache, what the peer answered it
-                has, and the payload slices staged meanwhile."""
-                # echo-cache consult first (ISSUE 16 satellite): a
-                # digest this peer hash-echo-confirmed THIS SESSION
-                # under the current epoch is first-party evidence,
-                # stronger than a bloom positive — credit the copy with
-                # NO ledger entry, skipping the probe AND the pre-ack
-                # verify round. Dead peers never qualify (same rule as
-                # filter trust).
-                remaining = wanted
-                if cache is not None and retries is None:
-                    echoed_skip = 0
-                    remaining = []
-                    for d, b in wanted:
-                        if cache.confirmed(node_id, d):
-                            echoed_skip += 1
-                            copies[d] += 1
-                            if (node_id, d) not in counted:
-                                counted.add((node_id, d))
-                                stats["dedupSkippedBytes"] += len(b)
-                        else:
-                            remaining.append((d, b))
-                    if echoed_skip:
-                        plane.echo_trusted += echoed_skip
-                        plane.probes_skipped += echoed_skip
-                filtered = False
-                to_probe = remaining
-                if plane is not None and plane.local_filter is not None \
-                        and retries is None \
-                        and plane.peer_filters.state(node_id) is not None:
-                    filtered = True
-                    ruled_out = 0
-                    to_probe = []
-                    for d, b in remaining:
-                        if d in credit:
-                            trusted.add(d)
-                            copies[d] += 1
-                            ledger.credit(node_id, d, len(b))
-                            if (node_id, d) not in counted:
-                                counted.add((node_id, d))
-                                stats["dedupSkippedBytes"] += len(b)
-                        elif plane.peer_filters.contains(
-                                node_id, d) is False:
-                            ruled_out += 1       # straight to transfer
-                        else:
-                            to_probe.append((d, b))
-                    plane.probes_skipped += ruled_out + len(trusted)
-                    plane.trusted += len(trusted)
-                    if not to_probe and remaining:
-                        plane.probe_rpcs_skipped += 1
-                digests = [d for d, _ in to_probe]
-                if plane is not None:
-                    plane.place_considered += len(wanted)
-                    plane.place_skipped += len(wanted) - len(digests)
-                staged = None
-                have: set[str] = set()
-                if to_probe and not filtered:
-                    # the has_chunks probe flies while the payload list
-                    # is staged into bounded slices — fresh data rarely
-                    # dedups, so the optimistic staging is usually
-                    # final; a dedup hit restages only the missing
-                    # remainder
-                    call = asyncio.create_task(self.client.call(
-                        peer, {"op": "has_chunks", "digests": digests,
-                               "residentOk": True},
-                        retries=retries))
-                    try:
-                        # staging runs on a worker thread so it is
-                        # GENUINELY concurrent with the probe's RTT:
-                        # the to_thread await yields the loop, which
-                        # runs the probe task's send before (and while)
-                        # the slicing executes — inline staging after
-                        # create_task would still serialize ahead of
-                        # the wire write
-                        staged = await asyncio.to_thread(
-                            self._slice_payloads, remaining,
-                            self._REPLICA_SLICE_BYTES)
-                        resp, _ = await call
-                    except BaseException:
-                        call.cancel()    # replicate cancelled/failed
-                        raise            # first: don't orphan the probe
-                    have = set(resp.get("have", []))
-                elif to_probe:
-                    # filter-trimmed probe: only what the filter could
-                    # not rule out goes over the wire
-                    resp, _ = await self.client.call(
-                        peer, {"op": "has_chunks", "digests": digests,
-                               "residentOk": True},
-                        retries=retries)
-                    have = set(resp.get("have", []))
-                    for d in digests:
-                        if d not in have:
-                            # the filter said maybe, the peer says no:
-                            # an OBSERVED false positive — counted, and
-                            # overridden so a retry stops re-trusting
-                            plane.peer_filters.note_fp(node_id, d)
-                return remaining, have, staged
-
-            try:
-                # peer-filter consultation (docs/index.md): split this
-                # peer's list into ruled-out (definitely absent —
-                # transfer without probing), trusted (a filter
-                # positive this leg was given to credit — probe AND
-                # transfer skipped, verified pre-ack), and to-probe. A
-                # dead peer's filter is never trusted (a stale summary
-                # crediting copies on a corpse is exactly the phantom
-                # the health registry exists to prevent); no replica of
-                # the peer's filter = the pre-index path.
-                with self.obs.span("upload.probe"):
-                    remaining, have, staged = await probe()
-                missing = [(d, b) for d, b in remaining
-                           if d not in have and d not in trusted]
-                for d, b in remaining:
-                    if d in have:
-                        # durable on the peer no matter what later
-                        # slices do — credit the copy immediately
-                        copies[d] += 1
-                        if cache is not None:
-                            cache.confirm(node_id, d)
-                        if (node_id, d) not in counted:
-                            counted.add((node_id, d))
-                            stats["dedupSkippedBytes"] += len(b)
-                            self.counters.inc("dedup_remote_hits")
-                if missing:
-                    # bounded RPCs: the receiver recomputes the hash echo
-                    # of everything in one call before replying, so an
-                    # unbounded payload turns into an unbounded server
-                    # pass — a ~300 MB push under 1-core contention blew
-                    # the request timeout and failed a whole 2 GiB-corpus
-                    # upload below quorum; bounded slices keep each
-                    # call's work (and any retry's re-send) small
-                    slices = staged if staged is not None and not have \
-                        else self._slice_payloads(
-                            missing, self._REPLICA_SLICE_BYTES)
-
-                    def make_on_slice(nid: int):
-                        def on_slice(part: list[tuple[str, bytes]],
-                                     echoed: list[str]) -> None:
-                            # hash-echo verification per slice (reference
-                            # contract, StorageNode.java:248-257) +
-                            # per-slice crediting: a verified slice is
-                            # durable on the peer even if a LATER slice
-                            # fails — end-of-call crediting forgot
-                            # delivered bytes on partial failure, and
-                            # handoff re-transferred (and re-counted)
-                            # them. The echo IS the session confirmation
-                            # the echo cache keys on.
-                            sent = {d for d, _ in part}
-                            if sent & set(echoed) != sent:
-                                raise RpcError(
-                                    f"hash echo mismatch from node {nid}")
-                            for d, b in part:
-                                copies[d] += 1
-                                if cache is not None:
-                                    cache.confirm(nid, d)
-                                if nid != node_id:
-                                    # hedge-backup copy: durable but on
-                                    # a non-canonical holder — queue it
-                                    # for repair like a handoff copy
-                                    self.under_replicated.add(d)
-                                if (nid, d) not in counted:
-                                    counted.add((nid, d))
-                                    stats["transferredBytes"] += len(b)
-                        return on_slice
-
-                    # hedged write (ISSUE 16 satellite): under a hedge
-                    # policy, race the slice train against a timer; if
-                    # the primary stalls past the p~99 envelope, open a
-                    # SECOND train to the next ring holder under the
-                    # shared token budget. Content-addressed puts make
-                    # the duplicate harmless — whichever copies land
-                    # are real copies — and per-slice crediting under
-                    # ``counted`` keeps the byte accounting exact.
-                    backup_id = None
-                    if self.serve.hedge is not None:
-                        # first digest in the batch with a live third
-                        # holder nominates the backup (the batch mixes
-                        # owner sets; anchoring on missing[0] alone
-                        # left whole trains unhedged on a coin flip)
-                        for dg, _ in missing:
-                            primaries = set(primary_targets(dg))
-                            backup_id = next(
-                                (t for t in handoff_ring(dg)
-                                 if t != node_id
-                                 and t != self.cfg.node_id
-                                 and t not in primaries
-                                 and self.health.is_alive(t)), None)
-                            if backup_id is not None:
-                                break
-                    if backup_id is None:
-                        peak = await self.client.store_chunks_windowed(
-                            peer, file_id, slices,
-                            window=self.cfg.ingest.slice_inflight,
-                            on_slice=make_on_slice(node_id))
-                        self.ingest_stalls.peak("sliceInflight", peak)
-                    else:
-                        await self._store_hedged(
-                            node_id, backup_id, file_id, slices,
-                            make_on_slice)
-                self.health.mark_alive(node_id)
-            except DeadlineExpired:
-                # the caller's budget died, not the peer: abort the
-                # upload as a 503-class refusal (see _place_batch's
-                # gather) — swallowing it here would count every peer
-                # as a replication failure and end in a quorum-fail 500
-                # on a healthy cluster
-                raise
-            except RpcError as e:
-                self.log.warning("replication to node %d failed: %s",
-                                 node_id, e)
-                self.counters.inc("replication_failures")
-                if isinstance(e, RpcUnreachable):
-                    # only transport-level exhaustion is liveness evidence;
-                    # an application error came from a live peer
-                    self.health.mark_dead(node_id)
-                    if cache is not None:
-                        # session confirmations were about THAT process;
-                        # its successor re-earns them
-                        cache.drop(node_id)
-
-        with self.obs.span("upload.replicate", latency=True):
-            try:
-                credits = filter_credits()
-                await gather_abort_siblings(
-                    put_local(local_puts),
-                    *(replicate(nid, w, credits.get(nid, ()))
-                      for nid, w in per_node.items()))
-            except OSError as e:
-                self._raise_if_disk_full(e)
-                raise
-        if self.chaos is not None:
-            self.chaos.maybe_crash("place.after_replicate")
-
-        # Sloppy-quorum fallback (hinted handoff): chunks still below
-        # quorum try the next nodes in their digest ring, so a dead
-        # canonical target costs availability only when fewer than
-        # ``write_quorum`` nodes in the WHOLE cluster are reachable. The
-        # reference aborts the entire upload on ANY dead peer
-        # (StorageNode.java:218-221); this keeps its >=2-copies durability
-        # without its write-all fragility. Handoff copies are queued for
-        # repair, which migrates them back to canonical placement.
-        # Effective quorum: write_quorum can't exceed the copies placement
-        # will ever make — rf (the policy) or the cluster size (a 1-node
-        # cluster's single copy IS every copy in the world). Without the
-        # clamp a legal `--nodes 1` deployment fails every upload.
-        quorum = min(self.cfg.write_quorum, rf, len(ids))
-        handoff: set[str] = set()
-        next_try = {d: len(primary_targets(d))       # ring index per digest
-                    for d in copies}
-        with self.obs.span("upload.handoff", latency=True):
-            while True:
-                need = [d for d, n in copies.items() if n < quorum]
-                if not need:
-                    break
-                groups: dict[int, list[tuple[str, bytes]]] = {}
-                local_handoff: list[tuple[str, bytes]] = []
-                progress = False
-                for d in need:
-                    order = handoff_ring(d)
-                    if next_try[d] >= len(order):
-                        continue                     # cluster exhausted
-                    target = order[next_try[d]]
-                    next_try[d] += 1
-                    progress = True
-                    handoff.add(d)
-                    if target == self.cfg.node_id:
-                        local_handoff.append((d, payload_of[d]))
-                        copies[d] += 1   # local copy counts even on dedup
-                    else:
-                        groups.setdefault(target, []).append(
-                            (d, payload_of[d]))
-                if not progress:
-                    break
-                jobs = []
-                if local_handoff:
-                    # count_dedup=False: the handoff path never counted
-                    # a local dedup hit (the copy was credited above)
-                    jobs.append(put_local(local_handoff,
-                                          count_dedup=False))
-                jobs.extend(replicate(nid, w)
-                            for nid, w in groups.items())
-                if jobs:
-                    try:
-                        await gather_abort_siblings(*jobs)
-                    except OSError as e:
-                        self._raise_if_disk_full(e)
-                        raise
-
-        # Write-quorum policy (vs reference write-all abort, :218-221).
-        failed = [d for d, n in copies.items() if n < quorum]
-        if failed:
-            # journaled: a quorum failure is the write path's loudest
-            # lifecycle event and the HTTP 500 it becomes carries no
-            # cluster state — the flight recorder keeps the evidence
-            self.obs.event("quorum_fail", chunksBelow=len(failed),
-                           quorum=quorum)
-            raise UploadError(
-                f"Replication failed: {len(failed)} chunks below quorum "
-                f"{quorum}")
-        for d, n in copies.items():
-            if n < rf or d in handoff:
-                self.under_replicated.add(d)
-        batch_min = min(copies.values(), default=rf)
-        stats["minCopies"] = batch_min if stats["minCopies"] is None \
-            else min(stats["minCopies"], batch_min)
-        stats["handoffChunks"] += len(handoff)
-        stats["degraded"] = stats["degraded"] or bool(
-            handoff or any(n < rf for n in copies.values()))
-
-    async def _store_hedged(self, primary_id: int, backup_id: int,
-                            file_id: str,
-                            slices: list[list[tuple[str, bytes]]],
-                            make_on_slice) -> None:
-        """Hedged replication store (ISSUE 16 satellite, the write-side
-        twin of :meth:`_hedged_get_chunks`): send the slice train to the
-        primary; if it outlives the latency-derived hedge delay and the
-        shared token bucket allows, open a SECOND train of the same
-        slices to ``backup_id``. Content-addressed puts make the
-        duplicate inherently safe — every hash-echo-verified slice is a
-        real durable copy wherever it landed, credited through the
-        caller's ``counted`` discipline — so unlike the read side there
-        is no result to pick: success of EITHER train completes the
-        call, and a loser cancelled mid-flight keeps the slices it
-        already landed. Exceptions propagate only when both trains fail
-        (the primary's error class, so the caller's health handling
-        stays aimed at the peer it chose)."""
-        hedge = self.serve.hedge
-        window = self.cfg.ingest.slice_inflight
-
-        async def issue(nid: int):
-            return await self.client.store_chunks_windowed(
-                self.cfg.cluster.peer(nid), file_id, slices,
-                window=window, on_slice=make_on_slice(nid))
-
-        task = asyncio.create_task(issue(primary_id))
-        btask: asyncio.Task | None = None
-
-        async def reap_on_cancel() -> None:
-            # our caller was cancelled: the trains must die with it —
-            # shield/asyncio.wait leave their tasks running detached
-            # otherwise, and an unretrieved RpcError would log
-            # 'exception was never retrieved' at GC
-            task.cancel()
-            if btask is not None:
-                btask.cancel()
-            await asyncio.gather(task,
-                                 *([btask] if btask is not None
-                                   else []),
-                                 return_exceptions=True)
-
-        delay = hedge.delay_s(
-            self.obs.rpc_client.recent_best_mean("store_chunks"))
-        try:
-            peak = await asyncio.wait_for(asyncio.shield(task), delay)
-            self.ingest_stalls.peak("sliceInflight", peak)
-            return
-        # absence-as-result: the timeout IS the hedge trigger — the
-        # shielded primary keeps running and is raced below
-        except asyncio.TimeoutError:  # dfslint: ignore[DFS007]
-            pass                        # primary still in flight: hedge
-        except asyncio.CancelledError:
-            await reap_on_cancel()
-            raise
-        except BaseException:
-            raise                       # primary failed fast — the
-            # caller's RpcUnreachable/RpcError handling applies as-is
-        if not hedge.take():
-            try:
-                peak = await task
-            except asyncio.CancelledError:
-                await reap_on_cancel()   # awaiting a Task does not
-                raise                    # cancel it — reap explicitly
-            self.ingest_stalls.peak("sliceInflight", peak)
-            return
-        hedge.note_fired()
-        self.obs.event("hedge_fired", op="store_chunks",
-                       primary=primary_id, backup=backup_id,
-                       slices=len(slices), delayS=round(delay, 4))
-        btask = asyncio.create_task(issue(backup_id))
-        try:
-            done, _ = await asyncio.wait(
-                {task, btask}, return_when=asyncio.FIRST_COMPLETED)
-        except asyncio.CancelledError:
-            await reap_on_cancel()
-            raise
-        first, other = (task, btask) if task in done else (btask, task)
-        first_id, other_id = (primary_id, backup_id) if first is task \
-            else (backup_id, primary_id)
-        ferr = first.exception()
-        if ferr is None:
-            other.cancel()
-            try:
-                await other
-            except (asyncio.CancelledError, RpcError, WireError):  # dfslint: ignore[DFS007]
-                pass    # reaped: the winner's train already landed
-            if not other.cancelled() \
-                    and isinstance(other.exception(), RpcUnreachable):
-                self.health.mark_dead(other_id)
-            if first_id == backup_id:
-                hedge.note_won()
-                self.obs.event("hedge_won", op="store_chunks",
-                               primary=primary_id, backup=backup_id)
-            else:
-                self.ingest_stalls.peak("sliceInflight", first.result())
-            return
-        # first train failed: fall to the other side — no third train
-        if isinstance(ferr, RpcUnreachable):
-            self.health.mark_dead(first_id)
-        try:
-            await other
-        except asyncio.CancelledError:
-            await reap_on_cancel()       # the train must die with us
-            raise
-        except (RpcError, WireError) as e:
-            # both failed: surface the PRIMARY's failure class so the
-            # caller's diagnosis targets the peer it actually chose
-            raise (ferr if first_id == primary_id else e) from None
-        if other_id == backup_id:
-            hedge.note_won()
-            self.obs.event("hedge_won", op="store_chunks",
-                           primary=primary_id, backup=backup_id)
-
-    def _new_trust_ledger(self) -> _TrustLedger | None:
-        """A trust ledger when the filter plane is on, else None (the
-        pre-index placement path, probe per batch per peer)."""
-        if self.index is not None and self.index.local_filter is not None:
-            return _TrustLedger()
-        return None
-
-    async def _verify_trusted(self, file_id: str, ledger: _TrustLedger,
-                              stats: dict, rf: int | None = None,
-                              placement: Mapping[str, tuple[int, ...]]
-                              | None = None) -> None:
-        """Confirm every filter-credited copy with ONE real has_chunks
-        round per peer — the pre-ack half of the probe-skipping
-        placement (docs/index.md). Runs after the last batch placed and
-        BEFORE the manifest write that acks the upload, so a bloom
-        false positive (or a peer that died between trust and verify)
-        costs a heal — re-fetching the bytes and re-placing them
-        through the normal batch path — never an ack backed by a
-        phantom copy. Observed FPs are counted (``index.filterFp``)
-        and overridden per peer, so a deterministic bloom collision
-        cannot wedge a retry loop into trusting the same phantom
-        forever."""
-        plane = self.index
-        assert plane is not None
-        unconfirmed: dict[str, int] = {}
-        with self.obs.span("upload.verify_trusted", latency=True):
-            for node_id, entries in sorted(ledger.by_peer.items()):
-                peer = self.cfg.cluster.peer(node_id)
-                digests = sorted(entries)
-                try:
-                    resp, _ = await self.client.call(
-                        peer, {"op": "has_chunks", "digests": digests,
-                               "residentOk": True})
-                    self.health.mark_alive(node_id)
-                except RpcError as e:
-                    # the peer answered the filter sync but not the
-                    # verify: every credit it granted is unconfirmed —
-                    # NOT a false positive (the filter made no mistake;
-                    # the peer is sick), so no FP count/override
-                    if isinstance(e, RpcUnreachable):
-                        self.health.mark_dead(node_id)
-                        if plane.echo_cache is not None:
-                            plane.echo_cache.drop(node_id)
-                    self.counters.inc("index_verify_failures")
-                    for d in digests:
-                        stats["dedupSkippedBytes"] -= entries[d]
-                        unconfirmed.setdefault(d, entries[d])
-                    continue
-                have = set(resp.get("have", []))
-                for d in digests:
-                    if d not in have:
-                        plane.peer_filters.note_fp(node_id, d)
-                        stats["dedupSkippedBytes"] -= entries[d]
-                        unconfirmed.setdefault(d, entries[d])
-                    elif plane.echo_cache is not None:
-                        # the verify round is first-party evidence too:
-                        # future re-uploads this session skip straight
-                        # past both the probe and the verify
-                        plane.echo_cache.confirm(node_id, d)
-        if not unconfirmed:
-            return
-        # heal pre-ack: re-fetch the bytes (local CAS first — this node
-        # is usually a holder — then any replica) and re-place through
-        # the normal batch path with NO ledger: real holders dedup, the
-        # phantom target receives an actual transfer (its FP override
-        # stops the filter from re-trusting), dead targets fall to
-        # handoff, and the quorum check re-runs for exactly these
-        # digests. Bytes that survive nowhere reachable fail the upload
-        # loudly — the ack was never given.
-        self.obs.event("filter_fp_replace", chunks=len(unconfirmed))
-        items: list[tuple[str, bytes]] = []
-        local = dict(await self.cas.get_many(sorted(unconfirmed)))
-        for d, ln in sorted(unconfirmed.items()):
-            b = local.get(d)
-            if b is None:
-                try:
-                    b = await self._fetch_chunk(d, ln)
-                except DeadlineExceeded:
-                    raise          # budget died: 503-class, never a
-                    # "held nowhere reachable" 500
-                except DownloadError:
-                    raise UploadError(
-                        f"filter-credited chunk {d[:12]}… held nowhere "
-                        "reachable — retry the upload (the filter "
-                        "override now forces a real transfer)")
-            items.append((d, b))
-        await self._place_batch(file_id, items, stats, rf=rf,
-                                placement=placement)
-
-    @_spanned("upload.commit")
-    async def _finalize_upload(self, manifest: Manifest) -> None:
-        # Manifest-last ordering (SURVEY.md §5.4), then best-effort announce
-        # (reference: announce failure only logged, StorageNode.java:338-346).
-        # A fresh upload clears tombstones (locally and via fresh=True at
-        # peers): re-uploading deleted content must resurrect the
-        # content-derived file id, not leave it permanently undownloadable.
-        # The save runs off-loop: with fsync durability it is a disk
-        # BARRIER (file + dir), and this is the write that acks the
-        # upload — the one moment the loop must not eat a barrier.
-        if self.chaos is not None:
-            self.chaos.maybe_crash("upload.before_manifest")
-        self.store.manifests.clear_tombstone(manifest.file_id)
-        try:
-            saved = await asyncio.to_thread(self.store.manifests.save,
-                                            manifest)
-        except OSError as e:
-            self._raise_if_disk_full(e)
-            raise
-        if not saved:
-            raise UploadError("manifest save refused (tombstone race)")
-        if self.chaos is not None:
-            self.chaos.maybe_crash("upload.after_manifest")
-        mj = manifest.to_json()          # once, not once per recipient
-
-        async def announce(peer) -> None:
-            try:
-                await self.client.announce(peer, mj, fresh=True)
-            except RpcError as e:
-                self.log.warning("announce to node %d failed: %s",
-                                 peer.node_id, e)
-                self.counters.inc("announce_failures")
-
-        await asyncio.gather(*(announce(p) for p in self._peers()))
-        self.counters.inc("uploads")
+        return self.ingest.dataplane_info()
 
     # ------------------------------------------------------------------ #
     # download (L4) — reference handleDownload, StorageNode.java:399-461
@@ -2782,17 +1191,32 @@ class StorageNodeServer:
             (t for t in self.cfg.cluster.sorted_ids()
              if t != self.cfg.node_id and t not in candidates),
             key=lambda t: not self.health.is_alive(t))
-        if self.serve.hedge is not None:
-            # hedged reads (docs/serve.md): same candidate walk, but a
-            # primary that outlives its latency-derived hedge delay
-            # races the NEXT replica — first verified answer wins
-            return await self._fetch_chunk_hedged(digest, length,
-                                                  candidates)
-        for target in candidates:
+        return await self._walk_replicas(digest, length, candidates)
+
+    async def _walk_replicas(self, digest: str, length: int,
+                             candidates: list[int]) -> bytes:
+        """:meth:`_fetch_chunk`'s candidate walk: one replica after
+        another, the first VERIFIED answer wins. Under a hedge policy
+        ("The Tail at Scale", docs/serve.md) a replica that has not
+        answered within ``HedgePolicy.delay_s`` of the best replica's
+        windowed mean latency races the next one in the
+        (dual-read/ring-aware) candidate order; the loser is cancelled,
+        and every hedge draws from the node's token bucket so hedging
+        can never double cluster fetch load. A hedge changes WHEN the
+        next replica is asked, never what counts as an answer.
+        Coalesced readers (serve/rpc single-flight) share the leader's
+        hedge decision by construction: the hedge fires inside the one
+        flight they all await."""
+        hedge = self.serve.hedge
+        rf = self.cfg.cluster.replication_factor
+
+        async def attempt(nid: int) -> bytes | None:
+            """One replica's verified bytes, or None — miss, corrupt,
+            or dead."""
             try:
                 data = await self.client.get_chunk(
-                    self.cfg.cluster.peer(target), digest)
-                self.health.mark_alive(target)
+                    self.cfg.cluster.peer(nid), digest)
+                self.health.mark_alive(nid)
             except DeadlineExpired as e:
                 # the budget died, not the replicas: stop the walk —
                 # touring the remaining candidates would count each
@@ -2800,62 +1224,16 @@ class StorageNodeServer:
                 # and waste exactly the work the deadline forbids
                 raise DeadlineExceeded(str(e)) from e
             except RpcUnreachable:
-                self.health.mark_dead(target)
-                continue
+                self.health.mark_dead(nid)
+                return None
             except RpcError:
                 # live peer without the chunk — not a death signal, but
                 # counted (DFS007): a ring walk that keeps missing is
                 # placement skew the terminal DownloadError hides
                 self.counters.inc("remote_chunk_misses")
-                continue
+                return None
             # Verify against the manifest digest before trusting a peer
             # (stronger than the reference, which only checks the whole file).
-            if len(data) == length and sha256_hex(data) == digest:
-                self.counters.inc("chunks_fetched_remote")
-                if self.ring.is_prev_only(digest, target, rf):
-                    # served through the dual-read window: the byte
-                    # came from a previous-epoch owner mid-move
-                    self.ring.note_dual_read_hit()
-                return data
-            self.log.warning("corrupt chunk %s from node %d",
-                             digest[:12], target)
-        raise DownloadError(f"Could not retrieve chunk {digest[:12]}…")
-
-    async def _fetch_chunk_hedged(self, digest: str, length: int,
-                                  candidates: list[int]) -> bytes:
-        """The hedged-read walk of :meth:`_fetch_chunk` ("The Tail at
-        Scale"): a primary replica that has not answered within
-        ``HedgePolicy.delay_s`` of ITS OWN windowed mean latency races
-        the next replica in the (dual-read/ring-aware) candidate order;
-        the first VERIFIED answer wins, the loser is cancelled, and
-        every hedge draws from the node's token bucket so hedging can
-        never double cluster fetch load. The per-replica outcome
-        handling (health marks, miss counters, digest verification) is
-        the serial walk's, verbatim — a hedge changes WHEN the next
-        replica is asked, never what counts as an answer. Coalesced
-        readers (serve/rpc single-flight) share the leader's hedge
-        decision by construction: the hedge fires inside the one flight
-        they all await."""
-        hedge = self.serve.hedge
-        rf = self.cfg.cluster.replication_factor
-
-        async def attempt(nid: int) -> bytes | None:
-            """One replica's verified bytes, or None — miss, corrupt,
-            or dead, with exactly the serial walk's bookkeeping."""
-            try:
-                data = await self.client.get_chunk(
-                    self.cfg.cluster.peer(nid), digest)
-                self.health.mark_alive(nid)
-            except DeadlineExpired as e:
-                raise DeadlineExceeded(str(e)) from e  # stop the walk
-            except RpcUnreachable:
-                self.health.mark_dead(nid)
-                return None
-            except RpcError:
-                # live peer without the chunk — not a death signal (see
-                # _fetch_chunk; counted for placement-skew visibility)
-                self.counters.inc("remote_chunk_misses")
-                return None
             if len(data) == length and sha256_hex(data) == digest:
                 return data
             self.log.warning("corrupt chunk %s from node %d",
@@ -2865,14 +1243,16 @@ class StorageNodeServer:
         def accept(data: bytes, src: int) -> bytes:
             self.counters.inc("chunks_fetched_remote")
             if self.ring.is_prev_only(digest, src, rf):
+                # served through the dual-read window: the byte came
+                # from a previous-epoch owner mid-move
                 self.ring.note_dual_read_hit()
             return data
 
         i = 0
         while i < len(candidates):
             nid = candidates[i]
-            backup_id = candidates[i + 1] if i + 1 < len(candidates) \
-                else None
+            backup_id = candidates[i + 1] \
+                if hedge is not None and i + 1 < len(candidates) else None
             if backup_id is None:
                 data = await attempt(nid)
                 if data is not None:
@@ -2960,115 +1340,6 @@ class StorageNodeServer:
             i += 2                         # both replicas consumed
         raise DownloadError(f"Could not retrieve chunk {digest[:12]}…")
 
-    async def _hedged_get_chunks(self, primary_id: int, backup_id: int,
-                                 digests: list[str], expect: int
-                                 ) -> tuple[list, int]:
-        """Hedged batched fetch (docs/serve.md): issue ``get_chunks``
-        to the primary; if it outlives its latency-derived hedge delay
-        and the token bucket allows, race the SAME batch against the
-        backup replica — first completed reply wins, loser cancelled.
-        Returns ``(pairs, winner_id)``; exceptions propagate only when
-        BOTH sides fail (attributed to the primary — the caller's
-        health/error handling stays aimed at the peer it chose), so a
-        hedge can only ever improve on the unhedged call."""
-        hedge = self.serve.hedge
-
-        async def issue(nid: int):
-            return await self.client.get_chunks(
-                self.cfg.cluster.peer(nid), digests,
-                retries=None if self.health.is_alive(nid) else 1,
-                expect_bytes=expect)
-
-        task = asyncio.create_task(issue(primary_id))
-        btask: asyncio.Task | None = None
-
-        async def reap_on_cancel() -> None:
-            """OUR caller was cancelled: the racers must die with it —
-            shield/asyncio.wait leave their tasks running detached
-            otherwise (up to two ~32 MiB transfers for a reader that
-            is gone), and an unretrieved RpcError would log 'exception
-            was never retrieved' at GC."""
-            task.cancel()
-            if btask is not None:
-                btask.cancel()
-            await asyncio.gather(task,
-                                 *([btask] if btask is not None
-                                   else []),
-                                 return_exceptions=True)
-
-        # best-replica seed, not the primary's own mean — see
-        # RpcStats.recent_best_mean for the observed failure mode
-        delay = hedge.delay_s(
-            self.obs.rpc_client.recent_best_mean("get_chunks"))
-        try:
-            return await asyncio.wait_for(asyncio.shield(task),
-                                          delay), primary_id
-        # absence-as-result: the timeout IS the hedge trigger — the
-        # shielded primary keeps running and is raced below
-        except asyncio.TimeoutError:  # dfslint: ignore[DFS007]
-            pass                        # primary still in flight: hedge
-        except asyncio.CancelledError:
-            await reap_on_cancel()
-            raise
-        except BaseException:
-            raise                       # primary failed fast — the
-            # caller's RpcUnreachable/RpcError handling applies as-is
-        if not hedge.take():
-            try:
-                return await task, primary_id
-            except asyncio.CancelledError:
-                await reap_on_cancel()   # awaiting a Task does not
-                raise                    # cancel it — reap explicitly
-        hedge.note_fired()
-        self.obs.event("hedge_fired", op="get_chunks",
-                       primary=primary_id, backup=backup_id,
-                       chunks=len(digests), delayS=round(delay, 4))
-        btask = asyncio.create_task(issue(backup_id))
-        try:
-            done, _ = await asyncio.wait(
-                {task, btask}, return_when=asyncio.FIRST_COMPLETED)
-        except asyncio.CancelledError:
-            await reap_on_cancel()
-            raise
-        first, other = (task, btask) if task in done else (btask, task)
-        first_id, other_id = (primary_id, backup_id) if first is task \
-            else (backup_id, primary_id)
-        ferr = first.exception()
-        if ferr is None:
-            # loser cancelled; if it had already failed unreachable,
-            # keep the evidence (the health registry would learn it
-            # from the next probe anyway — this is just sooner)
-            other.cancel()
-            try:
-                await other
-            except (asyncio.CancelledError, RpcError, WireError):  # dfslint: ignore[DFS007]
-                pass    # reaped: the winner's reply is the result
-            if not other.cancelled() \
-                    and isinstance(other.exception(), RpcUnreachable):
-                self.health.mark_dead(other_id)
-            if first_id == backup_id:
-                hedge.note_won()
-                self.obs.event("hedge_won", op="get_chunks",
-                               primary=primary_id, backup=backup_id)
-            return first.result(), first_id
-        # first finisher failed: fall to the other side — no third RPC
-        if isinstance(ferr, RpcUnreachable):
-            self.health.mark_dead(first_id)
-        try:
-            got = await other
-        except asyncio.CancelledError:
-            await reap_on_cancel()       # the racer must die with us
-            raise
-        except (RpcError, WireError) as e:
-            # both failed: surface the PRIMARY's failure class so the
-            # caller's diagnosis targets the peer it actually chose
-            raise (ferr if first_id == primary_id else e) from None
-        if other_id == backup_id:
-            hedge.note_won()
-            self.obs.event("hedge_won", op="get_chunks",
-                           primary=primary_id, backup=backup_id)
-        return got, other_id
-
     _FETCH_BATCH_BYTES = 32 * 1024 * 1024
     _PROBE_SLICE_DIGESTS = 2048   # digests per repair has_chunks call
 
@@ -3149,7 +1420,6 @@ class StorageNodeServer:
             return groups
 
         async def fetch_batches(node_id: int, digests: list[str]) -> None:
-            peer = self.cfg.cluster.peer(node_id)
             batch: list[str] = []
             size = 0
 
@@ -3173,22 +1443,35 @@ class StorageNodeServer:
                                 break
                     if votes:
                         backup_id = max(votes, key=votes.get)
-                src = node_id
-                try:
+                src, asked = node_id, list(batch)
+                expect = sum(need[d] for d in asked)
+
+                async def issue(nid: int):
                     # known-dead peers get one fast probe, not the full
                     # retry envelope (same rule replication uses) — a
                     # degraded EC read would otherwise pay retries per
                     # batch for holders that died
+                    return await self.client.get_chunks(
+                        self.cfg.cluster.peer(nid), asked,
+                        retries=None if self.health.is_alive(nid) else 1,
+                        expect_bytes=expect)
+
+                try:
                     if backup_id is not None:
-                        got, src = await self._hedged_get_chunks(
-                            node_id, backup_id, list(batch),
-                            sum(need[d] for d in batch))
+                        hedge = self.serve.hedge
+                        got, src = await hedge.race(
+                            issue, node_id, backup_id, op="get_chunks",
+                            # best-replica seed, not the primary's own
+                            # mean — see RpcStats.recent_best_mean for
+                            # the observed failure mode
+                            delay_s=hedge.delay_s(
+                                self.obs.rpc_client.recent_best_mean(
+                                    "get_chunks")),
+                            event=self.obs.event,
+                            mark_dead=self.health.mark_dead,
+                            chunks=len(asked))
                     else:
-                        got = await self.client.get_chunks(
-                            peer, batch,
-                            retries=None
-                            if self.health.is_alive(node_id) else 1,
-                            expect_bytes=sum(need[d] for d in batch))
+                        got = await issue(node_id)
                     self.health.mark_alive(src)
                 except DeadlineExpired as e:
                     # the budget died, not the peer: abort the gather
@@ -3281,11 +1564,9 @@ class StorageNodeServer:
 
             async def who_has(nid: int) -> None:
                 try:
-                    resp, _ = await self.client.call(
-                        self.cfg.cluster.peer(nid),
-                        {"op": "has_chunks", "digests": missing},
-                        retries=1)
-                    for d in resp.get("have", []):
+                    for d in await self.client.has_chunks(
+                            self.cfg.cluster.peer(nid), missing,
+                            retries=1):
                         claims.setdefault(d, nid)
                 except DeadlineExpired as e:
                     raise DeadlineExceeded(str(e)) from e
@@ -3825,7 +2106,7 @@ class StorageNodeServer:
         pipeline depths actually reached."""
         ing = self.cfg.ingest
         return {"window": ing.window,
-                "flushBytes": self._STREAM_FLUSH_BYTES,
+                "flushBytes": self.ingest.flush_bytes,
                 "creditBytes": ing.credit_bytes,
                 "sliceInflight": ing.slice_inflight,
                 "stalls": self.ingest_stalls.snapshot(),
@@ -4710,11 +2991,9 @@ class StorageNodeServer:
                     # a healthy peer was marked dead (PERF.md §6, PR 25)
                     for i in range(0, len(probe_digests),
                                    self._PROBE_SLICE_DIGESTS):
-                        resp, _ = await self.client.call(
-                            peer, {"op": "has_chunks",
-                                   "digests": probe_digests[
-                                       i:i + self._PROBE_SLICE_DIGESTS]})
-                        have.update(resp.get("have", []))
+                        have |= await self.client.has_chunks(
+                            peer, probe_digests[
+                                i:i + self._PROBE_SLICE_DIGESTS])
                     if filter_known:
                         for d in probe_digests:
                             if d not in have:
@@ -4765,8 +3044,8 @@ class StorageNodeServer:
                     # most of a corpus. Serial slices on purpose: repair
                     # is background work and must not compete with live
                     # ingest for per-peer bandwidth.
-                    for part in self._slice_payloads(
-                            payload, self._REPLICA_SLICE_BYTES):
+                    for part in slice_payloads(
+                            payload, self.placement.slice_bytes):
                         if migrating:
                             # rebalance byte credits: migration pushes
                             # are rate-bounded per node so a membership
@@ -5116,28 +3395,19 @@ class StorageNodeServer:
         file readable: parity before flip (a flip without parity would
         strip redundancy), flip before deletes (deletes only remove
         copies the cold layout no longer expects)."""
-        import dataclasses
-
         plane = self.tier
         plane.note_credit_stall(await plane.credits.acquire(m.size))
         data = await self._gather_chunks(m)
         cold_m, parity = await asyncio.to_thread(
-            self._ec_extend_from, dataclasses.replace(m, tier="cold"),
+            self.ingest.ec_extend,
+            dataclasses.replace(m, tier="cold"),
             data, self.cfg.tier.ec_k)
-        seen: set[str] = set()
-        batch: list[tuple[str, bytes]] = []
-        for c in m.chunks:
-            if c.digest not in seen:
-                seen.add(c.digest)
-                batch.append((c.digest, data[c.digest]))
-        for d, b in parity:
-            if d not in seen:     # k=1 makes Q == P (upload's rule)
-                seen.add(d)
-                batch.append((d, b))
-        stats = self._new_upload_stats()
-        placement = ec_placement_map(cold_m, self.ring.current)
-        await self._place_batch(m.file_id, batch, stats, rf=1,
-                                placement=placement)
+        # every shard once: a file repeats chunks, and k=1 makes Q == P
+        batch = {c.digest: data[c.digest] for c in m.chunks}
+        batch.update(parity)
+        await self.placement.place(
+            m.file_id, list(batch.items()), new_upload_stats(), rf=1,
+            placement=ec_placement_map(cold_m, self.ring.current))
         if self.chaos is not None:
             self.chaos.maybe_crash("demote.after_parity_write")
         # the COMMIT: a tombstone landing mid-demotion wins — the file
@@ -5269,8 +3539,6 @@ class StorageNodeServer:
         now-unreferenced parity through the delete_chunks discipline.
         Mirror-ordered to demotion: replicas before flip, flip before
         parity deletes."""
-        import dataclasses
-
         plane = self.tier
         deadline.clear()          # spawned from a request's context —
         # background re-materialization must not inherit its budget
@@ -5278,21 +3546,16 @@ class StorageNodeServer:
             plane.note_credit_stall(await plane.credits.acquire(m.size))
             data = await self._gather_chunks(m)
             hot_m = dataclasses.replace(m, ec=None, tier=None)
-            seen: set[str] = set()
-            batch: list[tuple[str, bytes]] = []
-            for c in m.chunks:
-                if c.digest not in seen:
-                    seen.add(c.digest)
-                    batch.append((c.digest, data[c.digest]))
-            stats = self._new_upload_stats()
-            await self._place_batch(m.file_id, batch, stats)
+            batch = {c.digest: data[c.digest] for c in m.chunks}
+            await self.placement.place(m.file_id, list(batch.items()),
+                                       new_upload_stats())
             # the COMMIT (tombstone race aborts, as in demotion)
             if not await asyncio.to_thread(self.store.manifests.save,
                                            hot_m):
                 return
             if self.index is not None:
                 def flip():
-                    for d in sorted(seen):
+                    for d in sorted(batch):
                         self.index.note_tier(d, False)
                 await asyncio.to_thread(flip)
             await self._announce_all(hot_m)
@@ -5355,7 +3618,7 @@ class StorageNodeServer:
 
     async def _announce_all(self, manifest: Manifest) -> None:
         """Best-effort manifest announce to every peer (the
-        _finalize_upload fan-out WITHOUT fresh=True: a tier flip must
+        upload ack's fan-out WITHOUT fresh=True: a tier flip must
         bounce off tombstones, never resurrect a deleted file)."""
         mj = manifest.to_json()
 
@@ -5398,6 +3661,7 @@ class StorageNodeServer:
         out["reclaimedBytes"] = plane.reclaimed_bytes
         out["promotedFiles"] = plane.promoted_files
         out["promotedBytes"] = plane.promoted_bytes
+        out["promoting"] = len(self._tier_promoting)   # in flight now
         out["errors"] = plane.errors
         out["creditStallS"] = round(plane.credit_stall_s, 3)
         out["sinceProgressS"] = round(

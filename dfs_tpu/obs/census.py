@@ -90,7 +90,7 @@ def expected_state_ring(manifests: Sequence, ring, prev_ring, rf: int
     None the two maps are the same object."""
     # EC placement reuses the runtime's memoized stripe->holder map;
     # imported lazily because the runtime imports this module back
-    from dfs_tpu.node.runtime import ec_placement_map, ec_shard_items
+    from dfs_tpu.node.placement import ec_placement_map, ec_shard_items
 
     union: dict[str, tuple[int, ...]] = {}
     current: dict[str, tuple[int, ...]] = union if prev_ring is None \

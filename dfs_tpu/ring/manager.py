@@ -53,7 +53,7 @@ class ByteRate:
 
     One oversized request (a chunk larger than a whole second of
     credit) is admitted by letting the deficit go negative — the
-    classic byte-semaphore rule (ByteBudget in node/runtime.py): it
+    classic byte-semaphore rule (ByteBudget in node/ingest.py): it
     simply pre-charges future seconds, so the long-run rate still
     holds."""
 

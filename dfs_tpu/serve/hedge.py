@@ -38,13 +38,22 @@ owning event loop, no locks.
 
 from __future__ import annotations
 
+import asyncio
 import collections
 import time
+from typing import Awaitable, Callable
+
+from dfs_tpu.comm.rpc import RpcError, RpcUnreachable
+from dfs_tpu.comm.wire import WireError
 
 # hedge delay = clamp(factor x windowed mean, floor, cap): 3x the mean
 # approximates "slower than this call usually is, by enough margin that
 # healthy jitter does not hedge" without keeping per-peer histograms
 HEDGE_MEAN_FACTOR = 3.0
+
+
+def _ignore(*args, **kwargs) -> None:
+    """The journal and the health registry of a caller with neither."""
 
 
 class HedgePolicy:
@@ -102,6 +111,106 @@ class HedgePolicy:
 
     def note_won(self) -> None:
         self.won += 1
+
+    async def race(self, issue: Callable[[int], Awaitable], primary_id: int,
+                   backup_id: int, *, op: str, delay_s: float,
+                   event: Callable[..., None] = _ignore,
+                   mark_dead: Callable[[int], None] = _ignore,
+                   **detail) -> tuple[object, int]:
+        """The hedged call every plane makes (docs/serve.md): ``issue``
+        the RPC to the primary; if it outlives ``delay_s`` (see
+        :meth:`delay_s`) and the token bucket allows, race the SAME
+        call against the backup — first completed reply wins, loser
+        cancelled. The node's read path and the smart client race a
+        batched ``get_chunks``; the write path a ``store_chunks`` slice
+        train, where content-addressed puts make the duplicate
+        harmless: every hash-echo-verified slice is a real durable copy
+        wherever it landed, and a loser cancelled mid-flight keeps the
+        slices it already landed. Returns ``(result, winner_id)``;
+        exceptions propagate only when BOTH sides fail (attributed to
+        the primary — the caller's health/error handling stays aimed at
+        the peer it chose), so a hedge can only ever improve on the
+        unhedged call. ``event`` is the node's journal (``detail`` goes
+        into its ``hedge_fired``), ``mark_dead`` its health registry;
+        the client has neither."""
+        task = asyncio.create_task(issue(primary_id))
+        btask: asyncio.Task | None = None
+
+        async def reap_on_cancel() -> None:
+            """OUR caller was cancelled: the racers must die with it —
+            shield/asyncio.wait leave their tasks running detached
+            otherwise (up to two ~32 MiB transfers for a caller that
+            is gone), and an unretrieved RpcError would log 'exception
+            was never retrieved' at GC."""
+            racers = [t for t in (task, btask) if t is not None]
+            for t in racers:
+                t.cancel()
+            await asyncio.gather(*racers, return_exceptions=True)
+
+        try:
+            return await asyncio.wait_for(asyncio.shield(task),
+                                          delay_s), primary_id
+        # absence-as-result: the timeout IS the hedge trigger — the
+        # shielded primary keeps running and is raced below
+        except asyncio.TimeoutError:  # dfslint: ignore[DFS007]
+            pass                        # primary still in flight: hedge
+        except asyncio.CancelledError:
+            await reap_on_cancel()
+            raise
+        # (a primary that failed fast raises through: the caller's
+        # RpcUnreachable/RpcError handling applies as-is)
+        if not self.take():
+            try:
+                return await task, primary_id
+            except asyncio.CancelledError:
+                await reap_on_cancel()   # awaiting a Task does not
+                raise                    # cancel it — reap explicitly
+        self.note_fired()
+        event("hedge_fired", op=op, primary=primary_id,
+              backup=backup_id, **detail, delayS=round(delay_s, 4))
+        btask = asyncio.create_task(issue(backup_id))
+        try:
+            done, _ = await asyncio.wait(
+                {task, btask}, return_when=asyncio.FIRST_COMPLETED)
+        except asyncio.CancelledError:
+            await reap_on_cancel()
+            raise
+        first, other = (task, btask) if task in done else (btask, task)
+        first_id, other_id = (primary_id, backup_id) if first is task \
+            else (backup_id, primary_id)
+        ferr = first.exception()
+        if ferr is None:
+            # loser cancelled; if it had already failed unreachable,
+            # keep the evidence (the health registry would learn it
+            # from the next probe anyway — this is just sooner)
+            other.cancel()
+            try:
+                await other
+            except (asyncio.CancelledError, RpcError, WireError):  # dfslint: ignore[DFS007]
+                pass    # reaped: the winner's reply is the result
+            if not other.cancelled() \
+                    and isinstance(other.exception(), RpcUnreachable):
+                mark_dead(other_id)
+            winner, result = first_id, first.result()
+        else:
+            # first finisher failed: fall to the other side — no third RPC
+            if isinstance(ferr, RpcUnreachable):
+                mark_dead(first_id)
+            try:
+                result = await other
+            except asyncio.CancelledError:
+                await reap_on_cancel()       # the racer must die with us
+                raise
+            except (RpcError, WireError) as e:
+                # both failed: surface the PRIMARY's failure class so the
+                # caller's diagnosis targets the peer it actually chose
+                raise (ferr if first_id == primary_id else e) from None
+            winner = other_id
+        if winner == backup_id:
+            self.note_won()
+            event("hedge_won", op=op, primary=primary_id,
+                  backup=backup_id)
+        return result, winner
 
     @staticmethod
     def _recent(ts: collections.deque, cutoff: float) -> int:

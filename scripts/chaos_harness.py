@@ -60,18 +60,25 @@ def _probe_free(port: int) -> bool:
         s.close()
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    p = s.getsockname()[1]
-    s.close()
-    return p
+# where a harness looks for its ports: BELOW the kernel's ephemeral
+# range (32768-60999 here). A node process takes seconds to reach its
+# bind, and a port the kernel handed out as "free" from that range was
+# taken meanwhile as the source port of some other test's outgoing
+# connection — "address already in use", a node dead at start-up, 2 of
+# 361 tests in one parallel run. Each process walks its own lane of the
+# range (by pid), so concurrent harnesses do not probe the same run.
+_PORT_LO, _PORT_HI, _PORT_LANES = 10240, 32768, 64
+_next_port = 0
 
 
 def contiguous_free_ports(n: int) -> int:
     """cmd_serve derives peer ports as base+i; find a free run of n."""
-    for _ in range(50):
-        base = _free_port()
+    global _next_port
+    lane = (_PORT_HI - _PORT_LO) // _PORT_LANES
+    lo = _PORT_LO + os.getpid() % _PORT_LANES * lane
+    for _ in range(lane):
+        base = lo + _next_port % (lane - n)
+        _next_port += n
         if all(_probe_free(base + i) for i in range(n)):
             return base
     raise RuntimeError("no contiguous free port run found")
@@ -150,11 +157,11 @@ class ClusterHarness:
                     tail = self.node_log(i)[-2000:]
                     if "address already in use" in tail \
                             and tries < respawns:
-                        # bind(0)-allocated harness ports sit in the
-                        # ephemeral range: any process's OUTBOUND
-                        # connection can squat one before the node
-                        # binds it. Squatters are short-lived —
-                        # re-spawn after a beat (same flags).
+                        # something took the port between the probe
+                        # and the node's bind (harness ports lie below
+                        # the ephemeral range, so not an outbound
+                        # connection: another harness on this lane).
+                        # Re-spawn after a beat (same flags).
                         tries += 1
                         time.sleep(1.5)
                         self.start(i)
@@ -199,11 +206,9 @@ class ClusterHarness:
                 self.wait_ready([node_id], timeout=timeout)
                 return
             except HarnessError:
-                # while the node was dead, any process's OUTBOUND
-                # connection may have landed on its port as an
-                # ephemeral source (harness ports come from bind(0)) —
-                # the reborn node then dies with EADDRINUSE. Ephemeral
-                # squatters are short-lived: wait a beat and re-spawn.
+                # the reborn node died at start-up (its port taken
+                # while it was dead, or a slow host): wait a beat and
+                # re-spawn.
                 if a + 1 >= attempts:
                     raise
                 time.sleep(1.5)

@@ -40,7 +40,7 @@ REQUIRED_ARTIFACTS = ("OBS_r09.json", "WIRE_r10.json", "OBS2_r11.json",
                       "REBALANCE_r14.json", "CDC_SHARD_r15.json",
                       "DEDUP_INDEX_r16.json", "OVERLOAD_r18.json",
                       "CLIENT_r19.json", "TIER_r20.json",
-                      "SIM_r21.json")
+                      "SIM_r21.json", "OVERLOAD_r29.json")
 
 
 def _tracked_files(root: Path) -> list[Path]:

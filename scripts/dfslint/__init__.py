@@ -47,7 +47,8 @@ def analyze(roots, repo_root,
     stale baseline entries. The one entry point the CLI and the tier-1
     test share. ``stats``, when given, is filled in place with the
     ``--stats`` timing breakdown: ``files``, ``walkS``, ``totalS``,
-    and per-phase ``phases`` (model + each rule + audit).
+    and per-phase ``phases`` (model + each rule + audit), plus ``cpu``,
+    the same three in this thread's CPU seconds.
 
     ``only_paths`` (the ``--changed`` mode): REPORT only findings whose
     path is in the set, but still walk and model the full ``roots`` —
@@ -55,9 +56,10 @@ def analyze(roots, repo_root,
     effects) stay whole-tree sound, so a changed callee still fires on
     its unchanged caller's path being absent rather than on a model
     built from a partial tree."""
-    t_start = time.perf_counter()
+    t_start, c_start = time.perf_counter(), time.thread_time()
     project = Project(collect_sources(roots, repo_root))
     t_walk = time.perf_counter() - t_start
+    c_walk = time.thread_time() - c_start
     timings: dict | None = {} if stats is not None else None
     findings = run_rules(project, timings=timings)
     live_keys = {f.key for f in findings}
@@ -67,6 +69,7 @@ def analyze(roots, repo_root,
         out = [f for f in out if f.path in only_paths]
     out.sort(key=lambda f: (f.path, f.line, f.rule))
     if stats is not None:
+        cpu = (timings or {}).pop("cpu", {})
         stats.update({
             "files": len(project.files),
             "findings": len(out),
@@ -74,5 +77,8 @@ def analyze(roots, repo_root,
             "phases": {k: round(v, 6)
                        for k, v in (timings or {}).items()},
             "totalS": round(time.perf_counter() - t_start, 6),
+            "cpu": {"walkS": round(c_walk, 6),
+                    "phases": {k: round(v, 6) for k, v in cpu.items()},
+                    "totalS": round(time.thread_time() - c_start, 6)},
         })
     return out

@@ -639,7 +639,7 @@ def check_config_drift(project: Project) -> Iterator[Finding]:
 # them reintroduces exactly the full-body memcpy the scatter-gather
 # wire exists to eliminate (WIRE_r10.json measures the cost)
 _COPY_PLANE = ("dfs_tpu/comm/", "dfs_tpu/serve/", "dfs_tpu/store/",
-               "dfs_tpu/node/runtime.py")
+               "dfs_tpu/node/")
 
 
 def _on_copy_plane(rel: str) -> bool:
@@ -856,7 +856,7 @@ def check_affinity_race(project: Project) -> Iterator[Finding]:
 # where borrowed views circulate: the zero-copy data plane plus the
 # staging/sharding engines (the r15 bug lived in fragmenter staging)
 _VIEW_PLANE = ("dfs_tpu/comm/", "dfs_tpu/serve/", "dfs_tpu/store/",
-               "dfs_tpu/node/runtime.py", "dfs_tpu/fragmenter/",
+               "dfs_tpu/node/", "dfs_tpu/fragmenter/",
                "dfs_tpu/parallel/", "dfs_tpu/index/")
 # container-mutating calls that retain their argument: a borrowed view
 # passed here outlives the frame/pool guard that makes it valid
@@ -1385,8 +1385,19 @@ def run_rules(project: Project,
     DFS000 findings (a syntax error must fail the gate, not silently
     shrink the scanned set). ``timings``, when given, is filled with
     per-phase seconds (``model`` + one entry per rule + ``audit``) —
-    the ``--stats`` breakdown backing the tier-1 wall-clock budget."""
+    the ``--stats`` breakdown — and under ``"cpu"`` the same phases in
+    this thread's CPU seconds, which the tier-1 budget compares: a
+    phase's share of the CPU does not move with the host's load."""
     import time as _time
+
+    def clocks() -> tuple[float, float]:
+        return _time.perf_counter(), _time.thread_time()
+
+    def lap(phase: str, t0: tuple[float, float]) -> None:
+        if timings is not None:
+            wall, cpu = clocks()
+            timings[phase] = wall - t0[0]
+            timings.setdefault("cpu", {})[phase] = cpu - t0[1]
 
     out: list[Finding] = []
     by_rel = {s.rel: s for s in project.files}
@@ -1396,21 +1407,18 @@ def run_rules(project: Project,
                 "DFS000", "error", src.rel,
                 src.parse_error.lineno or 0, 0,
                 f"syntax error: {src.parse_error.msg}", "<parse>"))
-    t0 = _time.perf_counter()
+    t0 = clocks()
     build_model(project)   # phase 1, built once, shared by every rule
-    if timings is not None:
-        timings["model"] = _time.perf_counter() - t0
+    lap("model", t0)
     for rule_id, _desc, fn in ALL_RULES:
-        t0 = _time.perf_counter()
+        t0 = clocks()
         for f in fn(project):
             src = by_rel.get(f.path)
             if src is not None and src.is_suppressed(f.rule, f.line):
                 continue
             out.append(f)
-        if timings is not None:
-            timings[rule_id] = _time.perf_counter() - t0
-    t0 = _time.perf_counter()
+        lap(rule_id, t0)
+    t0 = clocks()
     out.extend(audit_suppressions(project))
-    if timings is not None:
-        timings["audit"] = _time.perf_counter() - t0
+    lap("audit", t0)
     return out

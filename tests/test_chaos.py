@@ -350,7 +350,10 @@ def test_enospc_surfaces_as_507_reads_keep_serving(tmp_path):
             assert node.counters.snapshot()["disk_full_rejects"] >= 1
             assert node.chaos.stats()["injected"].get("disk_full",
                                                       0) >= 1
-            # the journal carries the disk_pressure evidence
+            # the journal carries the disk_pressure evidence — once
+            # its writer thread has it on disk: wait on the written-
+            # record count, not on the thread's luck under load
+            await asyncio.to_thread(node.obs.journal.flush)
             tail = await asyncio.to_thread(node.obs.journal.tail,
                                            0.0, 256)
             assert any(ev.get("type") == "disk_pressure"
@@ -416,6 +419,7 @@ def test_partition_budget_fastfail_and_journal(tmp_path):
                     await node.client.call(peer, {"op": "health"})
             assert node.client.retry_budget.stats()[
                 "exhausted"]["2"] >= 1
+            await asyncio.to_thread(node.obs.journal.flush)
             tail = await asyncio.to_thread(node.obs.journal.tail,
                                            0.0, 256)
             assert any(ev.get("type") == "retry_budget_exhausted"

@@ -180,6 +180,64 @@ def test_filter_verdict_tristate_and_staleness_bound():
 
 
 # ------------------------------------------------------------------ #
+# unit: the client's hedged batch read when BOTH owners fail
+# ------------------------------------------------------------------ #
+
+@pytest.mark.parametrize("backup_ends_first", [False, True],
+                         ids=["primary fails first", "backup fails first"])
+def test_hedged_get_both_failed_surfaces_the_primarys_error(
+        backup_ends_first):
+    """The striped download's hedged batch (``HedgePolicy.race``, shared
+    with the node since PR 29): both owners failing raises the error of
+    the owner the stripe plan CHOSE, whichever ended first — an
+    ``RpcError`` here, which ``fetch_group`` leaves to the mop-up walk.
+    The client's own copy of the race raised the side that ended LAST:
+    with the backup garbling its frame after the primary had refused,
+    that was a ``WireError`` no caller caught."""
+    from dfs_tpu.client.smart import _ClientRingView
+    from dfs_tpu.comm.rpc import RpcError
+    from dfs_tpu.comm.wire import WireError
+    from dfs_tpu.ring import RingMap
+
+    c = SmartClient(cfg=ClientConfig(hedge_budget_per_s=100.0,
+                                     hedge_floor_s=0.0, hedge_cap_s=0.0))
+    ring = RingMap.static([1, 2, 3])
+    c._ringview = _ClientRingView(ring)
+    c._peers = {n: PeerAddr(node_id=n, host="127.0.0.1", port=1,
+                            internal_port=1) for n in (1, 2, 3)}
+    d = sha256_hex(b"both-fail")
+    primary, backup = ring.owners(d, 3)[:2]
+    gate = {n: asyncio.Event() for n in (primary, backup)}
+    ended = {n: asyncio.Event() for n in (primary, backup)}
+
+    class Rpc:
+        async def get_chunks(self, peer, digests, expect_bytes=0):
+            try:
+                await gate[peer.node_id].wait()
+                if peer.node_id == primary:
+                    raise RpcError("primary refused")
+                raise WireError("backup garbled")
+            finally:
+                ended[peer.node_id].set()
+
+    async def run() -> None:
+        got = asyncio.ensure_future(
+            c._hedged_get(Rpc(), primary, [d], 9))
+        order = (backup, primary) if backup_ends_first \
+            else (primary, backup)
+        for n in order:
+            gate[n].set()
+            await ended[n].wait()
+            for _ in range(3):
+                await asyncio.sleep(0)
+        with pytest.raises(RpcError, match="primary refused"):
+            await got
+        assert c._hedge.fired == 1 and c._hedge.won == 0
+
+    asyncio.run(run())
+
+
+# ------------------------------------------------------------------ #
 # in-process cluster: smart path end to end
 # ------------------------------------------------------------------ #
 

@@ -2028,26 +2028,42 @@ def test_annotation_doc_anchors_cover_every_rule():
 
 def test_full_run_within_wall_clock_budget():
     """Acceptance gate: the full run — interprocedural model included —
-    stays within 2x the pre-PR lint wall-clock, measured by --stats.
-    Pre-PR (r16 rules, this host): 1.69 s CLI wall; the absolute bound
-    is 2x that, and the host-independent bound says the phase-1 model
-    + new rules may at most DOUBLE the legacy phases' cost. Phase 3
-    (DFS011-013) carries its own sub-budget: it rides the phase-1
-    call index rather than re-walking ASTs, so the three rules
-    together must stay well under the model build itself."""
+    costs at most 3x its own legacy phases (the walk + DFS001-007),
+    measured by --stats: the phase-1 model + the rules after DFS007
+    may at most double-and-a-bit the legacy phases' cost. Phase 3
+    (DFS011-013) carries its own sub-budget: it rides the phase-1 call
+    index rather than re-walking ASTs, so the three rules together
+    must stay well under the model build itself.
+
+    Both bounds compare phases of ONE run, in the analyzer thread's CPU
+    seconds with the collector off: a phase's wall clock takes whatever
+    preemption lands on it under six xdist workers, and a gen-2
+    collection lands in whichever phase allocates next (the same tree
+    read 2.0x and 3.4x in one minute); its CPU share without them reads
+    2.3-2.8x idle and loaded alike (six runs each, same minute: this
+    tree 2.39-2.72x, PR 28's tree 2.52-2.77x). The bound was 2.2x
+    beside an absolute 3.4 s that did the guarding — 3.2x the legacy
+    phases on the host it was measured on — and failed wherever the
+    host was busy, because 2.2x is not what either tree costs: 3.0x
+    is BELOW what the parent's test let through."""
+    import gc
+
     stats: dict = {}
-    analyze(list(DEFAULT_ROOTS), REPO,
-            baseline=load_baseline(DEFAULT_BASELINE), stats=stats)
+    gc.collect()
+    gc.disable()
+    try:
+        analyze(list(DEFAULT_ROOTS), REPO,
+                baseline=load_baseline(DEFAULT_BASELINE), stats=stats)
+    finally:
+        gc.enable()
+    stats = stats["cpu"]
     phases = stats["phases"]
     legacy = stats["walkS"] + sum(
         phases.get(f"DFS00{i}", 0.0) for i in range(1, 8))
-    # 2.2x since r22: the phase-3 persistence rules joined the
-    # interprocedural allowance (they cost ~a tenth of the model
-    # build, bounded separately below)
-    assert stats["totalS"] <= max(3.4, 2.2 * legacy), stats
+    assert stats["totalS"] <= 3.0 * legacy, stats
     phase3 = sum(phases.get(r, 0.0)
                  for r in ("DFS011", "DFS012", "DFS013"))
-    assert phase3 <= max(0.8, 0.75 * phases["model"]), stats
+    assert phase3 <= 0.75 * phases["model"], stats
 
 
 # ------------------------------------------------------------------ #

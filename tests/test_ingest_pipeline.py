@@ -20,7 +20,8 @@ import pytest
 from dfs_tpu.comm.rpc import InternalClient, RpcError, RpcUnreachable
 from dfs_tpu.config import (CDCParams, ClusterConfig, IngestConfig,
                             NodeConfig, PeerAddr)
-from dfs_tpu.node.runtime import ByteBudget, StorageNodeServer
+from dfs_tpu.node.ingest import ByteBudget
+from dfs_tpu.node.runtime import StorageNodeServer
 from dfs_tpu.store.aio import AsyncChunkStore
 from dfs_tpu.store.cas import ChunkStore
 from dfs_tpu.utils.hashing import sha256_hex
@@ -643,7 +644,7 @@ def test_transfer_accounting_counts_once_per_peer(tmp_path, rng):
                              ingest=IngestConfig(slice_inflight=1))
         try:
             up = nodes[1]
-            up._REPLICA_SLICE_BYTES = 16 * 1024    # several slices/peer
+            up.placement.slice_bytes = 16 * 1024    # several slices/peer
             orig = up.client.store_chunks
             delivered: list[tuple[int, str, int]] = []
             peer2_calls = {"n": 0}

@@ -473,7 +473,7 @@ def test_streaming_upload_multiflush(tmp_path, rng):
         cluster = make_cluster_cfg(3)
         nodes = await start_nodes(cluster, tmp_path)
         try:
-            nodes[1]._STREAM_FLUSH_BYTES = 50_000   # force several flushes
+            nodes[1].ingest.flush_bytes = 50_000   # force several flushes
 
             async def blocks():
                 for i in range(0, len(data), 9000):
@@ -843,10 +843,11 @@ def test_resumable_upload_transfers_only_missing(tmp_path, rng):
             # before the client died — no manifest was committed
             refs = nodes[1].fragmenter.chunk(data)
             placed = refs[:len(refs) * 4 // 5]
-            stats = nodes[1]._new_upload_stats()
-            await nodes[1]._place_batch(
+            from dfs_tpu.node.placement import new_upload_stats
+
+            await nodes[1].placement.place(
                 "", [(c.digest, data[c.offset:c.offset + c.length])
-                     for c in placed], stats)
+                     for c in placed], new_upload_stats())
             assert nodes[1].list_files() == []   # nothing committed
 
             info = await asyncio.to_thread(c1.upload_resume, data, "r.bin")

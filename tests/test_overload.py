@@ -23,7 +23,7 @@ Layers of coverage:
   the hedge fires, the backup wins, the read returns fast, and the
   journal carries hedge_fired/hedge_won.
 - The ``bench_overload.py --tiny`` subprocess smoke gating all five
-  scripted scenarios end to end + the OVERLOAD_r18.json schema lock.
+  scripted scenarios end to end + the OVERLOAD_r29.json schema lock.
 """
 
 from __future__ import annotations
@@ -188,6 +188,95 @@ def test_hedge_policy_token_bucket_and_windows():
         pass
     time.sleep(0.01)                          # ~10 tokens of refill
     assert h2.take()
+
+
+class _Racers:
+    """Two RPCs whose ends the test orders: ``issue(n)`` waits for its
+    gate, then returns or raises what ``outcome[n]`` holds."""
+
+    def __init__(self, outcome: dict) -> None:
+        self.outcome = outcome
+        self.gate = {n: asyncio.Event() for n in outcome}
+        self.ended = {n: asyncio.Event() for n in outcome}
+
+    async def issue(self, n: int):
+        try:
+            await self.gate[n].wait()
+            if isinstance(self.outcome[n], Exception):
+                raise self.outcome[n]
+            return self.outcome[n]
+        finally:
+            self.ended[n].set()
+
+    async def end_in_order(self, *order: int) -> None:
+        for n in order:
+            self.gate[n].set()
+            await self.ended[n].wait()
+            for _ in range(3):      # let race() see this end first
+                await asyncio.sleep(0)
+
+
+PRIMARY, BACKUP = 2, 3
+
+
+@pytest.mark.parametrize("order", [(PRIMARY, BACKUP), (BACKUP, PRIMARY)],
+                         ids=["primary fails first", "backup fails first"])
+def test_race_both_sides_failed_raises_the_primarys_error(order):
+    """Every plane's hedged call (node read, node write, smart client):
+    when BOTH sides fail the caller sees the failure of the peer it
+    chose, whichever side failed first — its health/error handling is
+    aimed at that peer. (Until PR 29 the smart client's own copy of the
+    race surfaced whichever side failed LAST.)"""
+    from dfs_tpu.comm.rpc import RpcError, RpcUnreachable
+    from dfs_tpu.comm.wire import WireError
+
+    async def run() -> None:
+        h = HedgePolicy(floor_s=0.0, cap_s=0.0, budget_per_s=100.0)
+        r = _Racers({PRIMARY: RpcUnreachable("primary down"),
+                     BACKUP: WireError("backup garbled")})
+        dead: list[int] = []
+        events: list[str] = []
+        racing = asyncio.ensure_future(h.race(
+            r.issue, PRIMARY, BACKUP, op="get_chunks", delay_s=0.0,
+            event=lambda t, **kw: events.append(t),
+            mark_dead=dead.append))
+        await r.end_in_order(*order)
+        with pytest.raises(RpcUnreachable, match="primary down"):
+            await racing
+        assert (h.fired, h.won) == (1, 0)
+        assert events == ["hedge_fired"]
+        # unreachable is evidence only from the side that failed FIRST
+        # (the other's failure is the error being raised)
+        assert dead == ([PRIMARY] if order[0] == PRIMARY else [])
+        assert issubclass(RpcUnreachable, RpcError)
+
+    asyncio.run(run())
+
+
+def test_race_winner_survives_a_loser_that_fails_garbled():
+    """The loser is cancelled and reaped; if it had ALREADY failed —
+    with a transport error or a garbled frame (``WireError``, which the
+    smart client's copy let through until PR 29) — the winner's reply
+    is still the result."""
+    from dfs_tpu.comm.wire import WireError
+
+    async def run() -> None:
+        h = HedgePolicy(floor_s=0.0, cap_s=0.0, budget_per_s=100.0)
+        r = _Racers({PRIMARY: "pairs", BACKUP: WireError("garbled")})
+        racing = asyncio.ensure_future(h.race(
+            r.issue, PRIMARY, BACKUP, op="get_chunks", delay_s=0.0))
+        while h.fired == 0:             # both in flight, at their gates
+            await asyncio.sleep(0)
+        await asyncio.sleep(0)
+        # both end in the SAME loop turn: the primary's reply is taken
+        # first, the backup is found already failed when it is reaped
+        r.gate[BACKUP].set()
+        r.gate[PRIMARY].set()
+        assert await racing == ("pairs", PRIMARY)
+        assert r.ended[BACKUP].is_set()
+        assert (h.fired, h.won) == (1, 0)
+
+    asyncio.run(run())
 
 
 def test_serve_config_validates_deadline_hedge_fields():
@@ -806,21 +895,25 @@ def test_bench_overload_tiny_smoke(tmp_path):
     gates (shed curve + Retry-After + goodput SLO + the deadline
     never-executed proof), compound faults, a membership change during
     a partition, EC reconstruction under a killed shard holder, and
-    the hedged-read p99/RPC gates — all green, plus the
-    OVERLOAD_r18.json schema lock against the committed artifact."""
+    the hedged-read held-count/RPC gates — all green, plus the
+    OVERLOAD_r29.json schema lock against the committed artifact
+    (OVERLOAD_r18.json is the same bench as recorded with its
+    timer-driven pulse, before PR 29; nothing produces it any more)."""
     out_path = tmp_path / "overload_tiny.json"
     res = subprocess.run(
         [sys.executable, str(REPO / "bench_overload.py"), "--tiny",
          "--out", str(out_path)],
         cwd=tmp_path, capture_output=True, text=True, timeout=540,
+        # TMPDIR: the bench's three clusters live and die in this
+        # test's own directory
         env={**os.environ, "JAX_PLATFORMS": "cpu",
-             "PYTHONPATH": str(REPO)})
+             "PYTHONPATH": str(REPO), "TMPDIR": str(tmp_path)})
     os.sync()   # drain our writeback before the next test's fsyncs
     assert res.returncode == 0, (
         f"bench_overload --tiny failed:\n{res.stdout[-2000:]}"
         f"\n{res.stderr[-4000:]}")
     out = json.loads(out_path.read_text())
-    assert out["metric"] == "overload_survival" and out["round"] == 18
+    assert out["metric"] == "overload_survival" and out["round"] == 29
     assert out["ok"] is True
     scenarios = out["scenarios"]
     assert set(scenarios) == {"overload", "compound", "ring_partition",
@@ -839,13 +932,20 @@ def test_bench_overload_tiny_smoke(tmp_path):
     assert scenarios["ec_faults"]["reconstruction_exercised"]
     assert scenarios["ec_faults"]["background_read_corruptions"] == 0
     hd = scenarios["hedged_reads"]
-    assert hd["p99_cut_x"] >= 2.0 and hd["rpc_ratio"] <= 1.2
+    # the tail cut as counts (bench_overload.py: a HELD read took at
+    # least half the injected delay; the p99 read is held with hedging
+    # off and is not with it on), not as a ratio of two p99s that are
+    # each the maximum of 48 wall-clock samples
+    tail = -(-hd["reads_per_arm"] // 100)
+    assert hd["held_reads_off"] > tail >= hd["held_reads_on"]
+    assert hd["rpc_ratio"] <= 1.2
     assert hd["hedge_fired"] > 0 and hd["hedge_won"] > 0
 
     # schema lock against the COMMITTED artifact: same keys, so the
-    # bench cannot drift away from what OVERLOAD_r18.json claims
-    committed = json.loads((REPO / "OVERLOAD_r18.json").read_text())
+    # bench cannot drift away from what OVERLOAD_r29.json claims
+    committed = json.loads((REPO / "OVERLOAD_r29.json").read_text())
     assert set(committed) == set(out)
+    assert set(committed["workload"]) == set(out["workload"])
     assert set(committed["scenarios"]) == set(out["scenarios"])
     for name in scenarios:
         assert set(committed["scenarios"][name]) \

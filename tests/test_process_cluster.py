@@ -9,7 +9,6 @@ fragmenter probe, and the periodic repair loop — end to end.
 """
 
 import os
-import socket
 import subprocess
 import sys
 import time
@@ -24,40 +23,14 @@ N = 5
 REPO = Path(__file__).resolve().parent.parent
 
 
-def _free_port() -> int:
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    p = s.getsockname()[1]
-    s.close()
-    return p
-
-
-def _contiguous_free_ports(n: int) -> int:
-    """cmd_serve derives peer ports as base+i; find a free run of n."""
-    for _ in range(50):
-        base = _free_port()
-        if all(_probe_free(base + i) for i in range(n)):
-            return base
-    raise RuntimeError("no contiguous free port run found")
-
-
 def _two_port_runs(n: int) -> tuple[int, int]:
     """One free run of 2n ports split into (http_base, internal_base) —
     probing the runs separately could hand back overlapping ranges,
     since nothing holds the first range while the second is probed."""
-    base = _contiguous_free_ports(2 * n)
+    from scripts.chaos_harness import contiguous_free_ports
+
+    base = contiguous_free_ports(2 * n)
     return base, base + n
-
-
-def _probe_free(port: int) -> bool:
-    s = socket.socket()
-    try:
-        s.bind(("127.0.0.1", port))
-        return True
-    except OSError:
-        return False
-    finally:
-        s.close()
 
 
 def _png(width: int = 64, height: int = 64) -> bytes:

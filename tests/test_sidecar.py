@@ -226,7 +226,7 @@ def test_node_streaming_upload_through_sidecar_bounded(tmp_path, rng):
 
         async def run():
             node = StorageNodeServer(cfg)
-            node._STREAM_FLUSH_BYTES = 128 * 1024   # scale the flush down
+            node.ingest.flush_bytes = 128 * 1024   # scale the flush down
             await node.start()
             try:
                 manifest, stats = await node.upload_stream(blocks(), "s.bin")

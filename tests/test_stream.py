@@ -125,7 +125,7 @@ def _stream_node(tmp_path, sub: str, window: int = 2,
         data_root=tmp_path / sub, fragmenter="cdc", cdc=PARAMS,
         health_probe_s=0, ingest=IngestConfig(window=window))
     node = StorageNodeServer(cfg)
-    node._STREAM_FLUSH_BYTES = flush   # several batches on small inputs
+    node.ingest.flush_bytes = flush   # several batches on small inputs
     return node
 
 
@@ -164,7 +164,7 @@ def test_upload_stream_abort_stops_body_and_commits_nothing(tmp_path, rng):
     from dfs_tpu.node.runtime import StorageNodeServer, UploadError
 
     node = _stream_node(tmp_path, "abort", window=2, flush=32 * 1024)
-    real_place = node._place_batch
+    real_place = node.placement.place
     calls = {"n": 0}
 
     async def flaky_place(file_id, batch, stats, rf=None,
@@ -175,7 +175,7 @@ def test_upload_stream_abort_stops_body_and_commits_nothing(tmp_path, rng):
         await real_place(file_id, batch, stats, rf=rf,
                          placement=placement, ledger=ledger)
 
-    node._place_batch = flaky_place
+    node.placement.place = flaky_place
     consumed = {"blocks": 0}
     cap = 50_000                      # hard stop if the abort never fires
 

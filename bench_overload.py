@@ -281,8 +281,11 @@ def scenario_compound(h: ClusterHarness, p: dict) -> dict:
     # acking (2 reaches both), node 3 answers 507, node 1 rides handoff
     h.set_chaos(1, partition="2")
     h.set_chaos(3, disk_full=True)
+    # 16 payloads' worth: a --tiny body is 1-6 chunks, and one such body
+    # in sixteen has no chunk that node 3 owns — it writes nothing and
+    # answers 201 with its disk full
     st507, _ = h.http(3, "POST", "/upload?name=full.bin",
-                      body=os.urandom(p["payload"]),
+                      body=os.urandom(16 * p["payload"]),
                       timeout=p["op_timeout"])
     fault_thread = threading.Thread(
         target=load.run_for, args=(p["fault_s"],), daemon=True)

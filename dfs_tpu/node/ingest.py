@@ -604,11 +604,21 @@ class Ingest:
         self.counters.inc("uploads")
 
 
+# The fragmenter thread crosses to the event loop once per HAND-OFF, not
+# once per chunk: it holds the chunks an engine (or a sidecar reply)
+# gives it until this share of ``ingest.credit_bytes`` is held — never
+# more than one placement batch (``flush_bytes``), so no batch starts
+# later than its last chunk's hand-off — or the budget is full, or the
+# engine returns. What is held is already charged to the budget.
+_HANDOFF_CREDIT_SHARE = 4
+
+
 class _StreamUpload:
     """One streamed upload's pipeline and what its stages share: the
-    feeder (socket → ``inq``), the fragmenter thread (``inq`` → chunks →
-    ``outq``, gated by byte credits), and the consume loop (``outq`` →
-    batches → up to ``ingest.window`` placements in flight)."""
+    feeder (socket → ``inq``), the fragmenter thread (``inq`` → chunks,
+    gated by byte credits → hand-offs on ``outq``), and the consume loop
+    (``outq`` → batches → up to ``ingest.window`` placements in
+    flight)."""
 
     def __init__(self, ing: Ingest, blocks, name: str) -> None:
         self.ing = ing
@@ -629,6 +639,13 @@ class _StreamUpload:
         # the gate until round 7 — let max-size chunks oversubscribe the
         # budget by orders of magnitude.)
         self.credits = ByteBudget(ing.cfg.ingest.credit_bytes)
+        # the fragmenter thread's own: chunks charged and not yet handed
+        # to the loop, and the seconds it spent on them (``seamReplyS``)
+        self.held: list[tuple[str, bytes]] = []
+        self.held_bytes = 0
+        self.handoff_bytes = max(1, min(
+            self.credits.budget // _HANDOFF_CREDIT_SHARE, ing.flush_bytes))
+        self.seam_s = 0.0
         self.stats = new_upload_stats()
         self.seen: set[str] = set()
         self.window = max(1, ing.cfg.ingest.window)
@@ -659,16 +676,36 @@ class _StreamUpload:
 
     def _on_chunk(self, digest: str, payload: bytes) -> None:
         t0 = time.perf_counter()
-        while not self.credits.acquire(len(payload), timeout=0.5):
-            if self.aborted.is_set():
-                raise RuntimeError("upload aborted")
-        waited = time.perf_counter() - t0
-        if waited > 0.001:   # stall attribution: chunking blocked on
-            # unconsumed output (downstream placement is the
-            # bottleneck); sub-ms lock noise is not a stall
-            self.ing.stalls.add("creditS", waited)
-        self.loop.call_soon_threadsafe(self.outq.put_nowait,
-                                       (digest, payload))
+        n, waited = len(payload), 0.0
+        if not self.credits.acquire(n, timeout=0):
+            # the budget is full: what is held goes over first — the
+            # consume loop releases only what it has been handed
+            self._hand_off()
+            t1 = time.perf_counter()
+            while not self.credits.acquire(n, timeout=0.5):
+                if self.aborted.is_set():
+                    raise RuntimeError("upload aborted")
+            waited = time.perf_counter() - t1
+            if waited > 0.001:   # stall attribution: chunking blocked
+                # on unconsumed output (downstream placement is the
+                # bottleneck); sub-ms lock noise is not a stall
+                self.ing.stalls.add("creditS", waited)
+        self.held.append((digest, payload))
+        self.held_bytes += n
+        if self.held_bytes >= self.handoff_bytes:
+            self._hand_off()
+        self.seam_s += time.perf_counter() - t0 - waited
+
+    def _hand_off(self) -> None:
+        """Everything held crosses to the loop as ONE list, in stream
+        order; nothing crosses once the upload is aborted."""
+        if self.aborted.is_set():
+            raise RuntimeError("upload aborted")
+        if self.held:
+            self.loop.call_soon_threadsafe(self.outq.put_nowait, self.held)
+            self.ing.counters.inc("seam_handoffs")
+            self.ing.counters.inc("seam_chunks", len(self.held))
+            self.held, self.held_bytes = [], 0
 
     def _run_fragmenter(self) -> None:
         put = self.outq.put_nowait
@@ -681,12 +718,17 @@ class _StreamUpload:
                 m = self.ing.fragmenter.manifest_stream(
                     self._feed_iter(), name=self.name or "stream",
                     store=self._on_chunk)
+                self._hand_off()          # what the engine's end left
             self.loop.call_soon_threadsafe(put, ("done", m))
         # not silent: surfaced to the async consumer via the
         # ("error", e) queue item, which re-raises on the loop
         except BaseException as e:  # dfslint: ignore[DFS007]
             self.loop.call_soon_threadsafe(put, ("error", e))
         finally:
+            # chunks an abort or an engine's failure left here were
+            # charged and will never be consumed
+            self.credits.release(self.held_bytes)
+            self.ing.stalls.add("seamReplyS", self.seam_s)
             self.frag_dead.set()
 
     def _put_block(self, b) -> None:
@@ -794,27 +836,36 @@ class _StreamUpload:
             while inflight and inflight[0][0].done():
                 await self._drain_one()
             item = await self.outq.get()
-            if item[0] == "placed":
-                task = item[1]
-                if not task.cancelled() and task.exception() is not None:
-                    await task   # re-raise the placement failure
-                    # NOW — reading the body stops immediately
-                continue         # success: head drain above merges
-            if item[0] == "error" and isinstance(item[1], BaseException):
-                raise UploadError(f"fragmenter failed: {item[1]}")
-            if item[0] == "done" and isinstance(item[1], Manifest):
-                manifest = item[1]
+            if type(item) is tuple:      # ("placed" | "error" | "done", _)
+                tag, what = item
+                if tag == "placed":
+                    if not what.cancelled() \
+                            and what.exception() is not None:
+                        await what   # re-raise the placement failure
+                        # NOW — reading the body stops immediately
+                    continue         # success: head drain above merges
+                if tag == "error":
+                    raise UploadError(f"fragmenter failed: {what}")
+                manifest = what
                 break
-            digest, payload = item
-            self.credits.release(len(payload))
-            if digest in self.seen:
-                continue
-            self.seen.add(digest)
-            batch.append((digest, payload))
-            pending += len(payload)
-            if pending >= self.ing.flush_bytes:
-                await self._submit(batch)
-                batch, pending = [], 0
+            # a hand-off: chunks in stream order. Its credit goes back
+            # once — and before a batch it fills is submitted: a batch
+            # in flight counts against the window, not the budget —
+            # while duplicates and the batch cut are per chunk
+            freed = 0
+            for digest, payload in item:
+                freed += len(payload)
+                if digest in self.seen:
+                    continue
+                self.seen.add(digest)
+                batch.append((digest, payload))
+                pending += len(payload)
+                if pending >= self.ing.flush_bytes:
+                    self.credits.release(freed)
+                    freed = 0
+                    await self._submit(batch)
+                    batch, pending = [], 0
+            self.credits.release(freed)
         if batch:
             await self._submit(batch)
         while inflight:        # tail drain: the stream is chunked,

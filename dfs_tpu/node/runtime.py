@@ -2105,11 +2105,16 @@ class StorageNodeServer:
         replication vs the disk tier's queue/busy split) and the peak
         pipeline depths actually reached."""
         ing = self.cfg.ingest
+        counted = self.counters.snapshot()
         return {"window": ing.window,
                 "flushBytes": self.ingest.flush_bytes,
                 "creditBytes": ing.credit_bytes,
                 "sliceInflight": ing.slice_inflight,
                 "stalls": self.ingest_stalls.snapshot(),
+                # the owner seam: crossings from a fragmenter thread to
+                # the loop, and the chunks they carried
+                "seam": {"handoffs": counted.get("seam_handoffs", 0),
+                         "chunks": counted.get("seam_chunks", 0)},
                 "cas": self.cas.stats()}
 
     def frag_stats(self) -> dict:

@@ -334,8 +334,6 @@ class SidecarFragmenter(Fragmenter):
         still reaches the uploading client end to end."""
         import threading
 
-        from dfs_tpu.meta.manifest import ChunkRef
-
         cond = threading.Condition()
         buf = bytearray()
         base = 0                      # absolute offset of buf[0]
@@ -367,25 +365,27 @@ class SidecarFragmenter(Fragmenter):
             for msg in self.client.chunk_hash_duplex(tee()):
                 if msg.get("done"):
                     return
-                refs = []
-                for c in msg["chunks"]:
-                    ref = ChunkRef(index=c["index"], offset=c["offset"],
-                                   length=c["length"], digest=c["digest"])
-                    if store is not None:
-                        with cond:
-                            lo = ref.offset - base
-                            payload = bytes(buf[lo:lo + ref.length])
-                        if len(payload) != ref.length:
-                            raise RuntimeError(
-                                "sidecar chunk reply outran the teed stream")
-                        store(ref.digest, payload)
-                    refs.append(ref)
+                refs = self._refs(msg)
+                end = refs[-1].offset + refs[-1].length
+                payloads = ()
+                # a reply is sliced and the tee trimmed under ONE lock:
+                # one view over the tee, one copy a chunk, the view
+                # released before the trim resizes what it exported
                 with cond:
-                    end = refs[-1].offset + refs[-1].length
+                    if store is not None:
+                        with memoryview(buf) as mv:
+                            payloads = [
+                                bytes(mv[(lo := r.offset - base):
+                                         lo + r.length]) for r in refs]
                     if end > base:
                         del buf[:end - base]
                         base = end
                     cond.notify_all()
+                for ref, payload in zip(refs, payloads):
+                    if len(payload) != ref.length:
+                        raise RuntimeError(
+                            "sidecar chunk reply outran the teed stream")
+                    store(ref.digest, payload)
                 yield refs
         finally:
             with cond:

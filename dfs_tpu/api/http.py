@@ -414,6 +414,8 @@ async def _route(node: "StorageNodeServer", reader: asyncio.StreamReader,
         # history-sampler config/state (r12, additive like "obs")
         snap["durability"] = node.durability_stats()  # fsync mode +
         # barrier count (r13, additive)
+        snap["repair"] = node.repair_stats()  # the repair cycle:
+        # cycles ended, manifests read vs remembered, seconds on the loop
         snap["chaos"] = node.chaos_stats()  # fault-injection knobs +
         # injected counters; {"enabled": false} on a chaos-less node
         snap["retryBudget"] = node.client.retry_budget.stats()

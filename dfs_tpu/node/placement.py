@@ -23,6 +23,7 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import errno
+import threading
 import types
 from typing import Awaitable, Callable, Collection, Mapping, Sequence
 
@@ -95,13 +96,17 @@ def ec_placement_map(manifest: Manifest, ring) -> Mapping[str, tuple[int, ...]]:
     hit = _EC_PLACEMENT_CACHE.get(key)
     if hit is None:
         hit = _ec_placement_build(manifest, ring)
-        if len(_EC_PLACEMENT_CACHE) >= 64:
-            _EC_PLACEMENT_CACHE.pop(next(iter(_EC_PLACEMENT_CACHE)))
-        _EC_PLACEMENT_CACHE[key] = hit
+        # the repair cycle's pass asks from a worker thread (PR 35),
+        # reads from the loop: the eviction iterates the dict
+        with _EC_PLACEMENT_MU:
+            if len(_EC_PLACEMENT_CACHE) >= 64:
+                _EC_PLACEMENT_CACHE.pop(next(iter(_EC_PLACEMENT_CACHE)))
+            _EC_PLACEMENT_CACHE[key] = hit
     return hit
 
 
 _EC_PLACEMENT_CACHE: dict = {}
+_EC_PLACEMENT_MU = threading.Lock()
 
 
 def _ec_placement_build(manifest: Manifest, ring: RingMap

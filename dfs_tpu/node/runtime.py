@@ -46,6 +46,8 @@ from dfs_tpu.node.health import HealthMonitor
 from dfs_tpu.node.ingest import Ingest
 from dfs_tpu.node.placement import (Placement, ec_placement_map,
                                     ec_shard_items, new_upload_stats)
+from dfs_tpu.node.repair import ManifestMemo
+from dfs_tpu.node.repair import walk as repair_walk
 from dfs_tpu.obs import Observability, Span, parse_wire_trace
 from dfs_tpu.ring.manager import RingManager
 from dfs_tpu.serve import BatchPrefetcher, ServingTier
@@ -54,7 +56,7 @@ from dfs_tpu.store.cas import NodeStore
 from dfs_tpu.utils import deadline
 from dfs_tpu.utils.hashing import (is_hex_digest, sha256_hex,
                                    sha256_many_hex, sha256_new)
-from dfs_tpu.utils.aio import create_logged_task
+from dfs_tpu.utils.aio import create_logged_task, on_loop_seconds
 from dfs_tpu.utils.logging import Counters, Stopwatches, get_logger
 from dfs_tpu.utils.trace import LatencyRecorder
 
@@ -160,6 +162,10 @@ class StorageNodeServer:
         self.ring = RingManager(cfg, self.store.root, obs=self.obs)
         self.ring.on_change = self._on_ring_change
         self._repair_lock = asyncio.Lock()
+        # manifests the repair cycle's pass remembers between cycles
+        # (node/repair.py), and the seconds the cycles held the loop
+        self._repair_memo = ManifestMemo(self.store)
+        self._repair_on_loop_s = 0.0
         # async CAS tier: every event-loop chunk put/get routes through a
         # bounded thread pool (store/aio.py) — the loop never blocks on
         # chunk file I/O and disk concurrency is explicit
@@ -2418,7 +2424,8 @@ class StorageNodeServer:
         return {"mode": self.cfg.durability.mode,
                 "fsyncs": self.store.chunks.fsync_count(),
                 "dirBarriers": self.store.chunks.dir_barrier_count(),
-                **self.store.chunks.resident_stats()}
+                **self.store.chunks.resident_stats(),
+                **self.store.chunks.look_stats()}
 
     def chaos_stats(self) -> dict:
         """``/metrics`` ``chaos`` section: active knobs + per-kind
@@ -2781,8 +2788,28 @@ class StorageNodeServer:
             # serialized: the periodic repair loop and the install-time
             # rebalance kick must not interleave two walks (their
             # confirmed-sets would cross-talk into a bogus
-            # finish_migration)
-            return await self._repair_once_locked()
+            # finish_migration). One trace a cycle — `repair.cycle`, with
+            # `repair.walk`, a `repair.probe` a peer and `repair.sweep`
+            # under it — and the seconds it held the loop between awaits
+            # (/metrics repair.onLoopS), which is what live requests wait
+            # for; the rest of a cycle is spent in worker threads and
+            # on peers.
+            with self.obs.request_span("repair.cycle"):
+                return await on_loop_seconds(self._repair_once_locked(),
+                                             self._repair_held_loop)
+
+    def _repair_held_loop(self, seconds: float) -> None:
+        self._repair_on_loop_s += seconds
+
+    def repair_stats(self) -> dict:
+        """``/metrics`` ``repair`` section: cycles ended, manifests the
+        cycles' passes read and parsed (ever) against those remembered
+        now (node/repair.py ManifestMemo), and the seconds the cycles
+        held the event loop."""
+        return {"cycles": self.counters.snapshot().get("repairs", 0),
+                "manifestsRead": self._repair_memo.read,
+                "manifestsRemembered": self._repair_memo.remembered,
+                "onLoopS": round(self._repair_on_loop_s, 6)}
 
     async def _repair_once_locked(self) -> int:
         await self._tombstone_antientropy()
@@ -2794,14 +2821,24 @@ class StorageNodeServer:
         prev = self.ring.previous
         migrating = prev is not None
         rf = self.cfg.cluster.replication_factor
-        need: dict[int, list[tuple[str, int]]] = {}
-        chunk_len: dict[str, int] = {}
-        own_missing: dict[str, int] = {}
-        own_missing_ec: list[tuple[Manifest, list[ChunkRef]]] = []
-        ec_digests: set[str] = set()
+        # the pass over the manifests — what this node lacks, what each
+        # peer should hold, what is stray — in a worker thread, from
+        # manifests remembered since the last cycle (node/repair.py):
+        # it shares nothing mutable with the loop but the two maps
+        # taken above
+        with self.obs.span("repair.walk"):
+            walked = await asyncio.to_thread(
+                repair_walk, self.store, self._repair_memo,
+                self.cfg.node_id, rf, cur, prev)
+        need = walked.need
+        chunk_len = walked.chunk_len
+        own_missing = walked.own_missing
+        own_missing_ec = walked.own_missing_ec
+        ec_digests = walked.ec_digests
         # previous-epoch holders of EC shards (designated-mover order);
         # replicated digests compute theirs on demand (one ring walk)
-        prev_ec_holders: dict[str, tuple[int, ...]] = {}
+        prev_ec_holders = walked.prev_ec_holders
+        stray = walked.stray
 
         def designated_mover(d: str) -> bool:
             """During a migration exactly ONE node streams a digest to
@@ -2821,56 +2858,6 @@ class StorageNodeServer:
                 if self.health.is_alive(p):
                     return False
             return True
-        # One readdir snapshot of the local catalog, off the loop. It
-        # serves BOTH sides of the walk below: the own-missing checks
-        # (which previously paid a stat() per canonical digest) and the
-        # stray detection — local copies of chunks this node is NOT a
-        # canonical holder of (sloppy-quorum handoff leftovers, stale
-        # placement), candidates for relocation-by-deletion once every
-        # canonical holder is confirmed. Net cost vs pre-r13: one
-        # listing replaces thousands of stats (gc at the end of this
-        # cycle already re-lists for its own sweep, as before).
-        local_digests = set(await asyncio.to_thread(
-            self.store.chunks.digests))
-        stray: dict[str, frozenset[int]] = {}
-        for m in self.store.manifests.list():
-            if m.ec is not None:
-                # EC shards live at stripe-derived holders, one copy
-                # each; a holder missing its shard regenerates it LOCALLY
-                # via parity decode (the push loop below only relocates
-                # surviving copies — it cannot invent lost bytes)
-                pl = ec_placement_map(m, cur)
-                pl_prev = ec_placement_map(m, prev) if migrating else {}
-                miss: dict[str, int] = {}
-                for d, ln in ec_shard_items(m):
-                    chunk_len[d] = ln
-                    ec_digests.add(d)
-                    if migrating:
-                        prev_ec_holders.setdefault(
-                            d, tuple(pl_prev.get(d, ())))
-                    for target in pl[d]:
-                        if target != self.cfg.node_id:
-                            need.setdefault(target, []).append((d, ln))
-                        elif d not in local_digests:
-                            miss[d] = ln
-                if miss:
-                    own_missing_ec.append(
-                        (m, [ChunkRef(index=0, offset=0, length=ln,
-                                      digest=d)
-                             for d, ln in miss.items()]))
-                continue
-            for c in m.chunks:
-                chunk_len[c.digest] = c.length
-                targets = cur.owners(c.digest, rf)
-                for target in targets:
-                    if target != self.cfg.node_id:
-                        need.setdefault(target, []).append(
-                            (c.digest, c.length))
-                    elif c.digest not in local_digests:
-                        own_missing[c.digest] = c.length
-                if self.cfg.node_id not in targets \
-                        and c.digest in local_digests:
-                    stray[c.digest] = frozenset(targets)
 
         repaired = 0
         # restore this node's OWN canonical copies first (lost to scrub
@@ -2961,7 +2948,12 @@ class StorageNodeServer:
         # holder is in this set, so a copy is never deleted on faith
         confirmed: dict[str, set[int]] = {}
         plane = self.index
-        for node_id, wanted in need.items():
+
+        async def peer_pass(node_id: int, wanted) -> int:
+            """Probe one peer for what it should hold, push what it
+            lacks; returns the chunks pushed and echoed."""
+            nonlocal verified
+            repaired = 0
             peer = self.cfg.cluster.peer(node_id)
             digests = sorted({d for d, _ in wanted})
             # peer-filter trim (docs/index.md): digests the peer's
@@ -3078,7 +3070,11 @@ class StorageNodeServer:
                 # date on it
                 self.obs.event("repair_push_fail", peer=peer.node_id,
                                cause=type(e).__name__)
-                continue
+            return repaired
+
+        for node_id, wanted in need.items():
+            with self.obs.span("repair.probe", peer=node_id):
+                repaired += await peer_pass(node_id, wanted)
         # only drop repair entries we actually confirmed on a peer
         self.under_replicated -= verified
         # Relocation: sloppy-quorum handoff parked copies on
@@ -3126,7 +3122,14 @@ class StorageNodeServer:
         # before their manifest existed, then never committed) have no
         # other reclamation path; the 1h grace keeps in-flight uploads
         # safe (manifest-last ordering makes their chunks look orphaned)
-        swept = self.store.gc(min_age_s=3600.0)
+        # — in a worker thread, over the pass's listing and rows; the
+        # manifests saved since the pass are read before anything is
+        # deleted (store/cas.py sweep_orphans)
+        with self.obs.span("repair.sweep"):
+            swept = await asyncio.to_thread(
+                self.store.sweep_orphans, walked.local_digests,
+                set(chunk_len), 3600.0,
+                lambda: self._repair_memo.named_since(walked.seen))
         if swept:
             self.serve.drop_cached(swept)
             self.log.info("gc: swept %d aged orphan chunks", len(swept))

@@ -84,6 +84,30 @@ _TMP_SWEEP_AGE_S = 3600.0
 _RESIDENT_MAX = 1 << 20
 
 
+# A look at the disk for a batch (``ChunkStore.has_many`` without
+# ``resident_ok``, index off) lists a shard directory once where that
+# is cheaper than a ``stat`` for each name the batch asks of it.
+# Measured on the file system of the benchmark's machine (9p under
+# gVisor; PERF.md §6, PR 35, scripts/fsprice.py), directories of 30 /
+# 120 / 500 / 3 400 files of 8 KiB: a ``stat`` 0.08 ms idle and 0.43 ms
+# beside eight fsyncing writers; a listing (``os.scandir``, the wanted
+# names' ``is_file`` included — the entry's type is known there) 0.24 /
+# 0.63 / 2.2 / 15.3 ms idle and 0.7–1.2 / 1.0–3.6 / 6.9–11.1 / 70–94 ms
+# beside them: the time of 2.8 / 7.4 / 28 / 193 ``stat``s idle and
+# 1.4–2.8 / 2.5–8.2 / 16–25 / 160–219 busy. So a listing costs what
+# two to three ``stat``s cost plus one more for every ~17 entries it
+# reads, loaded or not: it pays where the batch asks about at least
+# ``_LIST_MIN_NAMES`` names of a directory and about more than one in
+# ``_LIST_ENTRIES_PER_STAT`` of the names the directory holds (taken as
+# the store's chunk count over its 256 directories: sha256 spreads them
+# evenly). The repair cycle's probe — 2 048 sorted digests a slice, so
+# most of ~20 directories each — lists; a probe of a few names, or of
+# one object's chunks in a store of millions, is a ``stat`` a name.
+_LIST_MIN_NAMES = 4
+_LIST_ENTRIES_PER_STAT = 16
+_SHARD_DIRS = 256
+
+
 def _sweep_tmp_files(dirs, max_age_s: float = _TMP_SWEEP_AGE_S) -> int:
     """Unlink ``.tmp-*`` entries older than ``max_age_s`` in the given
     directories; returns the number removed. Shared by the chunk and
@@ -158,6 +182,9 @@ class ChunkStore:
         self._unlinks = 0                  # chunk unlinks ended
         # resident answers / went on to a stat / entries found stale
         self._res_hits = self._res_misses = self._res_drops = 0
+        # disk-looking batches (has_many without resident_ok): names
+        # looked for by a stat / answered from a listing / listings made
+        self._look_stats = self._look_listed = self._look_listings = 0
         self._count_lock = threading.Lock()   # puts run on CAS pool workers
         # orders the visible link/unlink against its index record: a
         # put racing a delete of the SAME digest could otherwise
@@ -432,8 +459,105 @@ class ChunkStore:
     def has_many(self, digests, resident_ok: bool = False) -> list[bool]:
         """Batched :meth:`has` — one call for a whole probe list, so
         async callers pay one thread-pool job instead of one per
-        digest (:meth:`AsyncChunkStore.has_many`)."""
-        return [self.has(d, resident_ok) for d in digests]
+        digest (:meth:`AsyncChunkStore.has_many`).
+
+        A batch that has to look at the disk (index off, no
+        ``resident_ok``: the repair cycle's probe of every name a peer
+        should hold, every cycle) looks by DIRECTORY: grouped by shard
+        directory, and a directory the batch asks enough names of that
+        one listing is cheaper than their ``stat``s (``_LIST_MIN_NAMES``
+        and ``_LIST_ENTRIES_PER_STAT``, above) is listed once and its
+        names answered from the listing (:meth:`_list_present`); the
+        others are a :meth:`has` each — a ``stat`` a name, as a small
+        batch (``who_has``, a resume probe) always is. The answers are
+        those of the :meth:`has` loop, name for name."""
+        if resident_ok or self.index is not None:
+            return [self.has(d, resident_ok) for d in digests]
+        ds = list(digests)
+        by_dir: dict[str, set[str]] = {}
+        for d in ds:
+            if not is_hex_digest(d):
+                raise ValueError(f"bad digest {d!r}")
+            by_dir.setdefault(d[:2], set()).add(d)
+        many = {sub: names for sub, names in by_dir.items()
+                if len(names) >= _LIST_MIN_NAMES}
+        listed = {}
+        if many:
+            held = self.count() / _SHARD_DIRS      # names a directory
+            listed = {sub: self._list_present(sub, names)
+                      for sub, names in many.items()
+                      if len(names) * _LIST_ENTRIES_PER_STAT >= held}
+        out = []
+        stats = 0
+        for d in ds:
+            present = listed.get(d[:2])
+            if present is None:
+                out.append(self.has(d))
+                stats += 1
+            else:
+                out.append(d in present)
+        with self._count_lock:
+            self._look_stats += stats
+            self._look_listed += len(ds) - stats
+            self._look_listings += len(listed)
+        return out
+
+    def _list_present(self, sub: str, wanted: set[str]) -> set[str]:
+        """Which of ``wanted`` — digests of shard directory ``sub`` —
+        are present, from ONE listing of it: the truth ``os.path.isfile``
+        told a name at a time (a regular file of exactly that name, or
+        a link to one; ``is_file`` asks the file system only where the
+        entry's type is unknown, and only for a wanted name). A name the
+        listing lacks may still be delta-stored. Heals the resident set
+        as :meth:`_raw_present` does: a listed name is entered under the
+        unlink-count rule of a ``stat`` outside ``_index_mu``; an entry
+        that was there BEFORE the listing began and that the listing
+        lacks is dropped — so a name linked while the directory was
+        being read is never forgotten."""
+        keys = {d: bytes.fromhex(d) for d in wanted}
+        with self._count_lock:
+            seen = self._unlinks
+            known = {d for d, k in keys.items() if k in self._resident}
+        found: set[str] = set()
+        try:
+            with os.scandir(f"{self._root_str}/{sub}") as it:
+                for e in it:
+                    if e.name in wanted:
+                        try:
+                            if e.is_file():
+                                found.add(e.name)
+                        # unlinked under the look, or unreadable: what
+                        # os.path.isfile calls absent
+                        except OSError:  # dfslint: ignore[DFS007]
+                            pass
+        # no such directory: nothing of it is there
+        except (FileNotFoundError, NotADirectoryError):
+            pass
+        new = found - known
+        if new:
+            with self._index_mu:
+                for d in new:
+                    self._remember(keys[d], seen)
+        for d in known - found:
+            self._forget(keys[d])
+        if len(found) < len(wanted) and self._deltas_possible():
+            found.update(d for d in wanted - found
+                         if self._chain_resolves(d))
+        if self._fsync:
+            for d in found:
+                # a name still owed its directory barrier gets it first
+                self._settle(d, self._path_str(d))
+        return found
+
+    def look_stats(self) -> dict:
+        """``/metrics`` ``durability.look*``: over the batches that
+        looked at the disk (:meth:`has_many`), the names looked for by
+        a ``stat``, the names answered from a listing, and the
+        directories listed."""
+        with self._count_lock:
+            return {"lookStats": self._look_stats,
+                    "lookListed": self._look_listed,
+                    "lookListings": self._look_listings}
 
     def put(self, digest: str, data: bytes, verify: bool = True,
             sketch=None) -> bool:
@@ -1388,23 +1512,40 @@ class NodeStore:
         live: set[str] = set()
         for m in self.manifests.list():
             live.update(m.all_digests())   # incl. erasure parity chunks
-        # delta-base pinning (similarity plane): a live delta-stored
-        # chunk reconstructs through its base chain, so every base
-        # under a live delta is live too — GC'ing one would break reads
-        # of a still-referenced file. chunks.delete()'s pin refusal
-        # backs this up; expanding the live set here keeps the dead
-        # list honest instead of relying on refusals.
-        for d in list(live):
-            cur = d
-            for _ in range(64):
-                base = self.chunks.delta_base(cur)
-                if base is None:
-                    break
-                live.add(base)
-                cur = base
+        return self.sweep_orphans(self.chunks.digests(), live, min_age_s)
+
+    def _with_delta_bases(self, live: set[str]) -> set[str]:
+        """Delta-base pinning (similarity plane): a live delta-stored
+        chunk reconstructs through its base chain, so every base under
+        a live delta is live too — GC'ing one would break reads of a
+        still-referenced file. chunks.delete()'s pin refusal backs this
+        up; expanding the live set keeps the dead list honest instead
+        of relying on refusals."""
+        if self.chunks.delta_count():
+            for d in list(live):
+                cur = d
+                for _ in range(64):
+                    base = self.chunks.delta_base(cur)
+                    if base is None:
+                        break
+                    live.add(base)
+                    cur = base
+        return live
+
+    def sweep_orphans(self, listing, live: set[str], min_age_s: float,
+                      named_since=None) -> list[str]:
+        """:meth:`gc` over a listing and a live set the caller already
+        has (the repair cycle's: both as old as its pass): delete the
+        digests of ``listing`` that ``live`` does not name and that
+        pass the age gate. ``named_since()`` is asked once there is
+        something to delete and just before it is deleted: the digests
+        named by manifests saved after ``live`` was taken — a manifest
+        committed meanwhile protects its chunks as it would from a
+        :meth:`gc` that began now."""
+        live = self._with_delta_bases(live)
         cutoff = time.time() - min_age_s
         dead = []
-        for d in self.chunks.digests():
+        for d in listing:
             if d in live:
                 continue
             if min_age_s > 0:
@@ -1418,6 +1559,9 @@ class NodeStore:
                 if st.st_mtime > cutoff:
                     continue
             dead.append(d)
+        if dead and named_since is not None:
+            fresh = self._with_delta_bases(named_since())
+            dead = [d for d in dead if d not in fresh]
         # dead deltas first: deleting one releases its base pin, so a
         # dead base in the SAME pass is reclaimable instead of being
         # refused until the next cycle

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import asyncio
+import time
 
 
 def create_logged_task(coro, log, what: str) -> asyncio.Task:
@@ -50,3 +51,39 @@ async def gather_abort_siblings(*coros):
             t.cancel()
         await asyncio.gather(*tasks, return_exceptions=True)
         raise
+
+
+class on_loop_seconds:
+    """``await on_loop_seconds(coro, add)`` is ``await coro`` — with
+    every step the coroutine runs between two suspensions timed, and
+    the seconds handed to ``add``: the time it HELD the event loop, as
+    opposed to the time it took. Coroutines it awaits count (they run
+    inside its steps); tasks it spawns do not."""
+
+    def __init__(self, coro, add) -> None:
+        self._coro = coro
+        self._add = add
+
+    def __await__(self):
+        coro, add = self._coro, self._add
+        exc = None
+        try:
+            while True:
+                t = time.perf_counter()
+                try:
+                    # a task resumes its coroutine with None, or throws
+                    # (cancellation): a future's result is read by the
+                    # innermost await, not sent from here
+                    waits_on = coro.send(None) if exc is None \
+                        else coro.throw(exc)
+                except StopIteration as done:
+                    return done.value
+                finally:
+                    add(time.perf_counter() - t)
+                exc = None
+                try:
+                    yield waits_on
+                except BaseException as e:  # noqa: BLE001 — thrown on
+                    exc = e
+        finally:
+            coro.close()

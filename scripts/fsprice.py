@@ -1,0 +1,128 @@
+#!/usr/bin/env python3
+"""What a ``stat`` and a directory listing cost on THIS file system, idle
+and beside fsyncing writers — the measurement the chunk store's
+``_LIST_MIN_NAMES`` / ``_LIST_ENTRIES_PER_STAT`` are derived from
+(``dfs_tpu/store/cas.py``; PERF.md §6, PR 35).
+
+    python scripts/fsprice.py            # here: a place to look, not a price
+    chiprun -- python scripts/fsprice.py # the benchmark's machine: the price
+
+Directories of 30 / 120 / 500 / 3 400 files of 8 KiB (a shard directory
+of a cell's node, and of the source's deployment) under ``$TMPDIR``:
+per directory size, the median ``os.path.isfile`` of a present and of an
+absent name, one ``os.scandir`` with the wanted names' ``is_file``, and
+how many ``stat``s that listing is worth — then the same beside eight
+threads that create, fsync and link files as the store's writers do.
+Jax-free, stdlib only; removes what it made.
+"""
+
+from __future__ import annotations
+
+import os
+import random
+import shutil
+import statistics
+import tempfile
+import threading
+import time
+
+SIZES = (30, 120, 500, 3400)
+WRITERS = 8
+
+
+def _names(seed: str, n: int) -> list[str]:
+    rng = random.Random(seed)       # 64 hex digits: a chunk file's name
+    return ["%064x" % rng.getrandbits(256) for _ in range(n)]
+
+
+def _fill(d: str, names: list[str], size: int = 8192) -> None:
+    os.makedirs(d, exist_ok=True)
+    for n in names:
+        fd = os.open(f"{d}/{n}", os.O_WRONLY | os.O_CREAT, 0o600)
+        os.write(fd, b"x" * size)
+        os.close(fd)
+
+
+def measure(tag: str, dirs: dict[str, list[str]]) -> None:
+    for size in SIZES:
+        stat_s, absent_s, list_s = [], [], []
+        for d, names in dirs.items():
+            if f"/s{size}-" not in d:
+                continue
+            asked = names[:200]
+            t = time.perf_counter()
+            for n in asked:
+                os.path.isfile(f"{d}/{n}")
+            stat_s.append((time.perf_counter() - t) / len(asked))
+            t = time.perf_counter()
+            for n in asked:
+                os.path.isfile(f"{d}/{n[::-1]}")
+            absent_s.append((time.perf_counter() - t) / len(asked))
+            want = set(names)
+            t = time.perf_counter()
+            with os.scandir(d) as it:
+                got = {e.name for e in it if e.name in want and e.is_file()}
+            list_s.append(time.perf_counter() - t)
+            assert len(got) == size
+        m = statistics.median
+        print(f"{tag} dir={size}: stat present {m(stat_s) * 1e3:.4f} ms, "
+              f"absent {m(absent_s) * 1e3:.4f} ms; listing "
+              f"{m(list_s) * 1e3:.3f} ms ({m(list_s) / size * 1e6:.2f} "
+              f"us/entry) = {m(list_s) / m(stat_s):.1f} stats; worst "
+              f"listing {max(list_s) * 1e3:.3f} ms", flush=True)
+
+
+def main() -> None:
+    root = tempfile.mkdtemp(prefix="fsprice_")
+    try:
+        t = time.perf_counter()
+        dirs: dict[str, list[str]] = {}
+        for size in SIZES:
+            for k in range(8 if size < 3400 else 2):
+                d = f"{root}/s{size}-{k}"
+                dirs[d] = _names(d, size)
+                _fill(d, dirs[d])
+        print(f"made {sum(map(len, dirs.values()))} files in "
+              f"{time.perf_counter() - t:.2f}s on {root}", flush=True)
+        measure("idle", dirs)
+        stop = threading.Event()
+        made = [0] * WRITERS
+
+        def writer(k: int) -> None:
+            d = f"{root}/w{k}"
+            os.makedirs(d)
+            while not stop.is_set():
+                p = f"{d}/{made[k]}"
+                fd = os.open(p + ".tmp",
+                             os.O_WRONLY | os.O_CREAT | os.O_EXCL, 0o600)
+                os.write(fd, b"y" * 8192)
+                os.fsync(fd)
+                os.close(fd)
+                # load beside the measurement, not a store: nothing
+                # here is read back  # dfslint: ignore[DFS013]
+                os.link(p + ".tmp", p)
+                os.unlink(p + ".tmp")
+                dfd = os.open(d, os.O_RDONLY)
+                os.fsync(dfd)
+                os.close(dfd)
+                made[k] += 1
+
+        threads = [threading.Thread(target=writer, args=(k,))
+                   for k in range(WRITERS)]
+        t = time.perf_counter()
+        for w in threads:
+            w.start()
+        time.sleep(0.5)
+        measure(f"beside {WRITERS} writers", dirs)
+        measure(f"beside {WRITERS} writers", dirs)
+        stop.set()
+        for w in threads:
+            w.join()
+        print(f"the writers made {sum(made)} files in "
+              f"{time.perf_counter() - t:.2f}s")
+    finally:
+        shutil.rmtree(root, ignore_errors=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -491,13 +491,24 @@ def test_reupload_through_another_coordinator_stats_no_known_chunk(
     asyncio.run(run())
 
 
+@pytest.mark.parametrize("look", ["a_stat_a_name", "a_listing_a_directory",
+                                  "as_shipped"])
 def test_repair_restores_a_file_removed_behind_a_store_that_remembers_it(
-        tmp_path, rng, monkeypatch):
+        tmp_path, rng, monkeypatch, look):
     """The one caveat and its bound: a chunk file unlinked behind the
     peer's store, after the store memoised it, is still "present" to a
     caller that takes a resident answer — and ONE repair cycle, which
     looks at the disk, drops the entry, pushes, and the push's pre-check
-    misses and writes: exactly that chunk, byte-identical."""
+    misses and writes: exactly that chunk, byte-identical. The look is
+    the same bound whether the peer's store answers a directory's names
+    by a ``stat`` each, from one listing of it (since PR 35), or — as
+    shipped, on a store this small — some directories one way and some
+    the other."""
+    import dfs_tpu.store.cas as cas
+    if look != "as_shipped":
+        monkeypatch.setattr(cas, "_LIST_MIN_NAMES",
+                            1 if look == "a_listing_a_directory"
+                            else 10 ** 9)
     data = rng.integers(0, 256, size=200_000, dtype=np.uint8).tobytes()
 
     async def run():
@@ -522,12 +533,41 @@ def test_repair_restores_a_file_removed_behind_a_store_that_remembers_it(
 
             monkeypatch.setattr(nodes[2], "_dispatch", dispatch)
             seen = _chunk_stats(monkeypatch, nodes)
+            listed = []
+            real_scandir = os.scandir
+
+            def scandir(path):
+                listed.append(os.fspath(path))
+                return real_scandir(path)
+
+            monkeypatch.setattr(os, "scandir", scandir)
+            before = ch.look_stats()
             assert await nodes[1].repair_once() == 1
             assert asked and not any("residentOk" in h for h in asked)
             assert sorted(d for h in asked for d in h["digests"]) \
                 == digests
-            # the cycle looked at every name on the peer's disk
-            assert {d for nid, d in seen if nid == 2} == set(digests)
+            # the cycle looked at every name on the peer's disk: each
+            # asked name by a stat, or from a listing of its directory
+            looked = {k: v - before[k] for k, v in ch.look_stats().items()}
+            assert looked["lookStats"] + looked["lookListed"] \
+                == len(digests)
+            dirs = {os.path.dirname(ch._path_str(d)) for d in digests}
+            statted = {d for nid, d in seen if nid == 2}
+            if look == "a_stat_a_name":
+                assert looked["lookListings"] == 0
+                assert statted == set(digests)
+            elif look == "a_listing_a_directory":
+                assert looked == {"lookStats": 0,
+                                  "lookListed": len(digests),
+                                  "lookListings": len(dirs)}
+                assert dirs <= set(listed)
+                assert nodes[2].durability_stats()["lookListed"] \
+                    == len(digests)
+            else:
+                assert looked["lookListings"] > 0 < looked["lookStats"]
+                assert statted | {
+                    d for d in digests if os.path.dirname(
+                        ch._path_str(d)) in set(listed)} == set(digests)
             assert os.path.isfile(ch._path_str(victim))
             assert ch.get(victim) == payload
             assert ch.resident_stats()["residentDrops"] == 1

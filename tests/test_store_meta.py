@@ -895,9 +895,28 @@ def test_known_names_cost_no_stat_index_off_and_the_parents_calls_index_on(
                 assert calls.events == []
                 assert len(store._resident) == 1700
             # without the caller's leave: a look at the disk (index off)
+            # for every name — since PR 35 by a listing of its directory
+            # where the batch asks four names of it or more (nearly all
+            # of a store asked whole), a stat a name in the others
             calls.events.clear()
+            before = store.look_stats()
             assert store.has_many(digests) == [True] * 1700
-            assert calls.events == ([] if index else finals)
+            looked = {k: v - before[k]
+                      for k, v in store.look_stats().items()}
+            if index:
+                assert calls.events == []
+                assert looked == {"lookStats": 0, "lookListed": 0,
+                                  "lookListings": 0}
+            else:
+                few = {d[:2] for d in digests
+                       if sum(e[:2] == d[:2] for e in digests) < 4}
+                assert [e for e in calls.events if e in set(finals)] \
+                    == [f for d, f in zip(digests, finals) if d[:2] in few]
+                assert looked["lookStats"] + looked["lookListed"] == 1700
+                assert looked["lookStats"] \
+                    == sum(d[:2] in few for d in digests) < 170
+                assert looked["lookListings"] \
+                    == len({d[:2] for d in digests} - few)
         if not index:
             assert life.resident_stats() == {
                 "residentHits": 2 * 1700, "residentMisses": 1700,
@@ -986,3 +1005,148 @@ def test_a_look_at_the_disk_and_a_get_miss_heal_the_set(tmp_path, fsync):
     assert cs.put(e, de) is False               # still believed: the caveat
     assert cs.has(e) is False and cs.put(e, de) is True
     assert cs.get(e) == de and _stale(cs) == []
+
+
+# ---------------------------------------------------------------------- #
+# a look at the disk for a batch lists the directory (PR 35) — index off,
+# no resident_ok: the repair cycle's probe
+# ---------------------------------------------------------------------- #
+
+def _named(sub, n, seed=0):
+    """``n`` (digest, data) whose digests all begin with ``sub``: names
+    of one shard directory (put with ``verify=False``)."""
+    import random
+    rng = random.Random(seed)
+    return [(sub + "%062x" % rng.getrandbits(248), rng.randbytes(48))
+            for _ in range(n)]
+
+
+def _look_tree(root, fsync):
+    """A store whose directory ``ab`` holds every kind of entry a look
+    can meet, and the names to ask about (present and absent)."""
+    import os
+
+    from dfs_tpu.sim.delta import make_delta
+    cs = ChunkStore(root, fsync=fsync)
+    raw = _named("ab", 40, seed=1)
+    assert all(cs.put_batch(raw, verify=False))
+    absent = [d for d, _ in _named("ab", 12, seed=2)]
+    other = _named("cd", 2, seed=3)         # a directory asked little of
+    assert all(cs.put_batch(other, verify=False))
+    sub = os.path.join(cs.root, "ab")
+    open(os.path.join(sub, ".tmp-123-7"), "wb").close()     # a stray temp
+    (adir, _), (alink, _), (dangling, _) = _named("ab", 3, seed=4)
+    os.mkdir(os.path.join(sub, adir))               # a name, not a file
+    os.symlink(cs._path_str(raw[0][0]), os.path.join(sub, alink))
+    os.symlink(os.path.join(sub, "nowhere"), os.path.join(sub, dangling))
+    (delta, target), = _named("ab", 1, seed=5)      # delta-stored
+    base_d, base = raw[1]
+    assert cs._put_delta(delta, base_d, make_delta(base_d, base, target),
+                         raw_len=len(target)) is True
+    (broken, _), (gone, gone_data) = _named("ab", 2, seed=6)
+    assert cs.put(gone, gone_data, verify=False)    # a delta whose base
+    assert cs._put_delta(broken, gone,              # is then removed
+                         make_delta(gone, gone_data, b"x"), raw_len=1)
+    os.unlink(cs._path_str(gone))
+    # every kind, and two names asked twice
+    names = [d for d, _ in raw] + absent + [adir, alink, dangling, delta,
+                                            broken, gone, absent[1],
+                                            raw[0][0]]
+    names += [d for d, _ in other] + [_named("cd", 1, seed=7)[0][0]]
+    return cs, names, [d for d, _ in raw], alink, delta
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("case", [
+    "as_found", "known_before", "unlinked_behind", "under_the_threshold",
+    "little_of_a_large_directory", "linked_while_listed"])
+def test_a_batch_look_lists_the_directory_and_answers_as_the_stats_do(
+        tmp_path, monkeypatch, fsync, case):
+    """``has_many`` without ``resident_ok`` (index off), the listing
+    engaged, against the ``has`` loop — a ``stat`` a name — over two
+    lives of one tree: the same answer name for name, the same entries
+    entered and dropped, the same ``residentDrops``; under the threshold
+    — fewer names than ``_LIST_MIN_NAMES``, or fewer than one in
+    ``_LIST_ENTRIES_PER_STAT`` of what a directory of the store holds —
+    no directory is listed at all; a name linked while its directory is
+    being read stays remembered."""
+    import os
+
+    import dfs_tpu.store.cas as cas
+    first, names, raw, alink, delta = _look_tree(tmp_path / "chunks", fsync)
+    by_list = ChunkStore(first.root, fsync=fsync)       # two lives that
+    by_stat = ChunkStore(first.root, fsync=fsync)       # know nothing
+    assert by_list._deltas_possible() and by_stat._deltas_possible()
+    if case in ("known_before", "unlinked_behind"):
+        for cs in (by_list, by_stat):
+            cs.has_many(names, resident_ok=True)        # learn the names
+            assert len(cs._resident) == 40 + 1 + 2      # raw, link, cd's
+    lost = []
+    if case == "unlinked_behind":
+        lost = [raw[3], raw[17], alink]
+        for d in lost:
+            os.unlink(first._path_str(d))               # behind both
+    if case == "under_the_threshold":
+        monkeypatch.setattr(cas, "_LIST_MIN_NAMES", len(names) + 1)
+    if case == "little_of_a_large_directory":
+        # 60 names of a directory of a store of 1 000 a directory
+        by_list._count = cas._SHARD_DIRS * 1000
+        assert 60 * cas._LIST_ENTRIES_PER_STAT < 1000
+    real_scandir = os.scandir
+    listed = []
+    late = _named("ab", 1, seed=9)[0]
+
+    def scandir(path):
+        listed.append(os.fspath(path))
+        it = real_scandir(path)
+        if case == "linked_while_listed" and path.endswith("/ab"):
+            entries = list(it)      # the directory is read: now a put
+            it.close()              # links a name the listing lacks
+            assert by_list.put(late[0], late[1], verify=False) is True
+            assert _key(late[0]) in by_list._resident
+            import contextlib
+            return contextlib.nullcontext(iter(entries))
+        return it
+
+    monkeypatch.setattr(os, "scandir", scandir)
+    if case == "linked_while_listed":
+        names = names + [late[0]]
+    got = by_list.has_many(names)
+    monkeypatch.undo()
+    if case == "linked_while_listed":
+        # the listing lacks the name (where deltas may be, the chain's
+        # end is looked for after it and finds the file): whatever the
+        # answer, the entry its put made stays
+        assert _key(late[0]) in by_list._resident
+        assert by_list.resident_stats()["residentDrops"] == 0
+        assert by_list.has(late[0], resident_ok=True) is True
+        by_stat.has(late[0])
+    want = [by_stat.has(d) for d in names[:len(got)]]
+    if case == "linked_while_listed":
+        want[-1] = got[-1]
+    assert got == want
+    assert sum(got) >= 40 + 2 + 2 - len(lost)   # raw, link, delta, cd's
+    assert by_list._resident == by_stat._resident
+    assert _stale(by_list) == []
+    assert by_list.resident_stats()["residentDrops"] \
+        == by_stat.resident_stats()["residentDrops"] \
+        == (len(lost) if case == "unlinked_behind" else 0)
+    look = by_list.look_stats()
+    asked_ab = sum(d[:2] == "ab" for d in names)
+    if case in ("under_the_threshold", "little_of_a_large_directory"):
+        assert listed == []
+        assert look == {"lookStats": len(names), "lookListed": 0,
+                        "lookListings": 0}
+    else:
+        # `ab` listed once; `cd`, asked three names of, a stat a name
+        assert listed == [os.path.join(first._root_str, "ab")]
+        assert look == {"lookStats": len(names) - asked_ab,
+                        "lookListed": asked_ab, "lookListings": 1}
+    assert by_stat.look_stats() == {"lookStats": 0, "lookListed": 0,
+                                    "lookListings": 0}
+    # has() of one name, a resident_ok batch and a second life's answers
+    # are what they were: no listing, no look counted
+    before = by_list.look_stats()
+    assert by_list.has_many(names, resident_ok=True) \
+        == [by_stat.has(d, resident_ok=True) for d in names]
+    assert by_list.look_stats() == before

@@ -406,6 +406,8 @@ async def _route(node: "StorageNodeServer", reader: asyncio.StreamReader,
         snap["serve"] = node.serve.stats()   # cache/flight/admission
         snap["ingest"] = node.ingest_stats()  # write-path pipeline:
         # window/credit bounds, stall attribution, CAS-tier queue/busy
+        snap["ec"] = node.ec_stats()   # erasure coding: objects,
+        # stripes, encode calls, parity bytes (additive)
         snap["frag"] = node.frag_stats()  # fragmenter execution knobs
         # (device sharding / staging depth) + the live engine name
         snap["obs"] = node.obs.stats()   # trace ring + RPC tables —

@@ -191,32 +191,65 @@ class Ingest:
     def ec_extend(self, manifest: Manifest,
                   chunk_bytes: Mapping[str, bytes], k: int
                   ) -> tuple[Manifest, list[tuple[str, bytes]]]:
-        """Compute P+Q parity per stripe of ``k`` data chunks (ops.ec;
-        device encode when the node's fragmenter already runs on one) and
-        return the EC manifest plus the parity (digest, payload) list.
-        Payloads come from a digest map, never one contiguous buffer —
-        the shape upload's batch and tier demotion's gathered dict both
-        have. Runs in a worker thread — NumPy/encode work."""
+        """Compute P+Q parity per stripe of ``k`` data chunks and return
+        the EC manifest plus the parity (digest, payload) list. An
+        object's stripes are packed into a few fixed widths
+        (``ops.ec.batch_width``: powers of two, six from 2 KiB to the
+        default ``max_chunk``) and each run of one width is ONE
+        ``encode_pq_batch`` call — 6 calls a 16 MiB object where a call
+        a stripe was ~600, each at a length of its own. Which twin runs
+        is what ``utils.device.holds_tpu`` says of THIS process: NumPy on
+        a node that only talks to a chip owner (``JAX_PLATFORMS=cpu``,
+        whatever the engine is called) and on a CPU engine; the jitted
+        twin, on the chip, where the node's own engine took it. The
+        manifest is byte-identical either way. Payloads come from a
+        digest map, never one contiguous buffer — the shape upload's
+        batch and tier demotion's gathered dict both have. Runs in a
+        worker thread — NumPy/encode work; the spans ``ec.pack``,
+        ``ec.math`` and ``ec.hash`` nest under the caller's
+        ``upload.ec_encode``."""
         import numpy as np
 
         from dfs_tpu.ops import ec as ec_ops
+        from dfs_tpu.utils.device import holds_tpu
 
-        device = "tpu" in self.fragmenter.name
+        device = holds_tpu()
+        groups = ec_stripe_groups(manifest.chunks, k)
+        pads = [stripe_shard_len(grp) for grp in groups]
         stripes: list[StripeRef] = []
         parity: list[tuple[str, bytes]] = []
-        for grp in ec_stripe_groups(manifest.chunks, k):
-            pad = stripe_shard_len(grp)
-            sh = np.zeros((len(grp), pad), dtype=np.uint8)
-            for j, c in enumerate(grp):
-                sh[j, :c.length] = np.frombuffer(
-                    chunk_bytes[c.digest], dtype=np.uint8,
-                    count=c.length)
-            p, q = ec_ops.encode_pq(sh, device=device)
-            pb, qb = p.tobytes(), q.tobytes()
-            pd, qd = sha256_hex(pb), sha256_hex(qb)
-            stripes.append(StripeRef(p=pd, q=qd, shard_len=pad))
-            parity.append((pd, pb))
-            parity.append((qd, qb))
+        calls = 0
+        lo = 0
+        while lo < len(groups):
+            # groups come sorted by length, so a width is one run of them
+            width = ec_ops.batch_width(pads[lo])
+            hi = lo + 1
+            most = lo + ec_ops.batch_rows(k, width)
+            while hi < min(most, len(groups)) and pads[hi] <= width:
+                hi += 1
+            with self.obs.span("ec.pack"):
+                sh = np.zeros((hi - lo, k, width), dtype=np.uint8)
+                for row, grp in zip(sh, groups[lo:hi]):
+                    # a short last stripe takes the LAST slots (ops.ec)
+                    for shard, c in zip(row[k - len(grp):], grp):
+                        shard[:c.length] = np.frombuffer(
+                            chunk_bytes[c.digest], dtype=np.uint8,
+                            count=c.length)
+            with self.obs.span("ec.math"):
+                p, q = ec_ops.encode_pq_batch(sh, device=device)
+            calls += 1
+            with self.obs.span("ec.hash"):
+                for prow, qrow, pad in zip(p, q, pads[lo:hi]):
+                    pb, qb = prow[:pad].tobytes(), qrow[:pad].tobytes()
+                    pd, qd = sha256_hex(pb), sha256_hex(qb)
+                    stripes.append(StripeRef(p=pd, q=qd, shard_len=pad))
+                    parity.append((pd, pb))
+                    parity.append((qd, qb))
+            lo = hi
+        self.counters.inc("ec_objects")
+        self.counters.inc("ec_stripes", len(stripes))
+        self.counters.inc("ec_encode_calls", calls)
+        self.counters.inc("ec_parity_bytes", 2 * sum(pads))
         ec = EcInfo(k=k, stripes=tuple(stripes))
         return dataclasses.replace(manifest, ec=ec), parity
 

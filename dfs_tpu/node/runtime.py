@@ -2123,6 +2123,17 @@ class StorageNodeServer:
                          "chunks": counted.get("seam_chunks", 0)},
                 "cas": self.cas.stats()}
 
+    def ec_stats(self) -> dict:
+        """Erasure coding for /metrics "ec" (``Ingest.ec_extend``, at
+        ingest or at a demotion): objects encoded, their stripes, the
+        ``encode_pq_batch`` calls that took (one a width bucket), and
+        the parity bytes made, before placement's dedup."""
+        counted = self.counters.snapshot()
+        return {"objects": counted.get("ec_objects", 0),
+                "stripes": counted.get("ec_stripes", 0),
+                "encodeCalls": counted.get("ec_encode_calls", 0),
+                "parityBytes": counted.get("ec_parity_bytes", 0)}
+
     def frag_stats(self) -> dict:
         """Fragmenter execution knobs for /metrics "frag" (DFS005: every
         FragmenterConfig field surfaces here) plus what is ACTUALLY

@@ -195,6 +195,76 @@ def encode_pq(shards: np.ndarray, device: bool | None = None
 
 
 # ---------------------------------------------------------------------------
+# batched encode: an object's stripes as a few fixed shapes
+# ---------------------------------------------------------------------------
+
+BATCH_MIN_WIDTH = 2048       # bytes: config.CDCParams' default min_chunk
+_BATCH_BYTES = 64 << 20      # most bytes of data shards one call packs
+
+
+def batch_width(shard_len: int) -> int:
+    """The width a stripe of this padded length is encoded at: the power
+    of two at or above it, ``BATCH_MIN_WIDTH`` at least. CDC stripes
+    have near-unique lengths (a 16 MiB object: ~570 stripes, ~300
+    distinct); zero-padding each to its bucket is parity-neutral
+    (``xtime(0) = 0``: P and Q are the first ``shard_len`` bytes of the
+    row), so an object encodes in one call a bucket — six from 2 KiB to
+    the default ``max_chunk`` — and a process that jits the encode
+    compiles a bounded set of shapes whatever the objects."""
+    return max(BATCH_MIN_WIDTH, 1 << max(shard_len - 1, 0).bit_length())
+
+
+def batch_rows(k: int, width: int) -> int:
+    """Most stripes one call takes at this width: the power of two that
+    keeps its data shards within ``_BATCH_BYTES`` — the bound on a call's
+    memory, and (with :func:`batch_width`) on the shapes ever compiled."""
+    n = _BATCH_BYTES // (k * width)
+    return 1 << (n.bit_length() - 1) if n else 1
+
+
+@functools.cache
+def _make_batch_encode_fn(k: int):
+    """Compiled batched encode: words [S, k, n] u32 -> (p, q) [S, n] u32
+    — :func:`pq_horner` over axis 1, the one definition."""
+    import jax
+
+    @jax.jit
+    def run(words):
+        return pq_horner(words, k, axis=1)
+
+    return run
+
+
+def encode_pq_batch(shards: np.ndarray, device: bool = False
+                    ) -> tuple[np.ndarray, np.ndarray]:
+    """shards [S, k, W] u8 (W % 4 == 0) -> (p [S, W] u8, q [S, W] u8):
+    :func:`encode_pq_np`'s recurrence over axis 1, S stripes at once.
+    A stripe of fewer than k shards puts them in the LAST slots: leading
+    zero shards leave Horner's Q alone, trailing ones would multiply it.
+    ``device=True`` runs the jitted twin on this process's default
+    backend, S rounded up to a power of two (zero rows) so the shapes
+    stay few; the caller asks ``utils.device.holds_tpu`` — the NumPy
+    form is what a process without a chip runs."""
+    s, k, w = shards.shape
+    if w % 4:
+        raise ValueError("shard length must be a multiple of 4")
+    words = shards.view(np.uint32)                 # [S, k, W/4]
+    if not device:
+        p, q = _xor_reduce(np, words), _horner_reduce(np, words, k)
+        return p.view(np.uint8), q.view(np.uint8)
+    import jax
+
+    rows = 1 << (s - 1).bit_length()
+    if rows != s:
+        padded = np.zeros((rows, k, w // 4), dtype=np.uint32)
+        padded[:s] = words
+        words = padded
+    p, q = _make_batch_encode_fn(k)(jax.device_put(words))
+    return (np.asarray(p)[:s].view(np.uint8),
+            np.asarray(q)[:s].view(np.uint8))
+
+
+# ---------------------------------------------------------------------------
 # decode: recover up to two missing shards (host path, degraded only)
 # ---------------------------------------------------------------------------
 

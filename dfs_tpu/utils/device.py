@@ -52,6 +52,9 @@ class DeviceError(RuntimeError):
     """The process was started for a device it did not get."""
 
 
+_took_tpu = False      # require_tpu came back in THIS process
+
+
 def compile_cache_dir() -> Path:
     """Where compiled executables persist: the environment's choice, else
     the fixed in-checkout directory."""
@@ -147,7 +150,20 @@ def require_tpu(what: str) -> dict:
             f"platform {info['platform']!r} ({info['device_kind']}, "
             f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}) — "
             f"{ONE_OWNER_HINT}")
+    global _took_tpu
+    _took_tpu = True
     return info
+
+
+def holds_tpu() -> bool:
+    """True iff THIS process took the TPU (:func:`require_tpu` came
+    back): the chip owner, a node whose own engine runs on the chip, a
+    bench. A node that only talks to an owner holds none, whatever its
+    engine is called (``sidecar:cdc-anchored-tpu``), and neither does
+    anything started ``JAX_PLATFORMS=cpu``. Host code with a jitted twin
+    (the erasure-coding encode) asks here, never an engine's name; the
+    question initialises nothing."""
+    return _took_tpu
 
 
 def bench_device(what: str) -> str:

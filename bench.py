@@ -114,7 +114,7 @@ def main() -> int:
     words = jax.device_put(region_buffer(reg, np.zeros((8,), np.uint8),
                                          params))
     out = region_dispatch(words, region, 0, True, params)
-    spans, consumed = region_collect(out)         # warm + sanity
+    spans, consumed, _cuts = region_collect(out)  # warm + sanity
     assert consumed == region and sum(ln for _, ln, _ in spans) == region
     # independent oracle, like the warm-path gate above
     # dfslint: ignore[DFS004]

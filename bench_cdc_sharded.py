@@ -91,7 +91,7 @@ def _params(geometry: str):
     from dfs_tpu.ops.cdc_anchored import AnchoredCdcParams
 
     if geometry == "full":
-        return AnchoredCdcParams()       # production: 96-128 KiB segments
+        return AnchoredCdcParams()       # production: 32-128 KiB segments
     from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
 
     # tiny: the anchored_sharded_parity_check geometry — compiles in
@@ -99,7 +99,7 @@ def _params(geometry: str):
     return AnchoredCdcParams(
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),
-        seg_min=2048, seg_max=4096, seg_mask=2047)
+        seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
 
 
 def _blocks(data: bytes, n: int = 1 << 20):

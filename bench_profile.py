@@ -49,7 +49,7 @@ def main() -> int:
 
     m_words = A.recover_m_words(int(words.shape[0]), params)
     m_tiles = m_words * 4 // A.TILE_BYTES
-    cap = m_words * 4 // params.seg_min + 1
+    cap = A.segment_cap(params, m_words)
     # stage sizing must match the PRODUCTION chain (cap_mode='tight'):
     # the lane tables are tight-provisioned while the select scan runs
     # at the full bound — otherwise the stage rows would overshoot the
@@ -68,7 +68,7 @@ def main() -> int:
     fin = A._dev_bool(True)
 
     tiles = anchor(words)
-    bounds = select(tiles, z, n, fin)
+    bounds, _cuts = select(tiles, z, n, fin)
     d = desc(bounds, z)
     (starts, seg_lens, w_off, sh8, real_blocks, tail_len, consumed,
      nseg) = d

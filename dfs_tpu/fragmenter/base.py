@@ -160,6 +160,11 @@ def fragmenter_from_description(desc: dict) -> Fragmenter:
         from dfs_tpu.ops.cdc_anchored import AnchoredCdcParams
         from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
 
+        if "strong_min" not in desc:
+            raise ValueError(
+                "cdc-anchored description without strong_min: written "
+                "before the strong-anchor segment rule, whose cuts this "
+                "build does not reproduce")
         c = desc["chunk"]
         return AnchoredCpuFragmenter(AnchoredCdcParams(
             chunk=AlignedCdcParams(
@@ -169,7 +174,9 @@ def fragmenter_from_description(desc: dict) -> Fragmenter:
                 strip_blocks=int(c["strip_blocks"]),
                 seed=int(c["seed"])),
             seg_min=int(desc["seg_min"]), seg_max=int(desc["seg_max"]),
-            seg_mask=int(desc["seg_mask"]), seed=int(desc["seed"])))
+            seg_mask=int(desc["seg_mask"]), seed=int(desc["seed"]),
+            strong_min=int(desc["strong_min"]),
+            strong_bits=int(desc["strong_bits"])))
     raise ValueError(f"undescribable fragmenter kind {kind!r}")
 
 

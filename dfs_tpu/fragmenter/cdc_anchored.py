@@ -125,7 +125,8 @@ class _AnchoredBase(Fragmenter):
                           "max_blocks": c.max_blocks,
                           "strip_blocks": c.strip_blocks, "seed": c.seed},
                 "seg_min": p.seg_min, "seg_max": p.seg_max,
-                "seg_mask": p.seg_mask, "seed": p.seed}
+                "seg_mask": p.seg_mask, "seed": p.seed,
+                "strong_min": p.strong_min, "strong_bits": p.strong_bits}
 
     def manifest(self, data: bytes, name: str,
                  file_id: str | None = None) -> Manifest:
@@ -266,6 +267,8 @@ class AnchoredCpuFragmenter(_AnchoredBase):
 # host), collecting one, suspended at ``yield`` while the caller takes
 # the batch.
 _PHASES = ("inputWaitS", "dispatchS", "collectS", "replyS")
+# ``Health.device``'s names for region_collect's cut counts
+_CUT_KEYS = ("segments", "strong_cuts", "window_cuts", "forced_cuts")
 
 
 class _StreamPhases:
@@ -381,6 +384,9 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         self._stats_lock = threading.Lock()
         self.regions_dispatched = 0
         self.overflow_redos = 0
+        # how the collected regions' segments came to end, as
+        # region_collect counts them
+        self._cuts = dict.fromkeys(_CUT_KEYS, 0)
         self._phases = _StreamPhases()
         # warm the _touch jit once at construction (trace + a trivial
         # 1-element compile): the readiness probe's one-time cost must
@@ -470,7 +476,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         certainly completed once the outputs are readable."""
         expect = chunks[-1].offset + chunks[-1].length if chunks else 0
         try:
-            spans, consumed = region_collect(out)
+            spans, consumed, cuts = region_collect(out)
         except CutCapacityOverflow:
             # this window's content out-chunked the tight provisioning
             # (cut capacity or segment lanes) — redo it alone at the
@@ -485,10 +491,13 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
             take = min(8, base)
             if take:
                 lookback[8 - take:] = fetch(base - take, take)
-            spans, consumed = region_chunks(
+            spans, consumed, cuts = region_chunks(
                 fetch(base, end - base), lookback, expect - base, final,
                 self.params, lane_multiple=self.lane_multiple,
                 cap_mode="full")
+        with self._stats_lock:
+            for key, count in zip(_CUT_KEYS, cuts):
+                self._cuts[key] += count
         self._pool_give(staged)
         for o, ln, dg in spans:
             off = base + o
@@ -555,6 +564,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         return {**device_info(),
                 "regions": self.regions_dispatched,
                 "overflow_redos": self.overflow_redos,
+                **self._cuts,
                 **self._phases.snapshot()}
 
     def chunks_stream(self, blocks, store=None):

@@ -10,8 +10,9 @@ pipeline compiles) on its OWN window, so a batch of ``devices`` windows
 chunks and hashes concurrently:
 
 - **pass A, batched**: the byte-granular anchor hash per window, the
-  8-byte lookback baked host-side — no collective. Its [2, m_tiles]
-  kept-anchor tables are the only thing pulled between passes.
+  8-byte lookback baked host-side — no collective. Its [3, m_tiles]
+  anchor tables (two kept planes, one strong) are the only thing pulled
+  between passes.
 - **segment selection on the host** (``ops.cdc_anchored.
   select_segments`` — the SAME function the oracle uses, metadata-sized)
   with the inter-region carry threaded exactly as the single-device walk
@@ -66,10 +67,9 @@ from dfs_tpu.fragmenter.sharded_common import (ShardedSteps,
                                                fixed_region_bytes)
 from dfs_tpu.meta.manifest import ChunkRef
 from dfs_tpu.ops.cdc_anchored import (TILE_BYTES, AnchoredCdcParams,
-                                      lane_tables_np, region_buffer,
-                                      region_buffer_size, select_segments)
-
-_NO_ANCHOR = 2**30     # make_anchor_fn's no-anchor sentinel
+                                      lane_tables_np, planes_positions,
+                                      region_buffer, region_buffer_size,
+                                      segment_cap, select_segments)
 
 
 _touch_shard_fn = None
@@ -115,7 +115,7 @@ class ShardedAnchoredCdcFragmenter(_StagingMeter, AnchoredCpuFragmenter):
         self._total_words = region_buffer_size(
             self.region_bytes, self.params, m_words=self._m_words) // 4
         # worst-case per-window segment count — ONE pass-B compile shape
-        self._s_pad = self.region_bytes // self.params.seg_min + 1
+        self._s_pad = segment_cap(self.params, self._m_words)
         # windows ride dp: one whole window per device
         self._steps = ShardedSteps(self.devices, self._build,
                                    dp=self.devices)
@@ -325,12 +325,9 @@ class ShardedAnchoredCdcFragmenter(_StagingMeter, AnchoredCpuFragmenter):
             starts = np.zeros((nb, self._s_pad), np.int32)
             seg_lens = np.zeros((nb, self._s_pad), np.int32)
             for i, (b, _, _) in enumerate(staged):
-                t = tiles[i]
-                anchors = t[t < _NO_ANCHOR].astype(np.int64)
-                anchors.sort()
-                bounds = select_segments(anchors, self.region_bytes,
-                                         self.params, start0=start0,
-                                         final=False)
+                bounds = select_segments(*planes_positions(tiles[i]),
+                                         self.region_bytes, self.params,
+                                         start0=start0, final=False)
                 # lane_tables_np is the ONE host-side mirror of the
                 # device descriptor encoding — never inline it
                 (starts[i], seg_lens[i], w_off[i], sh8[i], rb[i],

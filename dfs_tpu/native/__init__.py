@@ -122,7 +122,8 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.dfs_gear_cuts.restype = ctypes.c_int64
     lib.dfs_anchored_spans.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, ctypes.c_uint32,
-        ctypes.c_uint32, ctypes.c_uint64, ctypes.c_uint64,
+        ctypes.c_uint32, ctypes.c_uint32, ctypes.c_uint64,
+        ctypes.c_uint64, ctypes.c_uint64,
         ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32,
         ctypes.c_uint64, ctypes.c_uint64, ctypes.c_void_p,
         ctypes.c_uint64]
@@ -130,10 +131,11 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.dfs_anchored_spans_region.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p,
         ctypes.c_uint64, ctypes.c_int, ctypes.c_uint32,
-        ctypes.c_uint32, ctypes.c_uint64, ctypes.c_uint64,
+        ctypes.c_uint32, ctypes.c_uint32, ctypes.c_uint64,
+        ctypes.c_uint64, ctypes.c_uint64,
         ctypes.c_uint64, ctypes.c_uint32, ctypes.c_uint32,
         ctypes.c_uint64, ctypes.c_uint64, ctypes.c_void_p,
-        ctypes.c_uint64, ctypes.c_void_p]
+        ctypes.c_uint64, ctypes.c_void_p, ctypes.c_void_p]
     lib.dfs_anchored_spans_region.restype = ctypes.c_int64
 
 
@@ -175,12 +177,13 @@ def native_anchored_spans(data: bytes | np.ndarray,
         return np.zeros((0, 2), dtype=np.int64)
     cp = params.chunk
     # worst case: one cut per min_blocks plus one forced tail per segment
-    cap = n // (cp.min_blocks * 64) + n // params.seg_min + 3
+    cap = n // (cp.min_blocks * 64) + n // params.strong_min + 3
     spans = np.empty((cap, 2), dtype=np.uint64)
     from dfs_tpu.ops.cdc_anchored import TILE_BYTES
 
     wrote = lib.dfs_anchored_spans(
         arr.ctypes.data, n, params.seed, params.seg_mask,
+        params.strong_mask, params.strong_min,
         params.seg_min, params.seg_max, TILE_BYTES,
         cp.seed, cp.mask, cp.min_blocks, cp.max_blocks,
         spans.ctypes.data, cap)
@@ -191,11 +194,14 @@ def native_anchored_spans(data: bytes | np.ndarray,
 
 def native_anchored_spans_region(
         data: bytes | np.ndarray, lookback: np.ndarray, start0: int,
-        final: bool, params) -> tuple[np.ndarray, int] | None:
+        final: bool, params, cut_kinds: np.ndarray | None = None
+        ) -> tuple[np.ndarray, int] | None:
     """Window edition of :func:`native_anchored_spans` (the C mirror of
     ops.cdc_anchored.region_chunks semantics): returns ([n, 2] int64
     region-local (offset, length), consumed) or None if the native lib is
-    unavailable. The stream offset of data[0] must be TILE_BYTES-aligned."""
+    unavailable. The stream offset of data[0] must be TILE_BYTES-aligned.
+    ``cut_kinds`` ([4] uint64), when given, receives how many of the
+    emitted segments ended by each ``ops.cdc_anchored.CUT_*`` kind."""
     lib = get_lib()
     if lib is None:
         return None
@@ -204,9 +210,11 @@ def native_anchored_spans_region(
     arr = np.ascontiguousarray(arr)
     n = int(arr.shape[0])
     if n == 0:
+        if cut_kinds is not None:
+            cut_kinds[:] = 0
         return np.zeros((0, 2), dtype=np.int64), start0
     cp = params.chunk
-    cap = n // (cp.min_blocks * 64) + n // params.seg_min + 3
+    cap = n // (cp.min_blocks * 64) + n // params.strong_min + 3
     spans = np.empty((cap, 2), dtype=np.uint64)
     lb = np.ascontiguousarray(lookback, dtype=np.uint8)
     consumed = ctypes.c_uint64(0)
@@ -214,9 +222,11 @@ def native_anchored_spans_region(
 
     wrote = lib.dfs_anchored_spans_region(
         arr.ctypes.data, n, lb.ctypes.data, start0, int(final),
-        params.seed, params.seg_mask, params.seg_min, params.seg_max,
+        params.seed, params.seg_mask, params.strong_mask,
+        params.strong_min, params.seg_min, params.seg_max,
         TILE_BYTES, cp.seed, cp.mask, cp.min_blocks, cp.max_blocks,
-        spans.ctypes.data, cap, ctypes.byref(consumed))
+        spans.ctypes.data, cap, ctypes.byref(consumed),
+        None if cut_kinds is None else cut_kinds.ctypes.data)
     if wrote < 0:
         return None
     return spans[:wrote].astype(np.int64), int(consumed.value)

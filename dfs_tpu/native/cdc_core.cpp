@@ -148,27 +148,36 @@ int64_t dfs_gear_cuts(const uint8_t* data, uint64_t len,
 // at data[len-1] — otherwise the unfinished tail segment is withheld so
 // its bytes carry into the next window. Writes region-local (offset,
 // length) pairs; sets *consumed to the bound segments were emitted up
-// to (== len when final). Returns the pair count, or -1 on
-// overflow/alloc failure.
+// to (== len when final). `cut_counts`, when not null, receives how the
+// emitted segments came to end: [0] at the first strong anchor of the
+// window, [1] at the last kept anchor of [seg_min, seg_max], [2] forced
+// at seg_max, [3] with the stream — what the device chain counts.
+// Returns the pair count, or -1 on overflow/alloc failure.
 int64_t dfs_anchored_spans_region(const uint8_t* data, uint64_t len,
                                   const uint8_t* lookback, uint64_t start0,
                                   int final_region, uint32_t anchor_seed,
-                                  uint32_t seg_mask, uint64_t seg_min,
+                                  uint32_t seg_mask, uint32_t strong_mask,
+                                  uint64_t strong_min, uint64_t seg_min,
                                   uint64_t seg_max, uint64_t tile_bytes,
                                   uint32_t chunk_seed, uint32_t avg_mask,
                                   uint64_t min_blocks, uint64_t max_blocks,
                                   uint64_t* spans, uint64_t span_cap,
-                                  uint64_t* consumed) {
+                                  uint64_t* consumed,
+                                  uint64_t* cut_counts) {
   *consumed = start0;
+  uint64_t kinds[4] = {0, 0, 0, 0};
+  if (cut_counts) std::memcpy(cut_counts, kinds, sizeof(kinds));
   if (len == 0) return 0;
 
-  // ---- pass A: first TWO qualifying anchors per tile (-1 = none),
-  // interleaved [first, second] per tile — mirrors the device pass-A
-  // two-plane output (dfs_tpu/ops/cdc_anchored.make_anchor_fn) ----
+  // ---- pass A: per tile the first TWO qualifying anchors and the first
+  // STRONG position (-1 = none), interleaved [first, second, strong] —
+  // mirrors the device pass-A three-plane output
+  // (dfs_tpu/ops/cdc_anchored.make_anchor_fn). The strong position is
+  // tested on the hash alone, whether or not it is one of the two kept.
   uint64_t n_tiles = (len + tile_bytes - 1) / tile_bytes;
-  int64_t* tile_anchor = new (std::nothrow) int64_t[2 * n_tiles];
+  int64_t* tile_anchor = new (std::nothrow) int64_t[3 * n_tiles];
   if (!tile_anchor) return -1;
-  for (uint64_t t = 0; t < 2 * n_tiles; ++t) tile_anchor[t] = -1;
+  for (uint64_t t = 0; t < 3 * n_tiles; ++t) tile_anchor[t] = -1;
   uint64_t reg = 0;  // bytes[p-7..p], data[p] in the top byte (LE window)
   for (int i = 0; i < 8; ++i)
     reg = (reg >> 8) | (uint64_t(lookback[i]) << 56);
@@ -179,8 +188,12 @@ int64_t dfs_anchored_spans_region(const uint8_t* data, uint64_t len,
     uint32_t h = fmix32(fmix32(b) + anchor_seed + a);
     if ((h & seg_mask) == 0) {
       uint64_t t = p / tile_bytes;
-      if (tile_anchor[2 * t] < 0) tile_anchor[2 * t] = int64_t(p);
-      else if (tile_anchor[2 * t + 1] < 0) tile_anchor[2 * t + 1] = int64_t(p);
+      if (tile_anchor[3 * t] < 0) tile_anchor[3 * t] = int64_t(p);
+      else if (tile_anchor[3 * t + 1] < 0) tile_anchor[3 * t + 1] = int64_t(p);
+    }
+    if ((h & strong_mask) == 0) {
+      uint64_t t = p / tile_bytes;
+      if (tile_anchor[3 * t + 2] < 0) tile_anchor[3 * t + 2] = int64_t(p);
     }
   }
 
@@ -194,21 +207,34 @@ int64_t dfs_anchored_spans_region(const uint8_t* data, uint64_t len,
   bool ok = true;
   while (ok) {
     uint64_t bound;
+    int kind = 3;
     if (len - start <= seg_max) {
       if (!final_region) break;  // tail carries into the next window
       bound = len;               // final segment
     } else {
-      // last kept anchor a with start+seg_min <= a+1 <= start+seg_max;
-      // within a tile the second kept anchor is the larger, so it is
-      // checked first
-      uint64_t lo = start + seg_min - 1, hi = start + seg_max - 1;
+      // first strong anchor a with start+strong_min <= a+1 <= start+seg_max
+      // (hi < len - 1 here, so its tile is in the table)
+      uint64_t hi = start + seg_max - 1, hi_t = hi / tile_bytes;
       int64_t found = -1;
-      for (uint64_t t = hi / tile_bytes + 1; t-- > lo / tile_bytes;) {
-        for (int j = 1; j >= 0 && found < 0; --j) {
-          int64_t a = tile_anchor[2 * t + j];
-          if (a >= int64_t(lo) && a <= int64_t(hi)) found = a;
+      uint64_t slo = start + strong_min - 1;
+      for (uint64_t t = slo / tile_bytes; t <= hi_t; ++t) {
+        int64_t a = tile_anchor[3 * t + 2];
+        if (a >= int64_t(slo) && a <= int64_t(hi)) { found = a; break; }
+      }
+      kind = 0;
+      if (found < 0) {
+        // else the last kept anchor a with start+seg_min <= a+1 <=
+        // start+seg_max; within a tile the second kept anchor is the
+        // larger, so it is checked first
+        uint64_t lo = start + seg_min - 1;
+        for (uint64_t t = hi_t + 1; t-- > lo / tile_bytes;) {
+          for (int j = 1; j >= 0 && found < 0; --j) {
+            int64_t a = tile_anchor[3 * t + j];
+            if (a >= int64_t(lo) && a <= int64_t(hi)) found = a;
+          }
+          if (found >= 0) break;
         }
-        if (found >= 0) break;
+        kind = found >= 0 ? 1 : 2;
       }
       bound = found >= 0 ? uint64_t(found) + 1 : start + seg_max;
     }
@@ -240,10 +266,12 @@ int64_t dfs_anchored_spans_region(const uint8_t* data, uint64_t len,
       }
     }
     if (!ok) break;
+    ++kinds[kind];
     start = bound;
     if (bound == len) break;
   }
   delete[] tile_anchor;
+  if (cut_counts) std::memcpy(cut_counts, kinds, sizeof(kinds));
   *consumed = start;
   return ok ? int64_t(n_spans) : -1;
 }
@@ -253,6 +281,7 @@ int64_t dfs_anchored_spans_region(const uint8_t* data, uint64_t len,
 // starting from a zero lookback.
 int64_t dfs_anchored_spans(const uint8_t* data, uint64_t len,
                            uint32_t anchor_seed, uint32_t seg_mask,
+                           uint32_t strong_mask, uint64_t strong_min,
                            uint64_t seg_min, uint64_t seg_max,
                            uint64_t tile_bytes, uint32_t chunk_seed,
                            uint32_t avg_mask, uint64_t min_blocks,
@@ -261,9 +290,9 @@ int64_t dfs_anchored_spans(const uint8_t* data, uint64_t len,
   uint8_t zeros[8] = {0, 0, 0, 0, 0, 0, 0, 0};
   uint64_t consumed = 0;
   return dfs_anchored_spans_region(
-      data, len, zeros, 0, 1, anchor_seed, seg_mask, seg_min, seg_max,
-      tile_bytes, chunk_seed, avg_mask, min_blocks, max_blocks, spans,
-      span_cap, &consumed);
+      data, len, zeros, 0, 1, anchor_seed, seg_mask, strong_mask, strong_min,
+      seg_min, seg_max, tile_bytes, chunk_seed, avg_mask, min_blocks,
+      max_blocks, spans, span_cap, &consumed, nullptr);
 }
 
 }  // extern "C"

@@ -24,10 +24,27 @@ classic two-level scheme:
    first-only on the same corpus (TILE_PROBE_r04.json), where halving
    the tile to 256 B bought 96.8% but cost ~48% of chain throughput.
 
-2. **Segment selection** (host, metadata-sized, shared verbatim with the
-   oracle): segments end at the LAST kept anchor within
-   ``[start + seg_min, start + seg_max]`` — maximizing segment length keeps
-   device-lane utilization high — else forced at ``start + seg_max``.
+2. **Segment selection** (metadata-sized; the NumPy oracle, the XLA
+   scan, the Pallas walk and the C++ engine are held to each other bit
+   for bit): classical min / average / max cutting at the segment level.
+   An anchor is *strong* when its hash clears ``strong_bits`` more bits
+   (2^-16 a byte, mean gap 64 KiB); pass A keeps the FIRST strong
+   position of each tile as a third plane, tested on the hash alone. A
+   segment ends at the FIRST strong anchor p with
+   ``start + strong_min <= p + 1 <= start + seg_max``; if the window
+   holds none, at the LAST kept anchor within
+   ``[start + seg_min, start + seg_max]``; else forced at
+   ``start + seg_max``. Two streams whose cuts differ agree again at the
+   first strong anchor ``strong_min`` or more after both their cuts —
+   within one or two strong gaps — and stay agreed, since from a common
+   cut the walk is a function of content. Until PR 37 the rule was its
+   fallback alone ("the last kept anchor in the window: maximizing
+   segment length keeps device-lane utilization high", 96 % of a lane):
+   every cut then depended on the one before it with no pull towards
+   agreement, a shifted stream took ~1.2 MiB to re-synchronise, and a
+   snapshot in which 2 % of the files changed stored 40 % of its bytes
+   again. The lanes are now ~62 % full (mean segment ~80 KiB of 128);
+   that is the price, paid in device time nobody waits for.
 
 3. **Within a segment, the aligned v2 machinery runs with its 64-byte grid
    anchored at the segment start**: the device repacks each segment into
@@ -49,6 +66,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import hashlib
+import math
 
 import numpy as np
 
@@ -74,10 +92,15 @@ class AnchoredCdcParams:
     aligned chunk grid.
 
     ``seg_mask`` fires with probability 2^-13 per byte (mean anchor gap
-    8 KiB), dense enough that the last-anchor-in-window rule lands a
-    boundary close to ``seg_max`` (measured ~96% lane utilization);
-    ``seg_max`` must equal ``chunk.strip_blocks * 64`` — a segment is one
-    device lane.
+    8 KiB); a *strong* anchor clears ``strong_bits`` more bits of the
+    same hash (2^-16, mean gap 64 KiB). A segment ends at the first
+    strong anchor ``strong_min`` or more after its start (None = a
+    quarter of ``seg_max``, tile-aligned: 32 KiB), else at the last kept
+    anchor in ``[seg_min, seg_max]``, else at ``seg_max`` — see the
+    module docstring. The pair 2^-16 / 32 KiB is where the chunk count
+    stays within +3 % of the last-anchor rule's while a shifted snapshot
+    is re-found (ISSUE 37's table). ``seg_max`` must equal
+    ``chunk.strip_blocks * 64`` — a segment is one device lane.
     """
     chunk: AlignedCdcParams = dataclasses.field(
         default_factory=AlignedCdcParams)
@@ -85,6 +108,8 @@ class AnchoredCdcParams:
     seg_max: int = 128 * 1024
     seg_mask: int = 8191
     seed: int = 0x51ED270B
+    strong_min: int | None = None
+    strong_bits: int = 3
 
     def __post_init__(self):
         if self.seg_max != self.chunk.strip_blocks * BLOCK:
@@ -99,6 +124,22 @@ class AnchoredCdcParams:
         if self.seg_min % TILE_BYTES or self.seg_max % TILE_BYTES:
             raise ValueError("seg_min/seg_max must be multiples of "
                              f"{TILE_BYTES} (device selection window)")
+        if self.strong_min is None:
+            object.__setattr__(self, "strong_min", max(
+                TILE_BYTES, self.seg_max // 4 // TILE_BYTES * TILE_BYTES))
+        if not TILE_BYTES <= self.strong_min <= self.seg_min \
+                or self.strong_min % TILE_BYTES:
+            raise ValueError(f"strong_min must be a multiple of {TILE_BYTES}"
+                             " within [tile, seg_min]")
+        if not 1 <= self.strong_bits <= 8 \
+                or (self.seg_mask + 1) << self.strong_bits > 2**32:
+            raise ValueError("need 1 <= strong_bits <= 8 within 32 bits")
+
+    @property
+    def strong_mask(self) -> int:
+        """Hash mask of a strong anchor: ``seg_mask`` plus
+        ``strong_bits`` more low bits."""
+        return ((self.seg_mask + 1) << self.strong_bits) - 1
 
 
 # ---------------------------------------------------------------------------
@@ -129,63 +170,136 @@ def anchor_hash_np(data: np.ndarray, params: AnchoredCdcParams) -> np.ndarray:
     return _fmix32_np(_fmix32_np(b) + np.uint32(params.seed) + a)
 
 
-def _first_two_per_tile(pos: np.ndarray) -> np.ndarray:
-    """Keep the first TWO entries of each TILE_BYTES tile from sorted
-    byte positions — the single definition of the quantization rule
-    (kept_anchors_np and region_spans_np both apply it)."""
+def _first_per_tile(pos: np.ndarray, keep: int) -> np.ndarray:
+    """Keep the first ``keep`` entries of each TILE_BYTES tile from
+    sorted byte positions — the single definition of the quantization
+    rule (two for the kept anchors, one for the strong plane)."""
     if pos.size == 0:
         return pos.astype(np.int64)
     tile = pos // TILE_BYTES
-    first = np.ones_like(pos, dtype=bool)
-    first[1:] = tile[1:] != tile[:-1]
-    second = np.zeros_like(first)
-    second[1:] = first[:-1] & (tile[1:] == tile[:-1])
-    return pos[first | second].astype(np.int64)
+    idx = np.arange(pos.size)
+    opens = np.ones(pos.shape, dtype=bool)       # first entry of its tile
+    opens[1:] = tile[1:] != tile[:-1]
+    rank = idx - np.maximum.accumulate(np.where(opens, idx, 0))
+    return pos[rank < keep].astype(np.int64)
 
 
-def kept_anchors_np(data: np.ndarray,
-                    params: AnchoredCdcParams) -> np.ndarray:
-    """Sorted kept anchor positions: first TWO qualifying bytes per
-    TILE_BYTES tile (the oracle of the device pass-A output)."""
-    n = data.shape[0]
-    if n == 0:
-        return np.zeros((0,), dtype=np.int64)
-    hit = (anchor_hash_np(data, params)
-           & np.uint32(params.seg_mask)) == 0
-    return _first_two_per_tile(np.flatnonzero(hit))
+def anchor_positions_np(h: np.ndarray, params: AnchoredCdcParams
+                        ) -> tuple[np.ndarray, np.ndarray]:
+    """(kept, strong) sorted positions from the per-byte anchor hashes
+    ``h``: the first TWO anchors of each tile, and the first STRONG
+    position of each tile — the latter tested on the hash alone, not on
+    whether that position survived the two-a-tile quantisation, so a
+    strong anchor is a function of content and tile alignment only (the
+    oracle of the device pass-A output's three planes)."""
+    kept = _first_per_tile(np.flatnonzero(
+        (h & np.uint32(params.seg_mask)) == 0), 2)
+    strong = _first_per_tile(np.flatnonzero(
+        (h & np.uint32(params.strong_mask)) == 0), 1)
+    return kept, strong
+
+
+def anchors_np(data: np.ndarray, params: AnchoredCdcParams
+               ) -> tuple[np.ndarray, np.ndarray]:
+    """(kept, strong) anchor positions of a whole stream
+    (:func:`anchor_positions_np` over :func:`anchor_hash_np`)."""
+    if data.shape[0] == 0:
+        z = np.zeros((0,), dtype=np.int64)
+        return z, z
+    return anchor_positions_np(anchor_hash_np(data, params), params)
+
+
+def anchor_planes_np(kept: np.ndarray, strong: np.ndarray,
+                     m_tiles: int) -> np.ndarray:
+    """The [3, m_tiles] i32 table device pass A returns for these
+    positions (2^30 = none): what the parity checks hold it to."""
+    planes = np.full((3, m_tiles), 2**30, np.int32)
+    if kept.size:
+        tile = kept // TILE_BYTES
+        second = np.zeros(kept.shape, dtype=bool)
+        second[1:] = tile[1:] == tile[:-1]
+        planes[0, tile[~second]] = kept[~second]
+        planes[1, tile[second]] = kept[second]
+    planes[2, strong // TILE_BYTES] = strong
+    return planes
+
+
+def planes_positions(tiles: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Invert :func:`anchor_planes_np`: a pass-A [3, m_tiles] table to
+    the sorted (kept, strong) positions :func:`select_segments` takes."""
+    kept = tiles[:2][tiles[:2] < 2**30].astype(np.int64)
+    kept.sort()
+    strong = tiles[2][tiles[2] < 2**30].astype(np.int64)
+    return kept, strong
 
 
 # ---------------------------------------------------------------------------
 # segment selection — ONE implementation, used by oracle and production
 # ---------------------------------------------------------------------------
 
-def select_segments(anchors: np.ndarray, n: int,
-                    params: AnchoredCdcParams, start0: int = 0,
-                    final: bool = True) -> np.ndarray:
-    """Exclusive segment boundaries over a stream of ``n`` bytes; when
-    ``final``, the last element == n. Boundary after byte p means segment
-    ends at p (boundary value p+1). Rule: LAST kept anchor with
-    start+seg_min <= p+1 <= start+seg_max; none -> forced at
-    start+seg_max. ``start0``/``final=False`` give the region-walk
-    semantics (start at a carry position; withhold the unfinished tail
-    segment so it carries into the next region)."""
+# how a segment came to end: at the first strong anchor of its window,
+# at the last kept anchor of [seg_min, seg_max], forced at seg_max, or
+# with the stream
+CUT_STRONG, CUT_WINDOW, CUT_FORCED, CUT_END = 0, 1, 2, 3
+
+
+def select_segments_kinds(anchors: np.ndarray, strong: np.ndarray, n: int,
+                          params: AnchoredCdcParams, start0: int = 0,
+                          final: bool = True
+                          ) -> tuple[np.ndarray, np.ndarray]:
+    """Exclusive segment boundaries over a stream of ``n`` bytes, and how
+    each came about (``CUT_*``); when ``final``, the last element == n.
+    Boundary after byte p means segment ends at p (boundary value p+1).
+    Rule: the FIRST strong anchor with start+strong_min <= p+1 <=
+    start+seg_max; none -> the LAST kept anchor with start+seg_min <=
+    p+1 <= start+seg_max; none -> forced at start+seg_max.
+    ``start0``/``final=False`` give the region-walk semantics (start at
+    a carry position; withhold the unfinished tail segment so it carries
+    into the next region)."""
     bounds: list[int] = []
+    kinds: list[int] = []
     start = int(start0)
     ap = np.asarray(anchors, dtype=np.int64)
+    sp = np.asarray(strong, dtype=np.int64)
     while n - start > params.seg_max:
         lo = start + params.seg_min            # min admissible boundary
         hi = start + params.seg_max            # forced boundary
-        # anchors p with lo <= p+1 <= hi  <=>  lo-1 <= p <= hi-1
-        j = int(np.searchsorted(ap, hi - 1, side="right")) - 1
-        if j >= 0 and ap[j] >= lo - 1:
-            b = int(ap[j]) + 1
+        # strong p with start+strong_min <= p+1 <= hi
+        j = int(np.searchsorted(sp, start + params.strong_min - 1,
+                                side="left"))
+        if j < sp.shape[0] and sp[j] <= hi - 1:
+            b, kind = int(sp[j]) + 1, CUT_STRONG
         else:
-            b = hi
+            # anchors p with lo <= p+1 <= hi  <=>  lo-1 <= p <= hi-1
+            k = int(np.searchsorted(ap, hi - 1, side="right")) - 1
+            if k >= 0 and ap[k] >= lo - 1:
+                b, kind = int(ap[k]) + 1, CUT_WINDOW
+            else:
+                b, kind = hi, CUT_FORCED
         bounds.append(b)
+        kinds.append(kind)
         start = b
     if final:
         bounds.append(n)
-    return np.asarray(bounds, dtype=np.int64)
+        kinds.append(CUT_END)
+    return (np.asarray(bounds, dtype=np.int64),
+            np.asarray(kinds, dtype=np.int64))
+
+
+def select_segments(anchors: np.ndarray, strong: np.ndarray, n: int,
+                    params: AnchoredCdcParams, start0: int = 0,
+                    final: bool = True) -> np.ndarray:
+    """The boundaries of :func:`select_segments_kinds` alone."""
+    return select_segments_kinds(anchors, strong, n, params, start0,
+                                 final)[0]
+
+
+def cut_counts(kinds: np.ndarray) -> tuple[int, int, int, int]:
+    """(segments, strong_cuts, window_cuts, forced_cuts) of a walk's
+    ``kinds`` — what the device chain and the C++ engine count."""
+    by = np.bincount(np.asarray(kinds, dtype=np.int64), minlength=4)
+    return (int(by.sum()), int(by[CUT_STRONG]), int(by[CUT_WINDOW]),
+            int(by[CUT_FORCED]))
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +329,7 @@ def chunk_spans_anchored_np(data: np.ndarray, params: AnchoredCdcParams
     n = data.shape[0]
     if n == 0:
         return []
-    bounds = select_segments(kept_anchors_np(data, params), n, params)
+    bounds = select_segments(*anchors_np(data, params), n, params)
     spans: list[tuple[int, int]] = []
     start = 0
     for b in bounds.tolist():
@@ -240,10 +354,10 @@ def region_spans_np(data: np.ndarray, lookback: np.ndarray, start0: int,
         return [], int(start0)
     ext = np.concatenate([np.asarray(lookback, np.uint8).reshape(8),
                           np.asarray(data)])
-    hit = (anchor_hash_np(ext, params) & np.uint32(params.seg_mask)) == 0
-    anchors = _first_two_per_tile(np.flatnonzero(hit[8:]))  # region-local
-    bounds = select_segments(anchors, n, params, start0=int(start0),
-                             final=bool(final))
+    anchors, strong = anchor_positions_np(      # region-local
+        anchor_hash_np(ext, params)[8:], params)
+    bounds = select_segments(anchors, strong, n, params,
+                             start0=int(start0), final=bool(final))
     spans: list[tuple[int, int]] = []
     start = int(start0)
     for b in bounds.tolist():
@@ -266,9 +380,10 @@ def chunk_file_anchored_np(data: np.ndarray, params: AnchoredCdcParams
 @functools.cache
 def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
     """Compiled: words_le [>= 2 + m_words] u32 (extra trailing words —
-    the region buffer's lane slack — are ignored) -> first-two-anchor
-    byte positions per TILE_BYTES tile ([2, m_words*4/TILE_BYTES] i32;
-    row 0 < row 1 where present, 2^30 = no anchor). The leading 2 words
+    the region buffer's lane slack — are ignored) -> per TILE_BYTES tile
+    the first two anchor byte positions and the first STRONG position
+    ([3, m_words*4/TILE_BYTES] i32; row 0 < row 1 where present, row 2
+    tested on the hash alone, 2^30 = none). The leading 2 words
     are the 8 stream bytes BEFORE the region (zeros at true stream
     start), so anchor hashes near the region start see real history and
     batching is transparent; positions are region-local."""
@@ -278,6 +393,7 @@ def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
     tile_w = TILE_BYTES // 4
     seed = jnp.uint32(params.seed)
     mask = jnp.uint32(params.seg_mask)
+    strong_mask = jnp.uint32(params.strong_mask)
 
     def fmix(x):
         x = x ^ (x >> jnp.uint32(16))
@@ -300,6 +416,7 @@ def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
         # so the sentinel is the only shared value and it is absorbing
         b1 = jnp.full((m_words,), jnp.int32(2**30))
         b2 = jnp.full((m_words,), jnp.int32(2**30))
+        s1 = jnp.full((m_words,), jnp.int32(2**30))     # first strong
         for r in range(4):
             if r == 3:
                 b_all = v
@@ -314,6 +431,8 @@ def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
             x = jnp.where(hit, pos, 2**30)
             b2 = jnp.minimum(b2, jnp.maximum(b1, x))
             b1 = jnp.minimum(b1, x)
+            s1 = jnp.minimum(
+                s1, jnp.where((h & strong_mask) == 0, pos, 2**30))
         # per-tile two smallest of the union of (b1, b2) pairs: the tile
         # min comes from b1; the runner-up is the min after the argmin
         # word's entry is replaced by its own second (any other word's b2
@@ -322,7 +441,7 @@ def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
         w2 = b2.reshape(-1, tile_w)
         m1 = jnp.min(w1, axis=1)
         m2 = jnp.min(jnp.where(w1 == m1[:, None], w2, w1), axis=1)
-        return jnp.stack([m1, m2])
+        return jnp.stack([m1, m2, jnp.min(s1.reshape(-1, tile_w), axis=1)])
 
     return run
 
@@ -333,11 +452,13 @@ def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
 
 @functools.cache
 def make_select_fn(params: AnchoredCdcParams, m_tiles: int, cap: int):
-    """Compiled: (tiles [2, m_tiles] i32 — pass-A output, n i32) ->
-    bounds [cap] i32: exclusive segment boundaries in stream order, the
-    final one == n, -1 padding after it. A sequential scan with a
-    fixed-width two-row window gather per step — the walk is tiny (cap ~
-    hundreds) so only the boundary list ever reaches the host."""
+    """Compiled: (tiles [3, m_tiles] i32 — pass-A output, start0 i32,
+    n i32, final bool) -> (bounds [cap] i32: exclusive segment
+    boundaries in stream order, -1 padding after the last;
+    cuts [3] i32: how many of them are strong / window / forced cuts).
+    A sequential scan with a fixed-width three-row window gather per
+    step — the walk is tiny (cap ~ hundreds) so only the boundary list
+    ever reaches the host."""
     import jax
     import jax.numpy as jnp
 
@@ -346,6 +467,7 @@ def make_select_fn(params: AnchoredCdcParams, m_tiles: int, cap: int):
     win = select_window_tiles(params)
     seg_min = jnp.int32(params.seg_min)
     seg_max = jnp.int32(params.seg_max)
+    strong_min = jnp.int32(params.strong_min)
 
     @jax.jit
     def run(tiles, start0, n, final):
@@ -353,31 +475,46 @@ def make_select_fn(params: AnchoredCdcParams, m_tiles: int, cap: int):
         a non-final region the tail segment is NOT emitted (its bytes
         carry into the next region)."""
         tiles_p = jnp.concatenate(
-            [tiles, jnp.full((2, win), 2**30, jnp.int32)], axis=1)
+            [tiles, jnp.full((3, win), 2**30, jnp.int32)], axis=1)
 
         def body(carry, _):
             start, done = carry
+            slo = start + strong_min
             lo = start + seg_min
             hi = start + seg_max
-            t0 = (lo - 1) // jnp.int32(TILE_BYTES)
-            w = jax.lax.dynamic_slice(tiles_p, (0, t0), (2, win))
-            valid = (w >= lo - 1) & (w <= hi - 1)
-            last = jnp.max(jnp.where(valid, w, -1))
-            b = jnp.where(last >= 0, last + 1, hi)
+            # one window from the strong rule's low end: it holds the
+            # kept-anchor window too (strong_min <= seg_min)
+            t0 = (slo - 1) // jnp.int32(TILE_BYTES)
+            w = jax.lax.dynamic_slice(tiles_p, (0, t0), (3, win))
+            sw = w[2]
+            first = jnp.min(jnp.where((sw >= slo - 1) & (sw <= hi - 1),
+                                      sw, 2**30))
+            kw = w[:2]
+            last = jnp.max(jnp.where((kw >= lo - 1) & (kw <= hi - 1),
+                                     kw, -1))
+            kind = jnp.where(first < 2**30, CUT_STRONG,
+                             jnp.where(last >= 0, CUT_WINDOW, CUT_FORCED))
+            b = jnp.where(first < 2**30, first + 1,
+                          jnp.where(last >= 0, last + 1, hi))
             fin = n - start <= seg_max
             b = jnp.where(fin, n, b)
+            kind = jnp.where(fin, CUT_END, kind)
             # non-final regions keep the tail segment as carry: emit
             # nothing once the remaining bytes fit in one segment
-            out = jnp.where(done | (fin & ~final), -1, b)
-            return (jnp.where(out >= 0, b, start), done | fin), out
+            skip = done | (fin & ~final)
+            out = jnp.where(skip, -1, b)
+            return ((jnp.where(skip, start, b), done | fin),
+                    (out, jnp.where(skip, -1, kind)))
 
         # unroll amortizes the per-step scan overhead (the body itself is
         # ~100 ns of VPU work); 8 measured 1.80 -> 0.97-1.34 ms on v5e,
         # the best of {1, 2, 4, 8, 16}
-        _, bounds = jax.lax.scan(
+        _, (bounds, kinds) = jax.lax.scan(
             body, (start0.astype(jnp.int32), jnp.bool_(False)), None,
             length=cap, unroll=8)
-        return bounds
+        cuts = jnp.sum(kinds[:, None] == jnp.arange(3, dtype=jnp.int32),
+                       axis=0, dtype=jnp.int32)
+        return bounds, cuts
 
     return run
 
@@ -489,23 +626,45 @@ class CutCapacityOverflow(RuntimeError):
     retries the window at the full worst-case bound."""
 
 
+def segment_cap(params: AnchoredCdcParams, m_words: int) -> int:
+    """Most segments a region of ``m_words`` can hold: every cut a
+    strong one at ``strong_min`` (<= seg_min). The select walk's length
+    and the full-mode lane bound."""
+    return m_words * 4 // params.strong_min + 1
+
+
+def expected_segment_bytes(params: AnchoredCdcParams) -> float:
+    """Mean segment length the selection rule gives on random content.
+    With s = the strong anchors' rate a byte and W = seg_max -
+    strong_min, a window holds a strong anchor with probability
+    p = 1 - e^(-sW) and the first one lies an Exp(s) truncated to W
+    past strong_min; the other windows end at the last kept anchor
+    below seg_max, one mean anchor gap short of it. Default params:
+    0.78 x 68.4 KiB + 0.22 x 120 KiB = 79.9 KiB (a lane is 128 KiB)."""
+    s = 1.0 / (params.strong_mask + 1)
+    w = params.seg_max - params.strong_min
+    miss = math.exp(-s * w)
+    strong_len = params.strong_min + 1.0 / s - w * miss / (1.0 - miss)
+    window_len = max(params.seg_min, params.seg_max - (params.seg_mask + 1))
+    return (1.0 - miss) * strong_len + miss * window_len
+
+
 def _tight_segment_lanes(params: AnchoredCdcParams, m_words: int,
                          lane_multiple: int) -> int:
     """Lane count for cap_mode='tight': ~1.1x the EXPECTED segment
-    count, rounded up to the compaction tiling. The worst case (every
-    boundary at seg_min) provisions ~25% more lanes than real content
-    ever uses, and padding lanes are not free — repack writes them, the
-    transpose moves them, and the strip-scan SHA kernel computes over
-    them masked (measured ~17% of the scan half at default params).
-    Expected segment length = seg_max minus one mean anchor gap (the
-    boundary is the LAST anchor in the window, Exp(gap)-truncated below
-    it). Content denser in segments than the margin trips the exact
-    on-device segment count (nseg > lanes, counted by the full-bound
-    select scan) and redispatches at 'full' — same contract as the cut
-    capacity, and the carry stays exact throughout (make_chain_fn)."""
-    full = m_words * 4 // params.seg_min + 1
-    avg_seg = max(params.seg_min, params.seg_max - (params.seg_mask + 1))
-    expected = max(1, m_words * 4 // avg_seg)
+    count (:func:`expected_segment_bytes` — from the rule, not from a
+    constant), rounded up to the compaction tiling. The worst case
+    (every boundary a strong one at strong_min) provisions ~2.5x the
+    lanes real content ever uses, and padding lanes are not free —
+    repack writes them, the transpose moves them, and the strip-scan
+    SHA kernel computes over them masked (measured ~17% of the scan
+    half when the worst case was 1.25x). Content denser in segments
+    than the margin trips the exact on-device segment count (nseg >
+    lanes, counted by the full-bound select scan) and redispatches at
+    'full' — same contract as the cut capacity, and the carry stays
+    exact throughout (make_chain_fn)."""
+    full = segment_cap(params, m_words)
+    expected = max(1, int(m_words * 4 / expected_segment_bytes(params)))
     tight = -(-(expected * 11 // 10) // lane_multiple) * lane_multiple
     return min(tight, -(-full // lane_multiple) * lane_multiple)
 
@@ -730,7 +889,7 @@ def make_chain_fn(params: AnchoredCdcParams, total_words: int,
 
     cap_mode='tight' provisions the segment LANES (the repacked batch,
     the SHA strip grid, and the compaction capacity) at ~1.1x the
-    expected segment count instead of the all-boundaries-at-seg_min
+    expected segment count instead of the all-boundaries-at-strong_min
     worst case (_tight_segment_lanes). The select SCAN always runs at
     the full bound — it is lane-count-independent and computing the
     complete boundary list keeps the returned ``consumed`` carry exact
@@ -740,13 +899,15 @@ def make_chain_fn(params: AnchoredCdcParams, total_words: int,
     really has more segments than the lanes hold (strict: an exact fit
     is not an overflow) — region_collect raises CutCapacityOverflow and
     the caller redispatches THIS window at 'full', exactly like the cut
-    capacity."""
+    capacity. ``nseg`` and ``cuts`` (strong / window / forced, [3]) say
+    how the region's segments came to end — from the full boundary list
+    too, so a redo counts nothing twice."""
     import jax
     import jax.numpy as jnp
 
     m_words = recover_m_words(total_words, params)
     m_tiles = m_words * 4 // TILE_BYTES
-    cap = m_words * 4 // params.seg_min + 1
+    cap = segment_cap(params, m_words)
     if cap_mode == "tight":
         s_pad = _tight_segment_lanes(params, m_words, lane_multiple)
     else:
@@ -760,14 +921,15 @@ def make_chain_fn(params: AnchoredCdcParams, total_words: int,
     @jax.jit
     def run(words, start0, n, final):
         tiles = anchor(words)
-        bounds = select(tiles, start0, n, final)
+        bounds, cuts = select(tiles, start0, n, final)
         (starts, seg_lens, w_off, sh8, real_blocks, tail_len,
          consumed, nseg) = desc(bounds, start0)
         seg_overflow = (nseg > jnp.int32(s_pad)) if tight \
             else jnp.int32(0)
         count, q, offs, lens, dig = segfn(words, w_off, sh8, real_blocks,
                                           tail_len, starts, seg_lens)
-        return consumed, seg_overflow, count, q, offs, lens, dig
+        return (consumed, seg_overflow, count, q, offs, lens, dig,
+                nseg, cuts)
 
     return run
 
@@ -852,8 +1014,8 @@ def region_dispatch(words, n: int, start0, final: bool,
     already device_put). ``start0`` may be a host int or a device scalar —
     a device scalar keeps a multi-region walk entirely free of host syncs
     (the carry chains on device). Returns device arrays
-    (consumed i32, seg_overflow i32, count i32, q, offs, lens, digests);
-    nothing blocks.
+    (consumed i32, seg_overflow i32, count i32, q, offs, lens, digests,
+    nseg i32, cuts [3] i32); nothing blocks.
 
     The n/start0/final scalars are cached device constants — re-putting
     them per region is a host->device transfer each (dispatch is
@@ -867,15 +1029,18 @@ def region_dispatch(words, n: int, start0, final: bool,
     return chain(words, start0, _dev_i32(int(n)), _dev_bool(bool(final)))
 
 
-def region_collect(out) -> tuple[list[tuple[int, int, str]], int]:
+def region_collect(out) -> tuple[list[tuple[int, int, str]], int,
+                                 tuple[int, int, int, int]]:
     """Pull a :func:`region_dispatch` result to the host and format it:
-    ([(region_offset, length, sha256hex)], consumed). The only sync point
-    of the chain."""
+    ([(region_offset, length, sha256hex)], consumed, (segments,
+    strong_cuts, window_cuts, forced_cuts)). The only sync point of the
+    chain."""
     import jax
 
     from dfs_tpu.ops.cdc_pipeline import digests_to_hex
 
-    consumed, seg_of, count, q, offs, lens, dig = jax.device_get(out)
+    (consumed, seg_of, count, q, offs, lens, dig, nseg,
+     cuts) = jax.device_get(out)
     if int(seg_of):
         # more segments than the tight lane provisioning — the lane
         # tables dropped the tail segments (consumed is still exact:
@@ -892,15 +1057,16 @@ def region_collect(out) -> tuple[list[tuple[int, int, str]], int]:
     if count and (q[:count] < 0).any():
         raise AssertionError("anchored cut compaction overflowed a tile")
     hexes = digests_to_hex(dig[:count])
-    return [(int(o), int(ln), h)
-            for o, ln, h in zip(offs[:count], lens[:count], hexes)], \
-        int(consumed)
+    return ([(int(o), int(ln), h)
+             for o, ln, h in zip(offs[:count], lens[:count], hexes)],
+            int(consumed), (int(nseg), *(int(c) for c in cuts)))
 
 
 def region_chunks(data: np.ndarray, lookback: np.ndarray, start0: int,
                   final: bool, params: AnchoredCdcParams,
                   lane_multiple: int = 128, cap_mode: str = "tight"
-                  ) -> tuple[list[tuple[int, int, str]], int]:
+                  ) -> tuple[list[tuple[int, int, str]], int,
+                             tuple[int, int, int, int]]:
     """Chunk one stream region on device.
 
     data: [n] u8 region bytes (byte 0 = stream offset ``base``, any base);
@@ -910,9 +1076,10 @@ def region_chunks(data: np.ndarray, lookback: np.ndarray, start0: int,
     True iff the stream ends at data[-1] — otherwise the tail segment is
     withheld so its bytes can carry into the next region.
 
-    Returns ([(region_offset, length, sha256hex)], consumed): chunks of
-    every emitted segment, and the region offset up to which segments were
-    emitted (== n when final). Batching is transparent: for any region
+    Returns ([(region_offset, length, sha256hex)], consumed, cut counts
+    as :func:`region_collect`): chunks of every emitted segment, and the
+    region offset up to which segments were emitted (== n when final).
+    Batching is transparent: for any region
     split the concatenated output equals the whole-stream oracle
     (chunk_file_anchored_np), which tests enforce.
     """
@@ -920,7 +1087,7 @@ def region_chunks(data: np.ndarray, lookback: np.ndarray, start0: int,
 
     n = int(data.shape[0])
     if n == 0:
-        return [], 0
+        return [], 0, (0, 0, 0, 0)
     words = jax.device_put(region_buffer(data, lookback, params))
     out = region_dispatch(words, n, start0, final, params,
                           lane_multiple=lane_multiple, cap_mode=cap_mode)
@@ -938,7 +1105,6 @@ def batch_chunks_anchored(data: np.ndarray, params: AnchoredCdcParams,
                           lane_multiple: int = 128
                           ) -> list[tuple[int, int, str]]:
     """Whole-stream convenience wrapper over :func:`region_chunks`."""
-    chunks, _ = region_chunks(
+    return region_chunks(
         np.asarray(data), np.zeros((8,), np.uint8), 0, True, params,
-        lane_multiple=lane_multiple)
-    return chunks
+        lane_multiple=lane_multiple)[0]

@@ -207,8 +207,9 @@ def make_anchored_anchor_step(mesh: Mesh, params, m_local: int):
     span, the anchored analogue of the rolling pipeline's ppermute ring).
 
     step(spans [n_dev, 2 + m_local] u32) -> tiles
-    [2, n_dev * tiles_local] i32 (first-two-anchor byte positions per
-    TILE_BYTES tile, region-local; row 0 < row 1 where present).
+    [3, n_dev * tiles_local] i32 (per TILE_BYTES tile the first two
+    anchor byte positions, row 0 < row 1 where present, and the first
+    strong position; region-local).
     """
     from dfs_tpu.ops.cdc_anchored import TILE_BYTES, make_anchor_fn
 
@@ -220,7 +221,7 @@ def make_anchored_anchor_step(mesh: Mesh, params, m_local: int):
         # the span — rebase to region offsets with the device index.
         dev = jax.lax.axis_index("dp") * mesh.shape["sp"] \
             + jax.lax.axis_index("sp")
-        tiles = local_fn(span[0])                   # [2, tiles_local]
+        tiles = local_fn(span[0])                   # [3, tiles_local]
         return (tiles + jnp.where(tiles < 2**30,
                                   dev * jnp.int32(m_local * 4),
                                   0))[None, :, :]
@@ -233,7 +234,7 @@ def make_anchored_anchor_step(mesh: Mesh, params, m_local: int):
     )
     return jax.jit(lambda spans: jnp.swapaxes(
         shard_fn(spans), 0, 1).reshape(
-        2, mesh.devices.size * tiles_local))
+        3, mesh.devices.size * tiles_local))
 
 
 def shard_anchor_inputs(mesh: Mesh, words: np.ndarray, m_local: int):
@@ -344,8 +345,9 @@ def make_anchored_window_anchor_step(mesh: Mesh, params, m_words: int):
     CDC_SHARD_r15.json A/B).
 
     step(words [B, total_words] u32 — B == dp size, rows sharded over
-    dp, replicated over sp) -> tiles [B, 2, m_tiles] i32 (per-window
-    first-two-anchor tables, window-local positions)."""
+    dp, replicated over sp) -> tiles [B, 3, m_tiles] i32 (per-window
+    anchor tables — two kept planes, one strong — window-local
+    positions)."""
     from dfs_tpu.ops.cdc_anchored import make_anchor_fn
 
     local_fn = make_anchor_fn(params, m_words)
@@ -427,11 +429,11 @@ def host_lane_descriptors(data: np.ndarray, params, pad_multiple: int):
     the device-side make_descriptor_fn encoding (the sharded ingest
     walk uses the same function per window). Returns (starts, bounds,
     seg_lens, w_off, sh8, real_blocks, s_real)."""
-    from dfs_tpu.ops.cdc_anchored import (kept_anchors_np, lane_tables_np,
+    from dfs_tpu.ops.cdc_anchored import (anchors_np, lane_tables_np,
                                           select_segments)
 
     n = int(data.shape[0])
-    bounds = select_segments(kept_anchors_np(data, params), n, params)
+    bounds = select_segments(*anchors_np(data, params), n, params)
     starts = np.concatenate([[0], bounds[:-1]])
     seg_lens = bounds - starts
     s_real = starts.shape[0]
@@ -466,14 +468,15 @@ def anchored_sharded_parity_check(mesh: Mesh, n_devices: int) -> None:
     cutflags == per-segment selection, psum == population, reconstructed
     spans == whole-stream chunk_spans_anchored_np)."""
     from dfs_tpu.ops.cdc_anchored import (TILE_BYTES, AnchoredCdcParams,
+                                          anchor_planes_np, anchors_np,
                                           chunk_spans_anchored_np,
-                                          kept_anchors_np, region_buffer)
+                                          region_buffer)
     from dfs_tpu.ops.cdc_v2 import BLOCK, AlignedCdcParams
 
     params = AnchoredCdcParams(
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),        # 4 KiB lanes
-        seg_min=2048, seg_max=4096, seg_mask=2047)
+        seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
 
     m_local = 4 * TILE_BYTES // 4                       # 4 tiles per device
     m_words = m_local * n_devices
@@ -486,12 +489,8 @@ def anchored_sharded_parity_check(mesh: Mesh, n_devices: int) -> None:
     # ---- pass A sharded: tiles vs NumPy oracle ----
     astep = make_anchored_anchor_step(mesh, params, m_local)
     tiles = np.asarray(astep(shard_anchor_inputs(mesh, words, m_local)))
-    kept = kept_anchors_np(data, params)
-    expect_tiles = np.full((2, m_words * 4 // TILE_BYTES), 2**30, np.int32)
-    for p in kept:                  # kept is first-two-per-tile, sorted
-        t = int(p) // TILE_BYTES
-        row = 0 if expect_tiles[0, t] == 2**30 else 1
-        expect_tiles[row, t] = int(p)
+    expect_tiles = anchor_planes_np(*anchors_np(data, params),
+                                    m_words * 4 // TILE_BYTES)
     if not np.array_equal(tiles, expect_tiles):
         raise AssertionError("sharded anchored pass A tile mismatch")
 
@@ -529,13 +528,13 @@ def anchored_sharded_production_check(mesh: Mesh, n_devices: int,
                                       region_bytes: int = 64 * 2**20,
                                       ) -> dict:
     """The parity check above at PRODUCTION geometry: a full 64 MiB
-    region, default AnchoredCdcParams (96-128 KiB segments, 128 KiB
+    region, default AnchoredCdcParams (32-128 KiB segments, 128 KiB
     lanes), lane tables padded to lane_multiple=128 — the exact shapes
     the single-chip chain ships with (`__graft_entry__.entry` uses
     production lane_multiple but toy segments; the toy-mesh check uses
     4-tile devices). This exercises what those cannot: lane-table
-    provisioning at ~640 real lanes, halo/rebase correctness at 16K
-    tiles per device, and the [2, n_tiles] two-anchor planes across
+    provisioning at ~840 real lanes, halo/rebase correctness at 16K
+    tiles per device, and the [3, n_tiles] anchor planes across
     device boundaries. Oracle-checked end to end (pass-A tiles, pass-B
     cutflags per segment, psum, reconstructed spans == whole-stream
     oracle). Returns a timing/shape record for the committed artifact
@@ -545,8 +544,9 @@ def anchored_sharded_production_check(mesh: Mesh, n_devices: int,
     import time
 
     from dfs_tpu.ops.cdc_anchored import (TILE_BYTES, AnchoredCdcParams,
+                                          anchor_planes_np, anchors_np,
                                           chunk_spans_anchored_np,
-                                          kept_anchors_np, region_buffer)
+                                          region_buffer)
     from dfs_tpu.ops.cdc_v2 import BLOCK
 
     params = AnchoredCdcParams()               # production geometry
@@ -577,15 +577,13 @@ def anchored_sharded_production_check(mesh: Mesh, n_devices: int,
     t0 = time.perf_counter()
     tiles = np.asarray(jax.block_until_ready(astep(inp)))
     rec["pass_a_s"] = round(time.perf_counter() - t0, 3)
-    kept = kept_anchors_np(data, params)
-    expect_tiles = np.full((2, m_words * 4 // TILE_BYTES), 2**30, np.int32)
-    for p in kept:
-        t = int(p) // TILE_BYTES
-        row = 0 if expect_tiles[0, t] == 2**30 else 1
-        expect_tiles[row, t] = int(p)
+    kept, strong = anchors_np(data, params)
+    expect_tiles = anchor_planes_np(kept, strong,
+                                    m_words * 4 // TILE_BYTES)
     if not np.array_equal(tiles, expect_tiles):
         raise AssertionError("production sharded pass A tile mismatch")
     rec["kept_anchors"] = int(kept.shape[0])
+    rec["strong_anchors"] = int(strong.shape[0])
 
     # ---- host selection + production lane tables ----
     (starts, bounds, seg_lens, w_off, sh8, real_blocks,

@@ -6,17 +6,24 @@ import hashlib
 import numpy as np
 import pytest
 
-from dfs_tpu.ops.cdc_anchored import (TILE_BYTES, AnchoredCdcParams,
-                                      anchor_hash_np, batch_chunks_anchored,
+from dfs_tpu.ops.cdc_anchored import (CUT_END, CUT_FORCED, CUT_STRONG,
+                                      CUT_WINDOW, TILE_BYTES,
+                                      AnchoredCdcParams, anchor_hash_np,
+                                      anchor_planes_np, anchors_np,
+                                      batch_chunks_anchored,
                                       chunk_file_anchored_np,
-                                      chunk_spans_anchored_np,
-                                      kept_anchors_np, select_segments)
+                                      chunk_spans_anchored_np, cut_counts,
+                                      select_segments,
+                                      select_segments_kinds)
 from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
 
+# 4 KiB lanes; strong anchors every 4 KiB and admissible from 1 KiB
+# (strong_min = seg_max / 4, as in production), so about half the cuts
+# are strong ones and the other half take the fallback
 SMALL = AnchoredCdcParams(
     chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
-                           strip_blocks=64),           # 4 KiB lanes
-    seg_min=2048, seg_max=4096, seg_mask=2047)
+                           strip_blocks=64),
+    seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
 
 
 def corpus(n, seed=0):
@@ -41,7 +48,7 @@ def test_anchor_hash_window_is_8_bytes():
 
 def test_kept_anchors_two_per_tile():
     data = corpus(200000, seed=2)
-    kept = kept_anchors_np(data, SMALL)
+    kept = anchors_np(data, SMALL)[0]
     tiles = kept // TILE_BYTES
     counts = np.bincount(tiles)
     assert counts.max() <= 2
@@ -58,16 +65,47 @@ def test_kept_anchors_two_per_tile():
         assert np.array_equal(got, expect)
 
 
+def test_kept_strong_plane_is_first_strong_hash_per_tile():
+    """The strong plane is tested on the hash alone: a strong position
+    stands whether or not it is one of its tile's two kept anchors."""
+    data = corpus(400000, seed=2)
+    kept, strong = anchors_np(data, SMALL)
+    h = anchor_hash_np(data, SMALL)
+    pos = np.flatnonzero((h & np.uint32(SMALL.strong_mask)) == 0)
+    assert SMALL.strong_mask == 4095 and pos.size > 50
+    _, first = np.unique(pos // TILE_BYTES, return_index=True)
+    assert np.array_equal(strong, pos[first])
+    # every strong hash is an anchor hash; not every one was kept
+    assert np.all((h[strong] & np.uint32(SMALL.seg_mask)) == 0)
+    planes = anchor_planes_np(kept, strong, -(-data.shape[0] // TILE_BYTES))
+    assert planes.shape[0] == 3 and (planes[2] < 2**30).sum() == strong.size
+
+
 def test_segments_respect_bounds():
     data = corpus(300000, seed=3)
-    bounds = select_segments(kept_anchors_np(data, SMALL),
-                             data.shape[0], SMALL)
-    assert bounds[-1] == data.shape[0]
+    bounds, kinds = select_segments_kinds(*anchors_np(data, SMALL),
+                                          data.shape[0], SMALL)
+    assert bounds[-1] == data.shape[0] and kinds[-1] == CUT_END
+    low = {CUT_STRONG: SMALL.strong_min, CUT_WINDOW: SMALL.seg_min,
+           CUT_FORCED: SMALL.seg_max}
     prev = 0
-    for b in bounds[:-1].tolist():
-        assert SMALL.seg_min <= b - prev <= SMALL.seg_max
+    for b, k in zip(bounds[:-1].tolist(), kinds[:-1].tolist()):
+        assert low[k] <= b - prev <= SMALL.seg_max
         prev = b
     assert bounds[-1] - prev <= SMALL.seg_max
+    segments, strong, window, forced = cut_counts(kinds)
+    assert segments == len(bounds) == strong + window + forced + 1
+    assert strong > 20 and window > 20      # both arms of the rule run
+
+
+def test_default_params_state_the_rule():
+    p = AnchoredCdcParams()
+    assert (p.strong_min, p.strong_bits, p.strong_mask) == \
+        (32 * 1024, 3, 65535)
+    with pytest.raises(ValueError):
+        AnchoredCdcParams(strong_min=p.seg_min + TILE_BYTES)
+    with pytest.raises(ValueError):
+        AnchoredCdcParams(strong_min=1000)
 
 
 def test_spans_tile_stream_and_match_hashlib():
@@ -95,6 +133,223 @@ def test_shift_resilience_vs_aligned():
     shared = sum(ln for _, ln, dg in b if dg in a)
     assert shared / edited.shape[0] > 0.85, \
         f"only {shared / edited.shape[0]:.0%} of bytes deduped after insert"
+
+
+# ------------------------------------- the inputs every engine is held to --
+
+def _pattern(params, strong: bool) -> np.ndarray:
+    """Eight bytes whose anchor hash is a strong anchor (or an anchor that
+    is NOT strong) when they end at any position, and which set off no
+    other anchor where they overlap a run of zeros."""
+    data = corpus(1 << 20, seed=77)
+    h = anchor_hash_np(data, params)
+    hit = (h & np.uint32(params.seg_mask)) == 0
+    is_strong = (h & np.uint32(params.strong_mask)) == 0
+    for p in np.flatnonzero(hit & (is_strong == strong)):
+        if p < 7:
+            continue
+        pat = data[p - 7:p + 1]
+        probe = np.zeros(64, np.uint8)
+        probe[20:28] = pat
+        hp = anchor_hash_np(probe, params)
+        if np.flatnonzero((hp & np.uint32(params.seg_mask)) == 0
+                          ).tolist() == [27]:
+            return pat
+    raise AssertionError("no clean pattern in the probe corpus")
+
+
+def edge_stream(params) -> tuple[np.ndarray, list[tuple[int, int]]]:
+    """Zeros (no anchor) with anchors planted at the windows' edges, and
+    the (boundary, kind) list the rule must give for them: a strong
+    anchor one byte below the strong window and one at its top edge; one
+    at its bottom edge; one a byte above the top edge (a forced cut); a
+    plain anchor a byte below the kept window's bottom edge (forced
+    again); one at that edge."""
+    sp, ap = _pattern(params, True), _pattern(params, False)
+    smin, lo, hi = params.strong_min, params.seg_min, params.seg_max
+    plan = []                  # (boundary this anchor would give, pattern)
+    want = []
+    s = 0
+    plan += [(s + smin - 1, sp), (s + hi, sp)]
+    want.append((s + hi, CUT_STRONG))
+    s += hi
+    plan.append((s + smin, sp))
+    want.append((s + smin, CUT_STRONG))
+    s += smin
+    plan.append((s + hi + 1, sp))
+    want.append((s + hi, CUT_FORCED))
+    s += hi
+    plan.append((s + lo - 1, ap))
+    want.append((s + hi, CUT_FORCED))
+    s += hi
+    plan.append((s + lo, ap))
+    want.append((s + lo, CUT_WINDOW))
+    s += lo
+    n = s + hi - 100
+    want.append((n, CUT_END))
+    data = np.zeros(n, np.uint8)
+    for b, pat in plan:
+        data[b - 8:b] = pat
+    return data, want
+
+
+def dense_stream(params, strong: bool, n: int = 60000) -> np.ndarray:
+    """An anchor (strong or plain) every eight bytes: every tile's planes
+    are full, and with strong ones every cut lands on strong_min — the
+    most segments a region can hold."""
+    return np.tile(_pattern(params, strong), n // 8)
+
+
+CASES = {
+    "random": lambda: corpus(150001, seed=71),
+    "zeros": lambda: np.zeros(100000, np.uint8),
+    "dense": lambda: dense_stream(SMALL, strong=False),
+    "dense-strong": lambda: dense_stream(SMALL, strong=True),
+    "edges": lambda: edge_stream(SMALL)[0],
+}
+
+
+def test_edge_stream_cuts_where_the_rule_says():
+    data, want = edge_stream(SMALL)
+    bounds, kinds = select_segments_kinds(*anchors_np(data, SMALL),
+                                          data.shape[0], SMALL)
+    assert list(zip(bounds.tolist(), kinds.tolist())) == want
+
+
+def test_extreme_streams_take_the_arm_they_were_built_for():
+    def kinds_of(data):
+        return cut_counts(select_segments_kinds(
+            *anchors_np(data, SMALL), data.shape[0], SMALL)[1])
+
+    seg, strong, window, forced = kinds_of(CASES["zeros"]())
+    assert (strong, window) == (0, 0) and forced == seg - 1 > 10
+    seg, strong, window, forced = kinds_of(CASES["dense"]())
+    assert (strong, forced) == (0, 0) and window == seg - 1 > 10
+    seg, strong, window, forced = kinds_of(CASES["dense-strong"]())
+    assert (window, forced) == (0, 0) and strong == seg - 1
+    # every strong cut within a tile of strong_min (a tile keeps its
+    # first strong position only)
+    assert seg >= 60000 // (SMALL.strong_min + TILE_BYTES)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_four_implementations_cut_alike(case):
+    """NumPy oracle, C++ walk, XLA scan and the Pallas walk (interpret
+    mode) give the same anchor planes, boundaries, cut kinds and chunks —
+    as a whole stream and as a region with a carried start."""
+    import jax
+    import jax.numpy as jnp
+
+    import dfs_tpu.ops.cdc_anchored as A
+    from dfs_tpu.native import native_anchored_spans_region
+    from dfs_tpu.ops.select_pallas import make_select_fn_pallas
+
+    data = CASES[case]()
+    n = int(data.shape[0])
+    zeros8 = np.zeros(8, np.uint8)
+    kept, strong = anchors_np(data, SMALL)
+    words = A.region_buffer(data, zeros8, SMALL)
+    m_words = A.recover_m_words(words.shape[0], SMALL)
+    m_tiles = m_words * 4 // TILE_BYTES
+    tiles = A.make_anchor_fn(SMALL, m_words)(jnp.asarray(words))
+    np.testing.assert_array_equal(
+        np.asarray(tiles), anchor_planes_np(kept, strong, m_tiles))
+
+    cap = A.segment_cap(SMALL, m_words)
+    for start0, final in ((0, True), (0, False), (777, True), (777, False)):
+        bounds, kinds = select_segments_kinds(kept, strong, n, SMALL,
+                                              start0=start0, final=final)
+        counts = cut_counts(kinds)
+        args = (tiles, jnp.int32(start0), jnp.int32(n), jnp.bool_(final))
+        for name, fn in (
+                ("xla", A.make_select_fn(SMALL, m_tiles, cap)),
+                ("pallas", make_select_fn_pallas(SMALL, m_tiles, cap,
+                                                 interpret=True))):
+            got, cuts = jax.device_get(fn(*args))
+            assert got[got >= 0].tolist() == bounds.tolist(), name
+            assert (got[len(bounds):] == -1).all(), name
+            assert tuple(cuts.tolist()) == counts[1:], name
+
+        want_spans, want_end = A.region_spans_np(data, zeros8, start0,
+                                                 final, SMALL)
+        ck = np.zeros(4, np.uint64)
+        native = native_anchored_spans_region(data, zeros8, start0, final,
+                                              SMALL, cut_kinds=ck)
+        if native is not None:
+            assert [tuple(r) for r in native[0].tolist()] == want_spans
+            assert native[1] == want_end
+            assert ck.tolist() == np.bincount(kinds, minlength=4).tolist()
+
+        chunks, consumed, got_counts = A.region_chunks(
+            data, zeros8, start0, final, SMALL, lane_multiple=8)
+        assert [(o, ln) for o, ln, _ in chunks] == want_spans
+        assert consumed == want_end == (n if final else bounds[-1]
+                                        if len(bounds) else start0)
+        assert got_counts == counts
+
+
+# ------------------------------------------------------- re-synchronising --
+# The properties the last-anchor rule failed while every parity test
+# passed: lane utilisation was bought with dedup, unseen (PERF.md §6,
+# PR 37). Production parameters, through the CPU engine.
+
+def _cut_ends(data: np.ndarray, params) -> np.ndarray:
+    from dfs_tpu.native import native_anchored_spans
+
+    spans = native_anchored_spans(data, params)
+    if spans is None:
+        spans = np.asarray(chunk_spans_anchored_np(data, params))
+    return spans[:, 0] + spans[:, 1]
+
+
+def test_cuts_resynchronise_after_an_insert():
+    """Two versions of a stream differing by one insert of 512*k bytes
+    (0.5-16 KiB, a tar's shift): in content coordinates their cuts
+    coincide again within 4 strong gaps (256 KiB) of the insert in >= 95 %
+    of 64 seeds. (Read at PR 37: 62 of 64, the worst 598 KiB; the
+    last-anchor rule alone: 32 of 64, median 258 KiB, 1.4 MiB — the
+    stream's end — in the worst.)"""
+    params = AnchoredCdcParams()
+    within = 4 * (params.strong_mask + 1)
+    ok = 0
+    for seed in range(64):
+        rng = np.random.default_rng([37, seed])
+        base = rng.integers(0, 256, size=3 * 2**19, dtype=np.uint8)
+        k = int(rng.integers(1, 33))
+        at = int(rng.integers(128 * 1024, 256 * 1024))
+        ins = rng.integers(0, 256, size=512 * k, dtype=np.uint8)
+        edited = np.concatenate([base[:at], ins, base[at:]])
+        a = _cut_ends(base, params)
+        b = _cut_ends(edited, params) - 512 * k
+        differ = set(a[a > at].tolist()) ^ set(b[b > at].tolist())
+        ok += (max(differ) if differ else at) - at <= within
+    assert ok >= 61, f"cuts re-synchronised in only {ok} of 64 seeds"
+
+
+def test_tree_snapshot_with_2pct_of_files_edited_is_refound():
+    """A source tree of 300 files as a tar, and its next version (2 % of
+    the files edited, some added, removed and renamed): at most a quarter
+    of the version is stored anew at 12 KiB a file, and at most 0.12 at
+    32 KiB a file, where the edits lie further apart than the old rule's
+    walk took to agree again (read at PR 37: 0.153 and 0.074; the
+    last-anchor rule alone: 0.178 and 0.213)."""
+    import bench_dedup_tree as T
+
+    params = AnchoredCdcParams()
+    for mean_file_bytes, at_most in ((12 * 1024, 0.25), (32 * 1024, 0.12)):
+        rng = np.random.default_rng(17)
+        tree = T.make_tree(rng, 300, mean_file_bytes)
+        versions = [np.frombuffer(T.tar_bytes(t), np.uint8)
+                    for t in (tree, T.evolve(rng, tree, churn=0.02))]
+        tables = []
+        for v in versions:
+            ends = _cut_ends(v, params)
+            starts = np.concatenate([[0], ends[:-1]])
+            tables.append({hashlib.sha256(v[o:e]).digest(): int(e - o)
+                           for o, e in zip(starts.tolist(), ends.tolist())})
+        anew = sum(ln for dg, ln in tables[1].items()
+                   if dg not in tables[0])
+        assert anew / versions[1].shape[0] <= at_most, mean_file_bytes
 
 
 # ---------------------------------------------------------- device parity --
@@ -213,22 +468,38 @@ def test_region_walk_transparent():
     assert big.chunk(data) == small.chunk(data)
 
 
-def test_three_way_region_streaming_equality():
-    """Large-region one-shot == tiny-region walk == streaming, and all
-    equal the NumPy whole-stream oracle — the transparency property the
-    region/carry design exists to guarantee."""
-    arr = corpus(200000, seed=43)
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_three_way_region_streaming_equality(case):
+    """Large-region one-shot == tiny-region walk == streaming, on the
+    device engine and on the CPU engine, and all equal the NumPy
+    whole-stream oracle — the transparency property the region/carry
+    design exists to guarantee — and the owner's cut counters add up."""
+    from dfs_tpu.fragmenter.cdc_anchored import AnchoredCpuFragmenter
+
+    arr = CASES[case]()
     data = arr.tobytes()
     want = [(o, ln, dg) for o, ln, dg in chunk_file_anchored_np(arr, SMALL)]
 
-    one_shot = anchored_frag(region_bytes=1 << 30).chunk(data)
+    one_shot_frag = anchored_frag(region_bytes=1 << 30)
+    one_shot = one_shot_frag.chunk(data)
     tiny_frag = anchored_frag()            # 16 KiB regions: many carries
     tiny = tiny_frag.chunk(data)
     blocks = [data[i:i + 7333] for i in range(0, len(data), 7333)]
     streamed = tiny_frag.manifest_stream(blocks, name="f").chunks
+    cpu = AnchoredCpuFragmenter(SMALL, region_bytes=16384)
+    cpu_streamed = cpu.manifest_stream(blocks, name="f").chunks
 
-    for got in (one_shot, tiny, list(streamed)):
+    for got in (one_shot, tiny, list(streamed), cpu.chunk(data),
+                list(cpu_streamed)):
         assert [(c.offset, c.length, c.digest) for c in got] == want
+
+    counts = cut_counts(select_segments_kinds(
+        *anchors_np(arr, SMALL), arr.shape[0], SMALL)[1])
+    keys = ("segments", "strong_cuts", "window_cuts", "forced_cuts")
+    st = one_shot_frag.device_stats()
+    assert tuple(st[k] for k in keys) == counts
+    st = tiny_frag.device_stats()           # chunk() and the stream: twice
+    assert tuple(st[k] for k in keys) == tuple(2 * c for c in counts)
 
 
 def test_streaming_matches_chunk_any_blocking():
@@ -257,6 +528,25 @@ def test_streaming_block_lands_exactly_on_window_end():
     got = frag.manifest_stream(blocks, name="f").chunks
     want = anchored_frag().chunk(data)
     assert list(got) == want
+
+
+def test_describe_states_the_rule_and_round_trips():
+    """``describe()`` carries the rule's two constants, a fragmenter
+    rebuilt from it cuts alike, and a description from before the rule
+    (no ``strong_min``) is refused, not guessed at."""
+    from dfs_tpu.fragmenter.base import fragmenter_from_description
+    from dfs_tpu.fragmenter.cdc_anchored import AnchoredCpuFragmenter
+
+    desc = AnchoredCpuFragmenter(SMALL).describe()
+    assert (desc["strong_min"], desc["strong_bits"]) == (1024, 1)
+    rebuilt = fragmenter_from_description(desc)
+    assert rebuilt.params == SMALL
+    data = corpus(60000, seed=45).tobytes()
+    assert rebuilt.chunk(data) == AnchoredCpuFragmenter(SMALL).chunk(data)
+    old = {k: v for k, v in desc.items()
+           if k not in ("strong_min", "strong_bits")}
+    with pytest.raises(ValueError, match="strong_min"):
+        fragmenter_from_description(old)
 
 
 def test_factory_anchored_kinds():
@@ -425,11 +715,12 @@ def test_tight_segment_lane_overflow_in_pipelined_walk(monkeypatch):
         A.make_chain_fn.cache_clear()
 
 
-def _random_two_plane_tiles(rng, m_tiles, density=2):
-    """Random pass-A-shaped [2, m_tiles] tile planes: ~1/density tiles
+def _random_planes(rng, m_tiles, density=2, strong_density=8):
+    """Random pass-A-shaped [3, m_tiles] tile planes: ~1/density tiles
     hold a first anchor, about half of those also a second (strictly
-    larger, same tile) — mirrors make_anchor_fn's output invariants."""
-    tiles = np.full((2, m_tiles), 2**30, np.int32)
+    larger, same tile), ~1/strong_density a strong position anywhere in
+    the tile — mirrors make_anchor_fn's output invariants."""
+    tiles = np.full((3, m_tiles), 2**30, np.int32)
     k = max(1, m_tiles // density)
     idx = rng.choice(m_tiles, size=k, replace=False)
     off1 = rng.integers(0, TILE_BYTES - 1, size=k)   # <= TILE_BYTES - 2
@@ -438,7 +729,34 @@ def _random_two_plane_tiles(rng, m_tiles, density=2):
     off2 = off1 + 1 + rng.integers(0, TILE_BYTES - 1 - off1)
     tiles[1, idx[has2]] = (idx[has2] * TILE_BYTES
                            + off2[has2]).astype(np.int32)
+    ks = max(1, m_tiles // strong_density)
+    sidx = rng.choice(m_tiles, size=ks, replace=False)
+    tiles[2, sidx] = (sidx * TILE_BYTES
+                      + rng.integers(0, TILE_BYTES, size=ks)).astype(np.int32)
     return tiles
+
+
+def _assert_selects_agree(params, tiles, m_tiles, cap, start0, n, final):
+    """XLA scan == Pallas walk (interpret) == the NumPy rule, boundaries
+    and cut counts."""
+    import jax
+    import jax.numpy as jnp
+
+    import dfs_tpu.ops.cdc_anchored as A
+    from dfs_tpu.ops.select_pallas import make_select_fn_pallas
+
+    args = (jnp.asarray(tiles), jnp.int32(start0), jnp.int32(n),
+            jnp.bool_(final))
+    ref, ref_cuts = jax.device_get(
+        A.make_select_fn(params, m_tiles, cap)(*args))
+    got, got_cuts = jax.device_get(
+        make_select_fn_pallas(params, m_tiles, cap, interpret=True)(*args))
+    np.testing.assert_array_equal(ref, got)
+    np.testing.assert_array_equal(ref_cuts, got_cuts)
+    bounds, kinds = select_segments_kinds(
+        *A.planes_positions(tiles), n, params, start0=start0, final=final)
+    assert ref[ref >= 0].tolist() == bounds.tolist()
+    assert tuple(ref_cuts.tolist()) == cut_counts(kinds)[1:]
 
 
 def test_pallas_select_matches_xla_scan():
@@ -447,54 +765,39 @@ def test_pallas_select_matches_xla_scan():
     and non-final regions, zero and carried start0. Interpret mode on
     CPU; on real TPU the same kernel is exercised end-to-end by
     bench.py's hashlib gates (make_chain_fn picks it there)."""
-    import jax.numpy as jnp
-
-    from dfs_tpu.ops.select_pallas import make_select_fn_pallas
+    import dfs_tpu.ops.cdc_anchored as A
 
     rng = np.random.default_rng(11)
     params = SMALL
     for trial in range(2):
         n = int(rng.integers(20000, 120000))
         m_tiles = 1 << (-(-n // TILE_BYTES) - 1).bit_length()
-        cap = m_tiles * TILE_BYTES // params.seg_min + 1
-        tiles = _random_two_plane_tiles(rng, m_tiles)
-        import dfs_tpu.ops.cdc_anchored as A
+        cap = A.segment_cap(params, m_tiles * TILE_BYTES // 4)
+        tiles = _random_planes(rng, m_tiles)
         for final in (True, False):
             for start0 in (0, 1234):
-                ref = A.make_select_fn(params, m_tiles, cap)(
-                    jnp.asarray(tiles), jnp.int32(start0), jnp.int32(n),
-                    jnp.bool_(final))
-                got = make_select_fn_pallas(
-                    params, m_tiles, cap, interpret=True)(
-                    jnp.asarray(tiles), jnp.int32(start0), jnp.int32(n),
-                    jnp.bool_(final))
-                np.testing.assert_array_equal(
-                    np.asarray(ref), np.asarray(got))
+                _assert_selects_agree(params, tiles, m_tiles, cap, start0,
+                                      n, final)
 
 
-def test_pallas_select_large_region_block_addressing():
-    """Production-shaped geometry (96K/128K segments, 4 MiB region):
+@pytest.mark.parametrize("strong_density", [8, 128, 10**9])
+def test_pallas_select_large_region_block_addressing(strong_density):
+    """Production-shaped geometry (32K/96K/128K segments, 4 MiB region):
     t0 crosses the 1024-entry block boundary many times, so the kernel's
     8-row-aligned dynamic block read and (row + r0)*128 + col global
     index arithmetic are actually exercised (the small-n test's windows
-    all start in block zero)."""
-    import jax.numpy as jnp
-
+    all start in block zero) — with the 193-tile strong window holding a
+    strong anchor nearly always, at production's rate, and never."""
     import dfs_tpu.ops.cdc_anchored as A
-    from dfs_tpu.ops.select_pallas import make_select_fn_pallas
+    from dfs_tpu.ops.select_pallas import select_window_tiles
 
     params = AnchoredCdcParams()        # production segment geometry
+    assert select_window_tiles(params) == 193
     n = 4 * 2**20
     m_tiles = n // TILE_BYTES           # 8192 tiles -> t0 up to ~8192
-    cap = n // params.seg_min + 1
+    cap = A.segment_cap(params, n // 4)
     rng = np.random.default_rng(12)
-    tiles = _random_two_plane_tiles(rng, m_tiles, density=16)
+    tiles = _random_planes(rng, m_tiles, density=16,
+                           strong_density=strong_density)
     for final in (True, False):
-        ref = A.make_select_fn(params, m_tiles, cap)(
-            jnp.asarray(tiles), jnp.int32(0), jnp.int32(n),
-            jnp.bool_(final))
-        got = make_select_fn_pallas(params, m_tiles, cap,
-                                    interpret=True)(
-            jnp.asarray(tiles), jnp.int32(0), jnp.int32(n),
-            jnp.bool_(final))
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
+        _assert_selects_agree(params, tiles, m_tiles, cap, 0, n, final)

@@ -24,8 +24,10 @@ def test_entry_compiles_and_runs():
 
     fn, args = __graft_entry__.entry()
     jitted = jax.jit(fn)
-    consumed, seg_of, count, q, offs, lens, dig = jitted(*args)
+    consumed, seg_of, count, q, offs, lens, dig, nseg, cuts = jitted(*args)
     assert int(seg_of) == 0
+    cuts = np.asarray(cuts).tolist()
+    assert int(nseg) == sum(cuts) + 1 and min(cuts[:2]) > 0
     count = int(np.asarray(count))
     assert count > 0
     assert int(np.asarray(consumed)) == 128 * 1024   # final region
@@ -36,7 +38,8 @@ def test_entry_compiles_and_runs():
     params = AnchoredCdcParams(
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),
-        seg_min=2048, seg_max=4096, seg_mask=2047)   # mirrors entry()
+        seg_min=2048, seg_max=4096, seg_mask=2047,
+        strong_bits=1)                               # mirrors entry()
     words, _start0 = args
     n = 128 * 1024
     data = np.ascontiguousarray(words).view(np.uint8)[8:8 + n]

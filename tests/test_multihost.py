@@ -80,7 +80,7 @@ from dfs_tpu.parallel.sharded_cdc import (expected_segment_cutflags,
 aparams = AnchoredCdcParams(
     chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                            strip_blocks=64),
-    seg_min=2048, seg_max=4096, seg_mask=2047)
+    seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
 n = 64 * 1024
 adata = np.random.default_rng(77).integers(0, 256, size=n, dtype=np.uint8)
 awords = np.asarray(region_buffer(adata, np.zeros((8,), np.uint8), aparams))

@@ -9,6 +9,7 @@ from dfs_tpu.config import CDCParams
 from dfs_tpu.fragmenter.cdc_cpu import CpuCdcFragmenter, cdc_cuts_ref
 from dfs_tpu.native import get_lib, native_gear_cuts, native_sha256_many
 from dfs_tpu.utils.hashing import gear_table
+from tests.test_cdc_anchored import CASES, SMALL
 
 pytestmark = pytest.mark.skipif(get_lib() is None,
                                 reason="native toolchain unavailable")
@@ -62,7 +63,7 @@ def test_native_anchored_spans_matches_oracle(rng):
     params = AnchoredCdcParams(
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),
-        seg_min=2048, seg_max=4096, seg_mask=2047)
+        seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
     cases = [
         rng.integers(0, 256, size=300_000, dtype=np.uint8),
         rng.integers(0, 256, size=1, dtype=np.uint8),
@@ -74,6 +75,48 @@ def test_native_anchored_spans_matches_oracle(rng):
         got = native_anchored_spans(data, params)
         want = chunk_spans_anchored_np(data, params)
         assert [(int(o), int(ln)) for o, ln in got] == want
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("region_bytes", [8192, 16384, 1 << 20])
+def test_native_region_walk_cuts_alike_whole_and_windowed(case,
+                                                          region_bytes):
+    """The C++ walk, whole and as a fixed-stride window walk with the
+    carried tail (the CPU engine's streamed path), gives the NumPy
+    oracle's spans and counts the oracle's cut kinds — random, anchor-
+    free, anchor-dense and window-edge streams."""
+    from dfs_tpu.native import (native_anchored_spans,
+                                native_anchored_spans_region)
+    from dfs_tpu.ops.cdc_anchored import (anchors_np,
+                                          chunk_spans_anchored_np,
+                                          select_segments_kinds)
+    data = CASES[case]()
+    n = int(data.shape[0])
+    want = chunk_spans_anchored_np(data, SMALL)
+    assert [tuple(r) for r in native_anchored_spans(data, SMALL).tolist()] \
+        == want
+    kinds = select_segments_kinds(*anchors_np(data, SMALL), n, SMALL)[1]
+
+    stride = region_bytes - SMALL.seg_max
+    spans, total = [], np.zeros(4, np.uint64)
+    base = start0 = 0
+    while True:
+        final = base + region_bytes >= n
+        lookback = np.zeros(8, np.uint8)
+        take = min(8, base)
+        lookback[8 - take:] = data[base - take:base]
+        ck = np.zeros(4, np.uint64)
+        got, consumed = native_anchored_spans_region(
+            data[base:base + region_bytes], lookback, start0, final,
+            SMALL, cut_kinds=ck)
+        spans += [(base + int(o), int(ln)) for o, ln in got]
+        total += ck
+        if final:
+            break
+        start0 = consumed - stride
+        base += stride
+    assert spans == want
+    assert total.tolist() == np.bincount(kinds, minlength=4).tolist()
 
 
 def test_native_anchored_empty():

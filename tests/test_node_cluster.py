@@ -334,7 +334,7 @@ def test_cluster_with_anchored_device_pipeline(tmp_path, rng):
     small = AnchoredCdcParams(
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),
-        seg_min=2048, seg_max=4096, seg_mask=2047)
+        seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
     data = rng.integers(0, 256, size=150_000, dtype=np.uint8).tobytes()
 
     async def run():

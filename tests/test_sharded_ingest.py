@@ -26,6 +26,7 @@ from dfs_tpu.parallel.mesh import make_mesh
 from dfs_tpu.parallel.sharded_cdc import (make_sharded_bitmap_step,
                                           shard_bitmap_inputs)
 from dfs_tpu.utils.hashing import gear_table
+from tests.test_cdc_anchored import CASES
 
 PARAMS = CDCParams(min_size=64, avg_size=256, max_size=1024)
 # tiny regions so the sharded step compiles fast on the CI host; still a
@@ -37,7 +38,7 @@ REGION = 4 * 4096
 APARAMS = AnchoredCdcParams(
     chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                            strip_blocks=64),
-    seg_min=2048, seg_max=4096, seg_mask=2047)
+    seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
 AREGION = 4 * 4096
 
 
@@ -207,6 +208,21 @@ def test_anchored_sharded_byte_identical(size):
     assert [(c.offset, c.length, c.digest) for c in shd.chunks] \
         == [(c.offset, c.length, c.digest) for c in cpu.chunks]
     assert shd.file_id == cpu.file_id and shd.size == cpu.size
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_anchored_sharded_cuts_alike_on_the_rule_s_cases(case):
+    """The window-batched walk (device pass A's three planes pulled to
+    the host, the carry threaded through ``select_segments``) against
+    the host engine on the streams that drive each arm of the rule:
+    random, anchor-free (forced cuts), anchor-dense, strong anchors
+    every eight bytes (every cut at strong_min: the lane tables' full
+    bound) and anchors at the windows' edges."""
+    data = CASES[case]().tobytes()
+    cpu = AnchoredCpuFragmenter(APARAMS).chunk(data)
+    shd = _afrag().manifest_stream(_blocks(data, 1 << 13), name="x")
+    assert [(c.offset, c.length, c.digest) for c in shd.chunks] \
+        == [(c.offset, c.length, c.digest) for c in cpu]
 
 
 def test_anchored_sharded_carry_crosses_device_boundary():

@@ -42,16 +42,26 @@ def test_health_names_the_device_and_counts_regions(rng):
     try:
         dev = client.health()["device"]
         assert {k: dev[k] for k in ("platform", "device_kind", "regions",
-                                    "overflow_redos", "streams")} \
+                                    "overflow_redos", "streams",
+                                    "segments", "strong_cuts")} \
             == {"platform": "cpu", "device_kind": "cpu", "regions": 0,
-                "overflow_redos": 0, "streams": 0}
+                "overflow_redos": 0, "streams": 0, "segments": 0,
+                "strong_cuts": 0}
         # above the 2 MiB host cut-off, so the chain really dispatches
         data = rng.integers(0, 256, size=3 * 2**20 + 17,
                             dtype=np.uint8).tobytes()
         resp = client.chunk_hash_stream([data])
         assert [c["digest"] for c in resp["chunks"]] \
             == [c.digest for c in AnchoredCpuFragmenter().chunk(data)]
-        assert client.health()["device"]["regions"] == 1
+        dev = client.health()["device"]
+        assert dev["regions"] == 1 and dev["overflow_redos"] == 0
+        # how the region's ~40 segments came to end: most at a strong
+        # anchor, the rest at the last anchor of the window, one with
+        # the stream
+        assert dev["segments"] == dev["strong_cuts"] + dev["window_cuts"] \
+            + dev["forced_cuts"] + 1
+        assert 0.6 * dev["segments"] <= dev["strong_cuts"] \
+            <= 0.95 * dev["segments"]
     finally:
         client.close()
         srv.stop()
@@ -132,7 +142,7 @@ def _anchored_sidecar(region_bytes=16384):
     small = AnchoredCdcParams(
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),
-        seg_min=2048, seg_max=4096, seg_mask=2047)
+        seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
     srv = SidecarServer(port=0, fragmenter="fixed")   # placeholder
     srv.fragmenter = AnchoredCpuFragmenter(small, region_bytes=region_bytes)
     srv.start()

@@ -294,7 +294,8 @@ def test_spans_between_selects_by_the_shared_clock():
 
 PHASES = ("inputWaitS", "dispatchS", "collectS", "replyS")
 DEVICE_KEYS = {"platform", "device_kind", "count", "regions",
-               "overflow_redos", "deviceWaitS", "streams", "streamS",
+               "overflow_redos", "segments", "strong_cuts", "window_cuts",
+               "forced_cuts", "deviceWaitS", "streams", "streamS",
                "openS", "bytes", *PHASES}
 
 

@@ -1,0 +1,15 @@
+"""chunk store: seconds the nodes' write workers spent in a put job's
+link phase — holding the ordering mutex: ``os.link`` of the temp to
+the chunk's name, the index record, the resident set (and the
+book-keeping between two links)
+(``durability.put.linkS``, PR 38; ``ChunkStore.put_stats``), per GiB
+acked in the window, the nodes together. A phase's seconds include the
+thread's wait to take the interpreter lock back after its system call
+returned. Nothing on a program without the phase clock."""
+
+from program_totals import per_gib
+from put_phases import put_delta
+
+
+def read(w):
+    return per_gib(w, put_delta(w, "linkS"))

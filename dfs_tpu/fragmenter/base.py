@@ -85,6 +85,11 @@ class Fragmenter(abc.ABC):
             name=name, size=size, fragmenter=self.name,
             chunks=tuple(chunks))
 
+    # wall time of the first region a device engine ran, from the start
+    # of its dispatch (staging, tracing and compiling included) to its
+    # results on the host; None until then, and for a host engine
+    first_region_s: float | None = None
+
     def device_stats(self) -> dict | None:
         """What this engine computes on, for the chip owner's Health
         answer: ``{platform, device_kind, count}`` as JAX reports it,

@@ -388,6 +388,9 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         # region_collect counts them
         self._cuts = dict.fromkeys(_CUT_KEYS, 0)
         self._phases = _StreamPhases()
+        # the first region this engine ran, dispatch to collected: its
+        # outputs and when its dispatch began, until it is collected
+        self._first_region: tuple | None = None
         # warm the _touch jit once at construction (trace + a trivial
         # 1-element compile): the readiness probe's one-time cost must
         # never be billed to the first staging-bandwidth sample
@@ -410,6 +413,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         most once for the shorter tail window."""
         import jax
 
+        t_in = time.monotonic()
         end = min(n, base + self.region_bytes)
         lookback = np.zeros((8,), np.uint8)
         take = min(8, base)
@@ -450,6 +454,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         out = region_dispatch(words, end - base, start0, final,
                               self.params, lane_multiple=self.lane_multiple)
         with self._stats_lock:
+            if not self.regions_dispatched:
+                self._first_region = (out, t_in)
             self.regions_dispatched += 1
         return base, end, final, out, staged
 
@@ -498,6 +504,10 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         with self._stats_lock:
             for key, count in zip(_CUT_KEYS, cuts):
                 self._cuts[key] += count
+            if self._first_region and self._first_region[0] is out:
+                self.first_region_s = time.monotonic() \
+                    - self._first_region[1]
+                self._first_region = None
         self._pool_give(staged)
         for o, ln, dg in spans:
             off = base + o

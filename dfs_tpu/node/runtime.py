@@ -2431,12 +2431,15 @@ class StorageNodeServer:
         returned), ``dirBarriers`` the directory fsyncs that took: one
         per distinct directory of a batch, not one per file;
         ``resident*`` how often the store's resident set answered an
-        existence check in place of a ``stat`` (index off)."""
+        existence check in place of a ``stat`` (index off); ``put`` the
+        put job's phase clock (``ChunkStore.put_stats``): calls, items,
+        new files, and the write workers' seconds by phase."""
         return {"mode": self.cfg.durability.mode,
                 "fsyncs": self.store.chunks.fsync_count(),
                 "dirBarriers": self.store.chunks.dir_barrier_count(),
                 **self.store.chunks.resident_stats(),
-                **self.store.chunks.look_stats()}
+                **self.store.chunks.look_stats(),
+                "put": self.store.chunks.put_stats()}
 
     def chaos_stats(self) -> dict:
         """``/metrics`` ``chaos`` section: active knobs + per-kind

@@ -23,8 +23,10 @@ nothing, buy nothing). Methods (all under service ``dfs.Sidecar``):
 - ``Health``     unary-unary. Request: empty. Response: JSON status,
   including ``device`` — what the engine computes on, as JAX reports it,
   how many regions it has dispatched there and where its streams' wall
-  time went (null for host engines) — and ``spans``, the owner's span
-  totals. Counters only: it is polled.
+  time went (null for host engines) — ``spans``, the owner's span
+  totals, and ``compile``, what JAX spent tracing, lowering and
+  compiling in this process since it started (``_CompileClock``).
+  Counters only: it is polled.
 - ``Trace``      unary-unary. Request: JSON ``{"traceId"}`` or
   ``{"sinceMonoNs", "untilMonoNs"}``. Response: ``{"spans": [...]}``
   from the owner's span ring (dfs_tpu/obs): the owner is a node of the
@@ -44,6 +46,8 @@ process (NodeConfig.sidecar_port wires it into the node runtime).
 from __future__ import annotations
 
 import json
+import threading
+import time
 from concurrent import futures
 
 import grpc
@@ -67,12 +71,97 @@ def _identity(x: bytes) -> bytes:
     return x
 
 
+class _CompileClock:
+    """What JAX spent making programs in this process (``Health``
+    ``compile``): listeners on ``jax.monitoring``, registered before
+    the engine is built so that its first compile is in. ``traceS``,
+    ``lowerS`` and ``backendCompileS`` sum the three
+    ``/jax/core/compile/*_duration`` events (the last is a module's way
+    through the backend, a persistent-cache read included), ``modules``
+    counts the last; ``cacheRequests`` / ``cacheHits`` the modules that
+    asked the persistent compile cache and those it answered. An event
+    of those two families that this table does not know is kept under
+    its own name — seconds summed, occurrences counted — and not
+    dropped.
+
+    Events of one name NEST: a jit traced inside a jit reports its own
+    duration and the outer one reports both (the 16 MiB chain's plain
+    sum read 35 s of tracing inside a first region of 39 s that also
+    held 27 s of lowering and compiling; PERF.md §6, PR 38). A sum is
+    the seconds of the OUTERMOST events: what ended on this thread
+    inside an event's interval was counted already and is taken out
+    again."""
+
+    _DURATIONS = {
+        "/jax/core/compile/jaxpr_trace_duration": "traceS",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lowerS",
+        "/jax/core/compile/backend_compile_duration": "backendCompileS"}
+    _EVENTS = {
+        "/jax/compilation_cache/compile_requests_use_cache":
+            "cacheRequests",
+        "/jax/compilation_cache/cache_hits": "cacheHits"}
+    _KEPT = ("/jax/core/compile/", "/jax/compilation_cache/")
+    _PILE = 1 << 18     # events remembered per thread and name
+
+    def __init__(self) -> None:
+        from jax import monitoring
+
+        self._lock = threading.Lock()
+        # per (thread, name): (when it ended, seconds) of the events
+        # that no later event has enclosed yet
+        self._ended: dict[tuple, list] = {}
+        self._t: dict[str, float] = {
+            **dict.fromkeys(self._DURATIONS.values(), 0.0), "modules": 0,
+            **dict.fromkeys(self._EVENTS.values(), 0)}
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, secs: float, **_) -> None:
+        if not event.startswith(self._KEPT):
+            return
+        now = time.monotonic()      # an event reports as it ends
+        with self._lock:
+            ended = self._ended.setdefault(
+                (threading.get_ident(), event), [])
+            inside = 0.0
+            while ended and ended[-1][0] >= now - secs:
+                inside += ended.pop()[1]
+            ended.append((now, secs))
+            # one after the other they pile up until their parent ends:
+            # one kernel of the 16 MiB chain traces 31 369 ops, each an
+            # event of its own. Past the bound the oldest go, which are
+            # the likeliest to be enclosed by nothing any more.
+            del ended[:-self._PILE]
+            key = self._DURATIONS.get(event, event)
+            self._t[key] = self._t.get(key, 0.0) + secs - inside
+            if key == "backendCompileS":
+                self._t["modules"] += 1
+
+    def _event(self, event: str, **_) -> None:
+        if event.startswith(self._KEPT):
+            key = self._EVENTS.get(event, event)
+            with self._lock:
+                self._t[key] = self._t.get(key, 0) + 1
+
+    def close(self) -> None:
+        from jax import monitoring
+
+        monitoring.unregister_event_duration_listener(self._duration)
+        monitoring.unregister_event_listener(self._event)
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {k: round(v, 6) if isinstance(v, float) else v
+                    for k, v in self._t.items()}
+
+
 class SidecarServer:
     def __init__(self, port: int = 0, fragmenter: str = "auto",
                  cdc_params=None, max_workers: int = 4) -> None:
         from dfs_tpu.config import ObsConfig
         from dfs_tpu.fragmenter.base import get_fragmenter
 
+        self.compile_clock = _CompileClock()
         self.fragmenter = get_fragmenter(fragmenter, cdc_params=cdc_params)
         # ring and totals only (no journal, no sentinel): node id 0 is
         # the owner in a stitched tree. The engine opens its per-window
@@ -167,6 +256,10 @@ class SidecarServer:
                                "describe": desc,
                                "device": self.fragmenter.device_stats(),
                                "spans": self.obs.span_totals(),
+                               "compile": {
+                                   **self.compile_clock.snapshot(),
+                                   "firstRegionS":
+                                       self.fragmenter.first_region_s},
                                }).encode()
 
         def trace(request: bytes, ctx) -> bytes:
@@ -214,6 +307,7 @@ class SidecarServer:
 
     def stop(self, grace: float = 0.5) -> None:
         self._server.stop(grace)
+        self.compile_clock.close()
 
 
 class SidecarClient:

@@ -77,9 +77,12 @@ class AsyncChunkStore:
             max_workers=max(2, self._workers // 2),
             thread_name_prefix="cas-g")
         self._lock = threading.Lock()
-        self._ops = 0
-        self._queue_s = 0.0
-        self._busy_s = 0.0
+        # per lane, in the order w, r, g: [jobs ended, their seconds
+        # queued, their seconds on a worker]; stats() serves them, and
+        # their sums as the totals
+        self._lanes = {self._wpool: [0, 0.0, 0.0],
+                       self._rpool: [0, 0.0, 0.0],
+                       self._gpool: [0, 0.0, 0.0]}
         self._pending = 0   # submitted, not yet finished — the backlog
         # gauge the runtime sentinel samples (obs/sentinel.py): a value
         # persistently above the worker count means the disk tier is
@@ -90,6 +93,7 @@ class AsyncChunkStore:
         import asyncio
 
         t_submit = time.perf_counter()
+        lane = self._lanes[pool]
         with self._lock:
             self._pending += 1
 
@@ -100,20 +104,30 @@ class AsyncChunkStore:
             finally:
                 t_end = time.perf_counter()
                 with self._lock:
-                    self._ops += 1
                     self._pending -= 1
-                    self._queue_s += t_start - t_submit
-                    self._busy_s += t_end - t_start
+                    lane[0] += 1
+                    lane[1] += t_start - t_submit
+                    lane[2] += t_end - t_start
 
-        loop = asyncio.get_running_loop()
+        def recalled(cfut) -> None:
+            # a caller cancelled while the job still waited for a worker
+            # (an aborted upload's batch): the pool drops it unrun, its
+            # finally never runs, so the gauge is unwound here
+            if cfut.cancelled():
+                with self._lock:
+                    self._pending -= 1
+
         try:
-            fut = loop.run_in_executor(pool, job)
+            # run_in_executor, by hand: the pool's own future is needed
+            cfut = pool.submit(job)
         except BaseException:
             # submit failed (pool shut down): the job will never run its
             # finally, so the backlog gauge must be unwound here
             with self._lock:
                 self._pending -= 1
             raise
+        cfut.add_done_callback(recalled)
+        fut = asyncio.wrap_future(cfut)
         if self._obs is None or opname is None:
             return await fut
         with self._obs.span(opname):
@@ -248,11 +262,25 @@ class AsyncChunkStore:
             return self._pending
 
     def stats(self) -> dict:
+        """``/metrics`` ``ingest.cas``: jobs ended, their seconds queued
+        for a worker and their seconds on one — as totals, and by lane
+        under ``lanes``: ``w`` the write pool (puts), ``r`` the batch
+        reads (``get_many``, the census scan), ``g`` the 2-worker
+        latency lane (single gets and every ``has_many``: placement's
+        probes, the verify round, the repair cycle's). The totals are
+        the lanes' sums."""
         with self._lock:
-            return {"workers": self._workers, "ops": self._ops,
-                    "pending": self._pending,
-                    "queueS": round(self._queue_s, 6),
-                    "busyS": round(self._busy_s, 6)}
+            lanes = {name: {"ops": lane[0], "queueS": round(lane[1], 6),
+                            "busyS": round(lane[2], 6)}
+                     for name, lane in zip("wrg", self._lanes.values())}
+            pending = self._pending
+        total = {key: sum(lane[key] for lane in lanes.values())
+                 for key in ("ops", "queueS", "busyS")}
+        return {"workers": self._workers, "ops": total["ops"],
+                "pending": pending,
+                "queueS": round(total["queueS"], 6),
+                "busyS": round(total["busyS"], 6),
+                "lanes": lanes}
 
     def close(self) -> None:
         # wait=False: in-flight jobs finish on their worker threads, but

@@ -107,6 +107,14 @@ _LIST_MIN_NAMES = 4
 _LIST_ENTRIES_PER_STAT = 16
 _SHARD_DIRS = 256
 
+# The put job's phase clock (ChunkStore.put_stats, `/metrics`
+# `durability.put`): where a put call's seconds went on its calling
+# thread. Every instant of a call is in exactly one, so they add up to
+# `jobS`.
+_PUT_PHASES = ("precheckS", "settleS", "createS", "writeS",
+               "payloadFsyncS", "linkWaitS", "linkS", "dirBarrierS",
+               "unlinkS", "flushS")
+
 
 def _sweep_tmp_files(dirs, max_age_s: float = _TMP_SWEEP_AGE_S) -> int:
     """Unlink ``.tmp-*`` entries older than ``max_age_s`` in the given
@@ -185,6 +193,9 @@ class ChunkStore:
         # disk-looking batches (has_many without resident_ok): names
         # looked for by a stat / answered from a listing / listings made
         self._look_stats = self._look_listed = self._look_listings = 0
+        # the put job's phase clock: calls, items given, names linked,
+        # and the calling threads' seconds by phase (put_stats)
+        self._put = {"jobs": 0, "items": 0, **self._put_clock()}
         self._count_lock = threading.Lock()   # puts run on CAS pool workers
         # orders the visible link/unlink against its index record: a
         # put racing a delete of the SAME digest could otherwise
@@ -626,6 +637,13 @@ class ChunkStore:
         fresh_at: list[int] = []                   # their place in items
         queued: set[str] = set()
         hits = known = 0    # dedup hits by isfile; known to the index
+        # this call's phase clock: local floats, added to the store's
+        # table once, when the call returns. The pre-check loop is ONE
+        # pair of clock reads, less what _settle's barriers and the
+        # loop's own raw writes (similarity plane) took inside it.
+        ph = self._put_clock()
+        in_loop = 0.0
+        t_job = time.perf_counter()
         for i, (digest, data) in enumerate(items):
             if self.fault is not None:
                 self.fault("put", digest)
@@ -635,10 +653,10 @@ class ChunkStore:
             if self.index is None:
                 # resident, or one stat: a dedup hit either way
                 if self._raw_present(digest, p, True):
-                    self._settle(digest, p)
+                    self._settle(digest, p, ph)
                     continue
             elif os.path.isfile(p):
-                self._settle(digest, p)
+                self._settle(digest, p, ph)
                 hits += 1
                 if self.index.lookup(digest):
                     known += 1
@@ -673,44 +691,98 @@ class ChunkStore:
                     # no delta, or rolled back (base vanished
                     # mid-write): raw, and now — the next item may
                     # encode against this one
-                    stored = self._write_raw([(digest, p, data)])[0]
+                    t_raw = time.perf_counter()
+                    stored = self._write_raw([(digest, p, data)], ph)[0]
+                    in_loop += time.perf_counter() - t_raw
                 results[i] = stored
                 continue
             queued.add(digest)
             fresh.append((digest, p, data))
             fresh_at.append(i)
+        ph["precheckS"] = time.perf_counter() - t_job - in_loop \
+            - ph["settleS"]
         if hits:
             self.index.note_put_dedup(hits, known)
         if fresh:
-            for i, new in zip(fresh_at, self._write_raw(fresh)):
+            for i, new in zip(fresh_at, self._write_raw(fresh, ph)):
                 results[i] = new
+        ph["jobS"] = time.perf_counter() - t_job
+        self._note_put(ph, len(items))
         return results
 
-    def _settle(self, digest: str, p: str) -> None:
+    @staticmethod
+    def _put_clock() -> dict:
+        """One put call's phase clock, at zero."""
+        return {"newFiles": 0, "jobS": 0.0,
+                **dict.fromkeys(_PUT_PHASES, 0.0)}
+
+    def _note_put(self, ph: dict, items: int) -> None:
+        """A returned call's clock into the store's table: one lock
+        take a call, not a file."""
+        with self._count_lock:
+            put = self._put
+            put["jobs"] += 1
+            put["items"] += items
+            for key, v in ph.items():
+                put[key] += v
+
+    def put_stats(self) -> dict:
+        """``/metrics`` ``durability.put``, the put job's phase clock
+        (docs/observability.md "Unified metrics" has the table).
+        ``jobs`` counts the calls that returned — a placement batch is
+        up to four, one a write worker; a raw write from outside a
+        batch (re-materialisation) is one of its own — ``items`` what
+        they were given, ``newFiles`` the names they linked, ``jobS``
+        their wall time on the calling threads, and the ten
+        ``_PUT_PHASES`` add up to it. ``linkS`` holds the book-keeping
+        between two links too; the similarity plane's encode and delta
+        write have no phase of their own and count in ``precheckS``. A
+        phase's seconds include the thread's wait to take the
+        interpreter lock back after its system call returned."""
+        with self._count_lock:
+            return {k: round(v, 6) if isinstance(v, float) else v
+                    for k, v in self._put.items()}
+
+    def _settle(self, digest: str, p: str, ph: dict | None = None) -> None:
         """Before a dedup hit on ``p`` is answered: if the name was
         linked by a put whose directory barrier is still owed (the
         window between phases (c) and (d) of another thread's batch),
         issue that barrier here. No waiting on the other thread; the
-        fsync runs outside every lock."""
+        fsync runs outside every lock. A put's pre-check hands its
+        phase clock: the barrier's seconds are its ``settleS``."""
         if not self._fsync:
             return
         with self._count_lock:
             if digest not in self._unbarriered:
                 return
+        t0 = time.perf_counter()
         _fsync_path(os.path.dirname(p))
         with self._count_lock:
             self._dir_barriers += 1
             self._unbarriered.discard(digest)
+        if ph is not None:
+            ph["settleS"] += time.perf_counter() - t0
 
-    def _write_raw(self, batch) -> list[bool]:
+    def _write_raw(self, batch, ph: dict | None = None) -> list[bool]:
         """The raw-file write mechanics for ``(digest, path, data)``
         items, phases (b)–(e) of :meth:`put_batch` — shared by every
         put and by re-materialization, which must bypass the sim seam
-        (re-encoding what it just reconstructed would loop)."""
+        (re-encoding what it just reconstructed would loop).
+
+        Timed into ``ph``, the calling put's phase clock
+        (:meth:`put_stats`): a clock read at every switch of phase —
+        around the system calls of a file, never inside them — so the
+        phases add up to the call. Reached from outside a put, the call
+        is a job of its own."""
         new = [False] * len(batch)
         temps: list[str] = []
         parents: dict[str, None] = {}   # owed a barrier, in link order
         nlinked = nbytes = barriers = 0
+        own = ph is None
+        if own:
+            ph = self._put_clock()
+        clock = time.perf_counter
+        t_job = t = clock()
         try:
             for _, p, data in batch:
                 parent = os.path.dirname(p)
@@ -734,15 +806,23 @@ class ChunkStore:
                     except FileExistsError:
                         continue
                 temps.append(tmp)
+                t, t0 = clock(), t
+                ph["createS"] += t - t0
                 try:
                     view = memoryview(data)
                     done = os.write(fd, view)
                     while done < len(view):    # short write: disk nearly full
                         done += os.write(fd, view[done:])
                     if self._fsync:
+                        t, t0 = clock(), t
+                        ph["writeS"] += t - t0
                         os.fsync(fd)           # payload durable BEFORE the name
+                        t, t0 = clock(), t
+                        ph["payloadFsyncS"] += t - t0
                 finally:
                     os.close(fd)
+                    t, t0 = clock(), t
+                    ph["writeS"] += t - t0
             for k, (digest, p, data) in enumerate(batch):
                 if self._fsync:
                     # entered BEFORE the link: whoever sees the name finds
@@ -751,7 +831,11 @@ class ChunkStore:
                     with self._count_lock:
                         self._unbarriered.add(digest)
                     parents[os.path.dirname(p)] = None
+                t, t0 = clock(), t
+                ph["linkS"] += t - t0      # the book-keeping between links
                 with self._index_mu:
+                    t, t0 = clock(), t
+                    ph["linkWaitS"] += t - t0
                     try:
                         os.link(temps[k], p)
                     except FileExistsError:
@@ -787,6 +871,8 @@ class ChunkStore:
                 new[k] = True
                 nlinked += 1
                 nbytes += len(data)
+            t, t0 = clock(), t
+            ph["linkS"] += t - t0
             if self._fsync:
                 # a NAME is durable only once its directory block is:
                 # link/rename ordered the visible state, the dirfd fsync
@@ -799,6 +885,8 @@ class ChunkStore:
                     self._fsyncs += nlinked
                     self._unbarriered.difference_update(
                         d for d, _, _ in batch)
+                t, t0 = clock(), t
+                ph["dirBarrierS"] += t - t0
         finally:
             for tmp in temps:
                 try:
@@ -813,8 +901,15 @@ class ChunkStore:
                     self._count += nlinked
                 if self._bytes is not None:
                     self._bytes += nbytes
+        t, t0 = clock(), t
+        ph["unlinkS"] += t - t0
         if self.index is not None:
             self.index.maybe_flush()   # outside the ordering mutex
+            ph["flushS"] += clock() - t
+        ph["newFiles"] += nlinked
+        if own:
+            ph["jobS"] = clock() - t_job
+            self._note_put(ph, len(batch))
         return new
 
     def _put_delta(self, digest: str, base_digest: str, blob: bytes,

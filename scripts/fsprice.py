@@ -13,6 +13,8 @@ per directory size, the median ``os.path.isfile`` of a present and of an
 absent name, one ``os.scandir`` with the wanted names' ``is_file``, and
 how many ``stat``s that listing is worth — then the same beside eight
 threads that create, fsync and link files as the store's writers do.
+And what each call of a chunk file's put costs one thread (``price_put``,
+PR 38): the prices the put job's phase clock is read against.
 Jax-free, stdlib only; removes what it made.
 """
 
@@ -72,6 +74,53 @@ def measure(tag: str, dirs: dict[str, list[str]]) -> None:
               f"listing {max(list_s) * 1e3:.3f} ms", flush=True)
 
 
+def price_put(tag: str, root: str, n: int = 1500) -> None:
+    """What each call of a chunk file's put costs one thread — the
+    prices the put job's phase clock is held against (``/metrics``
+    ``durability.put``; PERF.md §5, PR 38): ``n`` files of 8 KiB over 64
+    directories, each created (``O_EXCL``), written, fsynced, closed,
+    linked to its name, its directory fsynced, its temp unlinked, as
+    ``ChunkStore._write_raw`` does a file — but for the directory
+    barrier, which a batch pays once a directory."""
+    dirs = [f"{root}/put-{tag.split()[0]}-{k:02x}" for k in range(64)]
+    for d in dirs:
+        os.makedirs(d)
+    s = dict.fromkeys(("create", "write", "payload fsync", "close",
+                       "link", "dir fsync", "unlink", "stat"), 0.0)
+    clock = time.perf_counter
+    payload = b"z" * 8192
+    for i in range(n):
+        d = dirs[i % len(dirs)]
+        tmp, p = f"{d}/.tmp-{i}", f"{d}/{i:064x}"
+        t0 = clock()
+        os.path.isfile(p)
+        t1 = clock()
+        fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_EXCL, 0o600)
+        t2 = clock()
+        os.write(fd, payload)
+        t3 = clock()
+        os.fsync(fd)
+        t4 = clock()
+        os.close(fd)
+        t5 = clock()
+        # a price list, not a store: nothing here is read back
+        # dfslint: ignore[DFS013]
+        os.link(tmp, p)
+        t6 = clock()
+        dfd = os.open(d, os.O_RDONLY)
+        os.fsync(dfd)
+        os.close(dfd)
+        t7 = clock()
+        os.unlink(tmp)
+        t8 = clock()
+        for key, dt in zip(s, (t2 - t1, t3 - t2, t4 - t3, t5 - t4,
+                               t6 - t5, t7 - t6, t8 - t7, t1 - t0)):
+            s[key] += dt
+    print(f"{tag} put of {n} files, ms a call: "
+          + ", ".join(f"{k} {v / n * 1e3:.4f}" for k, v in s.items())
+          + f"; a file {sum(s.values()) / n * 1e3:.3f}", flush=True)
+
+
 def main() -> None:
     root = tempfile.mkdtemp(prefix="fsprice_")
     try:
@@ -85,6 +134,7 @@ def main() -> None:
         print(f"made {sum(map(len, dirs.values()))} files in "
               f"{time.perf_counter() - t:.2f}s on {root}", flush=True)
         measure("idle", dirs)
+        price_put("idle", root)  # dfslint: ignore[DFS013] - as above
         stop = threading.Event()
         made = [0] * WRITERS
 
@@ -115,6 +165,7 @@ def main() -> None:
         time.sleep(0.5)
         measure(f"beside {WRITERS} writers", dirs)
         measure(f"beside {WRITERS} writers", dirs)
+        price_put(f"beside {WRITERS} writers", root)
         stop.set()
         for w in threads:
             w.join()

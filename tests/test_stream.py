@@ -4,6 +4,7 @@ plus the node-level streaming-ingest contracts (windowed placement
 equivalence, the abort path of a failed placement)."""
 
 import asyncio
+import time
 
 import numpy as np
 import pytest
@@ -155,15 +156,25 @@ def test_upload_stream_windowed_matches_serial(tmp_path, rng):
     assert s1 == s3            # per-batch stats merged deterministically
 
 
-def test_upload_stream_abort_stops_body_and_commits_nothing(tmp_path, rng):
+@pytest.mark.parametrize("slow_half", [False, True],
+                         ids=["store", "store-slow-in-half-its-dirs"])
+def test_upload_stream_abort_stops_body_and_commits_nothing(
+        tmp_path, rng, slow_half):
     """Placement failure mid-stream must abort: stop consuming the body
     (an endless client cannot be drained into memory), commit NO
     manifest, and leave the already-placed chunks as orphans that only
     the AGED GC reclaims (a young orphan may belong to an in-flight
-    upload)."""
+    upload). ``slow_half``: the pool jobs of a batch end far apart, as
+    on a loaded machine."""
     from dfs_tpu.node.runtime import StorageNodeServer, UploadError
 
     node = _stream_node(tmp_path, "abort", window=2, flush=32 * 1024)
+    if slow_half:
+        # a batch is cut by directory into up to four pool jobs: the
+        # jobs of the upper directories link their names ~0.2 s after
+        # the others have
+        node.store.chunks.fault = lambda op, digest: \
+            time.sleep(0.004) if digest[0] in "89abcdef" else None
     real_place = node.placement.place
     calls = {"n": 0}
 
@@ -195,17 +206,16 @@ def test_upload_stream_abort_stops_body_and_commits_nothing(tmp_path, rng):
     asyncio.run(run())
     assert consumed["blocks"] < cap        # reading STOPPED mid-body
     assert node.store.manifests.ids() == []   # no manifest committed
-    # an aborted batch's already-submitted CAS-pool job cannot be
-    # recalled mid-write — a few orphan puts may land moments after the
-    # abort returns; wait for the store to go quiet before snapshotting
-    import time as _time
-    orphans: list = []
-    for _ in range(100):
-        cur = sorted(node.store.chunks.digests())
-        if cur and cur == orphans:
-            break
-        orphans = cur
-        _time.sleep(0.05)
+    # an aborted batch's pool jobs that a worker had begun cannot be
+    # recalled mid-write — their orphan puts land moments after the
+    # abort returns, a job at a time: wait until the pool says none is
+    # left (names "equal twice, 50 ms apart" were seen between two jobs
+    # of one batch), then snapshot
+    deadline = time.monotonic() + 60.0
+    while node.cas.pending and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert node.cas.pending == 0
+    orphans = sorted(node.store.chunks.digests())
     assert orphans                         # batch 1 placed, then aborted
     # the aged sweep spares them (could be an in-flight upload's chunks)…
     assert node.store.gc(min_age_s=3600.0) == []

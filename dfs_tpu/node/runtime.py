@@ -932,12 +932,13 @@ class StorageNodeServer:
             # (this used to ride the unbounded to_thread executor); with
             # the index plane on, each answer is a memtable/run hit
             # instead of a stat syscall (docs/index.md). With it off the
-            # answer is a look at the disk, a stat a digest — unless
-            # the CALLER sets `residentOk` (placement's probes and
-            # pre-ack rounds only): then the store's resident set may
-            # answer (store/cas.py has). A caller that does not send
+            # answer is a look at the disk, a stat a digest. Either way,
+            # where the CALLER sets `residentOk` (placement's probes and
+            # pre-ack rounds only) the store's resident set answers
+            # first (store/cas.py has). A caller that does not send
             # the key — the repair cycle, who_has, an older peer — is
-            # answered from the disk, and that look heals the set.
+            # answered from the index or the disk, and a look at the
+            # disk heals the set.
             mask = await self.cas.has_many(
                 digests, resident_ok=bool(header.get("residentOk")))
             return {"ok": True,
@@ -2431,7 +2432,7 @@ class StorageNodeServer:
         returned), ``dirBarriers`` the directory fsyncs that took: one
         per distinct directory of a batch, not one per file;
         ``resident*`` how often the store's resident set answered an
-        existence check in place of a ``stat`` (index off); ``put`` the
+        existence check in place of a ``stat`` or an index lookup; ``put`` the
         put job's phase clock (``ChunkStore.put_stats``): calls, items,
         new files, and the write workers' seconds by phase."""
         return {"mode": self.cfg.durability.mode,
@@ -3275,6 +3276,11 @@ class StorageNodeServer:
             self.index.note_put(d)
         for d in phantom:
             self.index.note_delete(d)
+            # and a look at the disk: the store's resident set stands in
+            # front of the index for placement's callers, and the
+            # backstop's stat drops the entry (or re-records a name
+            # linked since the listing)
+            self.store.chunks.has(d)
         if missing or phantom:
             self.counters.inc("index_healed_missing", len(missing))
             self.counters.inc("index_healed_phantom", len(phantom))

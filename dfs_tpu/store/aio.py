@@ -149,14 +149,15 @@ class AsyncChunkStore:
         probe list. The ``has_chunks`` server path and the resume
         probe used to pay a per-digest job (or, worse, inline loop
         stats); a hot probe service must cost one worker dispatch per
-        LIST. Each ``has`` rides the index fast path when the dedup
-        plane is on (store/cas.py). With it off, a caller that says a
-        resident answer will do (``resident_ok``: placement's probes
-        and pre-ack rounds) is answered from the store's resident set —
+        LIST. A caller that says a resident answer will do
+        (``resident_ok``: placement's probes and pre-ack rounds) is
+        answered from the store's resident set, dedup plane on or off —
         microseconds a list once the store has linked or seen the
-        names — and everyone else by a ``stat`` a digest, which on a
-        busy file system measured 0.67 ms each (PERF.md §6, PR 28): a
-        repair slice of 2 048 digests is over a second of one worker.
+        names. Everyone else rides the index when the plane is on
+        (store/cas.py: ~0.15 ms a lookup) and with it off pays a
+        ``stat`` a digest, which on a busy file system measured 0.67 ms
+        each (PERF.md §6, PR 28): a repair slice of 2 048 digests is
+        over a second of one worker.
         On the LATENCY lane (``cas-g``), not the batch-read lane:
         peers time budget a probe like a metadata op, so it must never
         queue behind a multi-second ``get_many`` gather."""

@@ -80,7 +80,8 @@ _TMP_SWEEP_AGE_S = 3600.0
 
 # bound of ChunkStore's resident set: the source's deployment holds
 # ~870 000 digests a node (PERF.md §4) -> 2**20 entries, ~100 MB at the
-# worst; past it the set is emptied and refills from stats
+# worst; past it the set is emptied and refills from what stands
+# behind it (the index where the plane is attached, else stats)
 _RESIDENT_MAX = 1 << 20
 
 
@@ -179,16 +180,17 @@ class ChunkStore:
         # (_settle). Entered BEFORE the link, so a visible name is always
         # found here until a barrier covers it.
         self._unbarriered: set[str] = set()
-        # what is on the disk, remembered (index off only): raw 32-byte
-        # digests whose raw file this process linked, or found by a stat,
-        # and has not unlinked since. Positives only — an absent name is
-        # never cached. The put pre-check and has(resident_ok=True)
-        # answer from it in place of the stat; every other look at the
-        # disk heals it. Entered and discarded-before-the-unlink under
-        # _index_mu; dies with the process.
+        # what is on the disk, remembered — index plane on or off: raw
+        # 32-byte digests whose raw file this process linked, or found by
+        # a stat or (has(resident_ok=True), the put pre-check) by an index
+        # positive, and has not unlinked since. Positives only — an absent
+        # name is never cached. The put pre-check and has(resident_ok=True)
+        # answer from it in front of the index and of the stat; every look
+        # at the disk heals it. Entered and discarded-before-the-unlink
+        # under _index_mu; dies with the process.
         self._resident: set[bytes] = set()
         self._unlinks = 0                  # chunk unlinks ended
-        # resident answers / went on to a stat / entries found stale
+        # resident answers / went on (index, stat) / entries found stale
         self._res_hits = self._res_misses = self._res_drops = 0
         # disk-looking batches (has_many without resident_ok): names
         # looked for by a stat / answered from a listing / listings made
@@ -358,17 +360,17 @@ class ChunkStore:
             cur = base
         return False
 
-    # -- the resident set (index off) ----------------------------------
+    # -- the resident set (in front of the index and of the stat) ------
 
     def _remember(self, key: bytes, seen: int | None = None) -> None:
         """The raw name of ``key`` was just linked or seen. The caller
         holds ``_index_mu`` — the lock ``delete`` holds from its discard
         to the end of its unlink — so no entry outlives its file. A name
-        seen by a ``stat`` outside that lock passes ``seen``, the count
-        of unlinks when its look began: if one ended since, it may have
-        been this name's, and nothing is entered (the next look does)."""
-        if self.index is not None:
-            return
+        seen outside that lock (a ``stat``, a listing, an index positive)
+        passes ``seen``, the count of unlinks when its look began: if one
+        ended since, it may have been this name's, and nothing is entered
+        (the next look does). The same with the index plane attached:
+        the index records beside it, under the same lock."""
         with self._count_lock:
             if seen is not None and seen != self._unlinks:
                 return
@@ -383,33 +385,45 @@ class ChunkStore:
                 self._resident.remove(key)
                 self._res_drops += 1
 
-    def _raw_present(self, digest: str, p: str, resident_ok: bool) -> bool:
-        """Index off: is the raw file of ``digest`` there? With
-        ``resident_ok`` a resident entry is the answer and no ``stat``
-        is issued. Otherwise one ``stat``, which heals the set both
-        ways: "absent" drops the entry, "present" enters it."""
-        key = bytes.fromhex(digest)
+    def _look_begins(self, key: bytes, counted: bool) -> tuple[bool, int]:
+        """A look for the raw name of ``key`` begins: is it resident,
+        and the count of unlinks ended so far — :meth:`_remember`'s
+        ``seen`` for what the look finds outside ``_index_mu``.
+        ``counted``: the caller takes a resident answer (the put
+        pre-check, ``resident_ok``), so this is a hit or a miss of
+        :meth:`resident_stats`."""
         with self._count_lock:
-            seen = self._unlinks
             known = key in self._resident
-            if resident_ok:
+            if counted:
                 if known:
                     self._res_hits += 1
-                    return True
-                self._res_misses += 1
+                else:
+                    self._res_misses += 1
+            return known, self._unlinks
+
+    def _saw(self, key: bytes, seen: int) -> None:
+        """A look outside ``_index_mu`` found the raw name — a ``stat``,
+        an index positive: entered unless an unlink ended since the
+        look began."""
+        with self._index_mu:
+            self._remember(key, seen)
+
+    def _stat_raw(self, key: bytes, p: str, known: bool, seen: int) -> bool:
+        """One ``stat`` of the raw file, which heals the set both ways:
+        "absent" drops the entry, "present" enters it."""
         if not os.path.isfile(p):
             if known:
                 self._forget(key)
             return False
         if not known:
-            with self._index_mu:
-                self._remember(key, seen)
+            self._saw(key, seen)
         return True
 
     def resident_stats(self) -> dict:
         """``/metrics`` ``durability.resident*``: existence checks the
-        resident set answered, those that went on to a ``stat``, entries
-        held now, entries dropped because the disk disagreed."""
+        resident set answered, those that went on to the index or to a
+        ``stat``, entries held now, entries dropped because the disk
+        disagreed. Counted with the index plane on as with it off."""
         with self._count_lock:
             return {"residentHits": self._res_hits,
                     "residentMisses": self._res_misses,
@@ -417,18 +431,22 @@ class ChunkStore:
                     "residentDrops": self._res_drops}
 
     def has(self, digest: str, resident_ok: bool = False) -> bool:
-        """Local existence. With the index plane off the answer is a look
-        at the disk — unless the caller says a resident answer will do
-        (``resident_ok``: placement's probes and pre-ack rounds), and the
-        raw name is in the resident set: this process linked or saw it
-        and has not begun to unlink it since (``delete`` discards before
-        the unlink, under the mutex that orders both). The argument is
-        the index's below, minus persistence — so minus its crash cases;
-        the one caveat is the same external directory mutation, and a
-        look without ``resident_ok`` (the repair cycle's, every cycle)
-        drops what the disk no longer has.
+        """Local existence, asked in the order memory → index → disk.
 
-        With the index plane attached, a positive
+        **Memory**, only where the caller says a resident answer will do
+        (``resident_ok``: placement's probes and pre-ack rounds): the raw
+        name is in the resident set — this process linked or saw it and
+        has not begun to unlink it since (``delete`` discards before the
+        unlink, under the mutex that orders both). The argument is the
+        index's below, minus persistence — so minus its crash cases; the
+        one caveat is the same external directory mutation, and a look
+        at the disk (index off: the repair cycle's, every cycle; index
+        on: the backstop below, once scrub has expunged the phantom)
+        drops what the disk no longer has. The set stands in front of
+        the index as in front of the ``stat``: what either finds for such
+        a caller is entered, under :meth:`_remember`'s rule.
+
+        **Index**, where the plane is attached: a positive
         index answer is final — puts are recorded only AFTER the link
         is visible and deletes BEFORE the unlink (see ``put`` /
         ``delete``), so "present" in the index implies the file was
@@ -443,24 +461,45 @@ class ChunkStore:
         digest (under the same ordering mutex a racing delete takes),
         so a crash-lost record costs one stat, not one per probe
         forever — and the first post-restart repair probe sweep
-        re-indexes everything it touches."""
+        re-indexes everything it touches. Without ``resident_ok`` (the
+        repair cycle, ``who_has``, relocation, the smart client) this
+        is the whole of the path, the set not consulted.
+
+        **Disk**, with the plane off (one ``stat``; a batch:
+        :meth:`has_many`)."""
         p = self._path_str(digest)
-        if self.index is None:
-            present = self._raw_present(digest, p, resident_ok) \
+        key = bytes.fromhex(digest)
+        index = self.index
+        known = False
+        if resident_ok or index is None:
+            known, seen = self._look_begins(key, resident_ok)
+        if known and resident_ok:
+            present = True
+        elif index is None:
+            present = self._stat_raw(key, p, known, seen) \
                 or (self._deltas_possible()
                     and self._chain_resolves(digest))
-        elif self.index.lookup(digest):
+        elif index.lookup(digest):
             present = True
+            # raw names only: an index positive may be a delta-stored
+            # chunk once the delta tree exists (the flag never falls)
+            if resident_ok and not self._deltas_possible():
+                self._saw(key, seen)
         else:
             with self._index_mu:
-                present = os.path.isfile(p) \
+                raw = os.path.isfile(p)
+                present = raw \
                     or (self._deltas_possible()
                         and self._chain_resolves(digest))
                 if present:
-                    self.index.note_put(digest, defer_flush=True)
-            self.index.note_stat_fallback(present)
+                    index.note_put(digest, defer_flush=True)
+                if raw:
+                    self._remember(key)    # seen under the mutex
+            if not raw:
+                self._forget(key)
+            index.note_stat_fallback(present)
             if present:
-                self.index.maybe_flush()   # outside the ordering mutex
+                index.maybe_flush()        # outside the ordering mutex
         if present:
             # "present" is what a coordinator counts as a copy before it
             # acks: a name still owed its directory barrier gets it first
@@ -636,7 +675,8 @@ class ChunkStore:
         fresh: list[tuple[str, str, bytes]] = []   # raw writes owed
         fresh_at: list[int] = []                   # their place in items
         queued: set[str] = set()
-        hits = known = 0    # dedup hits by isfile; known to the index
+        index = self.index
+        hits = known = 0    # dedup hits on a raw name; known to the index
         # this call's phase clock: local floats, added to the store's
         # table once, when the call returns. The pre-check loop is ONE
         # pair of clock reads, less what _settle's barriers and the
@@ -650,27 +690,32 @@ class ChunkStore:
             p = self._path_str(digest)
             if digest in queued:
                 continue           # twice in one batch: written once
-            if self.index is None:
-                # resident, or one stat: a dedup hit either way
-                if self._raw_present(digest, p, True):
-                    self._settle(digest, p, ph)
-                    continue
-            elif os.path.isfile(p):
+            # resident → a dedup hit, plane on or off; else the look of
+            # the mode: one stat (index off), isfile then the index
+            key = bytes.fromhex(digest)
+            resident, seen = self._look_begins(key, True)
+            if resident or (self._stat_raw(key, p, False, seen)
+                            if index is None else os.path.isfile(p)):
                 self._settle(digest, p, ph)
                 hits += 1
-                if self.index.lookup(digest):
-                    known += 1
-                else:
-                    # dedup hit on a chunk the index forgot (crash-lost
-                    # WAL buffer): heal here too — a repair push
-                    # re-sending a restarted node its own chunks is
-                    # exactly how that node's catalog re-enters the
-                    # index (same ordering mutex discipline as has()'s
-                    # backstop)
-                    with self._index_mu:
-                        if os.path.isfile(p):
-                            self.index.note_put(digest, defer_flush=True)
-                    self.index.maybe_flush()
+                if resident:
+                    known += 1     # linked or seen this life: recorded
+                elif index is not None:
+                    if index.lookup(digest):
+                        known += 1
+                        self._saw(key, seen)
+                    else:
+                        # dedup hit on a chunk the index forgot
+                        # (crash-lost WAL buffer): heal here too — a
+                        # repair push re-sending a restarted node its
+                        # own chunks is exactly how that node's catalog
+                        # re-enters the index (same ordering mutex
+                        # discipline as has()'s backstop)
+                        with self._index_mu:
+                            if os.path.isfile(p):
+                                index.note_put(digest, defer_flush=True)
+                                self._remember(key)
+                        index.maybe_flush()
                 continue
             if self._deltas_possible():
                 with self._delta_mu:
@@ -701,8 +746,8 @@ class ChunkStore:
             fresh_at.append(i)
         ph["precheckS"] = time.perf_counter() - t_job - in_loop \
             - ph["settleS"]
-        if hits:
-            self.index.note_put_dedup(hits, known)
+        if hits and index is not None:
+            index.note_put_dedup(hits, known)
         if fresh:
             for i, new in zip(fresh_at, self._write_raw(fresh, ph)):
                 results[i] = new

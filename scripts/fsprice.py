@@ -14,16 +14,22 @@ absent name, one ``os.scandir`` with the wanted names' ``is_file``, and
 how many ``stat``s that listing is worth — then the same beside eight
 threads that create, fsync and link files as the store's writers do.
 And what each call of a chunk file's put costs one thread (``price_put``,
-PR 38): the prices the put job's phase clock is read against.
+PR 38): the prices the put job's phase clock is read against. And how
+links scale (``price_links``, PR 39; alone: ``fsprice.py links``): P
+processes of four writer threads, with a lock a process around the link
+and with none — whether the chunk store's one mutex or the file system
+below it serialises the links of several nodes.
 Jax-free, stdlib only; removes what it made.
 """
 
 from __future__ import annotations
 
+import multiprocessing
 import os
 import random
 import shutil
 import statistics
+import sys
 import tempfile
 import threading
 import time
@@ -121,9 +127,97 @@ def price_put(tag: str, root: str, n: int = 1500) -> None:
           + f"; a file {sum(s.values()) / n * 1e3:.3f}", flush=True)
 
 
+def _link_process(root: str, locked: bool, threads: int, seconds: float,
+                  start, out) -> None:
+    """One node's write workers: ``threads`` threads that each make a
+    file as ``ChunkStore._write_raw`` does (create ``O_EXCL``, write
+    8 KiB, fsync, close), link it to its name — under this process's
+    one lock when ``locked`` — and unlink the temp."""
+    dirs = [f"{root}/{k:02x}" for k in range(64)]
+    for d in dirs:
+        os.makedirs(d)
+    mu = threading.Lock()
+    tally = [[0, 0.0, 0.0] for _ in range(threads)]  # links, wait s, link s
+    payload = b"z" * 8192
+    clock = time.perf_counter
+
+    def worker(k: int) -> None:
+        mine = tally[k]
+        start.wait()
+        end = clock() + seconds
+        i = 0
+        while clock() < end:
+            d = dirs[(i * threads + k) % len(dirs)]
+            tmp, p = f"{d}/.tmp-{k}-{i}", f"{d}/{k:02x}{i:062x}"
+            fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_EXCL, 0o600)
+            os.write(fd, payload)
+            os.fsync(fd)
+            os.close(fd)
+            t0 = clock()
+            if locked:
+                mu.acquire()
+            t1 = clock()
+            # a price list, not a store: nothing here is read back
+            # dfslint: ignore[DFS013]
+            os.link(tmp, p)
+            t2 = clock()
+            if locked:
+                mu.release()
+            os.unlink(tmp)
+            mine[0] += 1
+            mine[1] += t1 - t0
+            mine[2] += t2 - t1
+            i += 1
+
+    ws = [threading.Thread(target=worker, args=(k,)) for k in range(threads)]
+    for w in ws:
+        w.start()
+    for w in ws:
+        w.join()
+    out.put([sum(col) for col in zip(*tally)])
+
+
+def price_links(root: str, procs=(1, 3, 5), threads: int = 4,
+                seconds: float = 4.0) -> None:
+    """Do links run side by side? ``P`` processes (a node each) of
+    ``threads`` write workers make files for ``seconds``; once every
+    process holds ONE lock around its link (``ChunkStore._index_mu``),
+    once none. If the lock is the ceiling, "no lock" makes more files a
+    second and a link stays near its idle price; if the file system
+    serialises links machine-wide, the files a second are the same and
+    the wait moves from the lock into the link, whose time then grows
+    with ``P`` in both columns (PERF.md §7, PR 39)."""
+    ctx = multiprocessing.get_context("spawn")
+    for p in procs:
+        for locked in (True, False):
+            base = f"{root}/links-{p}-{int(locked)}"
+            start, out = ctx.Barrier(p * threads), ctx.Queue()
+            children = [ctx.Process(
+                target=_link_process,
+                args=(f"{base}/n{k}", locked, threads, seconds, start, out))
+                for k in range(p)]
+            for c in children:
+                c.start()
+            links, wait_s, link_s = (
+                sum(col) for col in zip(*(out.get() for _ in children)))
+            for c in children:
+                c.join()
+            print(f"links P={p} x {threads} threads, "
+                  f"{'a lock a process' if locked else 'no lock'}: "
+                  f"{links / seconds:.0f} links/s, a link "
+                  f"{link_s / links * 1e3:.3f} ms, waited for the lock "
+                  f"{wait_s / links * 1e3:.3f} ms, the rest of a file "
+                  f"{(p * threads * seconds - wait_s - link_s) / links * 1e3:.3f}"
+                  f" ms", flush=True)
+            shutil.rmtree(base, ignore_errors=True)
+
+
 def main() -> None:
     root = tempfile.mkdtemp(prefix="fsprice_")
     try:
+        if sys.argv[1:] == ["links"]:
+            price_links(root)
+            return
         t = time.perf_counter()
         dirs: dict[str, list[str]] = {}
         for size in SIZES:
@@ -171,6 +265,7 @@ def main() -> None:
             w.join()
         print(f"the writers made {sum(made)} files in "
               f"{time.perf_counter() - t:.2f}s")
+        price_links(root)
     finally:
         shutil.rmtree(root, ignore_errors=True)
 

@@ -449,17 +449,29 @@ def _chunk_stats(monkeypatch, nodes):
     return seen
 
 
+@pytest.mark.parametrize("index", [False, True],
+                         ids=["index-off", "index-on"])
 def test_reupload_through_another_coordinator_stats_no_known_chunk(
-        tmp_path, rng, monkeypatch):
+        tmp_path, rng, monkeypatch, index):
     """What every node holds it linked itself: a re-upload through
     another coordinator — its own pre-check, both peers' has_chunks —
     issues no ``stat`` for a chunk name on any node, where the first
-    upload paid one a digest a holder; /metrics says so."""
+    upload paid one a digest a holder; /metrics says so. With the index
+    plane on every node (since PR 39) the same, and no index lookup
+    either: the resident set stands in front of the index."""
+    from dfs_tpu.config import IndexConfig
     data = rng.integers(0, 256, size=300_000, dtype=np.uint8).tobytes()
 
     async def run():
         cluster = _cluster_cfg(3, rf=2)
-        nodes = await _start(cluster, tmp_path)
+        nodes = await _start(
+            cluster, tmp_path,
+            **({"index": IndexConfig(enabled=True)} if index else {}))
+
+        def lookups():
+            return sum(n.index.lsi.stats()["lookups"]
+                       for n in nodes.values()) if index else 0
+
         try:
             seen = _chunk_stats(monkeypatch, nodes)
             manifest, _ = await nodes[1].upload(data, "first.bin")
@@ -472,10 +484,14 @@ def test_reupload_through_another_coordinator_stats_no_known_chunk(
             held = {nid: set(n.store.chunks.digests())
                     for nid, n in nodes.items()}
             seen.clear()
+            asked = lookups()
             again, stats = await nodes[2].upload(data, "first.bin")
             assert again.file_id == manifest.file_id
-            assert seen == []
-            assert stats["transferredBytes"] == 0
+            assert seen == [] and lookups() == asked
+            # (plane on, before the first filter gossip: a peer's filter
+            # rules the chunks out and they are sent; the peer's put
+            # pre-check is then what finds them — in the set)
+            assert index or stats["transferredBytes"] == 0
             for nid, n in nodes.items():
                 dur = n.durability_stats()
                 assert dur["residentEntries"] == len(held[nid]) > 0
@@ -573,6 +589,48 @@ def test_repair_restores_a_file_removed_behind_a_store_that_remembers_it(
             assert ch.resident_stats()["residentDrops"] == 1
             assert sorted(ch.digests()) == digests
             assert await nodes[1].repair_once() == 0
+        finally:
+            for n in nodes.values():
+                await n.stop()
+
+    asyncio.run(run())
+
+
+def test_index_on_scrub_drops_what_was_removed_behind_a_store_that_remembers(
+        tmp_path, rng):
+    """The one caveat with the index plane on: a chunk file unlinked
+    behind the store is "present" to the index AND to the resident set
+    in front of it. Scrub, which expunges the index's phantom, drops the
+    resident entry with it — a caller that takes a resident answer hears
+    "absent" from then on — and one repair cycle puts the file back."""
+    from dfs_tpu.config import IndexConfig
+    data = rng.integers(0, 256, size=200_000, dtype=np.uint8).tobytes()
+
+    async def run():
+        cluster = _cluster_cfg(2, rf=2)
+        nodes = await _start(cluster, tmp_path,
+                             index=IndexConfig(enabled=True))
+        try:
+            manifest, _ = await nodes[1].upload(data, "s.bin")
+            digests = sorted({c.digest for c in manifest.chunks})
+            victim = digests[len(digests) // 3]
+            ch = nodes[2].store.chunks
+            payload = ch.get(victim)
+            assert ch.resident_stats()["residentEntries"] == len(digests)
+            os.unlink(ch._path_str(victim))             # behind its back
+            assert ch.has(victim, resident_ok=True)     # the caveat,
+            assert ch.has(victim)                       # the index's too
+            out = await nodes[2].scrub_once()
+            assert out["healedPhantom"] == 1
+            assert not ch.has(victim) \
+                and not ch.has(victim, resident_ok=True)
+            assert ch.resident_stats()["residentDrops"] == 1
+            assert ch.resident_stats()["residentEntries"] \
+                == len(digests) - 1
+            assert await nodes[1].repair_once() == 1
+            assert ch.get(victim) == payload
+            assert ch.has(victim, resident_ok=True) and ch.has(victim)
+            assert (await nodes[2].scrub_once())["healedPhantom"] == 0
         finally:
             for n in nodes.values():
                 await n.stop()

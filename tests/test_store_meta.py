@@ -639,12 +639,30 @@ def test_barrier_dirs_covers_what_a_dead_life_left(tmp_path, monkeypatch,
 
 
 # ---------------------------------------------------------------------- #
-# the resident set: what is on the disk, remembered (PR 28) — index off;
-# with the index plane attached, the parent's calls to the letter
+# the resident set: what is on the disk, remembered (PR 28) — since PR 39
+# with the index plane attached too, in front of the index
 # ---------------------------------------------------------------------- #
 
 def _key(d):
     return bytes.fromhex(d)
+
+
+def _plane(root, cs):
+    """An index plane over ``cs``'s chunks, attached to it."""
+    from dfs_tpu.config import IndexConfig
+    from dfs_tpu.index import IndexPlane
+    plane = IndexPlane(IndexConfig(enabled=True), root)
+    plane.open_or_rebuild(cs.digests)
+    cs.index = plane
+    return plane
+
+
+def _lookups(plane):
+    return plane.lsi.stats()["lookups"]
+
+
+_INDEX = pytest.mark.parametrize("index", [False, True],
+                                 ids=["index-off", "index-on"])
 
 
 def _stale(cs):
@@ -789,18 +807,21 @@ def test_a_stat_positive_racing_a_delete_enters_nothing(
 
 
 @pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
 def test_deletes_racing_looks_from_many_threads_leave_no_stale_entry(
-        tmp_path, fsync):
+        tmp_path, fsync, index):
     """More threads than cores at a 10 µs switch interval: lookers enter
-    names from stats while deleters unlink and writers put them back —
-    an entry never outlives its file, and every answer that says
-    "present" from the set is backed by the disk at the end."""
+    names from stats — with the index plane attached, from index
+    positives — while deleters unlink and writers put them back: an
+    entry never outlives its file, and every answer that says "present"
+    from the set is backed by the disk at the end."""
     import os
     import sys
     import threading
     pool = _batch(96, seed=7, size=32)
     ChunkStore(tmp_path / "chunks", fsync=fsync).put_batch(pool)
     cs = ChunkStore(tmp_path / "chunks", fsync=fsync)   # learns by looking
+    plane = _plane(tmp_path / "plane", cs) if index else None
     digests = [d for d, _ in pool]
     n_threads = 2 * (os.cpu_count() or 4)
     errors = []
@@ -847,26 +868,24 @@ def test_deletes_racing_looks_from_many_threads_leave_no_stale_entry(
     assert cs.has_many(digests, resident_ok=True) \
         == [d in on_disk for d in digests]
     assert cs._unbarriered == set()
+    if plane is not None:
+        assert cs.has_many(digests) == [d in on_disk for d in digests]
+        plane.close()
 
 
 @pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
-@pytest.mark.parametrize("index", [False, True], ids=["index-off", "index-on"])
-def test_known_names_cost_no_stat_index_off_and_the_parents_calls_index_on(
+@_INDEX
+def test_known_names_cost_no_stat_index_off_and_no_lookup_index_on(
         tmp_path, monkeypatch, fsync, index):
-    """A leg's list of 1 700 digests the store already holds: with the
-    index off the second ``has_many`` and a ``put_batch`` of 1 700 dedup
-    hits issue no file-system call at all; with the index plane attached
-    the calls are the parent's — no stat for ``has`` (the index
-    answers), one ``isfile`` a digest for the put pre-check — and the
-    set stays empty."""
+    """A leg's list of 1 700 digests the store already holds: the second
+    ``has_many`` and a ``put_batch`` of 1 700 dedup hits issue no
+    file-system call at all — and, with the index plane attached, no
+    ``index.lookup`` either: the store that linked the names never asks,
+    a store of another life pays one lookup a name once (index off: one
+    ``stat``) and none after. Without the caller's leave the path is the
+    parent's in both modes."""
     cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
-    plane = None
-    if index:
-        from dfs_tpu.config import IndexConfig
-        from dfs_tpu.index import IndexPlane
-        plane = IndexPlane(IndexConfig(enabled=True), tmp_path / "plane")
-        plane.open_or_rebuild(cs.digests)
-        cs.index = plane
+    plane = _plane(tmp_path / "plane", cs) if index else None
     items = _batch(1700, seed=8)
     digests = [d for d, _ in items]
     assert all(cs.put_batch(items))
@@ -874,39 +893,48 @@ def test_known_names_cost_no_stat_index_off_and_the_parents_calls_index_on(
     life.index = plane
     calls = _Calls(monkeypatch, cs.root)
     finals = [("stat", life._path_str(d)) for d in digests]
+    asked = [0]
+
+    def looked_up():
+        n = _lookups(plane) if index else 0
+        asked[0], d = n, n - asked[0]
+        return d
+
     try:
         for store in (cs, life):
             calls.events.clear()
+            looked_up()
             assert store.has_many(digests, resident_ok=True) \
                 == [True] * 1700                    # the first list
             first = list(calls.events)
+            first_lookups = looked_up()
             calls.events.clear()
             assert store.has_many(digests, resident_ok=True) \
                 == [True] * 1700                    # the second
-            assert calls.events == []
             assert store.put_batch(items) == [False] * 1700
+            assert calls.events == [] and looked_up() == 0
+            assert len(store._resident) == 1700
             if index:
-                assert first == []                  # index positives
-                assert calls.events == finals       # the parent's isfile
-                assert store._resident == set()
-                assert store.resident_stats()["residentHits"] == 0
+                assert first == []      # linked here, or index positives
+                assert first_lookups == (0 if store is cs else 1700)
             else:
                 assert first == ([] if store is cs else finals)
-                assert calls.events == []
-                assert len(store._resident) == 1700
-            # without the caller's leave: a look at the disk (index off)
-            # for every name — since PR 35 by a listing of its directory
-            # where the batch asks four names of it or more (nearly all
-            # of a store asked whole), a stat a name in the others
+            # without the caller's leave: index on, the parent's lookup a
+            # name and no set; index off, a look at the disk for every
+            # name — since PR 35 by a listing of its directory where the
+            # batch asks four names of it or more (nearly all of a store
+            # asked whole), a stat a name in the others
             calls.events.clear()
             before = store.look_stats()
+            set_before = store.resident_stats()
             assert store.has_many(digests) == [True] * 1700
             looked = {k: v - before[k]
                       for k, v in store.look_stats().items()}
             if index:
-                assert calls.events == []
+                assert calls.events == [] and looked_up() == 1700
                 assert looked == {"lookStats": 0, "lookListed": 0,
                                   "lookListings": 0}
+                assert store.resident_stats() == set_before
             else:
                 few = {d[:2] for d in digests
                        if sum(e[:2] == d[:2] for e in digests) < 4}
@@ -917,22 +945,223 @@ def test_known_names_cost_no_stat_index_off_and_the_parents_calls_index_on(
                     == sum(d[:2] in few for d in digests) < 170
                 assert looked["lookListings"] \
                     == len({d[:2] for d in digests} - few)
-        if not index:
-            assert life.resident_stats() == {
-                "residentHits": 2 * 1700, "residentMisses": 1700,
-                "residentEntries": 1700, "residentDrops": 0}
+        assert life.resident_stats() == {
+            "residentHits": 2 * 1700, "residentMisses": 1700,
+            "residentEntries": 1700, "residentDrops": 0}
+        assert cs.resident_stats() == {     # its own 1 700 fresh names
+            "residentHits": 3 * 1700, "residentMisses": 1700,
+            "residentEntries": 1700, "residentDrops": 0}
+        if index:
+            # a resident dedup hit is a hit the index knows
+            seam = plane.stats()
+            assert seam["putDedupHits"] == seam["putDedupIndexKnown"] \
+                == 2 * 1700
+            assert seam["statFallbacks"] == 0
     finally:
         if plane is not None:
             plane.close()
 
 
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("when", ["after_the_lookup", "inside_the_delete",
+                                  "a_stat_past_the_discard"])
+def test_an_index_positive_racing_a_delete_enters_nothing(
+        tmp_path, monkeypatch, fsync, when):
+    """The plane attached: the index said "present"; the unlink ended
+    before the look could enter the name. Nothing is entered — whether
+    the whole delete fell between the lookup and the entry, or the
+    lookup fell inside the delete before its record and its discard and
+    the entry waited for the mutex; nor by the put pre-check whose
+    ``stat`` saw the name past the discard (the index says no by then,
+    the heal looks again under the mutex)."""
+    import os
+    import threading
+    first = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    plane = _plane(tmp_path / "plane", first)
+    (d, data), = _batch(1, seed=13)
+    assert first.put(d, data)
+    cs = ChunkStore(first.root, fsync=fsync)    # knows nothing yet
+    cs.index = plane
+    final = cs._path_str(d)
+    real_lookup = plane.lookup
+    try:
+        if when == "after_the_lookup":
+            once = []
+
+            def lookup(digest):
+                out = real_lookup(digest)
+                if digest == d and not once:
+                    once.append(out)
+                    assert cs.delete(d) is True     # between lookup and entry
+                return out
+            monkeypatch.setattr(plane, "lookup", lookup)
+            assert cs.has(d, resident_ok=True) is True  # true when it looked
+            assert once == [True]
+        else:
+            answered = threading.Event()
+            go = threading.Event()
+            real_stat, real_unlink = os.stat, os.unlink
+            if when == "inside_the_delete":
+                answers = []
+                looker = threading.Thread(target=lambda: (
+                    go.wait(10), answers.append(cs.has(d, resident_ok=True))))
+
+                def lookup(digest):
+                    out = real_lookup(digest)
+                    if threading.current_thread() is looker:
+                        answers.append(out)
+                        answered.set()
+                    return out
+                monkeypatch.setattr(plane, "lookup", lookup)
+
+                def stat(path, *a, **kw):
+                    out = real_stat(path, *a, **kw)
+                    if str(path) == final and cs._index_mu.locked() \
+                            and threading.current_thread() is not looker \
+                            and not go.is_set():
+                        go.set()                # delete's getsize: inside
+                        assert answered.wait(10)    # its mutex, before its
+                    return out                  # record and its discard
+                monkeypatch.setattr(os, "stat", stat)
+            else:
+                answers = []
+                looker = threading.Thread(target=lambda: (
+                    go.wait(10), answers.append(cs.put(d, data))))
+
+                def stat(path, *a, **kw):
+                    out = real_stat(path, *a, **kw)
+                    if str(path) == final and not answered.is_set() \
+                            and threading.current_thread() is looker:
+                        answered.set()          # the pre-check's isfile
+                    return out
+
+                def unlink(path, *a, **kw):
+                    if str(path) == final:      # inside delete's mutex,
+                        go.set()                # past record and discard
+                        assert answered.wait(10)
+                    return real_unlink(path, *a, **kw)
+                monkeypatch.setattr(os, "stat", stat)
+                monkeypatch.setattr(os, "unlink", unlink)
+            looker.start()
+            assert cs.delete(d) is True
+            looker.join(10)
+            assert not looker.is_alive()
+            # an index positive and the answer of the moment it looked;
+            # the pre-check's stat-positive is the parent's dedup hit
+            assert answers == ([True, True] if when == "inside_the_delete"
+                               else [False])
+        monkeypatch.undo()
+        assert cs._resident == set() and cs._unlinks == 1
+        assert cs.has(d, resident_ok=True) is False
+        assert cs.has(d) is False
+        assert cs.put(d, data) is True              # the pre-check missed
+        assert cs._resident == {_key(d)} and cs.get(d) == data
+    finally:
+        plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_has_without_leave_index_on_issues_the_parents_calls(
+        tmp_path, monkeypatch, fsync):
+    """``has`` without ``resident_ok`` — the repair cycle, ``who_has``,
+    relocation, the smart client — with the plane attached: one lookup;
+    a ``stat`` only behind a negative (the backstop, which re-records a
+    name the index forgot). The resident set is not consulted, and is
+    healed only by what the backstop's ``stat`` says."""
+    import os
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    (held, dh), (forgot, df), (absent, _), (gone, dg) = _batch(4, seed=14)
+    plain = ChunkStore(cs.root, fsync=fsync)    # no plane: the index never
+    assert plain.put(forgot, df)                # hears of this one
+    plane = _plane(tmp_path / "plane", ChunkStore(tmp_path / "empty"))
+    cs.index = plane
+    assert cs.put(held, dh) and cs.put(gone, dg)
+    os.unlink(cs._path_str(gone))               # behind its back, and
+    plane.note_delete(gone)                     # scrub expunged the phantom
+    calls = _Calls(monkeypatch, cs.root)
+    order = []
+    real_lookup = plane.lookup
+
+    def lookup(digest):
+        order.append(("lookup", digest))
+        return real_lookup(digest)
+    monkeypatch.setattr(plane, "lookup", lookup)
+    calls.on_call = lambda call, path: order.append((call, path))
+    try:
+        before = cs.resident_stats()
+        assert cs._resident == {_key(held), _key(gone)}
+        assert cs.has_many([held, forgot, absent, gone, forgot, held]) \
+            == [True, True, False, False, True, True]
+        assert order == [
+            ("lookup", held),
+            ("lookup", forgot), ("stat", cs._path_str(forgot)),
+            ("lookup", absent), ("stat", cs._path_str(absent)),
+            ("lookup", gone), ("stat", cs._path_str(gone)),
+            ("lookup", forgot),                 # re-recorded: no stat
+            ("lookup", held)]
+        assert plane.stats()["statFallbacks"] == 3
+        assert plane.stats()["statFallbackHits"] == 1
+        # the backstop's looks healed the set: a hit entered, a phantom
+        # dropped; no probe of the set was counted
+        assert cs._resident == {_key(held), _key(forgot)}
+        assert cs.resident_stats() == {
+            **before, "residentDrops": 1}
+        assert cs.has(gone, resident_ok=True) is False
+    finally:
+        plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_index_off_call_sequences_are_the_parents(tmp_path, monkeypatch,
+                                                  fsync):
+    """The plane off: a put batch over a resident name, a name of
+    another life, a fresh one and a repeat, then the looks with and
+    without the caller's leave, issue the parent's file-system calls in
+    the parent's order (PR 28's: this list is the parent's recorder on
+    the same script, to the letter)."""
+    import os
+    other = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    (mine, dm), (theirs, dt), (fresh, dn), (absent, _) = _batch(4, seed=15)
+    assert other.put(theirs, dt)
+    cs = ChunkStore(other.root, fsync=fsync)
+    assert cs.put(mine, dm)
+    path = cs._path_str
+    parent_dir = os.path.dirname(path(fresh))
+    calls = _Calls(monkeypatch, cs.root)
+    assert cs.put_batch([(mine, dm), (theirs, dt), (fresh, dn),
+                         (fresh, dn)]) == [False, False, True, False]
+    put = [(c, p) for c, p in calls.events
+           if not _is_temp(p) and p != calls.root]      # makedirs' look
+    temps = [(c, p) for c, p in calls.events if _is_temp(p)]
+    barrier = [("open", parent_dir), ("fsync", parent_dir),
+               ("close", parent_dir)] if fsync else []
+    assert put == [("stat", path(theirs)), ("stat", path(fresh)),
+                   ("link", path(fresh))] + barrier
+    assert [c for c, _ in temps] \
+        == ["open", "write"] + (["fsync"] if fsync else []) \
+        + ["close", "unlink"]
+    calls.events.clear()
+    assert cs.has_many([mine, theirs, fresh, absent], resident_ok=True) \
+        == [True, True, True, False]
+    assert calls.events == [("stat", path(absent))]
+    calls.events.clear()
+    assert [cs.has(d) for d in (mine, absent)] == [True, False]
+    assert calls.events == [("stat", path(mine)), ("stat", path(absent))]
+    assert cs.resident_stats() == {
+        "residentHits": 4, "residentMisses": 4, "residentEntries": 3,
+        "residentDrops": 0}
+
+
+@_INDEX
 def test_a_resident_hit_still_settles_a_name_owed_its_barrier(
-        tmp_path, monkeypatch):
+        tmp_path, monkeypatch, index):
     """Between another thread's link and its directory barrier the name
     is resident AND owed a barrier: ``has`` and the put pre-check issue
-    it before they answer, as a stat-positive did at the parent."""
+    it before they answer, as a stat-positive did at the parent — and,
+    with the index plane attached, as an index positive did."""
     import os
     cs = ChunkStore(tmp_path / "chunks", fsync=True)
+    plane = _plane(tmp_path / "plane", cs) if index else None
     (d, data), = _batch(1, seed=10)
     parent = os.path.dirname(cs._path_str(d))
     os.makedirs(parent)
@@ -959,26 +1188,44 @@ def test_a_resident_hit_still_settles_a_name_owed_its_barrier(
     #                                             writer's own pre-check
     assert cs.dir_barrier_count() == 2 and cs.fsync_count() == 1
     assert cs.resident_stats()["residentHits"] == 2
+    if plane is not None:
+        assert _lookups(plane) == 0
+        plane.close()
 
 
 @pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
 def test_overflow_empties_the_set_and_answers_stay_right(
-        tmp_path, monkeypatch, fsync):
+        tmp_path, monkeypatch, fsync, index):
+    """Beyond ``_RESIDENT_MAX`` the set is emptied and refilled by what
+    stands behind it — ``stat``s, or with the plane attached the index
+    (no ``stat`` for a ``has``) — never a wrong answer."""
     import dfs_tpu.store.cas as cas
     monkeypatch.setattr(cas, "_RESIDENT_MAX", 64)
     cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    plane = _plane(tmp_path / "plane", cs) if index else None
     items = _batch(150, seed=11)
     digests = [d for d, _ in items]
     assert all(cs.put_batch(items))
     assert len(cs._resident) == 150 - 128       # emptied twice on the way
     assert cs._resident == {_key(d) for d in digests[128:]}
+    calls = _Calls(monkeypatch, cs.root)
     assert cs.has_many(digests, resident_ok=True) == [True] * 150
+    # what the set held went out with the refill's first overflow: every
+    # name is asked of what stands behind the set, once
+    if index:
+        assert calls.events == [] and _lookups(plane) == 150
+    else:
+        assert calls.events == [("stat", cs._path_str(d)) for d in digests]
     assert len(cs._resident) <= 64 and _stale(cs) == []
     assert cs.put_batch(items) == [False] * 150
     assert cs.delete(digests[0]) and cs.delete(digests[149])
     assert cs.has_many(digests, resident_ok=True) \
         == [False] + [True] * 148 + [False]
     assert cs.count() == 148 and _stale(cs) == []
+    if plane is not None:
+        assert cs.has_many(digests) == [False] + [True] * 148 + [False]
+        plane.close()
 
 
 @pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])

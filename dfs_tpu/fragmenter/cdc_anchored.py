@@ -21,6 +21,12 @@ the walk measures its own staging bandwidth and serializes transfers
 while the link is slower than ``overlap_min_bw`` (see
 AnchoredTpuFragmenter.__init__).
 
+A stream too small to fill a window does not walk at all: the engine
+lays the small streams that wait side by side in one **packed region**
+(``_Packer``; ops.cdc_anchored "packed regions") — one dispatch, every
+stream's table its own — so a body of any size is chunked and hashed on
+the device and there is one path for every size.
+
 - ``AnchoredCpuFragmenter`` — NumPy oracle path (chunk_file_anchored_np).
 - ``AnchoredTpuFragmenter`` — full device pipeline, bounded-memory
   streaming in ~regions of ``region_bytes``.
@@ -28,6 +34,7 @@ AnchoredTpuFragmenter.__init__).
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
@@ -38,14 +45,20 @@ from dfs_tpu.fragmenter.base import Fragmenter
 from dfs_tpu.meta.manifest import ChunkRef, Manifest
 from dfs_tpu.ops.cdc_anchored import (TILE_BYTES, AnchoredCdcParams,
                                       CutCapacityOverflow,
-                                      chunk_file_anchored_np, region_buffer,
-                                      region_buffer_size, region_chunks,
-                                      region_collect, region_dispatch,
-                                      region_spans_np)
+                                      chunk_file_anchored_np, packed_buffer,
+                                      packed_collect, packed_dispatch,
+                                      packed_lanes, packed_layout,
+                                      packed_next, packed_redo,
+                                      region_buffer, region_buffer_size,
+                                      region_chunks, region_collect,
+                                      region_dispatch, region_spans_np)
 from dfs_tpu.ops.cdc_v2 import file_id_from_digests
 
 _REGION_BYTES = 64 * 1024 * 1024
-_CPU_CUTOFF = 2 * 1024 * 1024
+# payload bytes of the ONE packed-region shape (never more than a
+# sixteenth of a region): a lone small file pays for staging 2 MiB, not
+# a window; a stream it cannot hold walks windows of its own
+_PACK_BYTES = 2 * 1024 * 1024
 _REMEASURE_EVERY = 8     # overlapped mode re-times every Nth transfer
 
 
@@ -263,9 +276,10 @@ class AnchoredCpuFragmenter(_AnchoredBase):
 
 # What a streamed walk can be doing, as exclusive phases of its wall
 # time: blocked taking the next block from its caller, staging and
-# dispatching a window (or, under the CPU cutoff, chunking on the
-# host), collecting one, suspended at ``yield`` while the caller takes
-# the batch.
+# dispatching a window, collecting one (a packed stream: waiting for
+# the region that carries it, of which only that region's
+# ``block_until_ready`` is ``deviceWaitS``), suspended at ``yield``
+# while the caller takes the batch.
 _PHASES = ("inputWaitS", "dispatchS", "collectS", "replyS")
 # ``Health.device``'s names for region_collect's cut counts
 _CUT_KEYS = ("segments", "strong_cuts", "window_cuts", "forced_cuts")
@@ -338,6 +352,165 @@ class _StreamClock:
         self._phases.closed(self._t, nbytes)
 
 
+# ``Health.device``'s counters of the packed regions (docs/observability.md)
+_PACK_KEYS = ("packedRegions", "packedStreams", "packedBytes",
+              "packedCapacityBytes", "packWaitS", "packRoundS")
+
+
+class _PackJob:
+    """One small stream waiting for its share of a packed region."""
+
+    __slots__ = ("arr", "lanes", "clocked", "t_in", "table", "error")
+
+    def __init__(self, arr: np.ndarray, lanes: int, clocked: bool) -> None:
+        self.arr = arr
+        self.lanes = lanes              # lanes it is provisioned
+        self.clocked = clocked          # its stream keeps a phase clock
+        self.t_in = time.monotonic()
+        self.table: list | None = None  # [(offset, length, digest)]
+        self.error: BaseException | None = None
+
+    @property
+    def done(self) -> bool:
+        return self.table is not None or self.error is not None
+
+
+class _Packer:
+    """Group commit of the small streams of one engine. A stream joins
+    the queue; whoever finds no region being driven drives: takes from
+    the head of the queue what one packed region holds (it closes on
+    its bytes or on its lanes), stages, dispatches, collects and hands
+    every stream its own table — and goes on until its own stream is
+    served, then hands the driving to whoever still waits. So with the
+    device free a lone stream goes at once and alone, and while a
+    region is in flight whatever arrives gathers and goes together in
+    the next: no timer, no linger knob. The waiting threads only sleep;
+    one thread at a time stages and collects."""
+
+    def __init__(self, engine: "AnchoredTpuFragmenter",
+                 pack_bytes: int) -> None:
+        self._engine = engine
+        p, mult = engine.params, engine.lane_multiple
+        pack_bytes = max(TILE_BYTES,
+                         int(pack_bytes) // TILE_BYTES * TILE_BYTES)
+        # (region words, lanes): the lanes of one stream that fills the
+        # region, rounded up to the compaction tiling
+        self.shape = (pack_bytes // 4,
+                      -(-packed_lanes(pack_bytes, p) // mult) * mult)
+        self.limit = pack_bytes                 # largest stream packed
+        self._cond = threading.Condition()
+        self._queue: collections.deque[_PackJob] = collections.deque()
+        self._driving = False
+
+    def chunk(self, arr: np.ndarray, clock=None
+              ) -> list[tuple[int, int, str]]:
+        """The chunk table of one stream of at most ``limit`` bytes,
+        through a packed region. ``clock``: the stream's phase clock
+        (``collectS`` when called)."""
+        job = _PackJob(arr, packed_lanes(arr.shape[0], self._engine.params),
+                       clock is not None)
+        with self._cond:
+            self._queue.append(job)
+            while self._driving and not job.done:
+                self._cond.wait()
+            if not job.done:
+                self._driving = True
+        if not job.done:
+            try:
+                while not job.done:
+                    self._round(clock)
+            finally:
+                with self._cond:
+                    self._driving = False
+                    self._cond.notify_all()
+        if job.error is not None:
+            raise job.error
+        return job.table
+
+    def _take(self, batch: list[_PackJob]) -> None:
+        """Move the head of the queue into ``batch`` as far as one
+        region holds it: the first stream always, the next while its
+        bytes and its lanes still fit."""
+        m_words, lanes = self.shape
+        at = used = 0           # the next stream's offset; lanes given
+        with self._cond:
+            while self._queue:
+                job = self._queue[0]
+                if batch and (at + job.arr.shape[0] > m_words * 4
+                              or used + job.lanes > lanes):
+                    break
+                batch.append(self._queue.popleft())
+                at = packed_next(at, job.arr.shape[0])
+                used += job.lanes
+
+    def _round(self, clock) -> None:
+        """One packed region, from the take to every stream's table. A
+        failure is handed to the streams that were taken, each its own
+        caller's to raise; the queue behind them is untouched."""
+        import jax
+
+        eng = self._engine
+        m_words, lanes = self.shape
+        obs = eng.obs
+        span = obs.span if obs is not None \
+            else lambda name: contextlib.nullcontext()
+        to = clock.to if clock is not None else lambda phase: None
+        batch: list[_PackJob] = []
+        staged = words = tables = None
+        try:
+            self._take(batch)
+            with obs.request_span("owner.pack") if obs is not None \
+                    else contextlib.nullcontext():
+                to("dispatchS")
+                t0 = time.monotonic()
+                streams = [j.arr for j in batch]
+                sizes = [int(a.shape[0]) for a in streams]
+                offs, _ = packed_layout(sizes)
+                with span("owner.dispatch"):
+                    staged = packed_buffer(
+                        streams, offs, eng.params, m_words,
+                        out=eng._pool_take(region_buffer_size(
+                            0, eng.params, m_words=m_words)))
+                    words = jax.device_put(staged)
+                    out = packed_dispatch(words, offs, sizes, eng.params,
+                                          lanes, eng.lane_multiple)
+                    eng._count_dispatch(out, t0)
+                to("collectS")
+                with span("owner.collect"):
+                    t1 = time.monotonic()
+                    jax.block_until_ready(out)
+                    # the device's part of every clocked stream's wait;
+                    # the rest of it is the queue and the host's work
+                    # on this region and the ones before (``packWaitS``,
+                    # ``packRoundS``)
+                    eng._phases.add("deviceWaitS", (time.monotonic() - t1)
+                                    * sum(j.clocked for j in batch))
+                    tables, cuts = packed_collect(out, offs, sizes)
+                    eng._pool_give(staged)
+                    staged = None
+                    # what the tight provisioning dropped, at the
+                    # worst-case bound; the others' tables stand
+                    redone = packed_redo(streams, tables, eng.params,
+                                         m_words, lanes, eng.lane_multiple)
+                eng._count_packed(batch, t0, m_words * 4, cuts, out, redone)
+        except BaseException as e:
+            if not batch:
+                raise
+            tables = None
+            for job in batch:
+                job.error = e
+            if staged is not None and words is not None:
+                # back to the pool once its transfer is certainly over
+                with contextlib.suppress(Exception):
+                    jax.block_until_ready(words)
+                    eng._pool_give(staged)
+        with self._cond:
+            if tables is not None:
+                for job, table in zip(batch, tables):
+                    job.table = table
+            self._cond.notify_all()
+
+
 class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
     """Device pipeline, region-batched; output is batching-independent."""
 
@@ -345,7 +518,6 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
 
     def __init__(self, params: AnchoredCdcParams | None = None,
                  region_bytes: int = _REGION_BYTES,
-                 cpu_cutoff: int = _CPU_CUTOFF,
                  lane_multiple: int = 128,
                  max_inflight: int = 2,
                  overlap_min_bw: float = float(1 << 30)) -> None:
@@ -357,9 +529,11 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         # fixed window stride: far enough that the previous window's carry
         # (>= window_end - seg_max) always lands inside the next window
         self.stride = region_bytes - self.params.seg_max
-        self.cpu_cutoff = int(cpu_cutoff)
         self.lane_multiple = int(lane_multiple)
         self.max_inflight = max(1, int(max_inflight))
+        # streams of up to ``_packer.limit`` bytes share packed regions
+        # of one shape of their own
+        self._packer = _Packer(self, min(_PACK_BYTES, region_bytes // 16))
         # recycled host staging buffers, keyed by byte size: fresh 64 MiB
         # allocations measured a large one-time transfer setup cost per
         # buffer on some host->device links; a buffer returns to the pool
@@ -387,6 +561,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         # how the collected regions' segments came to end, as
         # region_collect counts them
         self._cuts = dict.fromkeys(_CUT_KEYS, 0)
+        self._packed = dict.fromkeys(_PACK_KEYS, 0)
         self._phases = _StreamPhases()
         # the first region this engine ran, dispatch to collected: its
         # outputs and when its dispatch began, until it is collected
@@ -419,8 +594,9 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         take = min(8, base)
         if take:
             lookback[8 - take:] = fetch(base - take, take)
-        staged = region_buffer(fetch(base, end - base), lookback,
-                               self.params, out=self._pool_take(end - base))
+        staged = region_buffer(
+            fetch(base, end - base), lookback, self.params,
+            out=self._pool_take(region_buffer_size(end - base, self.params)))
         words = jax.device_put(staged)
         # adaptive staging serialization (see __init__): wait for this
         # transfer to REALLY complete (and time it) unless the link has
@@ -453,18 +629,48 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
             self._since_measure += 1
         out = region_dispatch(words, end - base, start0, final,
                               self.params, lane_multiple=self.lane_multiple)
+        self._count_dispatch(out, t_in)
+        return base, end, final, out, staged
+
+    def _count_dispatch(self, out, t_in: float) -> None:
         with self._stats_lock:
             if not self.regions_dispatched:
                 self._first_region = (out, t_in)
             self.regions_dispatched += 1
-        return base, end, final, out, staged
 
-    def _pool_take(self, n: int) -> np.ndarray | None:
+    def _count_collect(self, out, cuts) -> None:
+        with self._stats_lock:
+            for key, count in zip(_CUT_KEYS, cuts):
+                self._cuts[key] += count
+            if self._first_region and self._first_region[0] is out:
+                self.first_region_s = time.monotonic() \
+                    - self._first_region[1]
+                self._first_region = None
+
+    def _count_packed(self, batch: list, t0: float, capacity: int, cuts,
+                      out, redone: bool) -> None:
+        """A packed region's tables are on the host: it counts as any
+        region does, and in the six counters of its own (``t0``: when
+        its staging began)."""
+        self._count_collect(out, cuts)
+        now = time.monotonic()
+        with self._stats_lock:
+            self.overflow_redos += redone
+            pk = self._packed
+            pk["packedRegions"] += 1
+            pk["packedStreams"] += len(batch)
+            pk["packedBytes"] += sum(j.arr.shape[0] for j in batch)
+            pk["packedCapacityBytes"] += capacity
+            pk["packWaitS"] += sum(t0 - j.t_in for j in batch)
+            pk["packRoundS"] += now - t0
+
+    def _pool_take(self, size: int) -> np.ndarray | None:
+        """A recycled staging buffer of exactly ``size`` bytes."""
         # list.pop() is atomic under the GIL; try/except (not
         # check-then-pop) keeps concurrent walks on a shared fragmenter
         # from racing each other to the last free buffer
         try:
-            return self._buf_pool[region_buffer_size(n, self.params)].pop()
+            return self._buf_pool[size].pop()
         except (KeyError, IndexError):
             return None
 
@@ -501,13 +707,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 fetch(base, end - base), lookback, expect - base, final,
                 self.params, lane_multiple=self.lane_multiple,
                 cap_mode="full")
-        with self._stats_lock:
-            for key, count in zip(_CUT_KEYS, cuts):
-                self._cuts[key] += count
-            if self._first_region and self._first_region[0] is out:
-                self.first_region_s = time.monotonic() \
-                    - self._first_region[1]
-                self._first_region = None
+        self._count_collect(out, cuts)
         self._pool_give(staged)
         for o, ln, dg in spans:
             off = base + o
@@ -528,15 +728,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         self._since_measure = _REMEASURE_EVERY  # re-time on window 0:
         # a stale fast estimate from a previous walk must not leave
         # this one overlapped on a link that has since collapsed
-        if n <= self.cpu_cutoff:
-            spans = chunk_file_anchored_np(arr, self.params)
-            out = [ChunkRef(index=i, offset=o, length=ln, digest=dg)
-                   for i, (o, ln, dg) in enumerate(spans)]
-            if store is not None:
-                for c in out:
-                    store(c.digest,
-                          arr[c.offset:c.offset + c.length].tobytes())
-            return out
+        if n <= self._packer.limit:
+            return self._packed_refs(arr, store)
 
         fetch = lambda off, ln: arr[off:off + ln]       # noqa: E731
         chunks: list[ChunkRef] = []
@@ -560,6 +753,18 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
             raise AssertionError(f"anchored walk ended at {bound} != {n}")
         return chunks
 
+    def _packed_refs(self, arr: np.ndarray, store,
+                     clock=None) -> list[ChunkRef]:
+        """A stream no longer than ``_packer.limit``, through a packed
+        region."""
+        out = [ChunkRef(index=i, offset=o, length=ln, digest=dg)
+               for i, (o, ln, dg) in enumerate(
+                   self._packer.chunk(arr, clock))]
+        if store is not None:
+            for c in out:
+                store(c.digest, arr[c.offset:c.offset + c.length].tobytes())
+        return out
+
     def chunk(self, data: bytes) -> list[ChunkRef]:
         return self._walk(_to_u8(data))
 
@@ -575,6 +780,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 "regions": self.regions_dispatched,
                 "overflow_redos": self.overflow_redos,
                 **self._cuts,
+                **{k: round(v, 6) if isinstance(v, float) else v
+                   for k, v in self._packed.items()},
                 **self._phases.snapshot()}
 
     def chunks_stream(self, blocks, store=None):
@@ -670,13 +877,14 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 yield from advance(total, final_ok=False)
             if total == 0:
                 return
-            if total <= self.cpu_cutoff and not pending and base == 0:
-                # small streams take chunk()'s oracle fast path (identical
-                # output either way; this skips device dispatch entirely)
-                cl = self._walk(np.frombuffer(buf, np.uint8), store=store)
-                if cl:
-                    clock.to("replyS")
-                    yield cl
+            if total <= self._packer.limit and not pending and base == 0:
+                # too small for a window of its own: its share of a
+                # packed region (identical output either way)
+                clock.to("collectS")
+                cl = self._packed_refs(np.frombuffer(buf, np.uint8), store,
+                                       clock)
+                clock.to("replyS")
+                yield cl
                 return
             yield from advance(total, final_ok=True)
             bound = 0

@@ -450,6 +450,33 @@ def make_anchor_fn(params: AnchoredCdcParams, m_words: int):
 # device segment selection (mirrors select_segments bit-for-bit)
 # ---------------------------------------------------------------------------
 
+def _select_step(tiles_p, start, n, win: int, params: AnchoredCdcParams):
+    """One step of the segment walk, traced: the segment that starts at
+    ``start`` in a stream that ends at ``n`` -> (its exclusive boundary,
+    its CUT_* kind, whether it is the stream's last). ``tiles_p``: pass
+    A's three rows, padded by ``win`` tiles of 2**30."""
+    import jax
+    import jax.numpy as jnp
+
+    slo = start + jnp.int32(params.strong_min)
+    lo = start + jnp.int32(params.seg_min)
+    hi = start + jnp.int32(params.seg_max)
+    # one window from the strong rule's low end: it holds the
+    # kept-anchor window too (strong_min <= seg_min)
+    t0 = (slo - 1) // jnp.int32(TILE_BYTES)
+    w = jax.lax.dynamic_slice(tiles_p, (0, t0), (3, win))
+    sw = w[2]
+    first = jnp.min(jnp.where((sw >= slo - 1) & (sw <= hi - 1), sw, 2**30))
+    kw = w[:2]
+    last = jnp.max(jnp.where((kw >= lo - 1) & (kw <= hi - 1), kw, -1))
+    kind = jnp.where(first < 2**30, CUT_STRONG,
+                     jnp.where(last >= 0, CUT_WINDOW, CUT_FORCED))
+    b = jnp.where(first < 2**30, first + 1,
+                  jnp.where(last >= 0, last + 1, hi))
+    fin = n - start <= jnp.int32(params.seg_max)
+    return jnp.where(fin, n, b), jnp.where(fin, CUT_END, kind), fin
+
+
 @functools.cache
 def make_select_fn(params: AnchoredCdcParams, m_tiles: int, cap: int):
     """Compiled: (tiles [3, m_tiles] i32 — pass-A output, start0 i32,
@@ -465,9 +492,6 @@ def make_select_fn(params: AnchoredCdcParams, m_tiles: int, cap: int):
     from dfs_tpu.ops.select_pallas import select_window_tiles
 
     win = select_window_tiles(params)
-    seg_min = jnp.int32(params.seg_min)
-    seg_max = jnp.int32(params.seg_max)
-    strong_min = jnp.int32(params.strong_min)
 
     @jax.jit
     def run(tiles, start0, n, final):
@@ -479,26 +503,7 @@ def make_select_fn(params: AnchoredCdcParams, m_tiles: int, cap: int):
 
         def body(carry, _):
             start, done = carry
-            slo = start + strong_min
-            lo = start + seg_min
-            hi = start + seg_max
-            # one window from the strong rule's low end: it holds the
-            # kept-anchor window too (strong_min <= seg_min)
-            t0 = (slo - 1) // jnp.int32(TILE_BYTES)
-            w = jax.lax.dynamic_slice(tiles_p, (0, t0), (3, win))
-            sw = w[2]
-            first = jnp.min(jnp.where((sw >= slo - 1) & (sw <= hi - 1),
-                                      sw, 2**30))
-            kw = w[:2]
-            last = jnp.max(jnp.where((kw >= lo - 1) & (kw <= hi - 1),
-                                     kw, -1))
-            kind = jnp.where(first < 2**30, CUT_STRONG,
-                             jnp.where(last >= 0, CUT_WINDOW, CUT_FORCED))
-            b = jnp.where(first < 2**30, first + 1,
-                          jnp.where(last >= 0, last + 1, hi))
-            fin = n - start <= seg_max
-            b = jnp.where(fin, n, b)
-            kind = jnp.where(fin, CUT_END, kind)
+            b, kind, fin = _select_step(tiles_p, start, n, win, params)
             # non-final regions keep the tail segment as carry: emit
             # nothing once the remaining bytes fit in one segment
             skip = done | (fin & ~final)
@@ -538,6 +543,27 @@ def make_select(params: AnchoredCdcParams, m_tiles: int, cap: int):
 # device segment descriptors: bounds -> lane tables (keeps the chain fused)
 # ---------------------------------------------------------------------------
 
+def _lane_tables(starts, seg_lens, s_pad: int):
+    """Pass B's lane tables, traced, from every segment's start and
+    length ([cap] i32, 0 where there is none): cut or padded to
+    ``s_pad`` lanes -> (starts, seg_lens, w_off, sh8 u32, real_blocks,
+    tail_len), each [s_pad]. The encoding :func:`lane_tables_np`
+    mirrors."""
+    import jax.numpy as jnp
+
+    cap = starts.shape[0]
+
+    def fit(x):
+        return jnp.pad(x, (0, s_pad - cap)) if s_pad >= cap else x[:s_pad]
+
+    starts, seg_lens = fit(starts), fit(seg_lens)
+    w_off = starts // jnp.int32(4) + jnp.int32(2)     # +2: the lookback
+    sh8 = ((starts % jnp.int32(4)) * jnp.int32(8)).astype(jnp.uint32)
+    real_blocks = (seg_lens + jnp.int32(BLOCK - 1)) // jnp.int32(BLOCK)
+    tail_len = seg_lens % jnp.int32(BLOCK)
+    return starts, seg_lens, w_off, sh8, real_blocks, tail_len
+
+
 @functools.cache
 def make_descriptor_fn(params: AnchoredCdcParams, cap: int, s_pad: int):
     """Compiled: (bounds [cap] i32 — select output, start0 i32) ->
@@ -569,18 +595,7 @@ def make_descriptor_fn(params: AnchoredCdcParams, cap: int, s_pad: int):
         consumed = jnp.max(jnp.where(valid, bounds,
                                      start0.astype(jnp.int32)))
         nseg = jnp.sum(valid.astype(jnp.int32))
-        if s_pad >= cap:
-            starts_p = jnp.pad(starts, (0, s_pad - cap))
-            seg_lens_p = jnp.pad(seg_lens, (0, s_pad - cap))
-        else:
-            starts_p = starts[:s_pad]
-            seg_lens_p = seg_lens[:s_pad]
-        w_off = starts_p // jnp.int32(4) + jnp.int32(2)
-        sh8 = ((starts_p % jnp.int32(4)) * jnp.int32(8)).astype(jnp.uint32)
-        real_blocks = (seg_lens_p + jnp.int32(BLOCK - 1)) // jnp.int32(BLOCK)
-        tail_len = seg_lens_p % jnp.int32(BLOCK)
-        return (starts_p, seg_lens_p, w_off, sh8, real_blocks, tail_len,
-                consumed, nseg)
+        return (*_lane_tables(starts, seg_lens, s_pad), consumed, nseg)
 
     return run
 
@@ -1108,3 +1123,259 @@ def batch_chunks_anchored(data: np.ndarray, params: AnchoredCdcParams,
     return region_chunks(
         np.asarray(data), np.zeros((8,), np.uint8), 0, True, params,
         lane_multiple=lane_multiple)[0]
+
+
+# ---------------------------------------------------------------------------
+# packed regions: several independent streams, one dispatch
+# ---------------------------------------------------------------------------
+#
+# A small stream alone would leave a region nearly empty and still cost a
+# dispatch, so the owner lays the streams that wait side by side in ONE
+# staging buffer (docs/ingest.md "Packed regions"):
+#
+#   [8 zero bytes][stream 0 ....][zeros][stream 1 ..][zeros][stream 2 ...
+#                 ^ offs[0] = 0         ^ offs[1]           ^ offs[2]
+#
+# every offset a multiple of TILE_BYTES and at least 8 zero bytes after
+# the stream before it. The anchor hash reads bytes before a stream's
+# offset 0 as 0 and anchors are kept by ABSOLUTE tile, so a stream's
+# anchor planes are the ones it has alone; the walk below starts anew at
+# every stream's first byte and ends a stream's last segment at its last
+# byte, so no segment, chunk or SHA state crosses from one stream to the
+# next. The law (tests/test_packed_region.py): each stream's chunk table
+# is bit for bit chunk_file_anchored_np of that stream alone. Pass A
+# (make_anchor_fn) and pass B (make_anchored_segment_fn: one lane a
+# segment, wherever it starts) are the single-stream chain's own.
+
+def packed_next(off: int, length: int) -> int:
+    """Where the stream after one of ``length`` bytes at ``off`` starts:
+    the first tile boundary that leaves 8 zero bytes between them. The
+    single definition of the rule above."""
+    return -(-(off + int(length) + 8) // TILE_BYTES) * TILE_BYTES
+
+
+def packed_layout(lengths) -> tuple[list[int], int]:
+    """Region-local offsets of streams of these byte lengths laid one
+    after the other, and the bytes the layout takes (the last stream's
+    end)."""
+    offs, at = [], 0
+    for ln in lengths:
+        offs.append(at)
+        at = packed_next(at, ln)
+    return offs, (offs[-1] + int(lengths[-1]) if offs else 0)
+
+
+def packed_lanes(length: int, params: AnchoredCdcParams) -> int:
+    """Lanes a stream of ``length`` bytes is provisioned in a packed
+    region: one, and one more for every half lane of its bytes (a
+    segment is 62 % of a lane on average; a stream of a lane or less is
+    exactly one). What closes a region on its lanes, and what a shape's
+    tight lanes are sized from."""
+    return int(length) // (params.seg_max // 2) + 1
+
+
+def packed_segment_cap(params: AnchoredCdcParams, m_words: int,
+                       k_max: int) -> int:
+    """Most segments a packed region of ``m_words`` holding up to
+    ``k_max`` streams can have: :func:`segment_cap`'s bound, and one
+    stream-final segment a stream."""
+    return m_words * 4 // params.strong_min + k_max
+
+
+@functools.cache
+def make_packed_select_fn(params: AnchoredCdcParams, m_tiles: int,
+                          cap: int, k_max: int):
+    """Compiled: (tiles [3, m_tiles] i32 — pass-A output, offs [k_max]
+    i32, ends [k_max] i32 — each stream's first byte and the byte after
+    its last, region-local, k i32 — how many of them are streams) ->
+    (starts [cap] i32, bounds [cap] i32: every segment of every stream in
+    region order, -1 padding after the last; cuts [3] i32).
+    :func:`make_select_fn`'s step with the stream's own end for ``n``;
+    a stream's last segment ends at its last byte (``final``) and the
+    walk starts anew at the next stream's offset. An XLA loop on every
+    backend, one turn a segment and no more: ``cap`` is the bound a
+    region of strong cuts alone would reach, a region of small files
+    holds one segment a stream — a scan of ``cap`` steps spent 0.8 ms
+    of the chip and 1 300 trace events a region on the padding (my chip
+    run, PR 41)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dfs_tpu.ops.select_pallas import select_window_tiles
+
+    win = select_window_tiles(params)
+
+    @jax.jit
+    def run(tiles, offs, ends, k):
+        tiles_p = jnp.concatenate(
+            [tiles, jnp.full((3, win), 2**30, jnp.int32)], axis=1)
+        offs_p = jnp.concatenate([offs, jnp.zeros((1,), jnp.int32)])
+        ends_p = jnp.concatenate([ends, jnp.zeros((1,), jnp.int32)])
+
+        def body(state):
+            i, start, idx, starts, bounds, kinds = state
+            b, kind, fin = _select_step(tiles_p, start, ends_p[idx], win,
+                                        params)
+            nxt = jnp.where(fin, idx + 1, idx)
+            return (i + 1, jnp.where(fin, offs_p[nxt], b), nxt,
+                    starts.at[i].set(start), bounds.at[i].set(b),
+                    kinds.at[i].set(kind))
+
+        pad = jnp.full((cap,), -1, jnp.int32)
+        *_, starts, bounds, kinds = jax.lax.while_loop(
+            lambda state: (state[0] < cap) & (state[2] < k), body,
+            (jnp.int32(0), offs_p[0], jnp.int32(0),
+             jnp.zeros((cap,), jnp.int32), pad, pad))
+        cuts = jnp.sum(kinds[:, None] == jnp.arange(3, dtype=jnp.int32),
+                       axis=0, dtype=jnp.int32)
+        return starts, bounds, cuts
+
+    return run
+
+
+@functools.cache
+def make_packed_chain_fn(params: AnchoredCdcParams, total_words: int,
+                         lanes: int, lane_multiple: int, cap_mode: str):
+    """One compiled executable for a packed region: (words — the staging
+    buffer, offs [lanes] i32, ends [lanes] i32, k i32) -> (seg_overflow,
+    count, q, offs, lens, digests, nseg, cuts) as :func:`make_chain_fn`
+    less the carry. ``lanes`` is the shape's tight lane provisioning and
+    the length of the table of streams (a stream is at least one lane);
+    cap_mode='full' provisions every lane the walk can fill, for the
+    redo of what overflowed. The walk always runs at the full bound, so
+    ``nseg`` and ``cuts`` cover every stream whatever the lanes hold."""
+    import jax
+    import jax.numpy as jnp
+
+    m_words = recover_m_words(total_words, params)
+    cap = packed_segment_cap(params, m_words, lanes)
+    tight = cap_mode == "tight"
+    s_pad = lanes if tight else -(-cap // lane_multiple) * lane_multiple
+    anchor = make_anchor_fn(params, m_words)
+    select = make_packed_select_fn(params, m_words * 4 // TILE_BYTES, cap,
+                                   lanes)
+    segfn = make_anchored_segment_fn(params, total_words, s_pad, cap_mode)
+
+    @jax.jit
+    def run(words, offs, ends, k):
+        starts, bounds, cuts = select(anchor(words), offs, ends, k)
+        valid = bounds >= 0
+        nseg = jnp.sum(valid.astype(jnp.int32))
+        starts, seg_lens, w_off, sh8, real_blocks, tail_len = _lane_tables(
+            starts, jnp.where(valid, bounds - starts, 0), s_pad)
+        seg_overflow = (nseg > jnp.int32(s_pad)) if tight else jnp.int32(0)
+        count, q, c_offs, lens, dig = segfn(
+            words, w_off, sh8, real_blocks, tail_len, starts, seg_lens)
+        return seg_overflow, count, q, c_offs, lens, dig, nseg, cuts
+
+    return run
+
+
+def packed_buffer(streams, offs, params: AnchoredCdcParams, m_words: int,
+                  out: np.ndarray | None = None) -> np.ndarray:
+    """Host staging buffer of a packed region of ``m_words``: the
+    streams (u8 arrays) at their :func:`packed_layout` offsets, zeros
+    everywhere else, :func:`region_buffer`'s lookback and slack around
+    them. ``out`` recycles a buffer of that exact size."""
+    empty = np.zeros((8,), np.uint8)
+    buf = region_buffer(empty[:0], empty, params, m_words, out).view(np.uint8)
+    for arr, off in zip(streams, offs):
+        buf[8 + off:8 + off + arr.shape[0]] = arr
+    return buf.view("<u4")
+
+
+def packed_dispatch(words, offs, lengths, params: AnchoredCdcParams,
+                    lanes: int, lane_multiple: int = 128,
+                    cap_mode: str = "tight"):
+    """Dispatch the packed chain on a device-resident staging buffer;
+    the table of streams (metadata-sized) goes with the call. Returns
+    device arrays, nothing blocks."""
+    k = len(offs)
+    if not 0 < k <= lanes:
+        raise ValueError(f"{k} streams in a table of {lanes}")
+    table = np.zeros((2, lanes), np.int32)
+    table[0, :k] = offs
+    table[1, :k] = np.asarray(offs) + np.asarray(lengths)
+    chain = make_packed_chain_fn(params, int(words.shape[0]), lanes,
+                                 lane_multiple, cap_mode)
+    return chain(words, table[0], table[1], _dev_i32(k))
+
+
+def packed_collect(out, offs, lengths
+                   ) -> tuple[list[list[tuple[int, int, str]] | None],
+                              tuple[int, int, int, int]]:
+    """Pull a :func:`packed_dispatch` result to the host: one chunk
+    table a stream, offsets stream-local — or None for a stream the
+    tight provisioning did not hold whole (lanes or cuts ran out before
+    its last chunk; its neighbours' tables stand) — and the region's cut
+    counts as :func:`region_collect` gives them, every stream's in
+    them."""
+    import jax
+
+    from dfs_tpu.ops.cdc_pipeline import digests_to_hex
+
+    seg_of, count, q, c_offs, lens, dig, nseg, cuts = jax.device_get(out)
+    rows = min(int(count), q.shape[0])
+    if rows and (q[:rows] < 0).any():
+        raise AssertionError("anchored cut compaction overflowed a tile")
+    overflowed = bool(seg_of) or int(count) > rows
+    at = c_offs[:rows].astype(np.int64)
+    ln = lens[:rows].astype(np.int64)
+    hexes = digests_to_hex(dig[:rows])
+    # rows come lane by lane, lanes in region order: sorted by offset
+    los = np.searchsorted(at, np.asarray(offs, np.int64), "left")
+    tables: list[list[tuple[int, int, str]] | None] = []
+    for off, size, lo in zip(offs, lengths, los.tolist()):
+        table, expect = [], off
+        while expect < off + size and lo < rows and at[lo] == expect:
+            table.append((expect - off, int(ln[lo]), hexes[lo]))
+            expect += int(ln[lo])
+            lo += 1
+        if expect == off + size:
+            tables.append(table)
+        elif overflowed:
+            tables.append(None)
+        else:
+            raise AssertionError(
+                f"packed stream at {off} broke off at {expect - off} "
+                f"of {size}")
+    return tables, (int(nseg), *(int(c) for c in cuts))
+
+
+def packed_chunks(streams, params: AnchoredCdcParams, m_words: int,
+                  lanes: int, lane_multiple: int = 128,
+                  cap_mode: str = "tight"
+                  ) -> tuple[list[list[tuple[int, int, str]]],
+                             tuple[int, int, int, int]]:
+    """Chunk several streams in one packed region of ``m_words`` and
+    ``lanes`` (they have to fit: :func:`packed_layout`), synchronously;
+    streams the tight provisioning dropped are redone together at the
+    worst-case bound. One table a stream, and the cut counts."""
+    import jax
+
+    lengths = [int(s.shape[0]) for s in streams]
+    offs, used = packed_layout(lengths)
+    if used > m_words * 4:
+        raise ValueError(f"{used} B of streams in a region of "
+                         f"{m_words * 4}")
+    words = jax.device_put(packed_buffer(streams, offs, params, m_words))
+    tables, cuts = packed_collect(
+        packed_dispatch(words, offs, lengths, params, lanes,
+                        lane_multiple, cap_mode), offs, lengths)
+    packed_redo(streams, tables, params, m_words, lanes, lane_multiple)
+    return tables, cuts
+
+
+def packed_redo(streams, tables: list, params: AnchoredCdcParams,
+                m_words: int, lanes: int, lane_multiple: int = 128) -> bool:
+    """Fill in the tables :func:`packed_collect` left None: those
+    streams alone, in one region at the worst-case bound; the others'
+    tables stand (and the first walk, which ran at the full bound, has
+    counted every stream's cuts already). True if there were any."""
+    again = [i for i, t in enumerate(tables) if t is None]
+    if again:
+        redone, _ = packed_chunks([streams[i] for i in again], params,
+                                  m_words, lanes, lane_multiple, "full")
+        for i, t in zip(again, redone):
+            tables[i] = t
+    return bool(again)

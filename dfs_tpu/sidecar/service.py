@@ -156,8 +156,15 @@ class _CompileClock:
 
 
 class SidecarServer:
+    # A chunking call holds its pool thread from first byte to reply. A
+    # small stream's thread spends that time asleep, waiting for the
+    # packed region that carries its stream (fragmenter/cdc_anchored.py
+    # ``_Packer``): the pool is what bounds how many streams one region
+    # can gather, so it is sized for waiters — one thread at a time
+    # stages and collects, whatever the pool holds. (Four, until PR 41:
+    # a region could then never hold more than four streams.)
     def __init__(self, port: int = 0, fragmenter: str = "auto",
-                 cdc_params=None, max_workers: int = 4) -> None:
+                 cdc_params=None, max_workers: int = 32) -> None:
         from dfs_tpu.config import ObsConfig
         from dfs_tpu.fragmenter.base import get_fragmenter
 

@@ -447,7 +447,7 @@ def anchored_frag(**kw):
     from dfs_tpu.fragmenter.cdc_anchored import AnchoredTpuFragmenter
 
     kw.setdefault("region_bytes", 16384)
-    return AnchoredTpuFragmenter(SMALL, cpu_cutoff=0, lane_multiple=8, **kw)
+    return AnchoredTpuFragmenter(SMALL, lane_multiple=8, **kw)
 
 
 def test_fragmenter_matches_oracle_and_cpu():

@@ -342,7 +342,7 @@ def test_cluster_with_anchored_device_pipeline(tmp_path, rng):
         nodes = await start_nodes(cluster, tmp_path)
         try:
             nodes[1].fragmenter = AnchoredTpuFragmenter(
-                small, region_bytes=16384, cpu_cutoff=0, lane_multiple=8)
+                small, region_bytes=16384, lane_multiple=8)
             manifest, _ = await nodes[1].upload(data, "device.bin")
             _, got = await nodes[2].download(manifest.file_id)
             assert got == data

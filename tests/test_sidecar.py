@@ -47,15 +47,16 @@ def test_health_names_the_device_and_counts_regions(rng):
             == {"platform": "cpu", "device_kind": "cpu", "regions": 0,
                 "overflow_redos": 0, "streams": 0, "segments": 0,
                 "strong_cuts": 0}
-        # above the 2 MiB host cut-off, so the chain really dispatches
-        data = rng.integers(0, 256, size=3 * 2**20 + 17,
+        # more than a packed region holds: a window of its own, the
+        # shape tests/test_span_totals.py compiles too
+        data = rng.integers(0, 256, size=4 * 2**20 + 17,
                             dtype=np.uint8).tobytes()
         resp = client.chunk_hash_stream([data])
         assert [c["digest"] for c in resp["chunks"]] \
             == [c.digest for c in AnchoredCpuFragmenter().chunk(data)]
         dev = client.health()["device"]
         assert dev["regions"] == 1 and dev["overflow_redos"] == 0
-        # how the region's ~40 segments came to end: most at a strong
+        # how the region's ~50 segments came to end: most at a strong
         # anchor, the rest at the last anchor of the window, one with
         # the stream
         assert dev["segments"] == dev["strong_cuts"] + dev["window_cuts"] \

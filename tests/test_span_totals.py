@@ -296,20 +296,24 @@ PHASES = ("inputWaitS", "dispatchS", "collectS", "replyS")
 DEVICE_KEYS = {"platform", "device_kind", "count", "regions",
                "overflow_redos", "segments", "strong_cuts", "window_cuts",
                "forced_cuts", "deviceWaitS", "streams", "streamS",
-               "openS", "bytes", *PHASES}
+               "openS", "bytes", *PHASES,
+               # the packed regions' own (PR 41); none is packed here
+               "packedRegions", "packedStreams", "packedBytes",
+               "packedCapacityBytes", "packWaitS", "packRoundS"}
 
 
 @pytest.fixture(scope="module")
 def owner():
     """The chip owner as a deployment runs it — the anchored device
-    engine, here on JAX's CPU backend — and one 3 MiB stream through
-    it already (over the 2 MiB host cut-off, so the chain dispatches;
-    the first stream pays the compile)."""
+    engine, here on JAX's CPU backend — and one stream of a little
+    over 4 MiB through it already (more than a packed region holds, so
+    it walks a window of its own, tests/test_packed_region.py has the
+    packed ones; the first stream pays the compile)."""
     srv = SidecarServer(port=0, fragmenter="cdc-anchored-tpu")
     srv.start()
     client = SidecarClient(srv.port)
     data = np.random.default_rng(24).integers(
-        0, 256, size=3 * 2**20 + 17, dtype=np.uint8).tobytes()
+        0, 256, size=4 * 2**20 + 17, dtype=np.uint8).tobytes()
     list(client.chunk_hash_duplex(
         data[i:i + 2**20] for i in range(0, len(data), 2**20)))
     yield srv, client, data

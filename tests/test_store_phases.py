@@ -386,8 +386,7 @@ def test_first_region_is_timed_dispatch_to_collected(rng):
         chunk=AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
                                strip_blocks=64),
         seg_min=2048, seg_max=4096, seg_mask=2047, strong_bits=1)
-    frag = AnchoredTpuFragmenter(small, region_bytes=16384, cpu_cutoff=0,
-                                 lane_multiple=8)
+    frag = AnchoredTpuFragmenter(small, region_bytes=16384, lane_multiple=8)
     assert frag.first_region_s is None
     data = rng.integers(0, 256, size=100_000, dtype="uint8").tobytes()
     t0 = time.monotonic()

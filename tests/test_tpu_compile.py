@@ -54,3 +54,32 @@ def test_select_walk_compiles_for_v5e_at_production_shapes(one_chip,
         arg((3, m_tiles), jnp.int32), arg((), jnp.int32),
         arg((), jnp.int32), arg((), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_packed_walk_compiles_for_v5e_at_the_production_shape(one_chip):
+    """The walk of a packed region (PR 41: an XLA while loop, one turn
+    a segment, at most 192 of them, with the table of streams beside
+    the three planes) at the engine's one shape: 2 MiB, 128 lanes."""
+    import jax
+    import jax.numpy as jnp
+
+    from dfs_tpu.fragmenter import cdc_anchored as F
+    from dfs_tpu.ops.cdc_anchored import (TILE_BYTES, AnchoredCdcParams,
+                                          make_packed_select_fn,
+                                          packed_segment_cap)
+
+    params = AnchoredCdcParams()
+    region_mib = F._PACK_BYTES // 2**20
+    assert region_mib == 2
+    m_words, lanes = F._PACK_BYTES // 4, 128
+    m_tiles = m_words * 4 // TILE_BYTES
+    cap = packed_segment_cap(params, m_words, lanes)
+    assert cap == region_mib * 32 + lanes
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = make_packed_select_fn(params, m_tiles, cap, lanes).lower(
+        arg((3, m_tiles), jnp.int32), arg((lanes,), jnp.int32),
+        arg((lanes,), jnp.int32), arg((), jnp.int32)).compile()
+    assert "while" in compiled.as_text()

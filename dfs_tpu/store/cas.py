@@ -16,6 +16,7 @@ upload (SURVEY.md §5.4) is preserved by the node runtime.
 
 from __future__ import annotations
 
+import contextlib
 import errno
 import itertools
 import json
@@ -117,6 +118,13 @@ _PUT_PHASES = ("precheckS", "settleS", "createS", "writeS",
                "unlinkS", "flushS")
 
 
+def _stripe(digest: str) -> int:
+    """Which of ``ChunkStore._dir_mu`` orders ``digest``: its first
+    byte (``key[0]`` of its raw form), the shard directory its file
+    lives in."""
+    return int(digest[:2], 16)
+
+
 def _sweep_tmp_files(dirs, max_age_s: float = _TMP_SWEEP_AGE_S) -> int:
     """Unlink ``.tmp-*`` entries older than ``max_age_s`` in the given
     directories; returns the number removed. Shared by the chunk and
@@ -187,7 +195,7 @@ class ChunkStore:
         # name is never cached. The put pre-check and has(resident_ok=True)
         # answer from it in front of the index and of the stat; every look
         # at the disk heals it. Entered and discarded-before-the-unlink
-        # under _index_mu; dies with the process.
+        # under the digest's _dir_mu; dies with the process.
         self._resident: set[bytes] = set()
         self._unlinks = 0                  # chunk unlinks ended
         # resident answers / went on (index, stat) / entries found stale
@@ -199,11 +207,17 @@ class ChunkStore:
         # and the calling threads' seconds by phase (put_stats)
         self._put = {"jobs": 0, "items": 0, **self._put_clock()}
         self._count_lock = threading.Lock()   # puts run on CAS pool workers
-        # orders the visible link/unlink against its index record: a
-        # put racing a delete of the SAME digest could otherwise
-        # interleave (link, note_delete, unlink, note_put) and leave a
-        # stale "present" — the one divergence the index design forbids
-        self._index_mu = threading.Lock()
+        # orders the visible link/unlink against its index record and
+        # its resident entry: a put racing a delete of the SAME digest
+        # could otherwise interleave (link, note_delete, unlink,
+        # note_put) and leave a stale "present" — the one divergence the
+        # index design forbids. Two digests never needed to exclude each
+        # other, so it is a lock a shard directory (``_stripe``: the
+        # digest's first byte, which AsyncChunkStore._split cuts a batch
+        # by): a node's write workers link side by side. Everything
+        # reached under one has a lock of its own; only _put_delta holds
+        # two (its digest's and its base's, in ascending order).
+        self._dir_mu = tuple(threading.Lock() for _ in range(_SHARD_DIRS))
         self._dirs: set[str] = set()       # subdirs known to exist
         self._tmp_seq = itertools.count()  # cheap unique tmp names
         # similarity seam (dfs_tpu.sim.SimPlane): when set, eligible
@@ -364,13 +378,14 @@ class ChunkStore:
 
     def _remember(self, key: bytes, seen: int | None = None) -> None:
         """The raw name of ``key`` was just linked or seen. The caller
-        holds ``_index_mu`` — the lock ``delete`` holds from its discard
-        to the end of its unlink — so no entry outlives its file. A name
-        seen outside that lock (a ``stat``, a listing, an index positive)
-        passes ``seen``, the count of unlinks when its look began: if one
-        ended since, it may have been this name's, and nothing is entered
-        (the next look does). The same with the index plane attached:
-        the index records beside it, under the same lock."""
+        holds the digest's ``_dir_mu`` — the lock ``delete`` holds from
+        its discard to the end of its unlink — so no entry outlives its
+        file. A name seen outside that lock (a ``stat``, a listing, an
+        index positive) passes ``seen``, the count of unlinks when its
+        look began: if one ended since, it may have been this name's,
+        and nothing is entered (the next look does). The same with the
+        index plane attached: the index records beside it, under the
+        same lock."""
         with self._count_lock:
             if seen is not None and seen != self._unlinks:
                 return
@@ -388,7 +403,7 @@ class ChunkStore:
     def _look_begins(self, key: bytes, counted: bool) -> tuple[bool, int]:
         """A look for the raw name of ``key`` begins: is it resident,
         and the count of unlinks ended so far — :meth:`_remember`'s
-        ``seen`` for what the look finds outside ``_index_mu``.
+        ``seen`` for what the look finds outside ``_dir_mu``.
         ``counted``: the caller takes a resident answer (the put
         pre-check, ``resident_ok``), so this is a hit or a miss of
         :meth:`resident_stats`."""
@@ -402,10 +417,10 @@ class ChunkStore:
             return known, self._unlinks
 
     def _saw(self, key: bytes, seen: int) -> None:
-        """A look outside ``_index_mu`` found the raw name — a ``stat``,
+        """A look outside ``_dir_mu`` found the raw name — a ``stat``,
         an index positive: entered unless an unlink ended since the
         look began."""
-        with self._index_mu:
+        with self._dir_mu[key[0]]:
             self._remember(key, seen)
 
     def _stat_raw(self, key: bytes, p: str, known: bool, seen: int) -> bool:
@@ -486,7 +501,7 @@ class ChunkStore:
             if resident_ok and not self._deltas_possible():
                 self._saw(key, seen)
         else:
-            with self._index_mu:
+            with self._dir_mu[key[0]]:
                 raw = os.path.isfile(p)
                 present = raw \
                     or (self._deltas_possible()
@@ -560,7 +575,7 @@ class ChunkStore:
         entry's type is unknown, and only for a wanted name). A name the
         listing lacks may still be delta-stored. Heals the resident set
         as :meth:`_raw_present` does: a listed name is entered under the
-        unlink-count rule of a ``stat`` outside ``_index_mu``; an entry
+        unlink-count rule of a ``stat`` outside ``_dir_mu``; an entry
         that was there BEFORE the listing began and that the listing
         lacks is dropped — so a name linked while the directory was
         being read is never forgotten."""
@@ -585,7 +600,7 @@ class ChunkStore:
             pass
         new = found - known
         if new:
-            with self._index_mu:
+            with self._dir_mu[_stripe(sub)]:   # one directory: one lock
                 for d in new:
                     self._remember(keys[d], seen)
         for d in known - found:
@@ -711,7 +726,7 @@ class ChunkStore:
                         # own chunks is exactly how that node's catalog
                         # re-enters the index (same ordering mutex
                         # discipline as has()'s backstop)
-                        with self._index_mu:
+                        with self._dir_mu[key[0]]:
                             if os.path.isfile(p):
                                 index.note_put(digest, defer_flush=True)
                                 self._remember(key)
@@ -758,7 +773,7 @@ class ChunkStore:
     @staticmethod
     def _put_clock() -> dict:
         """One put call's phase clock, at zero."""
-        return {"newFiles": 0, "jobS": 0.0,
+        return {"newFiles": 0, "linkContended": 0, "jobS": 0.0,
                 **dict.fromkeys(_PUT_PHASES, 0.0)}
 
     def _note_put(self, ph: dict, items: int) -> None:
@@ -777,13 +792,15 @@ class ChunkStore:
         ``jobs`` counts the calls that returned — a placement batch is
         up to four, one a write worker; a raw write from outside a
         batch (re-materialisation) is one of its own — ``items`` what
-        they were given, ``newFiles`` the names they linked, ``jobS``
-        their wall time on the calling threads, and the ten
-        ``_PUT_PHASES`` add up to it. ``linkS`` holds the book-keeping
-        between two links too; the similarity plane's encode and delta
-        write have no phase of their own and count in ``precheckS``. A
-        phase's seconds include the thread's wait to take the
-        interpreter lock back after its system call returned."""
+        they were given, ``newFiles`` the names they linked,
+        ``linkContended`` the links among them whose directory's lock
+        (``_dir_mu``) was held when asked for, ``jobS`` their wall time
+        on the calling threads, and the ten ``_PUT_PHASES`` add up to
+        it. ``linkS`` holds the book-keeping between two links too; the
+        similarity plane's encode and delta write have no phase of
+        their own and count in ``precheckS``. A phase's seconds include
+        the thread's wait to take the interpreter lock back after its
+        system call returned."""
         with self._count_lock:
             return {k: round(v, 6) if isinstance(v, float) else v
                     for k, v in self._put.items()}
@@ -876,16 +893,23 @@ class ChunkStore:
                     with self._count_lock:
                         self._unbarriered.add(digest)
                     parents[os.path.dirname(p)] = None
+                key = bytes.fromhex(digest)
+                mu = self._dir_mu[key[0]]
                 t, t0 = clock(), t
                 ph["linkS"] += t - t0      # the book-keeping between links
-                with self._index_mu:
+                if not mu.acquire(blocking=False):
+                    # the directory's lock was held when asked for: a
+                    # link, a delete or a look of the same directory
+                    ph["linkContended"] += 1
+                    mu.acquire()
+                try:
                     t, t0 = clock(), t
                     ph["linkWaitS"] += t - t0
                     try:
                         os.link(temps[k], p)
                     except FileExistsError:
                         # dedup hit: the name is there, as a stat would say
-                        self._remember(bytes.fromhex(digest))
+                        self._remember(key)
                         continue
                     except OSError as e:
                         # filesystem without hard links: fall back to
@@ -910,9 +934,12 @@ class ChunkStore:
                         # false NEGATIVE — has()'s stat backstop covers
                         # it. The flush/compaction threshold runs AFTER
                         # the mutex drops (below) — a multi-second merge
-                        # inside it would freeze every CAS worker.
+                        # inside it would freeze every link of the
+                        # directory.
                         self.index.note_put(digest, defer_flush=True)
-                    self._remember(bytes.fromhex(digest))
+                    self._remember(key)
+                finally:
+                    mu.release()
                 new[k] = True
                 nlinked += 1
                 nbytes += len(data)
@@ -966,8 +993,10 @@ class ChunkStore:
         encoder's read and the pin registration below (a delete/GC
         completing in that window), so the write was rolled back and
         the caller must store raw. Once the pin IS registered (inside
-        the same ordering mutex delete() takes), no later delete can
-        remove the base."""
+        the ordering mutex delete() of the base takes: this is the one
+        place that orders two digests, so it holds the locks of both
+        directories, in ascending order), no later delete can remove
+        the base."""
         parent = f"{self._deltas_root}/{digest[:2]}"
         if parent not in self._dirs:
             os.makedirs(parent, exist_ok=True)
@@ -987,7 +1016,9 @@ class ChunkStore:
                 if self._fsync:
                     f.flush()
                     os.fsync(f.fileno())
-            with self._index_mu:
+            lo, hi = sorted((_stripe(digest), _stripe(base_digest)))
+            with self._dir_mu[lo], (self._dir_mu[hi] if hi != lo
+                                    else contextlib.nullcontext()):
                 try:
                     os.link(tmp, dp)
                 except FileExistsError:
@@ -1044,7 +1075,7 @@ class ChunkStore:
         still raw-resident — re-materialize leaves the chunk present."""
         dp = self._delta_path_str(digest)
         blob_len = 0
-        with self._index_mu:
+        with self._dir_mu[_stripe(digest)]:
             with self._delta_mu:
                 base = self._delta_base.pop(digest, None)
                 if base is not None:
@@ -1178,19 +1209,21 @@ class ChunkStore:
 
     def delete(self, digest: str) -> bool:
         p = self._path_str(digest)
+        key = bytes.fromhex(digest)
         try:
             # size BEFORE unlink, for the cached byte gauge; losing the
             # stat→unlink race to a concurrent delete means the unlink
             # raises and neither gauge moves — same story as put's
             # exactly-one-True link race
-            with self._index_mu:
+            with self._dir_mu[key[0]]:
                 if self._deltas_possible():
                     # pinned base: resident deltas reconstruct through
                     # this digest — refused until the dependents die or
                     # re-materialize. Checked INSIDE the ordering mutex:
-                    # _put_delta registers its pin under the same lock,
-                    # so a racing delta write either sees the base
-                    # survive or rolls itself back, never a broken chain
+                    # _put_delta registers its pin under the same lock
+                    # (it holds its base's beside its own), so a racing
+                    # delta write either sees the base survive or rolls
+                    # itself back, never a broken chain
                     with self._delta_mu:
                         if self._delta_refs.get(digest, 0) > 0:
                             return False
@@ -1206,7 +1239,7 @@ class ChunkStore:
                 # unlink counted once it ended: a stat that saw the name
                 # just before enters nothing after this (_raw_present)
                 with self._count_lock:
-                    self._resident.discard(bytes.fromhex(digest))
+                    self._resident.discard(key)
                 try:
                     os.unlink(p)
                 finally:
@@ -1221,7 +1254,7 @@ class ChunkStore:
                 self.index.maybe_flush()   # outside the ordering mutex
             return True
         except FileNotFoundError:
-            self._forget(bytes.fromhex(digest))    # no raw file: no entry
+            self._forget(key)              # no raw file: no entry
             if self._deltas_possible():
                 return self._drop_delta(digest)
             return False

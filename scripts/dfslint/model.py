@@ -46,7 +46,8 @@ LOOP = "loop"
 WORKER = "worker"
 
 # `with <expr>:` guards treated as locks. Wider than DFS003's _LOCKISH
-# on purpose: the store layer names its ordering mutexes `_index_mu` /
+# on purpose: the store layer names its ordering mutexes `_dir_mu` (a
+# lock a shard directory, taken as `self._dir_mu[k]`) / `_delta_mu` /
 # `_mu` and the model must see them as guards, not as unprotected
 # accesses.
 LOCKISH = re.compile(

@@ -17,8 +17,9 @@ And what each call of a chunk file's put costs one thread (``price_put``,
 PR 38): the prices the put job's phase clock is read against. And how
 links scale (``price_links``, PR 39; alone: ``fsprice.py links``): P
 processes of four writer threads, with a lock a process around the link
-and with none — whether the chunk store's one mutex or the file system
-below it serialises the links of several nodes.
+(the chunk store until PR 42), with a lock a directory (the store since)
+and with none — whether the store's one mutex or the file system below
+it serialised the links of several nodes.
 Jax-free, stdlib only; removes what it made.
 """
 
@@ -127,16 +128,24 @@ def price_put(tag: str, root: str, n: int = 1500) -> None:
           + f"; a file {sum(s.values()) / n * 1e3:.3f}", flush=True)
 
 
-def _link_process(root: str, locked: bool, threads: int, seconds: float,
+_LOCKINGS = ("a lock a process", "a lock a directory", "no lock")
+
+
+def _link_process(root: str, locking: str, threads: int, seconds: float,
                   start, out) -> None:
     """One node's write workers: ``threads`` threads that each make a
     file as ``ChunkStore._write_raw`` does (create ``O_EXCL``, write
     8 KiB, fsync, close), link it to its name — under this process's
-    one lock when ``locked`` — and unlink the temp."""
+    one lock, under the lock of the name's directory (what the store
+    does since PR 42: ``ChunkStore._dir_mu``), or under none — and
+    unlink the temp."""
     dirs = [f"{root}/{k:02x}" for k in range(64)]
     for d in dirs:
         os.makedirs(d)
-    mu = threading.Lock()
+    one = threading.Lock()
+    mus = {d: one if locking == "a lock a process" else threading.Lock()
+           for d in dirs}
+    locked = locking != "no lock"
     tally = [[0, 0.0, 0.0] for _ in range(threads)]  # links, wait s, link s
     payload = b"z" * 8192
     clock = time.perf_counter
@@ -153,6 +162,7 @@ def _link_process(root: str, locked: bool, threads: int, seconds: float,
             os.write(fd, payload)
             os.fsync(fd)
             os.close(fd)
+            mu = mus[d]
             t0 = clock()
             if locked:
                 mu.acquire()
@@ -181,20 +191,21 @@ def price_links(root: str, procs=(1, 3, 5), threads: int = 4,
                 seconds: float = 4.0) -> None:
     """Do links run side by side? ``P`` processes (a node each) of
     ``threads`` write workers make files for ``seconds``; once every
-    process holds ONE lock around its link (``ChunkStore._index_mu``),
-    once none. If the lock is the ceiling, "no lock" makes more files a
-    second and a link stays near its idle price; if the file system
-    serialises links machine-wide, the files a second are the same and
-    the wait moves from the lock into the link, whose time then grows
-    with ``P`` in both columns (PERF.md §7, PR 39)."""
+    process holds ONE lock around its link (the store until PR 42:
+    ``ChunkStore._index_mu``), once a lock a directory (the store since:
+    ``_dir_mu``), once none. If the lock is the ceiling, the other two
+    make more files a second and a link stays near its idle price; if
+    the file system serialises links machine-wide, the files a second
+    are the same and the wait moves from the lock into the link, whose
+    time then grows with ``P`` in every column (PERF.md §7, PR 39, 42)."""
     ctx = multiprocessing.get_context("spawn")
     for p in procs:
-        for locked in (True, False):
-            base = f"{root}/links-{p}-{int(locked)}"
+        for col, locking in enumerate(_LOCKINGS):
+            base = f"{root}/links-{p}-{col}"
             start, out = ctx.Barrier(p * threads), ctx.Queue()
             children = [ctx.Process(
                 target=_link_process,
-                args=(f"{base}/n{k}", locked, threads, seconds, start, out))
+                args=(f"{base}/n{k}", locking, threads, seconds, start, out))
                 for k in range(p)]
             for c in children:
                 c.start()
@@ -202,8 +213,7 @@ def price_links(root: str, procs=(1, 3, 5), threads: int = 4,
                 sum(col) for col in zip(*(out.get() for _ in children)))
             for c in children:
                 c.join()
-            print(f"links P={p} x {threads} threads, "
-                  f"{'a lock a process' if locked else 'no lock'}: "
+            print(f"links P={p} x {threads} threads, {locking}: "
                   f"{links / seconds:.0f} links/s, a link "
                   f"{link_s / links * 1e3:.3f} ms, waited for the lock "
                   f"{wait_s / links * 1e3:.3f} ms, the rest of a file "

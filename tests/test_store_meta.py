@@ -732,8 +732,10 @@ def test_entries_leave_before_the_unlink(tmp_path, monkeypatch, fsync, via):
     def on_call(call, path):
         if call == "unlink" and not _is_temp(path):
             d = path.rsplit("/", 1)[1]
+            # held: its own directory's lock, and no other
             seen.append((d, _key(d) in cs._resident,
-                         cs._index_mu.locked()))
+                         [k for k, mu in enumerate(cs._dir_mu)
+                          if mu.locked()] == [_key(d)[0]]))
 
     calls.on_call = on_call
     if via == "delete":
@@ -1016,7 +1018,8 @@ def test_an_index_positive_racing_a_delete_enters_nothing(
 
                 def stat(path, *a, **kw):
                     out = real_stat(path, *a, **kw)
-                    if str(path) == final and cs._index_mu.locked() \
+                    if str(path) == final \
+                            and cs._dir_mu[_key(d)[0]].locked() \
                             and threading.current_thread() is not looker \
                             and not go.is_set():
                         go.set()                # delete's getsize: inside
@@ -1397,3 +1400,234 @@ def test_a_batch_look_lists_the_directory_and_answers_as_the_stats_do(
     assert by_list.has_many(names, resident_ok=True) \
         == [by_stat.has(d, resident_ok=True) for d in names]
     assert by_list.look_stats() == before
+
+
+# -- a lock a shard directory (PR 42): the ordering rule a digest ----------
+
+def _on_stripe(stripe, tag, size=600):
+    """``(digest, data)`` whose digest's first byte — its shard
+    directory, the lock that orders it — is ``stripe``."""
+    for i in range(1 << 16):
+        data = f"{tag}-{i}-".encode() * (size // 8)
+        d = sha256_hex(data)
+        if _key(d)[0] == stripe:
+            return d, data
+    raise AssertionError("no such digest")
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_puts_racing_deletes_of_the_same_digests_end_agreeing(
+        tmp_path, fsync, index):
+    """Threads putting and deleting the same few digests — two of them
+    in one shard directory — with looks beside them: when all have
+    ended, the resident set holds no name the disk lacks, the index says
+    of every digest what the disk says, and the gauge counts the files."""
+    import os
+    import sys
+    import threading
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    plane = _plane(tmp_path / "plane", cs) if index else None
+    items = [_on_stripe(7, "a"), _on_stripe(7, "b"), _on_stripe(200, "c"),
+             *_batch(3, seed=42)]
+    assert cs.count() == 0
+    errors = []
+    start = threading.Barrier(6)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)         # threads change places mid-call
+
+    def worker(k):
+        try:
+            start.wait(10)
+            for i in range(60):
+                d, data = items[(i + k) % len(items)]
+                if k % 3 == 0:
+                    cs.delete(d)
+                elif k % 3 == 1:
+                    cs.put_batch([(d, data), items[(i + k + 1) % len(items)]])
+                else:
+                    cs.put(d, data)
+                    cs.has_many([x for x, _ in items], resident_ok=bool(i % 2))
+        except BaseException as e:      # noqa: BLE001 - shown by the assert
+            errors.append(repr(e))
+
+    threads = [threading.Thread(target=worker, args=(k,)) for k in range(6)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads) and errors == []
+        assert not any(mu.locked() for mu in cs._dir_mu)
+        on_disk = {d for d, _ in items if os.path.isfile(cs._path_str(d))}
+        assert _stale(cs) == []
+        assert {k.hex() for k in cs._resident} <= on_disk
+        if plane is not None:
+            assert {d for d, _ in items if plane.lookup(d)} == on_disk
+        assert cs.count() == len(on_disk) == len(cs.digests())
+        assert _temps(cs.root) == []
+        for d, data in items:
+            assert cs.has(d, resident_ok=True) is (d in on_disk)
+            assert cs.get(d) == (data if d in on_disk else None)
+    finally:
+        sys.setswitchinterval(interval)
+        if plane is not None:
+            plane.close()
+
+
+class _Recorded:
+    """A lock of ``ChunkStore._dir_mu`` that writes down who took it."""
+
+    def __init__(self, stripe, log):
+        import threading
+        self.stripe, self.log, self.mu = stripe, log, threading.Lock()
+
+    def acquire(self, blocking=True):
+        got = self.mu.acquire(blocking)
+        if got:
+            self.log.append(self.stripe)
+        return got
+
+    def release(self):
+        self.mu.release()
+
+    def locked(self):
+        return self.mu.locked()
+
+    def __enter__(self):
+        self.acquire()
+
+    def __exit__(self, *exc):
+        self.release()
+
+
+def _delta_of(cs, stripe, base, tag):
+    """A delta blob against ``base`` (stored raw in ``cs``) for a target
+    whose digest lives in shard directory ``stripe``."""
+    from dfs_tpu.sim.delta import make_delta
+    bd, bdata = base
+    for i in range(1 << 16):
+        target = bdata + f"{tag}-{i}".encode()
+        d = sha256_hex(target)
+        if _key(d)[0] == stripe:
+            return d, target, make_delta(bd, bdata, target)
+    raise AssertionError("no such digest")
+
+
+_STRIPES = pytest.mark.parametrize(
+    "delta_stripe,base_stripe", [(200, 3), (3, 200), (9, 9)],
+    ids=["base-below", "base-above", "same-directory"])
+
+
+@_STRIPES
+def test_put_delta_holds_its_own_lock_and_its_bases_in_ascending_order(
+        tmp_path, delta_stripe, base_stripe):
+    """The one place that orders two digests: the delta's link, its pin
+    and its index record happen under the locks of BOTH directories,
+    the lower taken first (one lock where they are the same); a delete
+    and a drop take their own digest's alone."""
+    cs = ChunkStore(tmp_path / "chunks", fsync=False)
+    log = []
+    cs._dir_mu = tuple(_Recorded(k, log) for k in range(256))
+    base = _on_stripe(base_stripe, "base")
+    assert cs.put(*base) and log == [base_stripe]
+    d, target, blob = _delta_of(cs, delta_stripe, base, "t")
+    del log[:]
+    assert cs._put_delta(d, base[0], blob, raw_len=len(target)) is True
+    assert log == sorted({delta_stripe, base_stripe})
+    assert cs.get(d) == target and cs.delta_pinned(base[0])
+    del log[:]
+    assert cs.delete(base[0]) is False          # pinned: refused
+    assert log == [base_stripe]
+    del log[:]
+    assert cs.delete(d) is True                 # the delta: dropped
+    assert set(log) == {delta_stripe}
+    assert cs.delete(base[0]) is True and not cs.delta_pinned(base[0])
+    assert not any(mu.locked() for mu in cs._dir_mu)
+
+
+@_STRIPES
+def test_a_delete_of_the_base_waits_for_the_delta_that_pins_it(
+        tmp_path, monkeypatch, delta_stripe, base_stripe):
+    """``delete(base)`` arriving while ``_put_delta`` is between its link
+    and its pin — inside both locks — waits, then finds the pin and
+    refuses: never a broken chain."""
+    import os
+    import threading
+    cs = ChunkStore(tmp_path / "chunks", fsync=False)
+    base = _on_stripe(base_stripe, "base")
+    assert cs.put(*base)
+    d, target, blob = _delta_of(cs, delta_stripe, base, "t")
+    dp = cs._delta_path_str(d)
+    answers = []
+    deleter = threading.Thread(
+        target=lambda: answers.append(cs.delete(base[0])))
+    real_link = os.link
+
+    def link(src, dst, *a, **kw):
+        out = real_link(src, dst, *a, **kw)
+        if str(dst) == dp:
+            deleter.start()                     # linked, not yet pinned
+            deleter.join(0.3)
+            assert deleter.is_alive() and answers == []
+        return out
+
+    monkeypatch.setattr(os, "link", link)
+    assert cs._put_delta(d, base[0], blob, raw_len=len(target)) is True
+    deleter.join(10)
+    assert answers == [False]
+    assert cs.get(d) == target and cs.get(base[0]) == base[1]
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_STRIPES
+def test_a_delta_put_racing_a_delete_of_its_base_pins_or_rolls_back(
+        tmp_path, fsync, delta_stripe, base_stripe):
+    """Under threads, round after round: either the delete came first and
+    the delta rolled itself back (``None``: the caller stores raw), or
+    the pin came first and the delete was refused — one of the two, the
+    chain never broken; beside them a second ``_put_delta`` whose
+    directories are the reverse pair, which must not deadlock."""
+    import threading
+    cs = ChunkStore(tmp_path / "chunks", fsync=fsync)
+    other = _on_stripe(delta_stripe, "other-base")
+    assert cs.put(*other)
+    for rnd in range(25):
+        base = _on_stripe(base_stripe, f"base{rnd}")
+        assert cs.put(*base)
+        d, target, blob = _delta_of(cs, delta_stripe, base, f"t{rnd}")
+        # the reverse pair: a delta in the base's directory against a
+        # base in the delta's
+        rd, rtarget, rblob = _delta_of(cs, base_stripe, other, f"r{rnd}")
+        got = {}
+        start = threading.Barrier(3)
+
+        def run(name, fn):
+            start.wait(10)
+            got[name] = fn()
+
+        threads = [
+            threading.Thread(target=run, args=("stored", lambda: cs._put_delta(
+                d, base[0], blob, raw_len=len(target)))),
+            threading.Thread(target=run, args=("deleted", lambda: cs.delete(
+                base[0]))),
+            threading.Thread(target=run, args=("reverse", lambda: cs._put_delta(
+                rd, other[0], rblob, raw_len=len(rtarget))))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        assert not any(t.is_alive() for t in threads), "deadlock"
+        assert not any(mu.locked() for mu in cs._dir_mu)
+        assert got["reverse"] is True and cs.get(rd) == rtarget
+        assert got["stored"] in (True, None)
+        assert got["deleted"] is (got["stored"] is None), got
+        if got["stored"]:
+            assert cs.delta_base(d) == base[0] and cs.delta_pinned(base[0])
+            assert cs.get(d) == target and cs.get(base[0]) == base[1]
+        else:
+            assert cs.delta_base(d) is None and not cs.delta_pinned(base[0])
+            assert cs.has(d) is False and cs.get(d) is None
+            assert cs.get(base[0]) is None
+        assert cs.delta_depth(rd) == 1 and cs.delta_pinned(other[0])
+    assert cs.delete(other[0]) is False         # 25 deltas lean on it

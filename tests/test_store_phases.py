@@ -16,7 +16,7 @@ from pathlib import Path
 import pytest
 
 from dfs_tpu.store.aio import AsyncChunkStore
-from dfs_tpu.store.cas import _PUT_PHASES, ChunkStore
+from dfs_tpu.store.cas import _PUT_PHASES, ChunkStore, _stripe
 from dfs_tpu.utils.hashing import sha256_hex
 
 REPO = Path(__file__).resolve().parent.parent
@@ -40,6 +40,7 @@ def _fresh_batch(store: ChunkStore) -> None:
     assert store.put_batch(_items("fresh")) == [True] * N
     d = _grown(before, store.put_stats())
     assert (d["jobs"], d["items"], d["newFiles"]) == (1, N, N)
+    assert d["linkContended"] == 0                 # nobody else links
     assert all(d[k] >= 0.0 for k in (*_PUT_PHASES, "jobS"))
     assert d["createS"] > 0.0 and d["linkS"] > 0.0 and d["unlinkS"] > 0.0
     assert d["payloadFsyncS"] > 0.0 and d["dirBarrierS"] > 0.0
@@ -74,11 +75,17 @@ def _dedup_batch(store: ChunkStore) -> None:
                                                           rel=0.05)
 
 
-def _mutex_held(store: ChunkStore) -> None:
+def _put_beside_a_held_lock(store: ChunkStore, tag: str,
+                            stripe_off: int) -> dict:
+    """What one new file's put job counted while another thread held,
+    for 250 ms, the lock of the shard directory ``stripe_off`` after the
+    file's own."""
+    (digest, data), = _items(tag, 1)
+    mu = store._dir_mu[(_stripe(digest) + stripe_off) % 256]
     held = threading.Event()
 
     def hold() -> None:
-        with store._index_mu:
+        with mu:
             held.set()
             time.sleep(0.25)
 
@@ -86,15 +93,53 @@ def _mutex_held(store: ChunkStore) -> None:
     holder.start()
     assert held.wait(5.0)
     before = store.put_stats()
-    assert store.put_batch(_items("waits", 1)) == [True]
+    assert store.put_batch([(digest, data)]) == [True]
+    d = _grown(before, store.put_stats())
     holder.join(5.0)
     assert not holder.is_alive()
-    d = _grown(before, store.put_stats())
+    assert sum(d[k] for k in _PUT_PHASES) == pytest.approx(d["jobS"],
+                                                           rel=0.05)
+    return d
+
+
+def _mutex_held(store: ChunkStore) -> None:
+    # a link into the held lock's OWN directory waits for it
+    d = _put_beside_a_held_lock(store, "waits", 0)
     # the create, write and payload fsync of one small file eat some of
     # the 250 ms before the link asks for the mutex (on a loaded machine
     # tens of ms)
     assert d["linkWaitS"] > 0.05
     assert d["linkS"] < 0.05
+    assert (d["newFiles"], d["linkContended"]) == (1, 1)
+
+
+def _another_directorys_mutex_held(store: ChunkStore) -> None:
+    # ... and a link into any other directory does not
+    for off in (1, 128, 255):
+        d = _put_beside_a_held_lock(store, f"passes{off}", off)
+        assert d["linkWaitS"] < 0.05
+        assert (d["newFiles"], d["linkContended"]) == (1, 0)
+
+
+def _every_directory_has_a_lock_of_its_own(store: ChunkStore) -> None:
+    assert len(store._dir_mu) == len(set(map(id, store._dir_mu))) == 256
+    assert not hasattr(store, "_index_mu")     # no store-wide lock left
+    # four workers linking side by side into directories of their own
+    # never meet; the table adds their calls up
+    batches = [[it for it in _items(f"side{k}", 64)
+                if _stripe(it[0]) % 4 == k] for k in range(4)]
+    before = store.put_stats()
+    workers = [threading.Thread(target=store.put_batch, args=(b,))
+               for b in batches]
+    for w in workers:
+        w.start()
+    for w in workers:
+        w.join(30.0)
+    d = _grown(before, store.put_stats())
+    assert d["jobs"] == 4 and d["linkContended"] == 0
+    assert d["newFiles"] == d["items"] == sum(map(len, batches)) > 0
+    assert sum(d[k] for k in _PUT_PHASES) == pytest.approx(d["jobS"],
+                                                           rel=0.05)
 
 
 def _raw_write_outside_a_put(store: ChunkStore) -> None:
@@ -118,6 +163,7 @@ def _failed_put_counts_nothing(store: ChunkStore) -> None:
 
 @pytest.mark.parametrize("case", [
     _fresh_batch, _fsync_off, _dedup_batch, _mutex_held,
+    _another_directorys_mutex_held, _every_directory_has_a_lock_of_its_own,
     _raw_write_outside_a_put, _failed_put_counts_nothing],
     ids=lambda f: f.__name__.strip("_"))
 def test_put_phase_clock(tmp_path, case):
@@ -271,13 +317,91 @@ def test_durability_stats_carries_the_put_clock(tmp_path):
 
     assert asyncio.run(run()) == [True] * 5
     put = node.durability_stats()["put"]
-    assert set(put) == {"jobs", "items", "newFiles", "jobS", *_PUT_PHASES}
+    assert set(put) == {"jobs", "items", "newFiles", "linkContended",
+                        "jobS", *_PUT_PHASES}
     assert (put["jobs"], put["items"], put["newFiles"]) == (1, 5, 5)
+    assert put["linkContended"] == 0 and isinstance(put["linkContended"],
+                                                    int)
+    assert sum(put[k] for k in _PUT_PHASES) == pytest.approx(put["jobS"],
+                                                             rel=0.05)
     assert put["payloadFsyncS"] > 0.0       # the node's default: fsync
     # the neighbours it is served beside keep their keys
     assert {"mode", "fsyncs", "dirBarriers", "residentHits",
             "lookStats"} <= set(node.durability_stats())
     assert node.ingest_stats()["cas"]["lanes"]["w"]["ops"] == 1
+
+
+# -- the reader of the new counter (benchmarks/layer_metrics/) ----------
+
+def _put_page(jobs: int, files: int, contended: int | None) -> dict:
+    """A node's ``/metrics`` as far as the put clock's readers look: the
+    parent's table, plus — on this program — ``linkContended``."""
+    put = {"jobs": jobs, "items": 2 * files, "newFiles": files,
+           "jobS": 0.01 * files, **dict.fromkeys(_PUT_PHASES, 0.001 * files)}
+    if contended is not None:
+        put["linkContended"] = contended
+    return {"durability": {"mode": "fsync", "fsyncs": files, "put": put}}
+
+
+def _reads_nothing_on_the_parent(read, window) -> None:
+    _, parents = window([_put_page(1, 100, None)] * 3,
+                        [_put_page(9, 900, None)] * 3)
+    assert read(parents) is None
+    _, older = window([{"durability": {"fsyncs": 1}}] * 3,
+                      [{"durability": {"fsyncs": 9}}] * 3)
+    assert read(older) is None              # no put clock at all (< PR 38)
+    _, empty = window([{}] * 3, [{}] * 3)
+    assert read(empty) is None
+
+
+def _reads_contended_over_new_files(read, window) -> None:
+    # over the window, three nodes: 3 x (33 - 1) of 3 x (900 - 100) files
+    _, ours = window([_put_page(1, 100, 1)] * 3, [_put_page(9, 900, 33)] * 3)
+    assert read(ours) == pytest.approx(100.0 * 96 / 2400)
+    _, calm = window([_put_page(1, 100, 0)] * 3, [_put_page(9, 900, 0)] * 3)
+    assert read(calm) == 0.0
+    # a node that came up inside the window counts from zero
+    _, late = window([_put_page(1, 100, 1), {}],
+                     [_put_page(9, 900, 33), _put_page(4, 200, 8)])
+    assert read(late) == pytest.approx(100.0 * 40 / 1000)
+
+
+def _reads_nothing_where_no_file_was_made(read, window) -> None:
+    _, idle = window([_put_page(1, 100, 2)] * 3, [_put_page(5, 100, 2)] * 3)
+    assert read(idle) is None
+
+
+def _is_declared_for_the_five_cells(read, window) -> None:
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "store.link_contended_pct"]
+    assert entry == {
+        "name": "store.link_contended_pct", "unit": "%", "better": "lower",
+        "source": "program_counter", "layer": "chunk store",
+        "moves": "ingest_mibps",
+        "workloads": ["tarball.ingest-fresh", "tarball.ingest-edited",
+                      "snapshots.ingest-versions", "archive.ingest-ec",
+                      "smallfiles.ingest-batch"]}
+    assert set(entry["workloads"]) <= {w["name"] for w in bench["workloads"]}
+    assert (REPO / "benchmarks" / "layer_metrics"
+            / "store.link_contended_pct.py").is_file()
+    # the wait the counter explains is read by the reader it always was,
+    # in the same cells
+    (waits,) = [m for m in bench["per_layer"]
+                if m["name"] == "store.put_link_wait_s_per_gib"]
+    assert waits["workloads"][:5] == entry["workloads"]
+
+
+@pytest.mark.parametrize("case", [
+    _reads_nothing_on_the_parent, _reads_contended_over_new_files,
+    _reads_nothing_where_no_file_was_made, _is_declared_for_the_five_cells],
+    ids=lambda f: f.__name__.strip("_"))
+def test_link_contended_reader(case):
+    from tests.test_repair_cycle import _bench_window
+    window, _ = _bench_window([{}], [{}])
+    case(window.load_by_name("layer_metrics",
+                             "store.link_contended_pct").read,
+         _bench_window)
 
 
 # -- the owner's compile clock ------------------------------------------

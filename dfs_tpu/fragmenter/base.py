@@ -98,6 +98,13 @@ class Fragmenter(abc.ABC):
         host (asking must not initialise a backend there)."""
         return None
 
+    def tee_stats(self) -> dict:
+        """What a delegating engine's tee did at this node, for
+        ``/metrics`` ``ingest.seam`` (``SidecarFragmenter``:
+        ``teePeakBytes``, ``teeWaitS``); an engine that chunks in the
+        node's own process has no tee and adds nothing."""
+        return {}
+
     def stream_span(self) -> int | None:
         """Upper bound on how far chunks_stream's reporting can lag the
         bytes it has consumed (the sidecar advertises this so a teeing

@@ -311,9 +311,15 @@ class _StreamPhases:
                 self._open_since = now
             self._open += 1
 
-    def closed(self, now: float, nbytes: int) -> None:
+    def took(self, nbytes: int) -> None:
+        """Bytes a walk has finished with: what a collected window
+        consumed, when it is collected (a stream that lasts half a
+        minute would land whole in whichever reading saw it close)."""
         with self._lock:
             self._bytes += nbytes
+
+    def closed(self, now: float) -> None:
+        with self._lock:
             self._open -= 1
             if not self._open:
                 self._s["openS"] += now - self._open_since
@@ -347,11 +353,24 @@ class _StreamClock:
         self._phases.add(self._phase, now - self._t)
         self._phase, self._t = phase, now
 
-    def close(self, nbytes: int) -> None:
+    def close(self) -> None:
         self.to(self._phase)
-        self._phases.closed(self._t, nbytes)
+        self._phases.closed(self._t)
 
 
+# ``Health.device``'s counters of the windows of a streamed walk — what
+# a long stream adds (docs/ingest.md "Long streams"), each counted when
+# it happens: ``windows`` / ``windowBytes`` at a window's collect (its
+# ``end - base``; a packed region is no window), ``tailWindows`` at the
+# dispatch of a ``final`` window that has a predecessor, ``stagedTimed``
+# / ``stagedTimedBytes`` / ``stagedTimedS`` in ``_dispatch_window``'s
+# ``measure`` arm (the transfers the walk waited for and timed),
+# ``pendingAtDispatch`` the windows dispatched and not yet collected
+# when the next is dispatched (0 ... ``max_inflight``, summed), and
+# ``bufferPeakBytes`` the most a stream's rolling buffer held (a peak)
+_WINDOW_KEYS = ("windows", "windowBytes", "tailWindows", "stagedTimed",
+                "stagedTimedBytes", "stagedTimedS", "pendingAtDispatch",
+                "bufferPeakBytes")
 # ``Health.device``'s counters of the packed regions (docs/observability.md)
 _PACK_KEYS = ("packedRegions", "packedStreams", "packedBytes",
               "packedCapacityBytes", "packWaitS", "packRoundS")
@@ -562,6 +581,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         # region_collect counts them
         self._cuts = dict.fromkeys(_CUT_KEYS, 0)
         self._packed = dict.fromkeys(_PACK_KEYS, 0)
+        self._windows = dict.fromkeys(_WINDOW_KEYS, 0)
         self._phases = _StreamPhases()
         # the first region this engine ran, dispatch to collected: its
         # outputs and when its dispatch began, until it is collected
@@ -576,11 +596,13 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
     # -- pipelined region walk shared by chunk() and manifest_stream() ----
 
     def _dispatch_window(self, fetch, base: int, n: int, start0,
-                         final: bool) -> tuple:
+                         final: bool, ahead: int = 0) -> tuple:
         """device_put window [base, min(n, base+region_bytes)) and dispatch
         the fused chain; returns (base, end, final, out) with out all
-        device arrays. ``fetch(off, ln)`` must return stream bytes as a u8
-        array for any span inside [base-8, end). ``final`` must be passed
+        device arrays. ``ahead``: the caller's windows dispatched and not
+        yet collected (counted, ``pendingAtDispatch``). ``fetch(off,
+        ln)`` must return stream bytes as a u8 array for any span inside
+        [base-8, end). ``final`` must be passed
         explicitly — inferring it from end == n would misfire mid-stream
         when the bytes received so far happen to land exactly on a window
         end. Buffer shapes bucket to the next power of two (region_buffer),
@@ -630,6 +652,14 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         out = region_dispatch(words, end - base, start0, final,
                               self.params, lane_multiple=self.lane_multiple)
         self._count_dispatch(out, t_in)
+        with self._stats_lock:
+            wn = self._windows
+            wn["pendingAtDispatch"] += ahead
+            wn["tailWindows"] += final and base > 0
+            if measure:
+                wn["stagedTimed"] += 1
+                wn["stagedTimedBytes"] += staged.nbytes
+                wn["stagedTimedS"] += dt
         return base, end, final, out, staged
 
     def _count_dispatch(self, out, t_in: float) -> None:
@@ -638,10 +668,22 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 self._first_region = (out, t_in)
             self.regions_dispatched += 1
 
-    def _count_collect(self, out, cuts) -> None:
+    def _count_buffer(self, held: int) -> None:
+        """A stream's rolling buffer holds ``held`` bytes, more than it
+        has held before."""
+        with self._stats_lock:
+            if held > self._windows["bufferPeakBytes"]:
+                self._windows["bufferPeakBytes"] = held
+
+    def _count_collect(self, out, cuts, window: int | None = None) -> None:
+        """A region's table is on the host; ``window``: its bytes, where
+        it is a window of a walk (a packed region is none)."""
         with self._stats_lock:
             for key, count in zip(_CUT_KEYS, cuts):
                 self._cuts[key] += count
+            if window is not None:
+                self._windows["windows"] += 1
+                self._windows["windowBytes"] += window
             if self._first_region and self._first_region[0] is out:
                 self.first_region_s = time.monotonic() \
                     - self._first_region[1]
@@ -707,7 +749,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 fetch(base, end - base), lookback, expect - base, final,
                 self.params, lane_multiple=self.lane_multiple,
                 cap_mode="full")
-        self._count_collect(out, cuts)
+        self._count_collect(out, cuts, window=end - base)
         self._pool_give(staged)
         for o, ln, dg in spans:
             off = base + o
@@ -740,7 +782,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
             if len(pending) >= self.max_inflight:   # cap live windows
                 self._collect_window(*pending.pop(0), fetch, chunks, store)
             final = base + self.region_bytes >= n
-            win = self._dispatch_window(fetch, base, n, start0, final)
+            win = self._dispatch_window(fetch, base, n, start0, final,
+                                        ahead=len(pending))
             pending.append(win)
             if final:
                 break
@@ -781,7 +824,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 "overflow_redos": self.overflow_redos,
                 **self._cuts,
                 **{k: round(v, 6) if isinstance(v, float) else v
-                   for k, v in self._packed.items()},
+                   for k, v in (*self._packed.items(),
+                                *self._windows.items())},
                 **self._phases.snapshot()}
 
     def chunks_stream(self, blocks, store=None):
@@ -804,6 +848,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         start0 = 0
         base = 0
         done = False
+        taken = 0                      # bytes the collected windows consumed
+        peak = 0                       # the most buf has held
         self._since_measure = _REMEASURE_EVERY  # see _walk
         span = self.obs.span if self.obs is not None \
             else lambda name: contextlib.nullcontext()
@@ -827,6 +873,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
         def collect():
             """Collect the oldest window; yields its batch, with the
             time suspended there on the clock as the reply."""
+            nonlocal taken
             clock.to("collectS")
             n0 = len(chunks)
             win = pending.pop(0)
@@ -835,6 +882,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 jax.block_until_ready(win[3])   # the device's part of it
                 self._phases.add("deviceWaitS", time.monotonic() - t0)
                 bound = self._collect_window(*win, fetch, chunks, store)
+            self._phases.took(bound - taken)
+            taken = bound
             trim()
             if len(chunks) > n0:
                 clock.to("replyS")
@@ -855,7 +904,8 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                     yield from collect()
                 with span("owner.dispatch"):
                     win = self._dispatch_window(fetch, base, n_known,
-                                                start0, final)
+                                                start0, final,
+                                                ahead=len(pending))
                 pending.append(win)
                 trim()
                 if final:
@@ -874,6 +924,9 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                     break
                 buf += blk
                 total += len(blk)
+                if len(buf) > peak:
+                    peak = len(buf)
+                    self._count_buffer(peak)
                 yield from advance(total, final_ok=False)
             if total == 0:
                 return
@@ -883,6 +936,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 clock.to("collectS")
                 cl = self._packed_refs(np.frombuffer(buf, np.uint8), store,
                                        clock)
+                self._phases.took(total)
                 clock.to("replyS")
                 yield cl
                 return
@@ -894,7 +948,7 @@ class AnchoredTpuFragmenter(_StagingMeter, _AnchoredBase):
                 raise AssertionError(
                     f"anchored stream ended at {bound} != {total}")
         finally:
-            clock.close(total)
+            clock.close()
 
     def manifest_stream(self, blocks, name: str, store=None) -> Manifest:
         return self._manifest_via_chunks_stream(blocks, name, store)

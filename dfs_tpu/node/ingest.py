@@ -622,6 +622,10 @@ class Ingest:
         if self.chaos is not None:
             self.chaos.maybe_crash("upload.after_manifest")
         mj = manifest.to_json()          # once, not once per recipient
+        # the document as saved (ASCII: its characters are its bytes)
+        self.counters.inc("manifests_saved")
+        self.counters.inc("manifest_bytes", len(mj))
+        self.counters.inc("manifest_chunks", len(manifest.chunks))
 
         async def announce(peer) -> None:
             try:
@@ -678,6 +682,10 @@ class _StreamUpload:
         self.held_bytes = 0
         self.handoff_bytes = max(1, min(
             self.credits.budget // _HANDOFF_CREDIT_SHARE, ing.flush_bytes))
+        # ... since the last hand-off. (Every stopwatch of a stream is
+        # added to when the wait happens — a block, a hand-off — not at
+        # the stream's end: a stream that lasts half a minute would land
+        # whole in one reading of /metrics, or in none.)
         self.seam_s = 0.0
         self.stats = new_upload_stats()
         self.seen: set[str] = set()
@@ -739,6 +747,8 @@ class _StreamUpload:
             self.ing.counters.inc("seam_handoffs")
             self.ing.counters.inc("seam_chunks", len(self.held))
             self.held, self.held_bytes = [], 0
+            self.ing.stalls.add("seamReplyS", self.seam_s)
+            self.seam_s = 0.0
 
     def _run_fragmenter(self) -> None:
         put = self.outq.put_nowait
@@ -782,12 +792,12 @@ class _StreamUpload:
         # the body's two waits, told apart: for the next block from
         # the socket (the client, or TCP backpressure) and for
         # put_block (the fragmenter side is not draining inq)
-        body_wait = feed_wait = 0.0
+        waited = self.ing.stalls.add        # as it happens, a block
         with self.ing.obs.span("upload.body") as sp:
             try:
                 t = time.perf_counter()
                 async for b in self.blocks:
-                    body_wait += time.perf_counter() - t
+                    waited("bodyWaitS", time.perf_counter() - t)
                     if self.aborted.is_set():
                         break    # placement failed: stop reading, do
                         # NOT drain the rest of the body into memory
@@ -796,15 +806,13 @@ class _StreamUpload:
                     t = time.perf_counter()
                     await asyncio.to_thread(self._put_block, b)
                     now = time.perf_counter()
-                    feed_wait += now - t
+                    waited("feedWaitS", now - t)
                     t = now
                 else:       # the wait that found the body's end
-                    body_wait += time.perf_counter() - t
+                    waited("bodyWaitS", time.perf_counter() - t)
             finally:
                 await asyncio.to_thread(self._put_block, None)
                 sp.bytes = total
-                self.ing.stalls.add("bodyWaitS", body_wait)
-                self.ing.stalls.add("feedWaitS", feed_wait)
         return total
 
     async def _drain_one(self) -> None:

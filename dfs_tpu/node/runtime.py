@@ -2119,9 +2119,17 @@ class StorageNodeServer:
                 "sliceInflight": ing.slice_inflight,
                 "stalls": self.ingest_stalls.snapshot(),
                 # the owner seam: crossings from a fragmenter thread to
-                # the loop, and the chunks they carried
+                # the loop, and the chunks they carried; where a chip
+                # owner chunks, the tee's peak and its waits at the cap
                 "seam": {"handoffs": counted.get("seam_handoffs", 0),
-                         "chunks": counted.get("seam_chunks", 0)},
+                         "chunks": counted.get("seam_chunks", 0),
+                         **self.fragmenter.tee_stats()},
+                # the documents the acks saved (``Ingest._finalize``)
+                "commit": {"manifests": counted.get("manifests_saved", 0),
+                           "manifestBytes":
+                               counted.get("manifest_bytes", 0),
+                           "manifestChunks":
+                               counted.get("manifest_chunks", 0)},
                 "cas": self.cas.stats()}
 
     def ec_stats(self) -> dict:

@@ -404,6 +404,21 @@ class SidecarFragmenter(Fragmenter):
         # backend (fixed split) — then the tee cannot be safely capped
         self.stream_window = int(h.get("window") or 0)
         self._describe = h.get("describe")
+        # what the tees of this node's streams did, over its life
+        # (``tee_stats``): concurrent uploads share one adapter
+        self._tee_lock = threading.Lock()
+        self._tee_peak = 0
+        self._tee_wait_s = 0.0
+        self.last_peak_buffer = 0
+
+    def tee_stats(self) -> dict:
+        """``/metrics`` ``ingest.seam``: the most bytes any stream's tee
+        held (a peak; the cap is 2 x ``stream_window``) and the seconds
+        tees waited at the cap — each counted when it happens, not at a
+        stream's end."""
+        with self._tee_lock:
+            return {"teePeakBytes": self._tee_peak,
+                    "teeWaitS": round(self._tee_wait_s, 6)}
 
     def describe(self) -> dict:
         if not self._describe:
@@ -430,16 +445,15 @@ class SidecarFragmenter(Fragmenter):
         trimmed to the last reported chunk end. Peak node memory is
         therefore ~the sidecar's in-flight window span plus transport
         slack — never the whole body (``last_peak_buffer`` records the
-        high-water mark; tests assert the bound). gRPC flow control
+        newest stream's high-water mark, ``tee_stats`` the largest of
+        all; tests assert the bound). gRPC flow control
         paces the sender off the sidecar's walk, so TCP backpressure
         still reaches the uploading client end to end."""
-        import threading
-
         cond = threading.Condition()
         buf = bytearray()
         base = 0                      # absolute offset of buf[0]
         dead = False
-        self.last_peak_buffer = 0
+        peak = self.last_peak_buffer = 0
         # cap the un-trimmed tee at 2x the sidecar's advertised
         # reporting-lag bound (gRPC's own flow control buffers multiple
         # MB, so without this the tee grows to ~the whole body). 2x the
@@ -449,17 +463,25 @@ class SidecarFragmenter(Fragmenter):
         budget = 2 * self.stream_window if self.stream_window else None
 
         def tee():
+            nonlocal peak
             for b in blocks:
                 bb = bytes(b)
                 with cond:
+                    t0 = None
                     while (budget is not None and not dead
                            and len(buf) + len(bb) > budget + 2 * len(bb)):
+                        t0 = t0 or time.perf_counter()
                         cond.wait(0.2)
+                    if t0 is not None:
+                        with self._tee_lock:
+                            self._tee_wait_s += time.perf_counter() - t0
                     if dead:
                         return
                     buf.extend(bb)
-                    self.last_peak_buffer = max(self.last_peak_buffer,
-                                                len(buf))
+                    if len(buf) > peak:
+                        peak = self.last_peak_buffer = len(buf)
+                        with self._tee_lock:
+                            self._tee_peak = max(self._tee_peak, peak)
                 yield bb
 
         try:

@@ -285,7 +285,8 @@ def test_new_cells_and_metrics_are_declared_and_have_their_files():
     resident = per_layer["store.resident_hit_pct"]
     assert resident["workloads"] == ["tarball.ingest-fresh",
                                      "tarball.ingest-edited",
-                                     "smallfiles.ingest-batch"]  # PR 41
+                                     "smallfiles.ingest-batch",  # PR 41
+                                     "images.ingest-nightly"]    # PR 43
     assert (BENCH / "layer_metrics" / f"{resident['name']}.py").is_file()
 
 

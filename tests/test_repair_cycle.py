@@ -524,13 +524,15 @@ def test_a_reader_reads_this_program_and_nothing_on_the_parents(name):
     all3 = ["tarball.ingest-fresh", "tarball.ingest-edited",
             "snapshots.ingest-versions"]
     # PR 36's five nodes run the cycle and look at their disks (index
-    # off) as the tarball cells' three do: its cell was appended, and
-    # PR 41's (three nodes, index off, ~10^4 manifests a cycle) after it
+    # off) as the tarball cells' three do: its cell was appended, PR 41's
+    # (three nodes, index off, ~10^4 manifests a cycle) after it, and
+    # PR 43's (a few manifests of ~131 000 chunks each) after that
     assert m == {"name": name, "unit": unit, "better": better,
                  "source": source, "layer": layer,
                  "moves": "ingest_mibps",
                  "workloads": all3[:cells] + ["archive.ingest-ec",
-                                              "smallfiles.ingest-batch"]}
+                                              "smallfiles.ingest-batch",
+                                              "images.ingest-nightly"]}
     names = [n["name"] for n in bench["per_layer"]]
     assert sorted(names.index(n) for n in READERS) == list(range(
         names.index("repair.cycle_s_per_gib"),

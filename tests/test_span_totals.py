@@ -299,7 +299,11 @@ DEVICE_KEYS = {"platform", "device_kind", "count", "regions",
                "openS", "bytes", *PHASES,
                # the packed regions' own (PR 41); none is packed here
                "packedRegions", "packedStreams", "packedBytes",
-               "packedCapacityBytes", "packWaitS", "packRoundS"}
+               "packedCapacityBytes", "packWaitS", "packRoundS",
+               # a streamed walk's windows (PR 43)
+               "windows", "windowBytes", "tailWindows", "stagedTimed",
+               "stagedTimedBytes", "stagedTimedS", "pendingAtDispatch",
+               "bufferPeakBytes"}
 
 
 @pytest.fixture(scope="module")

@@ -300,7 +300,9 @@ class StorageNodeServer:
         # listen — so nothing can be in flight — reclaim every
         # crash-leaked temp file (all from the previous life) and run
         # the aged orphan GC, reconciling a crash between CAS put and
-        # manifest write with the same path aborted streams already use
+        # manifest write with the same path aborted streams already use.
+        # The sweep's listing also seeds the chunk store's resident set,
+        # complete from here on: "absent" needs no stat (store/cas.py)
         if self.index is not None:
             # open (or rebuild from the CAS walk — the chunk files are
             # ground truth) BEFORE the boot sweep and the servers: the
@@ -935,9 +937,11 @@ class StorageNodeServer:
             # answer is a look at the disk, a stat a digest. Either way,
             # where the CALLER sets `residentOk` (placement's probes and
             # pre-ack rounds only) the store's resident set answers
-            # first (store/cas.py has). A caller that does not send
-            # the key — the repair cycle, who_has, an older peer — is
-            # answered from the index or the disk, and a look at the
+            # first (store/cas.py has) — "present", and, complete since
+            # the boot sweep seeded it, "absent" too: no stat and no
+            # lookup for a digest nobody has. A caller that does not
+            # send the key — the repair cycle, who_has, an older peer —
+            # is answered from the index or the disk, and a look at the
             # disk heals the set.
             mask = await self.cas.has_many(
                 digests, resident_ok=bool(header.get("residentOk")))
@@ -2440,7 +2444,8 @@ class StorageNodeServer:
         returned), ``dirBarriers`` the directory fsyncs that took: one
         per distinct directory of a batch, not one per file;
         ``resident*`` how often the store's resident set answered an
-        existence check in place of a ``stat`` or an index lookup; ``put`` the
+        existence check — "present" or, complete, "absent" — in place of
+        a ``stat`` or an index lookup; ``put`` the
         put job's phase clock (``ChunkStore.put_stats``): calls, items,
         new files, and the write workers' seconds by phase."""
         return {"mode": self.cfg.durability.mode,

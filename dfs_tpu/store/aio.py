@@ -153,7 +153,8 @@ class AsyncChunkStore:
         (``resident_ok``: placement's probes and pre-ack rounds) is
         answered from the store's resident set, dedup plane on or off —
         microseconds a list once the store has linked or seen the
-        names. Everyone else rides the index when the plane is on
+        names, and on a booted node (the set complete) for the names
+        nobody has as well. Everyone else rides the index when the plane is on
         (store/cas.py: ~0.15 ms a lookup) and with it off pays a
         ``stat`` a digest, which on a busy file system measured 0.67 ms
         each (PERF.md §6, PR 28): a repair slice of 2 048 digests is

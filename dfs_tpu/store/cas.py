@@ -81,8 +81,9 @@ _TMP_SWEEP_AGE_S = 3600.0
 
 # bound of ChunkStore's resident set: the source's deployment holds
 # ~870 000 digests a node (PERF.md §4) -> 2**20 entries, ~100 MB at the
-# worst; past it the set is emptied and refills from what stands
-# behind it (the index where the plane is attached, else stats)
+# worst; past it the set is emptied — and is no longer complete, where
+# it was — and refills from what stands behind it (the index where the
+# plane is attached, else stats)
 _RESIDENT_MAX = 1 << 20
 
 
@@ -191,12 +192,28 @@ class ChunkStore:
         # what is on the disk, remembered — index plane on or off: raw
         # 32-byte digests whose raw file this process linked, or found by
         # a stat or (has(resident_ok=True), the put pre-check) by an index
-        # positive, and has not unlinked since. Positives only — an absent
-        # name is never cached. The put pre-check and has(resident_ok=True)
-        # answer from it in front of the index and of the stat; every look
-        # at the disk heals it. Entered and discarded-before-the-unlink
-        # under the digest's _dir_mu; dies with the process.
+        # positive, and has not unlinked since. Positives only — no absent
+        # name is cached one by one. The put pre-check and
+        # has(resident_ok=True) answer from it in front of the index and of
+        # the stat; every look at the disk heals it. Entered and
+        # discarded-before-the-unlink under the digest's _dir_mu; dies
+        # with the process.
         self._resident: set[bytes] = set()
+        # is the set COMPLETE — does it hold every raw name on the disk,
+        # so that a miss IS the answer "absent"? A fact somebody
+        # established, never a default. None: nobody has tried (a bare
+        # store: a miss goes on to the index or the stat, as ever). True:
+        # a listing seeded the set (digests(complete=True): the node's
+        # boot sweep, before anything is in flight) and every link since
+        # entered its name, every unlink discarded it first. False: the
+        # listing could not (_establish), or it was and the overflow of
+        # _RESIDENT_MAX emptied the set; a node lists for it once, so
+        # nothing raises it again in that life. What another hand puts
+        # into the directory is the one thing a complete set lacks: such
+        # a name costs a redundant write that ends as a dedup hit
+        # (_write_raw), never a copy counted that is not there.
+        self._complete: bool | None = None
+        self._res_absent = 0               # misses answered "absent" here
         self._unlinks = 0                  # chunk unlinks ended
         # resident answers / went on (index, stat) / entries found stale
         self._res_hits = self._res_misses = self._res_drops = 0
@@ -385,36 +402,81 @@ class ChunkStore:
         look began: if one ended since, it may have been this name's,
         and nothing is entered (the next look does). The same with the
         index plane attached: the index records beside it, under the
-        same lock."""
+        same lock. At ``_RESIDENT_MAX`` the set is emptied, and with it
+        goes its completeness: a miss is a question for the disk again."""
         with self._count_lock:
             if seen is not None and seen != self._unlinks:
                 return
             if len(self._resident) >= _RESIDENT_MAX:
                 self._resident.clear()
+                if self._complete:
+                    self._complete = False
             self._resident.add(key)
 
+    def _establish(self, raw: list[str], seen: int) -> None:
+        """``raw`` is a listing of every raw name on the disk, begun when
+        ``seen`` unlinks had ended: seed the set from it and note that it
+        is complete. :meth:`_remember`'s rule for a look outside
+        ``_dir_mu``, for the listing as a whole: with every directory's
+        lock held (ascending, as ``_put_delta`` takes its two) no link
+        and no delete is half way, and if an unlink ended while the
+        listing was read nothing is entered and nothing declared — as
+        where the names would pass ``_RESIDENT_MAX``. A name linked
+        meanwhile entered itself."""
+        keys = [bytes.fromhex(d) for d in raw]
+        with contextlib.ExitStack() as held:
+            for mu in self._dir_mu:
+                held.enter_context(mu)
+            with self._count_lock:
+                ok = seen == self._unlinks and \
+                    len(self._resident) + len(keys) <= _RESIDENT_MAX
+                if ok:
+                    self._resident.update(keys)
+                # tried and could not: False, no longer None
+                self._complete = ok or bool(self._complete)
+
     def _forget(self, key: bytes) -> None:
-        """The disk says the raw file of ``key`` is not there."""
+        """A look outside ``_dir_mu`` found no raw file of ``key``: its
+        entry goes. A complete set has something to lose here that no
+        other has — a link that ended since the look entered the name,
+        and dropping that would leave a file the set does not hold — so
+        it looks again under the directory's lock, where no link is
+        half way, before it drops."""
         with self._count_lock:
-            if key in self._resident:
+            if key not in self._resident:
+                return
+            if not self._complete:
                 self._resident.remove(key)
                 self._res_drops += 1
+                return
+        with self._dir_mu[key[0]]:
+            if not os.path.isfile(self._path_str(key.hex())):
+                with self._count_lock:
+                    if key in self._resident:
+                        self._resident.remove(key)
+                        self._res_drops += 1
 
-    def _look_begins(self, key: bytes, counted: bool) -> tuple[bool, int]:
-        """A look for the raw name of ``key`` begins: is it resident,
-        and the count of unlinks ended so far — :meth:`_remember`'s
-        ``seen`` for what the look finds outside ``_dir_mu``.
-        ``counted``: the caller takes a resident answer (the put
-        pre-check, ``resident_ok``), so this is a hit or a miss of
-        :meth:`resident_stats`."""
+    def _look_begins(self, key: bytes,
+                     counted: bool) -> tuple[bool, bool, int]:
+        """A look for the raw name of ``key`` begins: is it resident; is
+        it ABSENT, the look over before it reached the disk; and the
+        count of unlinks ended so far — :meth:`_remember`'s ``seen`` for
+        what the look finds outside ``_dir_mu``. ``counted``: the caller
+        takes a resident answer (the put pre-check, ``resident_ok``), so
+        this is a hit or a miss of :meth:`resident_stats` — and a miss
+        of a complete set is the answer: no raw file of that name."""
         with self._count_lock:
             known = key in self._resident
+            absent = False
             if counted:
                 if known:
                     self._res_hits += 1
                 else:
                     self._res_misses += 1
-            return known, self._unlinks
+                    if self._complete:
+                        absent = True
+                        self._res_absent += 1
+            return known, absent, self._unlinks
 
     def _saw(self, key: bytes, seen: int) -> None:
         """A look outside ``_dir_mu`` found the raw name — a ``stat``,
@@ -436,14 +498,23 @@ class ChunkStore:
 
     def resident_stats(self) -> dict:
         """``/metrics`` ``durability.resident*``: existence checks the
-        resident set answered, those that went on to the index or to a
-        ``stat``, entries held now, entries dropped because the disk
-        disagreed. Counted with the index plane on as with it off."""
+        resident set answered "present", those about names it does not
+        hold, entries held now, entries dropped because the disk
+        disagreed. Counted with the index plane on as with it off. Once
+        somebody has established that the set is complete (a node, at
+        its boot sweep) two more: ``residentAbsent``, the misses — they
+        count in ``residentMisses`` too — answered "absent" there and
+        then, where the others went on to the index or to a ``stat``;
+        and ``residentComplete``, whether the set is complete now."""
         with self._count_lock:
-            return {"residentHits": self._res_hits,
-                    "residentMisses": self._res_misses,
-                    "residentEntries": len(self._resident),
-                    "residentDrops": self._res_drops}
+            out = {"residentHits": self._res_hits,
+                   "residentMisses": self._res_misses,
+                   "residentEntries": len(self._resident),
+                   "residentDrops": self._res_drops}
+            if self._complete is not None:
+                out["residentAbsent"] = self._res_absent
+                out["residentComplete"] = self._complete
+            return out
 
     def has(self, digest: str, resident_ok: bool = False) -> bool:
         """Local existence, asked in the order memory → index → disk.
@@ -460,6 +531,23 @@ class ChunkStore:
         drops what the disk no longer has. The set stands in front of
         the index as in front of the ``stat``: what either finds for such
         a caller is entered, under :meth:`_remember`'s rule.
+
+        The same caller hears "absent" from memory too, where the set is
+        COMPLETE: it holds every raw name on the disk, so a name it
+        lacks has no raw file — no ``stat``, no lookup (only the delta
+        map is still asked, where a delta tree exists). Complete is a
+        fact that is established, not assumed: a node's boot sweep
+        seeds the set from the listing it makes anyway, before the
+        servers listen (``digests(complete=True)``); from then on every
+        link enters its name and every unlink discards it first, under
+        ``_dir_mu``. It ends where the set overflows ``_RESIDENT_MAX``
+        and is emptied (:meth:`_remember`). A bare store never is, and
+        answers a miss from the index or the disk as it always did. The
+        caveat's other face: a file ANOTHER hand put there is "absent"
+        here until a look at the disk enters it — which costs a
+        redundant transfer that ends as a dedup hit (``_write_raw``),
+        where the first face costs a copy believed in until the repair
+        cycle's look. No "present" is ever answered that was not.
 
         **Index**, where the plane is attached: a positive
         index answer is final — puts are recorded only AFTER the link
@@ -485,11 +573,16 @@ class ChunkStore:
         p = self._path_str(digest)
         key = bytes.fromhex(digest)
         index = self.index
-        known = False
+        known = absent = False
         if resident_ok or index is None:
-            known, seen = self._look_begins(key, resident_ok)
+            known, absent, seen = self._look_begins(key, resident_ok)
         if known and resident_ok:
             present = True
+        elif absent:
+            # no raw file: only a delta of its own can hold it
+            present = self._deltas_possible() \
+                and self.delta_base(digest) is not None \
+                and self._chain_resolves(digest)
         elif index is None:
             present = self._stat_raw(key, p, known, seen) \
                 or (self._deltas_possible()
@@ -705,12 +798,14 @@ class ChunkStore:
             p = self._path_str(digest)
             if digest in queued:
                 continue           # twice in one batch: written once
-            # resident → a dedup hit, plane on or off; else the look of
+            # resident → a dedup hit, plane on or off; a miss of a
+            # complete set → no raw file, nothing asked; else the look of
             # the mode: one stat (index off), isfile then the index
             key = bytes.fromhex(digest)
-            resident, seen = self._look_begins(key, True)
-            if resident or (self._stat_raw(key, p, False, seen)
-                            if index is None else os.path.isfile(p)):
+            resident, absent, seen = self._look_begins(key, True)
+            if resident or (not absent and (
+                    self._stat_raw(key, p, False, seen)
+                    if index is None else os.path.isfile(p))):
                 self._settle(digest, p, ph)
                 hits += 1
                 if resident:
@@ -1284,7 +1379,14 @@ class ChunkStore:
         with self._count_lock:
             return self._count
 
-    def digests(self) -> list[str]:
+    def digests(self, complete: bool = False) -> list[str]:
+        """Every digest the store holds, raw names first, by a listing of
+        every shard directory. ``complete`` (``NodeStore.boot_sweep``:
+        before the servers listen, so nothing is in flight): the raw
+        names of this listing — not the delta tree's — seed the resident
+        set, which is complete from here on (:meth:`_establish`)."""
+        with self._count_lock:
+            seen = self._unlinks
         out = []
         hexdigits = set("0123456789abcdef")
         for sub in sorted(self.root.iterdir()) if self.root.is_dir() else []:
@@ -1294,15 +1396,17 @@ class ChunkStore:
                 out.extend(sorted(
                     p.name for p in sub.iterdir()
                     if len(p.name) == 64 and set(p.name) <= hexdigits))
+        if complete:
+            self._establish(out, seen)
         if self._deltas_possible():
-            seen = set(out)
+            raw = set(out)
             droot = Path(self._deltas_root)
             for sub in sorted(droot.iterdir()) if droot.is_dir() else []:
                 if sub.is_dir():
                     out.extend(sorted(
                         p.name for p in sub.iterdir()
                         if len(p.name) == 64 and set(p.name) <= hexdigits
-                        and p.name not in seen))
+                        and p.name not in raw))
         return out
 
     def total_bytes(self) -> int:
@@ -1664,15 +1768,24 @@ class NodeStore:
         batch's links and its directory barriers left names that no
         barrier covers and that this life cannot tell from durable
         ones — after this sweep every name on disk is durable, so a
-        dedup hit on it may be acked."""
+        dedup hit on it may be acked.
+
+        The listing the orphan GC makes is the one moment at which this
+        process knows every name in its chunk directory and nothing can
+        change it: the chunk store's resident set is seeded from it and
+        is COMPLETE from here on (``ChunkStore.digests``), so the put
+        pre-check and placement's probes hear "absent" from memory. The
+        orphans deleted next leave the set through ``delete``, as any
+        name does."""
         tmps = self.chunks.sweep_tmp(max_age_s=0.0) \
             + self.manifests.sweep_tmp(max_age_s=0.0)
         barriers = self.chunks.barrier_dirs()
-        orphans = self.gc(min_age_s=3600.0)
+        orphans = self.gc(min_age_s=3600.0, complete=True)
         return {"tmps": tmps, "orphans": len(orphans),
                 "dirBarriers": barriers}
 
-    def gc(self, min_age_s: float = 0.0) -> list[str]:
+    def gc(self, min_age_s: float = 0.0,
+           complete: bool = False) -> list[str]:
         """Delete chunks referenced by no manifest (the reference has no
         delete/GC at all — SURVEY.md §2.5(5)). Returns deleted digests.
 
@@ -1681,11 +1794,14 @@ class NodeStore:
         until it commits — the periodic orphan sweep (repair loop) passes
         a generous age so it only reclaims chunks from genuinely
         abandoned streams (aborted chunked uploads), never from a live
-        one. Delete-triggered GC keeps age 0: explicit user intent."""
+        one. Delete-triggered GC keeps age 0: explicit user intent.
+        ``complete``: :meth:`boot_sweep`'s word that nothing is in
+        flight, handed to the listing (``ChunkStore.digests``)."""
         live: set[str] = set()
         for m in self.manifests.list():
             live.update(m.all_digests())   # incl. erasure parity chunks
-        return self.sweep_orphans(self.chunks.digests(), live, min_age_s)
+        return self.sweep_orphans(self.chunks.digests(complete), live,
+                                  min_age_s)
 
     def _with_delta_bases(self, live: set[str]) -> set[str]:
         """Delta-base pinning (similarity plane): a live delta-stored

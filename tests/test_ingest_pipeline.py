@@ -455,8 +455,8 @@ def test_reupload_through_another_coordinator_stats_no_known_chunk(
         tmp_path, rng, monkeypatch, index):
     """What every node holds it linked itself: a re-upload through
     another coordinator — its own pre-check, both peers' has_chunks —
-    issues no ``stat`` for a chunk name on any node, where the first
-    upload paid one a digest a holder; /metrics says so. With the index
+    issues no ``stat`` for a chunk name on any node, nor did the first
+    upload (until PR 44 one a digest a holder); /metrics says so. With the index
     plane on every node (since PR 39) the same, and no index lookup
     either: the resident set stands in front of the index."""
     from dfs_tpu.config import IndexConfig
@@ -477,10 +477,14 @@ def test_reupload_through_another_coordinator_stats_no_known_chunk(
             manifest, _ = await nodes[1].upload(data, "first.bin")
             digests = {c.digest for c in manifest.chunks}
             assert len(digests) > 300
-            # first sight: the holders' put pre-checks and the probes
-            assert {d for _, d in seen} == digests
-            assert all(n.durability_stats()["residentHits"] == 0
-                       for n in nodes.values())
+            # first sight: the holders' put pre-checks and the probes —
+            # answered "absent" from memory, a booted node's set being
+            # complete (PR 44): no stat of a name nobody has either
+            assert seen == []
+            for n in nodes.values():
+                dur = n.durability_stats()
+                assert dur["residentHits"] == 0 and dur["residentComplete"]
+                assert dur["residentAbsent"] == dur["residentMisses"] > 0
             held = {nid: set(n.store.chunks.digests())
                     for nid, n in nodes.items()}
             seen.clear()

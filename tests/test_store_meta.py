@@ -1258,6 +1258,403 @@ def test_a_look_at_the_disk_and_a_get_miss_heal_the_set(tmp_path, fsync):
 
 
 # ---------------------------------------------------------------------- #
+# the COMPLETE set (PR 44): a node's boot sweep seeds the resident set
+# from the listing it makes anyway; from then on a miss is the answer
+# "absent" — no stat, no isfile, no index lookup. A bare store (every
+# case above) never is.
+# ---------------------------------------------------------------------- #
+
+def _booted(tmp_path, fsync, index=False, held=()):
+    """A node's store over what a previous life left (``held``), its
+    boot sweep done — the index plane opened before it, as the node
+    does."""
+    ns = NodeStore(tmp_path, 1, fsync=fsync)
+    if held:
+        assert all(ChunkStore(ns.chunks.root, fsync=fsync).put_batch(held))
+    plane = _plane(tmp_path / "plane", ns.chunks) if index else None
+    ns.boot_sweep()
+    return ns, ns.chunks, plane
+
+
+def _name_stats(calls):
+    """The recorded ``stat``s of chunk names (``isfile`` and ``getsize``
+    are one each), temps and directories left out."""
+    return [(c, p) for c, p in calls.of("stat")
+            if len(p.rsplit("/", 1)[1]) == 64]
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_a_booted_store_answers_absent_and_present_from_memory(
+        tmp_path, monkeypatch, fsync, index):
+    """After the boot sweep: the names of a previous life are present
+    and the names nobody has are absent — for ``has_many(resident_ok)``
+    and for the put pre-check — with no ``stat`` of any chunk name and,
+    the plane attached, no lookup; the fresh names are then written and
+    present. ``residentAbsent`` counts inside ``residentMisses``."""
+    held = _batch(40, seed=20)
+    fresh = _batch(60, seed=21)
+    ns, cs, plane = _booted(tmp_path, fsync, index, held)
+    try:
+        assert cs._resident == {_key(d) for d, _ in held}
+        assert cs.resident_stats() == {
+            "residentHits": 0, "residentMisses": 0, "residentEntries": 40,
+            "residentDrops": 0, "residentAbsent": 0,
+            "residentComplete": True}
+        calls = _Calls(monkeypatch, cs.root)
+        asked = _lookups(plane) if index else 0
+        names = [d for d, _ in held + fresh]
+        assert cs.has_many(names, resident_ok=True) \
+            == [True] * 40 + [False] * 60
+        assert calls.events == []
+        assert cs.put_batch(held) == [False] * 40
+        assert calls.events == []
+        assert cs.put_batch(fresh) == [True] * 60       # written, and
+        assert _name_stats(calls) == []                 # never asked for
+        assert len(calls.of("link")) == 60
+        calls.events.clear()
+        assert cs.has_many(names, resident_ok=True) == [True] * 100
+        assert calls.events == []
+        assert (_lookups(plane) if index else 0) == asked
+        assert cs.resident_stats() == {
+            "residentHits": 40 + 40 + 100, "residentMisses": 60 + 60,
+            "residentEntries": 100, "residentDrops": 0,
+            "residentAbsent": 120, "residentComplete": True}
+        assert sorted(cs.digests()) == sorted(names) and _stale(cs) == []
+        assert all(cs.get(d) == data for d, data in held + fresh)
+        if index:
+            assert plane.stats()["statFallbacks"] == 0
+            assert cs.has_many(names) == [True] * 100   # recorded at the link
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_has_without_leave_still_looks_at_the_disk_of_a_booted_store(
+        tmp_path, monkeypatch, fsync, index):
+    """No ``resident_ok`` — the repair cycle, ``who_has``, relocation,
+    the smart client: a complete set changes nothing for them. The look
+    is the parent's (index off: a ``stat``; index on: a lookup, a
+    ``stat`` behind its negative), counts in neither ``residentMisses``
+    nor ``residentAbsent``, and heals the set both ways."""
+    held = _batch(3, seed=22)
+    (absent, _), = _batch(1, seed=23)
+    ns, cs, plane = _booted(tmp_path, fsync, index, held)
+    try:
+        calls = _Calls(monkeypatch, cs.root)
+        before = cs.resident_stats()
+        asked = _lookups(plane) if index else 0
+        assert cs.has(absent) is False
+        assert calls.events == [("stat", cs._path_str(absent))]
+        assert (_lookups(plane) - asked if index else 1) == 1
+        calls.events.clear()
+        # (index on: the previous life ran no plane, so the lookup's
+        # negative falls to the backstop's stat, which re-records it)
+        assert cs.has(held[0][0]) is True
+        assert calls.events == [("stat", cs._path_str(held[0][0]))]
+        assert (_lookups(plane) - asked if index else 2) == 2
+        assert cs.resident_stats() == before
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_complete_put_then_delete_then_put_across_threads(
+        tmp_path, monkeypatch, fsync):
+    """put → present, delete → absent, put again → written, each step on
+    a thread of its own, each answer from memory: the one ``stat`` of
+    the name is ``delete``'s ``getsize``."""
+    import threading
+    ns, cs, _ = _booted(tmp_path, fsync)
+    (d, data), = _batch(1, seed=24)
+    calls = _Calls(monkeypatch, cs.root)
+    out = []
+
+    def on_thread(fn, *args):
+        t = threading.Thread(target=lambda: out.append(fn(*args)))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+        return out.pop()
+
+    assert cs.has(d, resident_ok=True) is False
+    assert on_thread(cs.put, d, data) is True
+    assert cs.has(d, resident_ok=True) is True
+    assert on_thread(cs.put, d, data) is False
+    assert _name_stats(calls) == []
+    assert on_thread(cs.delete, d) is True
+    assert _name_stats(calls) == [("stat", cs._path_str(d))]
+    assert on_thread(cs.has, d, True) is False and cs._resident == set()
+    assert on_thread(cs.put, d, data) is True           # written again
+    assert cs.has(d, resident_ok=True) is True and cs.get(d) == data
+    assert len(_name_stats(calls)) == 1 and len(calls.of("link")) == 2
+    assert cs.resident_stats()["residentAbsent"] == 4   # 2 has, 2 pre-checks
+    assert cs.resident_stats()["residentComplete"] is True
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_a_file_added_by_another_hand_costs_a_redundant_write(
+        tmp_path, monkeypatch, fsync, index):
+    """The one thing a complete set lacks: a file another hand put into
+    the directory is "absent" from memory, so it is written again — a
+    write that loses its link (``[False]``: a dedup hit), leaves one
+    file with its bytes, and enters the name. A look at the disk enters
+    such a name too."""
+    ns, cs, plane = _booted(tmp_path, fsync, index)
+    try:
+        (a, da), (b, db) = _batch(2, seed=25)
+        other = ChunkStore(cs.root, fsync=fsync)        # another hand
+        assert other.put(a, da) and other.put(b, db)
+        assert cs.has_many([a, b], resident_ok=True) == [False, False]
+        calls = _Calls(monkeypatch, cs.root)
+        assert cs.put_batch([(a, da)]) == [False]
+        assert _name_stats(calls) == [] and len(calls.of("link")) == 1
+        assert sorted(cs.digests()) == sorted([a, b])
+        assert _temps(cs.root) == [] and cs.get(a) == da
+        assert _key(a) in cs._resident
+        assert cs.has(b) is True                        # a look at the disk
+        calls.events.clear()
+        assert cs.has_many([a, b], resident_ok=True) == [True, True]
+        assert calls.events == []
+        assert cs.resident_stats()["residentAbsent"] == 3
+        assert cs.count() == 2
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+def test_a_file_removed_by_another_hand_is_dropped_by_the_repair_look(
+        tmp_path, monkeypatch, fsync):
+    """The caveat's old face, unchanged: believed in until a look at the
+    disk (the repair cycle's ``has_many``, no ``resident_ok``) — which
+    drops it, after which "absent" comes from memory. The boot sweep's
+    aged orphans left the set through ``delete``."""
+    import os
+    import time
+    held = _batch(12, seed=26)
+    old = [d for d, _ in held[:2]]
+    ns = NodeStore(tmp_path, 1, fsync=fsync)
+    assert all(ns.chunks.put_batch(held))
+    for d in old:                                   # aborted streams' chunks
+        t = time.time() - 7200
+        os.utime(ns.chunks._path_str(d), (t, t))
+    ns, cs, _ = _booted(tmp_path, fsync)
+    names = [d for d, _ in held]
+    assert cs._resident == {_key(d) for d in names[2:]}
+    assert cs._unlinks == 2
+    gone = names[5]
+    os.unlink(cs._path_str(gone))                   # behind its back
+    assert cs.has_many(names, resident_ok=True) \
+        == [False] * 2 + [True] * 10                # the caveat
+    assert cs.has_many(names) \
+        == [d not in (*old, gone) for d in names]   # the repair cycle's look
+    calls = _Calls(monkeypatch, cs.root)
+    assert cs.has_many(names, resident_ok=True) \
+        == [d not in (*old, gone) for d in names]
+    assert calls.events == []
+    assert cs.resident_stats() == {
+        "residentHits": 10 + 9, "residentMisses": 2 + 3,
+        "residentEntries": 9, "residentDrops": 1, "residentAbsent": 5,
+        "residentComplete": True}
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_overflow_ends_completeness_and_the_disk_answers_again(
+        tmp_path, monkeypatch, fsync, index):
+    """At ``_RESIDENT_MAX`` the set is emptied and is complete no more:
+    a miss goes on to what stands behind the set, as in a bare store,
+    and the answers stay right."""
+    import dfs_tpu.store.cas as cas
+    monkeypatch.setattr(cas, "_RESIDENT_MAX", 64)
+    held = _batch(40, seed=27)
+    more = _batch(30, seed=28)
+    absent = [d for d, _ in _batch(20, seed=29)]
+    ns, cs, plane = _booted(tmp_path, fsync, index, held)
+    try:
+        assert cs.resident_stats()["residentComplete"] is True
+        assert cs.put_batch(more[:24]) == [True] * 24   # 64 held: full
+        calls = _Calls(monkeypatch, cs.root)
+        assert cs.has_many(absent, resident_ok=True) == [False] * 20
+        assert calls.events == []
+        assert cs.put_batch(more[24:]) == [True] * 6    # the 65th empties it
+        stats = cs.resident_stats()
+        assert stats["residentComplete"] is False
+        assert stats["residentEntries"] == 6
+        # (a batch's pre-checks all come before its first link)
+        assert stats["residentAbsent"] == 24 + 20 + 6
+        calls.events.clear()
+        asked = _lookups(plane) if index else 0
+        names = [d for d, _ in held] + absent
+        assert cs.has_many(names, resident_ok=True) \
+            == [True] * 40 + [False] * 20
+        # index on: a lookup a name, and a stat behind each negative (the
+        # absent ones; the previous life's too — it ran no plane)
+        assert (_lookups(plane) - asked if index else 60) == 60
+        assert calls.events == [("stat", cs._path_str(d)) for d in names]
+        assert cs.resident_stats()["residentAbsent"] == 50
+        assert cs.put_batch(held[:3]) == [False] * 3
+        assert _stale(cs) == []
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@pytest.mark.parametrize("why", ["an_unlink_ended_during_the_listing",
+                                 "more_names_than_the_bound"])
+def test_a_listing_that_cannot_vouch_declares_nothing(
+        tmp_path, monkeypatch, fsync, why):
+    """``_remember``'s rule for a look outside ``_dir_mu``, for the
+    listing as a whole: an unlink that ended while it was read, or more
+    names than ``_RESIDENT_MAX``, and nothing is entered and nothing
+    declared — the store answers from the disk, and says so."""
+    import dfs_tpu.store.cas as cas
+    held = _batch(30, seed=30)
+    ns = NodeStore(tmp_path, 1, fsync=fsync)
+    cs = ns.chunks
+    assert all(ChunkStore(cs.root, fsync=fsync).put_batch(held))
+    if why == "more_names_than_the_bound":
+        monkeypatch.setattr(cas, "_RESIDENT_MAX", 29)
+        ns.boot_sweep()
+        on_disk = [d for d, _ in held]
+    else:
+        real = cs._establish
+
+        def establish(raw, seen):
+            assert cs.delete(raw[0]) is True    # ended before the seeding
+            return real(raw, seen)
+        monkeypatch.setattr(cs, "_establish", establish)
+        ns.boot_sweep()
+        on_disk = sorted(d for d, _ in held)[1:]
+    assert cs._resident == set()
+    assert cs.resident_stats()["residentComplete"] is False
+    (absent, _), = _batch(1, seed=31)
+    calls = _Calls(monkeypatch, cs.root)
+    assert cs.has_many([absent, *on_disk], resident_ok=True) \
+        == [False] + [True] * len(on_disk)
+    assert calls.events == [("stat", cs._path_str(d))
+                            for d in [absent, *on_disk]]
+    assert cs.resident_stats()["residentAbsent"] == 0
+    monkeypatch.undo()
+    assert sorted(cs.digests(complete=True)) == sorted(on_disk)  # a quiet one
+    assert cs.resident_stats()["residentComplete"] is True
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_a_delta_stored_digest_is_present_to_a_complete_set(
+        tmp_path, monkeypatch, fsync, index):
+    """With a ``deltas/`` tree the set holds raw names only, and the
+    delta map is still asked behind a miss: a delta-stored digest is
+    present (and a dedup hit for a put), a name nobody has absent with
+    no ``stat`` of its raw name."""
+    from dfs_tpu.sim.delta import make_delta
+    (base_d, base), (delta, target), (absent, _) = _batch(3, seed=32)
+    ns = NodeStore(tmp_path, 1, fsync=fsync)
+    first = ns.chunks
+    assert first.put(base_d, base)
+    assert first._put_delta(delta, base_d, make_delta(base_d, base, target),
+                            raw_len=len(target)) is True
+    ns, cs, plane = _booted(tmp_path, fsync, index)
+    try:
+        assert cs._deltas_possible() and cs.delta_count() == 1
+        assert cs._resident == {_key(base_d)}
+        assert sorted(cs.digests()) == sorted([base_d, delta])
+        calls = _Calls(monkeypatch, cs.root)
+        assert cs.has_many([base_d, delta, absent], resident_ok=True) \
+            == [True, True, False]
+        assert cs.put_batch([(delta, target), (base_d, base)]) \
+            == [False, False]
+        # the chain's end is looked at (the base's raw file); the raw
+        # names of the delta and of the absent one never
+        assert set(_name_stats(calls)) <= {("stat", cs._path_str(base_d))}
+        assert cs.get(delta) == target
+        assert cs._resident == {_key(base_d)}
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+@pytest.mark.parametrize("fsync", [True, False], ids=["fsync", "none"])
+@_INDEX
+def test_a_complete_set_stays_the_disk_under_many_threads(
+        tmp_path, fsync, index):
+    """More threads than cores at a 10 µs switch interval over a booted
+    store: probes and pre-checks answered from memory while deleters
+    unlink and writers put back. At the end the set IS the directory —
+    no entry without its file, no file without its entry — still
+    complete, and every file holds its bytes."""
+    import os
+    import sys
+    import threading
+    pool = _batch(96, seed=33, size=32)
+    ns, cs, plane = _booted(tmp_path, fsync, index, pool[:48])
+    digests = [d for d, _ in pool]
+    n_threads = 2 * (os.cpu_count() or 4)
+    errors = []
+    stop = threading.Event()
+
+    def looker(k):
+        try:
+            while not stop.is_set():
+                cs.has_many(digests[k % 7::3], resident_ok=True)
+        except BaseException as e:
+            errors.append(e)
+
+    def churner(k):
+        try:
+            for _ in range(3):
+                for d, data in pool[k % 5::5]:
+                    cs.delete(d)
+                    if (int(d[:2], 16) + k) & 1:
+                        cs.put(d, data)
+        except BaseException as e:
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        lookers = [threading.Thread(target=looker, args=(k,))
+                   for k in range(n_threads)]
+        churners = [threading.Thread(target=churner, args=(k,))
+                    for k in range(n_threads)]
+        for t in lookers + churners:
+            t.start()
+        for t in churners:
+            t.join(120)
+        stop.set()
+        for t in lookers:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    try:
+        assert not errors
+        assert not any(t.is_alive() for t in lookers + churners)
+        on_disk = set(cs.digests())
+        assert 0 < len(on_disk) < 96
+        assert cs._resident == {_key(d) for d in on_disk}
+        stats = cs.resident_stats()
+        assert stats["residentComplete"] is True
+        assert stats["residentAbsent"] == stats["residentMisses"] > 0
+        assert stats["residentDrops"] == 0
+        assert cs.has_many(digests, resident_ok=True) \
+            == [d in on_disk for d in digests]
+        assert all(cs.get(d) == data for d, data in pool if d in on_disk)
+        assert cs._unbarriered == set() and _temps(cs.root) == []
+        if plane is not None:
+            assert cs.has_many(digests) == [d in on_disk for d in digests]
+    finally:
+        if plane is not None:
+            plane.close()
+
+
+# ---------------------------------------------------------------------- #
 # a look at the disk for a batch lists the directory (PR 35) — index off,
 # no resident_ok: the repair cycle's probe
 # ---------------------------------------------------------------------- #

@@ -67,8 +67,8 @@ CRASH_POINTS = frozenset({
     # Ingest._finalize: chunks durable, manifest NOT yet written — the
     # classic "after CAS put, before manifest" torn-upload window
     "upload.before_manifest",
-    # Ingest._finalize: manifest written (upload is durable), before
-    # the announce fan-out / HTTP ack
+    # Ingest._finalize: the LOCAL manifest written (upload is durable),
+    # the peers' announces possibly in flight, before the HTTP ack
     "upload.after_manifest",
     # _demote_file: parity durable at its stripe holders, the cold
     # manifest NOT yet written — the file must stay readable replicated

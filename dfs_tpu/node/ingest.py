@@ -601,31 +601,40 @@ class Ingest:
         return manifest, stats
 
     async def _finalize(self, manifest: Manifest) -> None:
-        # Manifest-last ordering (SURVEY.md §5.4), then best-effort announce
-        # (reference: announce failure only logged, StorageNode.java:338-346).
-        # A fresh upload clears tombstones (locally and via fresh=True at
-        # peers): re-uploading deleted content must resurrect the
-        # content-derived file id, not leave it permanently undownloadable.
-        # The save runs off-loop: with fsync durability it is a disk
-        # BARRIER (file + dir), and this is the write that acks the
-        # upload — the one moment the loop must not eat a barrier.
+        """The commit: ONE pass in a worker thread on each node, every
+        node's started at once (docs/ingest.md "The commit").
+
+        Manifest-last ordering (SURVEY.md §5.4) is the caller's: every
+        batch is placed and verified before this runs. Nothing orders
+        the nodes' saves among themselves, so the peers' announces
+        (best-effort — reference: a failure is only logged,
+        StorageNode.java:338-346) run BESIDE the local save, and the ack
+        waits for the slower of the two, not for their sum. Both saves
+        are fresh: a new upload clears tombstones (here and, through
+        ``fresh=True``, at the peers) — re-uploading deleted content
+        must resurrect the content-derived file id, not leave it
+        permanently undownloadable — inside the save's own pass
+        (``ManifestStore.save``). With fsync durability a save is a disk
+        BARRIER (file + dir) and this is the write that acks the upload:
+        no call of it runs on the loop.
+
+        A local save that fails leaves an unacked upload whose manifest
+        some peers hold — the mirror image of a crash at
+        ``upload.after_manifest`` — and the manifest pull and the
+        tombstones' last-writer-wins converge it as they do that one;
+        its chunks are durable on ``rf`` nodes either way."""
         if self.chaos is not None:
             self.chaos.maybe_crash("upload.before_manifest")
-        self.manifests.clear_tombstone(manifest.file_id)
-        try:
-            saved = await asyncio.to_thread(self.manifests.save, manifest)
-        except OSError as e:
-            self.placement.raise_if_disk_full(e)
-            raise
-        if not saved:
-            raise UploadError("manifest save refused (tombstone race)")
-        if self.chaos is not None:
-            self.chaos.maybe_crash("upload.after_manifest")
-        mj = manifest.to_json()          # once, not once per recipient
-        # the document as saved (ASCII: its characters are its bytes)
-        self.counters.inc("manifests_saved")
-        self.counters.inc("manifest_bytes", len(mj))
-        self.counters.inc("manifest_chunks", len(manifest.chunks))
+        mj = manifest.to_json()     # once: saved here, sent to every peer
+
+        async def local() -> bool:
+            with self.obs.span("commit.save"):
+                saved = await asyncio.to_thread(
+                    self.manifests.save, manifest, fresh=True, text=mj)
+            if saved and self.chaos is not None:
+                # local manifest durable, announces possibly in flight
+                self.chaos.maybe_crash("upload.after_manifest")
+            return saved
 
         async def announce(peer) -> None:
             try:
@@ -635,9 +644,27 @@ class Ingest:
                                  peer.node_id, e)
                 self.counters.inc("announce_failures")
 
-        await asyncio.gather(*(
-            announce(p) for p in self.cfg.cluster.peers
-            if p.node_id != self.cfg.node_id))
+        async def announce_all() -> None:
+            with self.obs.span("commit.announce"):
+                await asyncio.gather(*(
+                    announce(p) for p in self.cfg.cluster.peers
+                    if p.node_id != self.cfg.node_id))
+
+        # return_exceptions: a failed save still waits for the announces
+        # already sent — no task is left behind a failed upload
+        saved, told = await asyncio.gather(local(), announce_all(),
+                                           return_exceptions=True)
+        if isinstance(saved, OSError):
+            self.placement.raise_if_disk_full(saved)
+        for failed in (saved, told):
+            if isinstance(failed, BaseException):
+                raise failed
+        if not saved:
+            raise UploadError("manifest save refused (tombstone race)")
+        # the document as saved (ASCII: its characters are its bytes)
+        self.counters.inc("manifests_saved")
+        self.counters.inc("manifest_bytes", len(mj))
+        self.counters.inc("manifest_chunks", len(manifest.chunks))
         self.counters.inc("uploads")
 
 

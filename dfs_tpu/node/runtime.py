@@ -1001,10 +1001,10 @@ class StorageNodeServer:
             return {"ok": True, "filters": metas}, blobs
         if op == "announce":
             m = Manifest.from_json(header["manifest"])
-            if header.get("fresh"):
-                self.store.manifests.clear_tombstone(m.file_id)
             # off-loop: with fsync durability the save is a disk barrier
-            if await asyncio.to_thread(self.store.manifests.save, m):
+            # — and a fresh one clears the tombstone in the same pass
+            if await asyncio.to_thread(self.store.manifests.save, m,
+                                       fresh=bool(header.get("fresh"))):
                 self.counters.inc("manifests_announced")
             else:
                 self.counters.inc("announce_rejected_tombstoned")

@@ -1602,6 +1602,7 @@ class ManifestStore:
         # manifest — one global mutex would queue every concurrent
         # ack's disk barrier behind one file's.
         self._mu = tuple(threading.Lock() for _ in range(16))
+        self._tmp_seq = itertools.count()  # cheap unique tmp names
 
     def _lock(self, file_id: str) -> threading.Lock:
         return self._mu[int(file_id[:2], 16) & 15]
@@ -1620,9 +1621,8 @@ class ManifestStore:
         return self._tomb_path(file_id).exists()
 
     def clear_tombstone(self, file_id: str) -> None:
-        """A fresh upload of previously-deleted content resurrects the
-        file id intentionally; without this, a content-derived file_id
-        would be permanently unuploadable after one delete."""
+        """One unlink (``save(fresh=True)`` makes it under the id's
+        lock, in its worker thread — never an event loop)."""
         self._tomb_path(file_id).unlink(missing_ok=True)
 
     def tombstones(self) -> list[str]:
@@ -1636,9 +1636,20 @@ class ManifestStore:
         return sorted(p.stem for p in self.root.glob("*.tomb")
                       if is_hex_digest(p.stem))
 
-    def save(self, m: Manifest, mtime: float | None = None) -> bool:
+    def save(self, m: Manifest, mtime: float | None = None, *,
+             fresh: bool = False, text: str | None = None) -> bool:
         """Persist a manifest; refused (False) when the file is
         tombstoned, so late announces cannot resurrect a deleted file.
+
+        ``fresh`` marks the save of an upload in progress (the
+        coordinator's commit, a peer's ``announce`` with ``fresh``): a
+        new upload resurrects deleted content on purpose — without this
+        a content-derived file id would be unuploadable after one
+        delete — so the tombstone is cleared HERE, under the id's lock
+        and in the caller's worker thread, and a save that has just
+        cleared it does not ask whether one exists. ``text`` is
+        ``m.to_json()`` where the caller has made it already (the
+        coordinator announces the same text).
 
         ``mtime`` carries the ORIGIN write time when a manifest is being
         ADOPTED from a peer (anti-entropy / download fallback): the file
@@ -1646,10 +1657,13 @@ class ManifestStore:
         stamping adoption time instead would make an adopted stale
         manifest look newer than a legitimate delete."""
         with self._lock(m.file_id):   # atomic vs delete() — __init__
-            if self.is_tombstoned(m.file_id):
+            if fresh:
+                self.clear_tombstone(m.file_id)
+            elif self.is_tombstoned(m.file_id):
                 return False
-            p = self._path(m.file_id)
-            _atomic_write(p, m.to_json().encode(), fsync=self._fsync)
+            p = os.fspath(self._path(m.file_id))
+            self._replace(p, (m.to_json() if text is None
+                              else text).encode())
             if mtime is not None:
                 os.utime(p, (mtime, mtime))
                 if self._fsync:
@@ -1658,8 +1672,49 @@ class ManifestStore:
                     # write time would make this adopted manifest beat
                     # a legitimate delete; utime is metadata the write
                     # fsync above did not cover
-                    _fsync_path(os.fspath(p))
+                    _fsync_path(p)
             return True
+
+    def _replace(self, path: str, data: bytes) -> None:
+        """A manifest's durable replace in the calls it needs and no
+        other — temp (``O_EXCL``) → write → fsync → close → replace →
+        the directory's barrier: payload durable, name visible, name
+        durable, return. ``_atomic_write`` beside it pays a ``makedirs``
+        of a root ``__init__`` made, ``mkstemp``'s name search and
+        ``fdopen``'s ``fstat`` for every save, and every node saves
+        every upload's manifest. Temp names as ``ChunkStore._write_raw``
+        makes them (the comment there says why not ``mkstemp``);
+        ``sweep_tmp`` reclaims one a crash leaves."""
+        root = os.fspath(self.root)
+        while True:
+            tmp = f"{root}/.tmp-{os.getpid()}-{next(self._tmp_seq)}"
+            try:
+                fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_EXCL,
+                             0o600)
+                break
+            except FileExistsError:
+                continue
+        try:
+            try:
+                view = memoryview(data)
+                done = os.write(fd, view)
+                while done < len(view):   # short write: disk nearly full
+                    done += os.write(fd, view[done:])
+                if self._fsync:
+                    os.fsync(fd)          # payload durable BEFORE the name
+            finally:
+                os.close(fd)
+            os.replace(tmp, path)
+        except BaseException:
+            try:
+                os.unlink(tmp)
+            # cleanup inside an unwinding write: the original error
+            # re-raises below; a temp left here the aged sweep reclaims
+            except OSError:  # dfslint: ignore[DFS007]
+                pass
+            raise
+        if self._fsync:
+            _fsync_path(root)
 
     def ids(self) -> list[str]:
         """File ids present, from filenames alone — no reads/parses (the
@@ -1725,9 +1780,9 @@ class ManifestStore:
                 return None
 
     def sweep_tmp(self, max_age_s: float = _TMP_SWEEP_AGE_S) -> int:
-        """Reclaim crash-leaked ``_atomic_write`` temps (crash between
-        mkstemp and replace) — same hour age gate as the chunk store
-        (and the same boot-sweep exception)."""
+        """Reclaim crash-leaked temps (a crash between a save's or a
+        tombstone write's create and its replace) — same hour age gate
+        as the chunk store (and the same boot-sweep exception)."""
         return _sweep_tmp_files([self.root], max_age_s)
 
     def mtime(self, file_id: str) -> float | None:

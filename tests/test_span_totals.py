@@ -430,7 +430,8 @@ def test_streamed_upload_stitches_the_owner_into_one_tree(tmp_path, owner):
     names = {s["name"] for s in spans}
     assert {"http./upload", "upload.body", "upload.fragment",
             "owner.stream", "owner.dispatch", "owner.collect",
-            "upload.place", "upload.commit"} <= names
+            "upload.place", "upload.commit", "commit.save",
+            "commit.announce"} <= names
     assert all(isinstance(s.get("m0"), int) for s in spans)
 
     def one(name):
@@ -443,6 +444,9 @@ def test_streamed_upload_stitches_the_owner_into_one_tree(tmp_path, owner):
                  "upload.commit"):
         assert by_id[one(name)["p"]]["name"] == "http./upload"
     assert by_id[one("upload.replicate")["p"]]["name"] == "upload.place"
+    # the commit's two sides, started together under the one span
+    for name in ("commit.save", "commit.announce"):
+        assert by_id[one(name)["p"]]["name"] == "upload.commit"
     # one tree: a single root line, nothing orphaned but the client's
     # own root span (which lives in no ring)
     roots = [s for s in spans if s["p"] not in by_id]
@@ -453,7 +457,8 @@ def test_streamed_upload_stitches_the_owner_into_one_tree(tmp_path, owner):
             assert by_id[s["p"]]["m0"] <= s["m0"]
     totals = metrics["obs"]["spans"]
     for name in ("http./upload", "upload.body", "upload.fragment",
-                 "upload.place", "upload.commit", "cas.put_many"):
+                 "upload.place", "upload.commit", "commit.save",
+                 "commit.announce", "cas.put_many"):
         assert totals[name]["count"] >= 1
         assert totals[name]["selfSeconds"] <= totals[name]["seconds"]
     stalls = metrics["ingest"]["stalls"]

@@ -394,11 +394,11 @@ def test_promotion_racing_a_redemotion_keeps_parity(tmp_path):
             at_flip, go_on = threading.Event(), threading.Event()
             save = n1.store.manifests.save
 
-            def held_save(manifest):
+            def held_save(manifest, *a, **kw):
                 if manifest.file_id == fid and manifest.tier == "cold":
                     at_flip.set()
                     assert go_on.wait(30)
-                return save(manifest)
+                return save(manifest, *a, **kw)
 
             n1.store.manifests.save = held_save
             try:

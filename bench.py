@@ -9,8 +9,8 @@ well under 1 GiB/s, but unmeasurable here — no JDK, SURVEY.md preamble).
 
 Measures the **anchored two-level CDC pipeline** (dfs_tpu.ops.cdc_anchored)
 — the production flagship: byte-granular content anchors re-sync the chunk
-grid after unaligned edits (dedup ratio: bench_dedup.py, latest artifact
-DEDUP_r03.json) while chunk+hash runs as the fused device chain
+grid after unaligned edits (what that stores: the benchmark's
+``stored_ratio``, PERF.md) while chunk+hash runs as the fused device chain
 anchor-hash -> segment-select -> lane repack -> windowed-Gear candidates ->
 lane-parallel selection -> strip-scan SHA-256 (Pallas, 8 blocks per grid
 step) -> on-device compaction with device-side offsets. The chain

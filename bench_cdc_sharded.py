@@ -25,7 +25,7 @@ data-plane gap). Two phases on one chart-ready schema:
    round-trip exactly (file_id == sha256(body) is re-checked).
 
 Acceptance (full mode): stream scaling at 4 devices >= 1.7x the
-single-device streaming rate (the rolling strategy's r10 bar), byte
+single-device streaming rate, byte
 identity everywhere. ``--tiny`` is the tier-1 smoke (seconds): same
 schema and machinery on a small geometry at 1-2 devices, identity gated,
 perf reported but not gated (CI hosts stall unpredictably; the committed

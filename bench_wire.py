@@ -22,34 +22,23 @@ Two claims on one chart-ready schema, plus a correctness gate:
    Both receivers run the same LIGHTWEIGHT dispatch (validate + echo the
    claimed digests — no hashing, no disk): the bench isolates the wire
    path; the full store path's hash/disk cost is identical in both arms
-   and only dilutes the ratio (phase 3 gates correctness through the
+   and only dilutes the ratio (phase 2 gates correctness through the
    real path).
 
-2. **cdc** — resident multi-device CDC+hash GiB/s vs device count on a
-   virtual CPU mesh (one fresh subprocess per count, the
-   MULTICHIP_SCALE_r05.json methodology): a 64 MiB region through
-   ``make_sharded_step`` (windowed Gear bitmap + SHA-256 states, halo
-   over the sp ring), intra-op threading pinned to ONE thread per
-   device so the scaling claim is the DEVICE axis, not a hidden
-   thread pool. Wall-clock on a shared-host mesh — honest per the
-   committed MULTICHIP_SCALE scope note. The largest count also runs
-   the full reconstruction gate: bitmap == the single-device NumPy
-   oracle, device digests == hashlib, and greedy cuts reassembled ==
-   the original bytes.
-
-3. **identity** — a real 3-node in-process cluster ingests a stream
+2. **identity** — a real 3-node in-process cluster ingests a stream
    through the r10 wire (hash echo, CAS, replication all live) and a
    DIFFERENT node serves it back: sha256(download) == sha256(upload).
 
-Acceptance (full mode): sg >= 1.3x joined at 64 KiB chunks, 4-device
-CDC >= 1.8x single-device, byte identity everywhere. ``--tiny`` is the
-tier-1 smoke (seconds): same schema, machinery + identity gated, perf
-reported but not gated (CI hosts stall unpredictably; the committed
-artifact carries the perf claim) and the CDC phase drops to 2 devices
-on a small region.
+Acceptance (full mode): sg >= 1.3x joined at 64 KiB chunks, byte identity
+everywhere. ``--tiny`` is the tier-1 smoke (seconds): same schema,
+machinery + identity gated, perf reported but not gated (CI hosts stall
+unpredictably; the committed artifact carries the perf claim).
+
+(Until PR 46 a middle phase timed the Gear bitmap step sharded over a
+virtual CPU mesh; it went with that engine, and its block with it from
+the committed artifact.)
 
 Usage: python bench_wire.py [--tiny] [--out PATH]
-(internal: --cdc-worker N runs one mesh size in a fresh process)
 """
 
 from __future__ import annotations
@@ -57,19 +46,7 @@ from __future__ import annotations
 import os
 import sys
 
-# --cdc-worker must configure XLA BEFORE any jax import (fresh process)
-if "--cdc-worker" in sys.argv:
-    _n = int(sys.argv[sys.argv.index("--cdc-worker") + 1])
-    os.environ["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={_n} "
-        "--xla_cpu_multi_thread_eigen=false "
-        "intra_op_parallelism_threads=1 "
-        + os.environ.get("XLA_FLAGS", ""))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from dfs_tpu.utils.device import enable_compile_cache  # noqa: E402
-
-enable_compile_cache()      # workers re-run this file: they share it
 
 import argparse          # noqa: E402
 import asyncio           # noqa: E402
@@ -77,7 +54,6 @@ import json              # noqa: E402
 import signal            # noqa: E402
 import socket            # noqa: E402
 import struct            # noqa: E402
-import subprocess        # noqa: E402
 import time              # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -89,11 +65,9 @@ WINDOW = 2
 
 FULL = dict(chunk_sizes=(64 * 1024, 256 * 1024, 1024 * 1024,
                          4 * 1024 * 1024),
-            wire_total=768 * 2**20, cdc_devices=(1, 2, 4),
-            cdc_region=64 * 2**20, ident_total=24 * 2**20)
+            wire_total=768 * 2**20, ident_total=24 * 2**20)
 TINY = dict(chunk_sizes=(64 * 1024, 1024 * 1024),
-            wire_total=48 * 2**20, cdc_devices=(),
-            cdc_region=0, ident_total=2 * 2**20)
+            wire_total=48 * 2**20, ident_total=2 * 2**20)
 
 
 def log(msg: str) -> None:
@@ -294,100 +268,7 @@ def wire_phase(p: dict) -> dict:
 
 
 # ------------------------------------------------------------------ #
-# phase 2 — sharded CDC resident throughput (fresh process per count)
-# ------------------------------------------------------------------ #
-
-def cdc_worker(n_dev: int, region: int, check: bool) -> int:
-    import jax
-
-    from dfs_tpu.config import CDCParams
-    from dfs_tpu.ops.sha256_jax import pad_messages, state_to_hex
-    from dfs_tpu.parallel.mesh import make_mesh
-    from dfs_tpu.parallel.sharded_cdc import make_sharded_step, shard_inputs
-    from dfs_tpu.utils.hashing import gear_table, sha256_many_hex
-
-    params = CDCParams()
-    table = gear_table(params.seed)
-    mesh = make_mesh(n_dev, dp=1)
-    msg = 8192                       # one hashed message per avg chunk
-    n_msgs = region // msg
-    rng = np.random.default_rng(3)
-    data = rng.integers(0, 256, size=(1, region), dtype=np.uint8)
-    flat = data.reshape(-1)
-    msgs = [flat[i * msg:(i + 1) * msg].tobytes() for i in range(n_msgs)]
-    words, nblocks = pad_messages(msgs, n_blocks=msg // 64 + 1,
-                                  batch=n_msgs)
-    step = make_sharded_step(mesh, table, params.mask)
-    inp = shard_inputs(mesh, data, words, nblocks)
-    out = jax.block_until_ready(step(*inp))     # compile + warm
-    best = float("inf")
-    for _ in range(5):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(step(*inp))
-        best = min(best, time.perf_counter() - t0)
-    rec = {"devices": n_dev, "region_bytes": region,
-           "seconds": round(best, 4),
-           "gibps": round(region / best / 2**30, 4)}
-    if check:
-        bitmap, state, n_cand = out
-        bitmap = np.asarray(bitmap)[0]
-        from dfs_tpu.fragmenter.cdc_cpu import gear_bitmap_numpy
-        from dfs_tpu.ops.boundary import cuts_to_spans, select_cuts
-        if not np.array_equal(bitmap,
-                              gear_bitmap_numpy(flat, table, params.mask)):
-            raise AssertionError("sharded bitmap != single-device oracle")
-        if state_to_hex(np.asarray(state)) != sha256_many_hex(msgs):
-            raise AssertionError("device digests != hashlib")
-        if int(n_cand) != int(bitmap.sum()):
-            raise AssertionError("candidate psum mismatch")
-        # greedy cuts -> spans tile the stream -> reassembly is
-        # byte-identical (the bench's download==upload analogue for the
-        # resident pipeline; phase 3 gates the full storage path)
-        spans = cuts_to_spans(select_cuts(bitmap, region, params.min_size,
-                                          params.max_size))
-        assert spans[-1][0] + spans[-1][1] == region
-        joined = b"".join(flat[o:o + ln].tobytes() for o, ln in spans)
-        if sha256_many_hex([joined]) != sha256_many_hex([flat.tobytes()]):
-            raise AssertionError("reconstructed spans != original bytes")
-        rec["chunks"] = len(spans)
-        rec["reconstruction_ok"] = True
-    print(json.dumps(rec))
-    return 0
-
-
-def cdc_phase(p: dict) -> dict:
-    out: dict = {"region_bytes": p["cdc_region"],
-                 "methodology": ("virtual CPU mesh, one intra-op thread "
-                                 "per device (MULTICHIP_SCALE_r05.json "
-                                 "scope: wall-clock, host-bound)"),
-                 "devices": [], "gibps": []}
-    if not p["cdc_devices"]:
-        out["skipped"] = "tiny mode"
-        return out
-    for n in p["cdc_devices"]:
-        check = n == max(p["cdc_devices"])
-        cmd = [sys.executable, __file__, "--cdc-worker", str(n),
-               "--cdc-region", str(p["cdc_region"])]
-        if check:
-            cmd.append("--cdc-check")
-        log(f"  cdc devices={n} (fresh process)…")
-        res = subprocess.run(cmd, capture_output=True, text=True,
-                             timeout=1800)
-        if res.returncode != 0:
-            raise RuntimeError(f"cdc worker failed:\n{res.stderr[-2000:]}")
-        rec = json.loads(res.stdout.strip().splitlines()[-1])
-        log(f"  cdc devices={n}: {rec['gibps']} GiB/s")
-        out["devices"].append(n)
-        out["gibps"].append(rec["gibps"])
-        if check:
-            out["reconstruction_ok"] = rec.get("reconstruction_ok", False)
-            out["chunks"] = rec.get("chunks")
-    out["scale_max_devices"] = round(out["gibps"][-1] / out["gibps"][0], 3)
-    return out
-
-
-# ------------------------------------------------------------------ #
-# phase 3 — byte identity through the real storage path
+# phase 2 — byte identity through the real storage path
 # ------------------------------------------------------------------ #
 
 async def _identity(root: Path, total: int) -> bool:
@@ -437,16 +318,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="tier-1 smoke: machinery+identity gated, perf "
                          "reported but not gated")
     ap.add_argument("--out", default=None)
-    ap.add_argument("--cdc-worker", type=int, default=None,
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--cdc-region", type=int, default=64 * 2**20,
-                    help=argparse.SUPPRESS)
-    ap.add_argument("--cdc-check", action="store_true",
-                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
-    if args.cdc_worker is not None:
-        return cdc_worker(args.cdc_worker, args.cdc_region,
-                          args.cdc_check)
     p = TINY if args.tiny else FULL
 
     import tempfile
@@ -455,9 +327,7 @@ def main(argv: list[str] | None = None) -> int:
                  "mode": "tiny" if args.tiny else "full"}
     log("phase 1: wire — joined vs scatter-gather…")
     out["wire"] = wire_phase(p)
-    log("phase 2: sharded CDC resident throughput…")
-    out["cdc"] = cdc_phase(p)
-    log("phase 3: byte identity through the real path…")
+    log("phase 2: byte identity through the real path…")
     base = "/dev/shm" if os.path.isdir("/dev/shm") \
         and os.access("/dev/shm", os.W_OK) else None
     with tempfile.TemporaryDirectory(prefix="bench_wire_",
@@ -468,13 +338,9 @@ def main(argv: list[str] | None = None) -> int:
     if args.tiny:
         out["ok"] = bool(out["byte_identical"])
     else:
-        out["ok"] = bool(
-            out["byte_identical"]
-            and out["cdc"].get("reconstruction_ok", False)
-            and out["wire"]["speedup_64k"] >= 1.3
-            and out["cdc"]["scale_max_devices"] >= 1.8)
-    log(f"ok={out['ok']} wire_speedup={out['wire']['speedup']} "
-        f"cdc={out['cdc'].get('gibps')}")
+        out["ok"] = bool(out["byte_identical"]
+                         and out["wire"]["speedup_64k"] >= 1.3)
+    log(f"ok={out['ok']} wire_speedup={out['wire']['speedup']}")
 
     path = args.out or (None if args.tiny
                         else Path(__file__).parent / ART)

@@ -8,8 +8,8 @@ TPU-first:
 
 - the reference's fixed-N positional fragmenter (StorageNode.java:138-171)
   becomes a pluggable :class:`~dfs_tpu.fragmenter.Fragmenter` interface whose
-  TPU backend runs content-defined chunking (Gear rolling hash) and batched
-  SHA-256 as JAX/XLA uint32 kernels (``dfs_tpu.ops``);
+  TPU backend runs anchored content-defined chunking and strip-scan SHA-256
+  as one JAX/Pallas chain (``dfs_tpu.ops``);
 - fragments become content-addressed chunks in a dedup-capable store
   (``dfs_tpu.store``), with chunk-granular manifests (``dfs_tpu.meta``) fixing
   the reference defect of digests not being persisted (StorageNode.java:620-626);

@@ -17,8 +17,8 @@ import sys
 from pathlib import Path
 
 from dfs_tpu.cli.client import DEFAULT_TIMEOUT_S, NodeClient
-from dfs_tpu.config import (CDCParams, CensusConfig, ChaosConfig,
-                            ClusterConfig, DurabilityConfig,
+from dfs_tpu.config import (FRAGMENTER_KINDS, CDCParams, CensusConfig,
+                            ChaosConfig, ClusterConfig, DurabilityConfig,
                             FragmenterConfig, IndexConfig, IngestConfig,
                             NodeConfig, ObsConfig, RingConfig,
                             ServeConfig, SimConfig, TierConfig)
@@ -595,20 +595,16 @@ def build_parser() -> argparse.ArgumentParser:
     serve.add_argument("--replication-factor", type=int, default=None)
     serve.add_argument("--data-root", default="data")
     serve.add_argument(
-        "--fragmenter", default="auto",
-        choices=["auto", "fixed", "cdc", "cdc-tpu", "cdc-aligned",
-                 "cdc-aligned-tpu", "cdc-anchored", "cdc-anchored-tpu"],
-        help="default 'auto': the flagship anchored pipeline — TPU device "
-             "path when a TPU is present, CPU oracle otherwise")
+        "--fragmenter", default="auto", choices=FRAGMENTER_KINDS,
+        help="default 'auto': the anchored chunker — its TPU chain when "
+             "a TPU is present, its CPU engine otherwise")
     serve.add_argument("--cdc-devices", type=int, default=0,
-                       help="shard 'cdc' / 'cdc-anchored' streaming "
-                            "regions over N JAX devices (0/1 = single-"
-                            "device; boundaries are byte-identical "
-                            "either way)")
+                       help="shard the 'cdc-anchored' streaming walk "
+                            "over N JAX devices (0/1 = single-device; "
+                            "boundaries are byte-identical either way)")
     serve.add_argument("--cdc-region-bytes", type=int, default=0,
-                       help="fixed device-region size for sharded CDC "
-                            "(0 = devices * 1 MiB rolling / 64 MiB "
-                            "anchored)")
+                       help="fixed device-region size for the sharded "
+                            "walk (0 = 64 MiB split across the devices)")
     serve.add_argument("--cdc-staging-buffers", type=int, default=2,
                        help="host staging buffers the sharded anchored "
                             "walk cycles through (2 = double-buffered "
@@ -903,10 +899,8 @@ def build_parser() -> argparse.ArgumentParser:
 
     sc = sub.add_parser("sidecar", help="run the chunk+hash sidecar service")
     sc.add_argument("--sidecar-port", type=int, default=50151)
-    sc.add_argument(
-        "--fragmenter", default="auto",
-        choices=["auto", "fixed", "cdc", "cdc-tpu", "cdc-aligned",
-                 "cdc-aligned-tpu", "cdc-anchored", "cdc-anchored-tpu"])
+    sc.add_argument("--fragmenter", default="auto",
+                    choices=FRAGMENTER_KINDS)
     sc.add_argument("--min-chunk", type=int, default=2048)
     sc.add_argument("--avg-chunk", type=int, default=8192)
     sc.add_argument("--max-chunk", type=int, default=65536)

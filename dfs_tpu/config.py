@@ -15,23 +15,30 @@ import json
 from pathlib import Path
 
 # uint32 Gear hash with shift-1 forgets bytes older than 32 positions (they
-# shift out mod 2**32): the effective window, and the halo threaded between
-# stream tiles / exchanged between sp-ring neighbors. Defined here (jax-free)
-# so CPU-only deployments never import jax.
+# shift out mod 2**32): the effective window of the ``cdc`` kind, and the
+# halo its streaming walk threads between tiles.
 GEAR_WINDOW = 32
 GEAR_HALO = GEAR_WINDOW - 1
+
+# what NodeConfig.fragmenter / --fragmenter may name (fragmenter/base.py
+# get_fragmenter builds them, and says why there are these)
+FRAGMENTER_KINDS = ("auto", "fixed", "cdc", "cdc-anchored",
+                    "cdc-anchored-tpu")
 
 
 @dataclasses.dataclass(frozen=True)
 class CDCParams:
-    """Content-defined-chunking parameters (Gear rolling hash).
+    """Content-defined-chunking parameters: the ``cdc`` kind's own (Gear
+    rolling hash), and the operator's chunk sizing for the anchored kinds,
+    which quantize it to 64-byte blocks (fragmenter/base.py
+    ``_anchored_params``).
 
     ``avg_size`` must be a power of two: the boundary test is
     ``(gear_hash & (avg_size - 1)) == 0`` which fires with probability
     1/avg_size per byte. ``window`` is fixed at 32 because the uint32 Gear
     hash with shift-1 forgets bytes older than 32 positions (they shift out
-    mod 2**32) — this is what makes the TPU bitmap computation exactly equal
-    to the sequential CPU rolling hash.
+    mod 2**32) — this is what makes the windowed bitmap exactly equal to
+    the sequential rolling hash.
     """
 
     min_size: int = 2048
@@ -144,26 +151,23 @@ class FragmenterConfig:
     (device sharding), vs :class:`CDCParams`' *what it computes* (chunk
     boundaries, which these knobs must never change).
 
-    ``devices > 1`` shards streaming-CDC regions over that many JAX
-    devices: the ROLLING ``cdc`` strategy via ``parallel/sharded_cdc.
-    make_sharded_bitmap_step`` (the 31-byte Gear halo rides the sp ring
-    via ppermute; the stream's region-to-region halo is carried in
-    host-side), and the flagship ANCHORED strategy via the sharded
-    anchor/segment passes (``make_anchored_anchor_step`` /
-    ``make_anchored_step``, fragmenter/cdc_anchored_sharded.py) — chunk
-    boundaries stay BYTE-IDENTICAL to the single-device path by
-    construction (tests/test_sharded_ingest.py asserts it). With fewer
-    devices visible than asked, the fragmenter logs once and runs
-    single-device.
+    ``devices > 1`` means one thing: the ``cdc-anchored`` region walk over
+    that many JAX devices, whole stream windows riding the mesh's dp axis
+    (``parallel/sharded_cdc.make_anchored_window_anchor_step`` /
+    ``make_anchored_window_step``, fragmenter/cdc_anchored_sharded.py) —
+    chunk boundaries stay BYTE-IDENTICAL to the single-device path by
+    construction (tests/test_sharded_ingest.py asserts it). Any other
+    kind says at start-up that it ignores the knob. With fewer devices
+    visible than asked, the CPU rehearsal (``JAX_PLATFORMS=cpu``) logs
+    once and runs single-device; anywhere else that is an error.
     """
 
     devices: int = 0        # 0/1 = single-device CDC; N > 1 = shard
                             # regions over N JAX devices when visible
     region_bytes: int = 0   # fixed device-region size streaming input is
                             # re-blocked to (the sharded step compiles
-                            # ONCE for this shape); 0 = devices * 1 MiB
-                            # (rolling) / 64 MiB split across the
-                            # window batch (anchored)
+                            # ONCE for this shape); 0 = 64 MiB split
+                            # across the window batch
     staging_buffers: int = 2  # host staging buffers the sharded anchored
                             # walk cycles through: 2 = double-buffered
                             # (device_put region k+1 while region k
@@ -171,10 +175,8 @@ class FragmenterConfig:
 
     def __post_init__(self) -> None:
         # no cross-field region/devices constraint here: alignment is
-        # strategy-owned (the rolling walk floors the region to a
-        # devices multiple, the anchored walk to the anchor tile — both
-        # via sharded_common.fixed_region_bytes), and a rule written
-        # for one strategy rejected valid configs of the other
+        # the walk's own (the anchor tile, via
+        # sharded_common.fixed_region_bytes)
         if self.devices < 0:
             raise ValueError("devices must be >= 0")
         if self.region_bytes < 0:
@@ -777,15 +779,15 @@ class NodeConfig:
     node_id: int
     cluster: ClusterConfig
     data_root: Path
-    fragmenter: str = "auto"       # "auto" (flagship: anchored, TPU when
-                                   # present) | "fixed" | "cdc" | "cdc-tpu"
-                                   # | "cdc-aligned[-tpu]"
+    fragmenter: str = "auto"       # "auto" (anchored, TPU when present)
+                                   # | "fixed" | "cdc"
                                    # | "cdc-anchored[-tpu]"
+                                   # (FRAGMENTER_KINDS)
     sidecar_port: int | None = None  # delegate chunk+hash to a sidecar
                                      # process (overrides `fragmenter`)
     cdc: CDCParams = dataclasses.field(default_factory=CDCParams)
-    # fragmenter execution knobs (multi-device CDC sharding); the default
-    # FragmenterConfig() is the historical single-device behavior
+    # fragmenter execution knobs (the anchored walk over several devices);
+    # the default FragmenterConfig() is single-device
     frag: FragmenterConfig = dataclasses.field(
         default_factory=FragmenterConfig)
     fixed_parts: int = 5           # FixedFragmenter part count (reference: TOTAL_NODES=5)

@@ -1,16 +1,9 @@
-"""Fragmenter plugins. TpuCdcFragmenter is exported lazily so that CPU-only
-storage nodes (fragmenter='fixed'|'cdc') never import jax."""
+"""Fragmenter plugins. Importing this package imports no jax: the anchored
+engines load theirs when built (fragmenter/base.py get_fragmenter)."""
 
 from dfs_tpu.fragmenter.base import Fragmenter, get_fragmenter  # noqa: F401
 from dfs_tpu.fragmenter.cdc_cpu import CpuCdcFragmenter  # noqa: F401
 from dfs_tpu.fragmenter.fixed import FixedFragmenter  # noqa: F401
 
 __all__ = ["Fragmenter", "get_fragmenter", "CpuCdcFragmenter",
-           "FixedFragmenter", "TpuCdcFragmenter"]
-
-
-def __getattr__(name):
-    if name == "TpuCdcFragmenter":
-        from dfs_tpu.fragmenter.cdc_tpu import TpuCdcFragmenter
-        return TpuCdcFragmenter
-    raise AttributeError(name)
+           "FixedFragmenter"]

@@ -6,16 +6,22 @@ plugin: the node runtime calls ``chunk(data)`` and gets back content-addressed
 chunk metadata; everything downstream (manifest, placement, replication,
 download, dedup) is strategy-agnostic.
 
-Implementations:
-- FixedFragmenter   — reference-equivalent positional split (CPU).
-- CpuCdcFragmenter  — Gear-hash content-defined chunking, NumPy (the oracle).
-- TpuCdcFragmenter  — the same chunking as batched JAX/XLA TPU kernels.
+The kinds (:data:`FRAGMENTER_KINDS`, :func:`get_fragmenter`):
+- ``fixed``            — reference-equivalent positional split (host).
+- ``cdc``              — byte-granular Gear content-defined chunking on the
+                         host (NumPy / C++), no JAX: the source's CPU CDC
+                         plugin.
+- ``cdc-anchored``     — the anchored two-level chunker on the host.
+- ``cdc-anchored-tpu`` — the same chunker as ONE chunk+hash chain on the
+                         device (ops/cdc_anchored.py): what the chip runs.
+- ``auto``             — the anchored pair, by what the machine has.
 """
 
 from __future__ import annotations
 
 import abc
 
+from dfs_tpu.config import FRAGMENTER_KINDS
 from dfs_tpu.meta.manifest import ChunkRef, Manifest
 from dfs_tpu.utils.hashing import sha256_hex
 
@@ -132,7 +138,8 @@ def _aligned_from_cdc(cdc_params):
     """CDCParams byte sizes -> 64-byte block units (quantized); grow the
     strip to fit large --max-chunk values (strips must hold at least one
     max-size chunk, and stay 128-block-aligned for the device compaction
-    tiling)."""
+    tiling): CLI values that are legal for ``cdc`` must not crash the
+    start-up of a node on an anchored kind."""
     from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
 
     max_blocks = max(1, cdc_params.max_size // 64)
@@ -214,49 +221,38 @@ def _anchored_params(cdc_params):
 def get_fragmenter(kind: str, *, cdc_params=None, fixed_parts: int = 5,
                    frag=None) -> Fragmenter:
     """Factory keyed by NodeConfig.fragmenter. ``"auto"`` (the serve
-    default) resolves ONCE, here, to the flagship anchored pipeline: the
-    TPU device engine iff this machine has a TPU platform
-    (utils.device.wants_tpu), its CPU engine otherwise — and says which
-    in the log. A TPU that is present but cannot be taken is an error
-    (DeviceError), never a quiet CPU engine; so is any ``*-tpu`` kind
-    that comes up on another platform, unless ``JAX_PLATFORMS=cpu`` asked
-    for the CPU by name.
+    default) resolves ONCE, here, to the anchored pair: the TPU device
+    engine iff this machine has a TPU platform (utils.device.wants_tpu),
+    its CPU engine otherwise — and says which in the log. A TPU that is
+    present but cannot be taken is an error (DeviceError), never a quiet
+    CPU engine; so is ``cdc-anchored-tpu`` coming up on another platform,
+    unless ``JAX_PLATFORMS=cpu`` asked for the CPU by name.
 
     ``frag`` (a FragmenterConfig) carries execution knobs: with
-    ``frag.devices > 1`` the ``"cdc"`` strategy's streaming walk shards
-    regions over that many JAX devices (fragmenter/cdc_sharded.py), and
-    the flagship ``"cdc-anchored"`` strategy's region walk shards over
-    the same mesh with double-buffered staging
+    ``frag.devices > 1`` the ``"cdc-anchored"`` region walk shards over
+    that many JAX devices with double-buffered staging
     (fragmenter/cdc_anchored_sharded.py) — byte-identical chunk
-    boundaries, multi-chip throughput."""
-    import warnings
+    boundaries, multi-chip throughput. No other kind shards."""
+    import logging
 
-    from dfs_tpu.config import CDCParams
-    from dfs_tpu.fragmenter.cdc_cpu import CpuCdcFragmenter
-    from dfs_tpu.fragmenter.cdc_tpu import TpuCdcFragmenter
-    from dfs_tpu.fragmenter.fixed import FixedFragmenter
-
+    log = logging.getLogger("dfs_tpu.fragmenter")
+    if kind not in FRAGMENTER_KINDS:
+        raise ValueError(f"unknown fragmenter {kind!r}: the kinds are "
+                         + ", ".join(FRAGMENTER_KINDS))
+    if frag is not None and frag.devices > 1 and kind != "cdc-anchored":
+        # silence would be indistinguishable from sharding working
+        # (/metrics frag reports the configured device count either way)
+        log.warning(
+            "--cdc-devices is ignored by fragmenter=%r; use "
+            "fragmenter='cdc-anchored' for multi-device ingest", kind)
+        frag = None
     if kind == "auto":
-        import logging
-
         from dfs_tpu.utils.device import wants_tpu
 
-        log = logging.getLogger("dfs_tpu.fragmenter")
-        if frag is not None and frag.devices > 1:
-            # auto picks the engine, not the mesh; it does not compose
-            # with the sharded walks. Silence here would be
-            # indistinguishable from sharding working (/metrics frag
-            # reports the configured device count either way).
-            log.warning(
-                "--cdc-devices is ignored by fragmenter='auto'; use "
-                "fragmenter='cdc-anchored' (or 'cdc') for multi-device "
-                "ingest")
         kind = "cdc-anchored-tpu" if wants_tpu() else "cdc-anchored"
         log.info("fragmenter 'auto' -> %s", kind)
-        frag = None                     # (warned above) auto never shards
-    sharded = (frag is not None and frag.devices > 1
-               and kind in ("cdc", "cdc-anchored"))
-    if kind.endswith("-tpu") or sharded:
+    sharded = frag is not None and frag.devices > 1
+    if kind == "cdc-anchored-tpu" or sharded:
         from dfs_tpu.utils.device import (DeviceError, cpu_on_purpose,
                                           require_tpu)
 
@@ -268,60 +264,23 @@ def get_fragmenter(kind: str, *, cdc_params=None, fixed_parts: int = 5,
                 raise DeviceError(
                     f"{what}: only {info['count']} TPU device(s) visible")
     if kind == "fixed":
+        from dfs_tpu.fragmenter.fixed import FixedFragmenter
+
         return FixedFragmenter(parts=fixed_parts)
-    if kind in ("cdc-anchored", "cdc-anchored-tpu"):
-        from dfs_tpu.fragmenter.cdc_anchored import (AnchoredCpuFragmenter,
-                                                     AnchoredTpuFragmenter)
-
-        params = _anchored_params(cdc_params)
-        if frag is not None and frag.devices > 1:
-            if kind == "cdc-anchored":
-                # the flagship ANCHORED walk sharded over the mesh
-                # (r15): identical chunks, multi-chip region compute
-                from dfs_tpu.fragmenter.cdc_anchored_sharded import \
-                    ShardedAnchoredCdcFragmenter
-
-                return ShardedAnchoredCdcFragmenter(params, frag)
-            # the single-device TPU pipeline does not compose with the
-            # sharded walk; silence would be indistinguishable from
-            # sharding working (/metrics frag reports the configured
-            # device count either way)
-            import logging
-
-            logging.getLogger("dfs_tpu.fragmenter").warning(
-                "--cdc-devices is ignored by fragmenter="
-                "'cdc-anchored-tpu'; use fragmenter='cdc-anchored' for "
-                "multi-device ingest")
-        cls = AnchoredCpuFragmenter if kind == "cdc-anchored" \
-            else AnchoredTpuFragmenter
-        return cls(params)
-    if kind in ("cdc-aligned", "cdc-aligned-tpu"):
-        from dfs_tpu.fragmenter.cdc_aligned import (AlignedCpuFragmenter,
-                                                    AlignedTpuFragmenter)
-        from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
-
-        if isinstance(cdc_params, AlignedCdcParams):
-            params = cdc_params
-        elif cdc_params is not None:
-            params = _aligned_from_cdc(cdc_params)
-        else:
-            params = AlignedCdcParams()
-        cls = AlignedCpuFragmenter if kind == "cdc-aligned" \
-            else AlignedTpuFragmenter
-        return cls(params)
-    params = cdc_params or CDCParams()
     if kind == "cdc":
-        if frag is not None and frag.devices > 1:
-            from dfs_tpu.fragmenter.cdc_sharded import ShardedCdcFragmenter
+        from dfs_tpu.config import CDCParams
+        from dfs_tpu.fragmenter.cdc_cpu import CpuCdcFragmenter
 
-            return ShardedCdcFragmenter(params, frag)
-        return CpuCdcFragmenter(params)
-    if kind == "cdc-tpu":
-        warnings.warn(
-            "the v1 'cdc-tpu' fragmenter pulls the full candidate bitmap "
-            "to the host and measured ~300x slower than 'cdc-anchored-tpu' "
-            "on v5e (commit 40a6f77); it is kept as a byte-granular "
-            "compatibility path only",
-            DeprecationWarning, stacklevel=2)
-        return TpuCdcFragmenter(params)
-    raise ValueError(f"unknown fragmenter {kind!r}")
+        return CpuCdcFragmenter(cdc_params or CDCParams())
+    params = _anchored_params(cdc_params)
+    if sharded:
+        from dfs_tpu.fragmenter.cdc_anchored_sharded import \
+            ShardedAnchoredCdcFragmenter
+
+        return ShardedAnchoredCdcFragmenter(params, frag)
+    from dfs_tpu.fragmenter.cdc_anchored import (AnchoredCpuFragmenter,
+                                                 AnchoredTpuFragmenter)
+
+    cls = AnchoredCpuFragmenter if kind == "cdc-anchored" \
+        else AnchoredTpuFragmenter
+    return cls(params)

@@ -3,8 +3,8 @@
 Strategy (ops.cdc_anchored): byte-granular content anchors choose segment
 boundaries; within each segment the aligned 64-byte chunk grid re-anchors
 at the segment start, so unaligned insertions only disturb their own
-segment (the aligned v2 grid loses all downstream dedup — see
-fragmenter/cdc_aligned.py). Chunking is identical whether the stream is
+segment (a grid anchored at stream offset 0 loses all downstream dedup
+— ops/cdc_anchored.py). Chunking is identical whether the stream is
 chunked whole, in any batching, or streamed: regions hand the device a
 tile-aligned window with 8 bytes of lookback, and the unfinished tail
 segment carries into the next region (ops.cdc_anchored.region_chunks).

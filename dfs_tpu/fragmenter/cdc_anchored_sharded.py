@@ -117,8 +117,7 @@ class ShardedAnchoredCdcFragmenter(_StagingMeter, AnchoredCpuFragmenter):
         # worst-case per-window segment count — ONE pass-B compile shape
         self._s_pad = segment_cap(self.params, self._m_words)
         # windows ride dp: one whole window per device
-        self._steps = ShardedSteps(self.devices, self._build,
-                                   dp=self.devices)
+        self._steps = ShardedSteps(self.devices, self._build)
         self._wbuf_pool: list[np.ndarray] = []   # region staging (u8)
         self._init_staging(overlap_min_bw)
 
@@ -185,7 +184,7 @@ class ShardedAnchoredCdcFragmenter(_StagingMeter, AnchoredCpuFragmenter):
 
         import jax
 
-        from dfs_tpu.ops.cdc_pipeline import digests_to_hex
+        from dfs_tpu.ops.cdc_v2 import digests_to_hex
         from dfs_tpu.utils.hashing import sha256_hex
 
         astep, bstep, row = steps["astep"], steps["bstep"], steps["row"]

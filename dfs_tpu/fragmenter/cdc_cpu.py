@@ -1,14 +1,16 @@
-"""CPU content-defined chunking — the bit-exactness oracle.
+"""Byte-granular Gear content-defined chunking on the host — the ``cdc``
+kind, the analogue of the source's CPU CDC plugin. It imports no JAX, which
+is why most of the suite boots its clusters on it; the device runs the
+anchored chunker (fragmenter/cdc_anchored.py), not this one.
 
 Two implementations of the same algorithm:
 
 - :func:`cdc_cuts_ref` — a deliberately naive pure-Python sequential rolling
-  hash + greedy cut walk. This is the *specification*; tests assert every
-  other backend (NumPy here, JAX/TPU in cdc_tpu, sharded in parallel/) matches
-  it bit-for-bit.
-- :class:`CpuCdcFragmenter` — the production CPU path: vectorized NumPy
-  windowed Gear bitmap + the shared host-side selection, with native/hashlib
-  SHA-256.
+  hash + greedy cut walk. This is the *specification*; tests assert the
+  NumPy and C++ engines match it bit-for-bit.
+- :class:`CpuCdcFragmenter` — the production path: the C++ scan
+  (``native_gear_cuts``) or the vectorized NumPy windowed Gear bitmap + the
+  host-side selection (ops/boundary.py), with hashlib SHA-256.
 """
 
 from __future__ import annotations
@@ -62,8 +64,10 @@ def cdc_cuts_ref(data: bytes, params: CDCParams,
 
 def gear_bitmap_carry(data: np.ndarray, table: np.ndarray, mask: int,
                       prev_g: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Vectorized windowed Gear bitmap — same math as ops.gear_jax, in NumPy.
-    data: [N] uint8; prev_g: [31] uint32 halo (zeros at stream start).
+    """Vectorized windowed Gear bitmap: because each shift-left discards one
+    high bit, h_i depends on exactly the last 32 bytes, h_i = sum_{k<32}
+    G[b_{i-k}] << k (mod 2**32) — 32 shifted adds, bit-for-bit the
+    sequential rolling hash. data: [N] uint8; prev_g: [31] uint32 halo (zeros at stream start).
     Returns (bitmap, new halo) — the single source of truth for the CPU
     kernel; both the one-shot and streaming paths call this."""
     n = data.shape[0]

@@ -1,15 +1,15 @@
-"""Shared plumbing of the multi-device sharded fragmenters (round 15).
+"""Shared plumbing of the multi-device walks.
 
-Both sharded strategies — the ROLLING ``cdc`` one (cdc_sharded.py, r10)
-and the flagship ANCHORED one (cdc_anchored_sharded.py, r15) — need the
-same two pieces, and they must not drift apart:
+The sharded anchored walk (cdc_anchored_sharded.py) and the sharded
+sketcher (dfs_tpu/sim/sketch.py) need the same two pieces, and they must
+not drift apart:
 
 - **one compile-shape policy** (:func:`fixed_region_bytes`): streaming
   input is re-blocked to a FIXED region size so the sharded step
   traces/compiles exactly once for the whole stream. The size must be a
-  multiple of the strategy's per-device granule (so static per-device
-  spans tile it evenly) and at least a strategy-specific floor (the
-  rolling halo source span / the anchored two-segment window).
+  multiple of the caller's granule (the anchor tile / the sketch window's)
+  and at least one granule; the anchored walk then enforces its own
+  two-segment floor.
 
 - **one fallback predicate** (:class:`ShardedSteps`): building the mesh
   + steps is LAZY (jax untouched until the first stream). Where the
@@ -47,10 +47,9 @@ class ShardedSteps:
     and every later ``get()`` returns None — callers fall back to their
     single-device kernel."""
 
-    def __init__(self, devices: int, build: Callable, dp: int = 1) -> None:
+    def __init__(self, devices: int, build: Callable) -> None:
         self.devices = int(devices)
         self._build = build
-        self._dp = int(dp)
         self._steps = None
         self.mesh = None
         self.unavailable = False
@@ -64,10 +63,9 @@ class ShardedSteps:
             raise RuntimeError(
                 f"{self.devices} devices configured, "
                 f"{len(jax.devices())} visible")
-        # dp=1: one stream, its byte axis tiled over every device
-        # (the rolling halo ring); dp=devices: windows ride the dp
-        # axis, one whole window per device (the anchored walk)
-        mesh = make_mesh(self.devices, dp=self._dp)
+        # windows (chunks to sketch) ride the dp axis, one whole
+        # window per device
+        mesh = make_mesh(self.devices, dp=self.devices)
         self._steps = self._build(mesh)
         self.mesh = mesh
 

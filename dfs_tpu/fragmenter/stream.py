@@ -8,10 +8,8 @@ finalizes a chunk as soon as either (a) a candidate at length >= min_size
 appears, or (b) max_size bytes are buffered — so resident state is at most
 max_size + one tile regardless of stream length.
 
-This is the single-host edition of the same decomposition the sharded
-pipeline runs across devices (dfs_tpu.parallel.sharded_cdc: halo via
-ppermute); the bitmap function is pluggable so CPU (NumPy) and TPU (JAX tile
-kernel) share the selection logic — and therefore produce identical chunks.
+This is the streaming walk of the ``cdc`` kind (fragmenter/cdc_cpu.py); the
+anchored kinds have their own region walk (fragmenter/cdc_anchored.py).
 """
 
 from __future__ import annotations
@@ -26,6 +24,8 @@ from dfs_tpu.utils.hashing import sha256_many_hex, sha256_new
 
 # bitmap_fn(tile_u8, prev_g_u32[31]) -> (bitmap_bool[N], new_prev_g_u32[31])
 BitmapFn = Callable[[np.ndarray, np.ndarray], tuple[np.ndarray, np.ndarray]]
+
+_HASH_BATCH = 256
 
 
 class StreamChunker:
@@ -85,41 +85,15 @@ class StreamChunker:
                 self._ci = 0
 
 
-def reblock(blocks: Iterable[bytes], tile: int) -> Iterator[np.ndarray]:
-    """Re-slice an arbitrary block stream into exact ``tile``-size arrays
-    (final block may be short) — device tile kernels need static shapes."""
-    pending = bytearray()
-    for b in blocks:
-        pending.extend(b)
-        while len(pending) >= tile:
-            yield np.frombuffer(bytes(pending[:tile]), dtype=np.uint8)
-            del pending[:tile]
-    if pending:
-        yield np.frombuffer(bytes(pending), dtype=np.uint8)
-
-
-def iter_file_blocks(path, block_size: int = 8 * 1024 * 1024
-                     ) -> Iterator[bytes]:
-    with open(path, "rb") as f:
-        while True:
-            b = f.read(block_size)
-            if not b:
-                return
-            yield b
-
-
 def manifest_from_stream(blocks: Iterable[bytes], params: CDCParams,
                          bitmap_fn: BitmapFn, name: str,
                          fragmenter_name: str,
                          store: Callable[[str, bytes], None] | None = None,
-                         hash_batch: int = 256,
-                         hash_fn: Callable[[list[bytes]], list[str]]
-                         = sha256_many_hex) -> Manifest:
+                         ) -> Manifest:
     """One-pass streaming upload core: file_id (whole-stream sha256), chunk
     spans, per-chunk digests — optionally persisting each chunk via ``store``
-    — without ever materializing the whole stream. ``hash_fn`` digests each
-    finalized batch (CPU native by default; the TPU fragmenter passes its
-    device batch hasher)."""
+    — without ever materializing the whole stream. Finalized chunks are
+    digested ``_HASH_BATCH`` at a time."""
     chunker = StreamChunker(params, bitmap_fn)
     whole = sha256_new()
     refs: list[ChunkRef] = []
@@ -127,7 +101,7 @@ def manifest_from_stream(blocks: Iterable[bytes], params: CDCParams,
     size = 0
 
     def flush() -> None:
-        digests = hash_fn([b for _, b in pending])
+        digests = sha256_many_hex([b for _, b in pending])
         for (off, payload), dg in zip(pending, digests):
             refs.append(ChunkRef(index=len(refs), offset=off,
                                  length=len(payload), digest=dg))
@@ -138,7 +112,7 @@ def manifest_from_stream(blocks: Iterable[bytes], params: CDCParams,
     def consume(spans: Iterator[tuple[int, bytes]]) -> None:
         for off, payload in spans:
             pending.append((off, payload))
-            if len(pending) >= hash_batch:
+            if len(pending) >= _HASH_BATCH:
                 flush()
 
     for block in blocks:

@@ -83,7 +83,11 @@ class Manifest:
     file_id: str
     name: str
     size: int
-    fragmenter: str               # "fixed" | "cdc" | "cdc-tpu"
+    fragmenter: str               # the kind that cut it ("fixed", "cdc",
+                                  # "cdc-anchored[-tpu]"); a record only:
+                                  # reads never dispatch on it, so stores
+                                  # hold names of kinds since retired
+                                  # ("cdc-tpu", "cdc-aligned[-tpu]")
     chunks: tuple[ChunkRef, ...]
     ec: EcInfo | None = None
     tier: str | None = None       # "cold" = demoted to EC cold storage

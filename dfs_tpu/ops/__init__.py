@@ -1,30 +1,4 @@
-"""Device kernels (jax) + host-side boundary selection (numpy).
-
-Only ``select_cuts``/``cuts_to_spans`` are imported eagerly — they're
-numpy-only and used by the CPU fragmenters; the jax kernel modules load
-lazily so CPU-only deployments never import jax.
-"""
-
-from dfs_tpu.ops.boundary import cuts_to_spans, select_cuts  # noqa: F401
-
-__all__ = ["cuts_to_spans", "select_cuts", "gear_bitmap_tile",
-           "make_gear_tile_fn", "pad_messages", "sha256_batch_hex",
-           "sha256_blocks", "state_to_hex"]
-
-_JAX_EXPORTS = {
-    "gear_bitmap_tile": "dfs_tpu.ops.gear_jax",
-    "make_gear_tile_fn": "dfs_tpu.ops.gear_jax",
-    "pad_messages": "dfs_tpu.ops.sha256_jax",
-    "sha256_batch_hex": "dfs_tpu.ops.sha256_jax",
-    "sha256_blocks": "dfs_tpu.ops.sha256_jax",
-    "state_to_hex": "dfs_tpu.ops.sha256_jax",
-}
-
-
-def __getattr__(name):
-    mod = _JAX_EXPORTS.get(name)
-    if mod is None:
-        raise AttributeError(name)
-    import importlib
-
-    return getattr(importlib.import_module(mod), name)
+"""Device kernels (jax) and their NumPy oracles, plus the ``cdc`` kind's
+host-side boundary selection (``boundary.py``, numpy only). Nothing is
+imported here: a CPU-only deployment that loads ``ops.boundary`` or the
+NumPy half of ``ops.ec`` never imports jax."""

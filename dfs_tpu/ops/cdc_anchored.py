@@ -1,11 +1,11 @@
 """Anchored two-level CDC (v3) — shift-resilient dedup at TPU speed.
 
-The aligned v2 pipeline (ops.cdc_v2 / ops.cdc_pipeline) quantizes cuts to
-a 64-byte grid anchored at absolute stream offset 0; an insertion whose
-length is not a multiple of 64 shifts all downstream content off the grid
-and kills dedup (measured 1.16x vs 3.91x for byte-granular rolling CDC on
-the versioned corpus — bench_dedup.py). v3 re-anchors the grid with a
-classic two-level scheme:
+The block-grid chunk cuts of ops.cdc_v2 are quantized to 64 bytes; on a
+grid anchored at absolute stream offset 0 (the aligned v2 chunker,
+retired at PR 46) an insertion whose length is not a multiple of 64
+shifts all downstream content off the grid and kills dedup (it measured
+1.16x against 3.91x for byte-granular rolling CDC on a versioned
+corpus). v3 re-anchors the grid with a classic two-level scheme:
 
 1. **Byte-granular anchors.** A cheap 8-byte windowed hash is evaluated at
    EVERY byte position (elementwise over the four byte phases of the LE
@@ -46,11 +46,11 @@ classic two-level scheme:
    again. The lanes are now ~62 % full (mean segment ~80 KiB of 128);
    that is the price, paid in device time nobody waits for.
 
-3. **Within a segment, the aligned v2 machinery runs with its 64-byte grid
-   anchored at the segment start**: the device repacks each segment into
-   its own lane (vmap'd dynamic_slice + per-lane byte funnel shift,
-   measured ~0.5 ms per 64 MiB), then candidates -> selection ->
-   strip-scan SHA-256 exactly as v2. A segment's chunking depends only on
+3. **Within a segment, the block-grid math of ops.cdc_v2 runs with its
+   64-byte grid anchored at the segment start**: the device repacks each
+   segment into its own lane (vmap'd dynamic_slice + per-lane byte funnel
+   shift, measured ~0.5 ms per 64 MiB), then candidates -> selection ->
+   strip-scan SHA-256. A segment's chunking depends only on
    the segment's bytes, and segment starts move WITH content — so an
    insertion re-syncs at the next anchor and dedup survives.
 
@@ -71,6 +71,7 @@ import math
 import numpy as np
 
 from dfs_tpu.ops.cdc_v2 import (BLOCK, AlignedCdcParams, candidates_np,
+                                cut_capacity, digests_to_hex,
                                 select_cuts_blocks)
 from dfs_tpu.utils.hashing import next_pow2
 
@@ -712,7 +713,6 @@ def make_anchored_segment_fn(params: AnchoredCdcParams, m_words: int,
     cp = params.chunk
     bps = cp.strip_blocks
     lane_words = bps * 16
-    from dfs_tpu.ops.cdc_pipeline import cut_capacity
     # capacity: per-lane bound AND the global bound — segments tile the
     # region disjointly, so total content blocks <= region blocks + one
     # rounded-up tail per lane, and cuts <= blocks/min + one forced
@@ -733,6 +733,10 @@ def make_anchored_segment_fn(params: AnchoredCdcParams, m_words: int,
         c_max = c_full
     use_pallas = s_pad % 128 == 0 and any(
         d.platform == "tpu" for d in jax.devices())
+    # cut-position compaction tiling: tiles never span a lane (t_tile |
+    # bps), so in-lane cuts are >= min_blocks apart and a tile holds at
+    # most t_tile//min_blocks + 2 cuts (+1 partial leading gap, +1 forced
+    # lane-final)
     t_tile = 128 if bps % 128 == 0 else bps
     k_max = t_tile // cp.min_blocks + 2
 
@@ -766,7 +770,11 @@ def make_anchored_segment_fn(params: AnchoredCdcParams, m_words: int,
                      real_blocks, tail_len, starts, seg_lens):
         count = jnp.sum(cf32)
 
-        # cut positions, tile-extracted (see ops.cdc_pipeline)
+        # stream-order cut positions q = lane*bps + t, compacted
+        # tile-wise: per tile, peel off the k-th lowest set bit (k <
+        # k_max) with masked min-reductions — all vector ops, no scatter
+        # over the full block space (jnp.nonzero measured 9 ms per
+        # 64 MiB; this path ~1 ms)
         flat = cf32.T.reshape(-1, t_tile) != 0
         nt = flat.shape[0]
         iota = jnp.arange(t_tile, dtype=jnp.int32)[None, :]
@@ -792,8 +800,8 @@ def make_anchored_segment_fn(params: AnchoredCdcParams, m_words: int,
         s = jnp.maximum(q, 0) // bps
 
         # chunk lengths come from the selection's own block counter (lanes
-        # are independent segments, so cross-lane position diffs — the v2
-        # trick — do not apply); the lane-tail chunk subtracts its pad
+        # are independent segments, so cross-lane position differences
+        # say nothing); the lane-tail chunk subtracts its pad
         blocks = jnp.take(since.reshape(-1),
                           t * jnp.int32(s_pad) + s)    # since is [bps, S]
         is_tail = (t == jnp.take(real_blocks, s) - 1) \
@@ -1052,8 +1060,6 @@ def region_collect(out) -> tuple[list[tuple[int, int, str]], int,
     chain."""
     import jax
 
-    from dfs_tpu.ops.cdc_pipeline import digests_to_hex
-
     (consumed, seg_of, count, q, offs, lens, dig, nseg,
      cuts) = jax.device_get(out)
     if int(seg_of):
@@ -1311,8 +1317,6 @@ def packed_collect(out, offs, lengths
     counts as :func:`region_collect` gives them, every stream's in
     them."""
     import jax
-
-    from dfs_tpu.ops.cdc_pipeline import digests_to_hex
 
     seg_of, count, q, c_offs, lens, dig, nseg, cuts = jax.device_get(out)
     rows = min(int(count), q.shape[0])

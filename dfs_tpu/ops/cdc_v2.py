@@ -1,10 +1,10 @@
-"""Aligned CDC v2 — the TPU-native content-defined chunking algorithm.
+"""Block-grid Gear candidate math of the anchored chunker.
 
-The reference splits files positionally (StorageNode.java:138-171); classic
-CDC (dfs_tpu.fragmenter.cdc_cpu / ops.gear_jax, the "rolling" variant) fixes
-the dedup problem but is hostile to TPU execution: per-byte rolling state,
-byte-granular cuts that force gathers, and a 31-byte halo threaded between
-tiles. v2 is re-derived from the hardware constraints (measured on v5e):
+The anchored chunker (``ops/cdc_anchored.py``) cuts a stream into
+content-defined segments and lays each into a lane of its own; WITHIN a
+lane the chunk cuts are decided here. A lane is a *strip*: a run of
+64-byte blocks whose grid starts at the lane's first byte. The math is
+shaped by the hardware (measured on v5e):
 
 - **cuts are quantized to 64-byte blocks** (the SHA-256 block size). A cut
   candidate after block ``t`` is decided by a Gear-style windowed hash over
@@ -16,20 +16,19 @@ tiles. v2 is re-derived from the hardware constraints (measured on v5e):
   The 32-byte window never crosses the block start, so the decision is local
   to each block: no rolling state, no halo, no sequential scan — one
   elementwise pass. (Identical to the rolling Gear hash evaluated at the
-  block's last byte, restricted to aligned positions — FastCDC-style
-  normalization taken to its TPU-native conclusion.)
+  block's last byte, restricted to aligned positions.)
 
 - **G is arithmetic, not a lookup table**: ``G[b] = fmix32(seed ^ b*PRIME)``
   (murmur-finalizer constants). A 256-entry ``jnp.take`` over 10^8 indices
   measured 1.4 s per 128 MiB on v5e; computing G in registers costs ~10
-  elementwise uint32 ops and rides the VPU at memory speed. The CPU oracle
-  precomputes the same 256 values into a table — bit-identical by
+  elementwise uint32 ops and rides the VPU at memory speed. The NumPy
+  oracle precomputes the same 256 values into a table — bit-identical by
   construction.
 
-- **the stream is segmented into fixed strips** (default 128 KiB): chunking
-  restarts at each strip boundary (forced cut), so strips are fully
-  independent — the lane dimension for every kernel, and the unit of
-  sequence-parallel sharding over a device mesh (no ppermute needed at all).
+- **strips are independent**: chunking restarts at each strip's start, so
+  strips are the lane dimension of every kernel and the unit the sharded
+  steps (``parallel/sharded_cdc.py``) spread over a mesh, with nothing
+  exchanged between devices.
 
 - **greedy selection is a lane-parallel scan**: the sequential min/max walk
   runs per-strip in lockstep across all strips (one ``lax.scan`` over blocks
@@ -39,15 +38,24 @@ tiles. v2 is re-derived from the hardware constraints (measured on v5e):
 Selection semantics per strip (mirrored exactly by the NumPy oracle below):
 walking blocks ``t``, with ``since`` = blocks accumulated so far including
 ``t``: cut after ``t`` iff ``(candidate(t) and since >= min_blocks)`` or
-``since == max_blocks`` or ``t`` is the strip's (or file's) last block.
-The file's final chunk may end in a partial block; its digest is computed
-host-side (hashlib) — every other chunk is a whole number of blocks and is
-hashed on device (ops.sha256_strip).
+``since == max_blocks`` or ``t`` is the strip's last block.
 
-Chunk digests are standard SHA-256 (== hashlib). The file id is
+On the TPU the served chain computes candidates, selection and SHA-256 in
+ONE Pallas kernel (``ops/sha256_strip.py`` ``strip_chunk_states``, which
+imports the three hash constants below); ``gear_candidates_device`` and
+``select_cuts_device`` are the same math in plain XLA — what the chain runs
+where Pallas does not (the CPU rehearsal, a lane count that is no multiple
+of 128), what the lane-sharded step runs, and what ``tests/test_sha256.py``
+holds the fused kernel to.
+
+Chunk digests are standard SHA-256 (== hashlib). An engine's own file id is
 ``sha256(digest_0 || digest_1 || ...)`` over the raw 32-byte chunk digests —
 content-derived like the reference's whole-file id (StorageNode.java:127)
 but computable from the chunk table alone.
+
+The module keeps the name it had when it was one of three chunker
+families ("aligned CDC v2", whose whole-file fragmenters went at PR 46):
+thirteen test files import it.
 """
 
 from __future__ import annotations
@@ -118,7 +126,7 @@ def g_table(seed: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# NumPy oracle (exact semantics; also the production CPU fragmenter core)
+# NumPy oracle (exact semantics; the CPU engine's per-segment core)
 # ---------------------------------------------------------------------------
 
 def block_hashes_np(data: np.ndarray, params: AlignedCdcParams) -> np.ndarray:
@@ -163,37 +171,6 @@ def select_cuts_blocks(cand_pos: np.ndarray, n_blocks: int,
     return np.asarray(cuts, dtype=np.int64)
 
 
-def chunk_spans_np(data: np.ndarray,
-                   params: AlignedCdcParams) -> list[tuple[int, int]]:
-    """Full-file [(offset, length)] spans (bytes), oracle path."""
-    n = data.shape[0]
-    if n == 0:
-        return []
-    cand = candidates_np(data, params)
-    spans: list[tuple[int, int]] = []
-    sl = params.strip_len
-    for s0 in range(0, n, sl):
-        s1 = min(s0 + sl, n)
-        nb = -(-(s1 - s0) // BLOCK)  # ceil: include trailing partial block
-        pos = np.flatnonzero(cand[s0 // BLOCK: s0 // BLOCK + (s1 - s0) // BLOCK])
-        cuts = select_cuts_blocks(pos, nb, params)
-        prev = 0
-        for c in cuts.tolist():
-            off = s0 + prev * BLOCK
-            end = min(s0 + c * BLOCK, s1)
-            spans.append((off, end - off))
-            prev = c
-    return spans
-
-
-def chunk_file_np(data: np.ndarray, params: AlignedCdcParams
-                  ) -> list[tuple[int, int, str]]:
-    """Oracle chunker: [(offset, length, sha256hex)]."""
-    mv = memoryview(np.ascontiguousarray(data))
-    return [(o, ln, hashlib.sha256(mv[o:o + ln]).hexdigest())
-            for o, ln in chunk_spans_np(data, params)]
-
-
 def file_id_from_digests(digests: list[str]) -> str:
     """sha256 over concatenated raw chunk digests (empty file: sha256(b''))."""
     h = hashlib.sha256()
@@ -207,28 +184,7 @@ def file_id_from_digests(digests: list[str]) -> str:
 # ---------------------------------------------------------------------------
 # Resident layout: words_t [strip_blocks * 16, S] uint32, where
 #   words_t[t*16 + w, s] = big-endian word w of block t of strip s.
-# S = number of strips (padded to a multiple of 128); lanes = strips.
-
-def host_to_strips(data: np.ndarray, params: AlignedCdcParams,
-                   lane_multiple: int = 128
-                   ) -> tuple[np.ndarray, int, int]:
-    """Host-side prep: [N] uint8 -> (words_t [strip_blocks*16, S] uint32,
-    S, n). Zero-pads to whole strips and S to ``lane_multiple``.
-
-    This is the one data-touching host pass (NumPy byteswap view + one
-    transpose copy); everything downstream runs on device.
-    """
-    n = data.shape[0]
-    sl = params.strip_len
-    s_real = max(1, -(-n // sl))
-    s_pad = -(-s_real // lane_multiple) * lane_multiple
-    buf = np.zeros((s_pad * sl,), dtype=np.uint8)
-    buf[:n] = data
-    words = buf.view(">u4").astype(np.uint32)       # BE -> native, one pass
-    words_t = np.ascontiguousarray(
-        words.reshape(s_pad, params.strip_blocks * 16).T)
-    return words_t, s_pad, n
-
+# S = number of strips (lanes).
 
 def gear_candidates_device(words_t, params: AlignedCdcParams):
     """Candidate bitmap [strip_blocks, S] bool from the resident words.
@@ -307,3 +263,21 @@ def select_cuts_device(cand, real_blocks, params: AlignedCdcParams,
         (cand.reshape(bps // u, u, s),
          jnp.arange(bps, dtype=jnp.int32).reshape(bps // u, u)))
     return cutflag.reshape(bps, s), since.reshape(bps, s)
+
+
+# ---------------------------------------------------------------------------
+# Chunk-table helpers of the chain
+# ---------------------------------------------------------------------------
+
+def cut_capacity(s: int, params: AlignedCdcParams) -> int:
+    """Static bound on cuts in ``s`` strips: each strip yields at most
+    ceil(bps / min_blocks) cuts plus the forced strip-final cut."""
+    per_strip = -(-params.strip_blocks // params.min_blocks) + 1
+    return s * per_strip
+
+
+def digests_to_hex(dig: np.ndarray) -> list[str]:
+    """[C, 8] uint32 -> lowercase hex, one string per row (vectorized)."""
+    be = np.ascontiguousarray(dig.astype(">u4"))
+    hx = be.tobytes().hex()
+    return [hx[i * 64:(i + 1) * 64] for i in range(dig.shape[0])]

@@ -10,6 +10,10 @@ messages of different lengths share one fused kernel.
 
 Bit-exactness against ``hashlib.sha256`` is enforced by tests for every
 length class (empty, <55, 55/56/64 boundary, multi-block).
+
+The served chain hashes with the strip-scan kernel (ops/sha256_strip.py),
+which takes its constants (``_H0``, ``_K``) from here; the batched kernel
+itself is the reference that kernel is tested against.
 """
 
 from __future__ import annotations
@@ -195,9 +199,9 @@ def state_to_hex(state: np.ndarray) -> list[str]:
 
 def sha256_batch_hex(chunks: list[bytes | np.ndarray]) -> list[str]:
     """Convenience one-shot: digest a batch of messages on the default JAX
-    backend. Production paths (TpuCdcFragmenter) do their own bucketing to
-    stabilize compile shapes; here batch and block dims are rounded up to
-    powers of two for the same reason (compiles are cached per shape)."""
+    backend — the plain-XLA reference tests/test_sha256.py holds the strip
+    kernel to. Batch and block dims are rounded up to powers of two
+    (compiles are cached per shape)."""
     if not chunks:
         return []
     n = len(chunks)

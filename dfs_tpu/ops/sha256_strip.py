@@ -5,7 +5,7 @@ round 1) needs each message gathered into its own row — and
 arbitrary-offset gathers measured ~0.6 s per 32 MiB on v5e, two orders
 slower than the hash itself. This kernel removes the
 gather: the stream stays in its strip-transposed resident layout
-(ops.cdc_v2.host_to_strips) and *chunk chaining follows the stream order*.
+(ops.cdc_v2, "Resident layout") and *chunk chaining follows the stream order*.
 
 Lane ``s`` walks its strip's 64-byte blocks sequentially (the grid axis);
 at every step it compresses the next block into its running state, writes

@@ -1,2 +1,1 @@
-from dfs_tpu.parallel.mesh import make_mesh  # noqa: F401
-from dfs_tpu.parallel.sharded_cdc import make_sharded_step  # noqa: F401
+"""Meshes and the steps that run sharded over them (``sharded_cdc.py``)."""

@@ -1,15 +1,15 @@
-"""Device-mesh construction for the distributed CDC pipeline.
+"""Device-mesh construction for the sharded steps (sharded_cdc.py).
 
 The reference's only 'distribution' is point-to-point HTTP between JVMs
-(SURVEY.md §2.3, §5.8). The TPU-native compute plane instead scales over a
+(SURVEY.md §2.3, §5.8). The compute plane instead scales over a
 ``jax.sharding.Mesh`` with two axes:
 
-- ``dp`` (data parallel): independent byte streams (files/uploads) — the
-  analogue of the reference serving concurrent uploads on different nodes;
-- ``sp`` (sequence parallel): one long stream tiled across devices, with the
-  31-byte Gear halo exchanged between ring neighbors over ICI — the
-  long-context story from SURVEY.md §5.7 (ring-attention-shaped, but the
-  exchanged state is the rolling-hash window, not KV blocks).
+- ``dp`` (data parallel): independent pieces — stream windows
+  (``--cdc-devices``), chunks to sketch — one a device;
+- ``sp``: the second axis of the steps that shard ONE piece's spans, lanes
+  or stripes over the flattened ('dp','sp') mesh. Nothing is exchanged
+  along it: what a device needs of its neighbour's bytes (the anchor
+  hash's 8-byte lookback) is baked into its span on the host.
 """
 
 from __future__ import annotations

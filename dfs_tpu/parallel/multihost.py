@@ -12,10 +12,10 @@ planes (SURVEY.md §5.8):
   routes inter-host collective legs over DCN — the role NCCL/MPI plays in
   GPU frameworks, with zero bespoke networking code here.
 
-``init_multihost`` + ``global_mesh`` are the entire API: after init,
-``dfs_tpu.parallel.sharded_cdc.make_sharded_step`` works unchanged on the
-global mesh — the sp-axis ppermute halo exchange crosses host boundaries
-transparently.
+``init_multihost`` + ``global_mesh`` are the entire API: after init, the
+steps of ``dfs_tpu.parallel.sharded_cdc`` work unchanged on the global mesh
+(tests/test_multihost.py runs ``make_anchored_step`` over two processes —
+its lanes and its ``psum`` cross the process boundary).
 """
 
 from __future__ import annotations
@@ -41,9 +41,7 @@ def init_multihost(coordinator: str, num_processes: int,
 def global_mesh(dp: int | None = None) -> Mesh:
     """('dp','sp') mesh over the *global* device set (all hosts). Mirrors
     parallel.mesh.make_mesh but over jax.devices() post-initialize, keeping
-    each host's local devices contiguous along sp so halo ppermutes between
-    same-host neighbors stay on ICI and only the tile-boundary legs cross
-    DCN."""
+    each host's local devices contiguous along sp."""
     devs = jax.devices()
     n = len(devs)
     if dp is None:

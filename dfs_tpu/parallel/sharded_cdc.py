@@ -1,24 +1,29 @@
-"""Multi-device CDC pipeline: shard_map over a ('dp','sp') mesh.
+"""Steps that run sharded over a ('dp','sp') mesh: shard_map, jitted once.
 
-This is the framework's 'training step' analogue — the full device-side
-upload computation, jitted once over the mesh:
+What is here, and how each shards:
 
-- **sp axis (sequence parallelism / long-context):** each row of the input is
-  a byte stream tiled across the sp axis. The Gear window straddles tile
-  borders, so each device sends its tile's last 31 Gear values to its right
-  ring neighbor via ``lax.ppermute`` over ICI (SURVEY.md §5.7 — the
-  ring-attention-shaped neighbor exchange, with rolling-hash state instead of
-  KV blocks). Device 0 receives zeros ≡ stream start.
-- **dp axis (data parallelism):** independent streams (concurrent uploads)
-  ride the other mesh axis — the batch of padded chunks for SHA-256 is
-  sharded over the *flattened* ('dp','sp') axes so every device hashes an
-  equal slice.
-- a ``psum`` over both axes reduces the global candidate count (cheap stats
-  used by the node runtime for chunk-size telemetry).
+- **the anchored chunker, by stream span and by segment lane**
+  (:func:`make_anchored_anchor_step`, :func:`make_anchored_step`): pass A's
+  byte-granular anchor hash is elementwise, so one stream shards over the
+  flattened mesh as overlapping spans with an 8-byte lookback baked into
+  each span on the host; pass B's segment lanes are independent, so the
+  lane axis shards over the flattened mesh too. No collective on the data
+  path; a ``psum`` reduces the chunk count. These two are the driver's
+  multi-chip dry run and the two-process test
+  (:func:`anchored_sharded_parity_check`, tests/test_multihost.py).
+- **the anchored chunker, by window** (:func:`make_anchored_window_anchor_step`,
+  :func:`make_anchored_window_step`): what ``--cdc-devices`` serves
+  (fragmenter/cdc_anchored_sharded.py) — whole stream windows ride the dp
+  axis, each device running the single-device chain on its own window.
+- **min-hash sketches** (:func:`make_sketch_step`, dfs_tpu.sim): chunks ride
+  dp, one batch row a device.
+- **erasure parity** (:func:`make_ec_step`, ops.ec): stripes are independent,
+  the stripe axis shards over the flattened mesh; a ``psum`` counts the
+  parity bytes.
 
 Contrast with the reference: its scale-out is N JVMs exchanging Base64 JSON
 over localhost HTTP (StorageNode.java:226-259); here the same byte-level work
-is one SPMD program with XLA collectives on ICI.
+is one SPMD program.
 """
 
 from __future__ import annotations
@@ -29,173 +34,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from dfs_tpu.ops.gear_jax import HALO, WINDOW
-from dfs_tpu.ops.sha256_jax import _sha256_blocks_impl
-
-
-def _rowwise_gear_bitmap(data: jax.Array, prev_g: jax.Array,
-                         table: jax.Array, mask: jax.Array) -> jax.Array:
-    """data: [B, S] uint8; prev_g: [B, 31] uint32 (halo per row)."""
-    bsz, s = data.shape
-    g = jnp.take(table, data.astype(jnp.int32), axis=0)
-    gp = jnp.concatenate([prev_g, g], axis=1)  # [B, S+31]
-    h = jnp.zeros((bsz, s), jnp.uint32)
-    for k in range(WINDOW):
-        h = h + (jax.lax.slice_in_dim(gp, HALO - k, HALO - k + s, axis=1)
-                 << np.uint32(k))
-    return (h & mask) == 0
-
-
-def make_sharded_step(mesh: Mesh, table: np.ndarray, mask: int):
-    """Build the jitted multi-device step.
-
-    step(data [B, S] u8  — B sharded over dp, S tiled over sp,
-         words [H, L, 16] u32, nblocks [H] i32 — H sharded over (dp, sp))
-      -> (bitmap [B, S] bool  (same sharding as data),
-          digest_state [H, 8] uint32,
-          n_candidates [] int32  (global psum))
-    """
-    table_j = jnp.asarray(table, dtype=jnp.uint32)
-    mask_j = jnp.uint32(mask)
-    sp_size = mesh.shape["sp"]
-
-    def local_step(data, words, nblocks):
-        # halo exchange along the sp ring: my last 31 gear values feed my
-        # right neighbor's window; the first tile rolls from h=0 (zeros).
-        g_tail = jnp.take(table_j, data[:, -HALO:].astype(jnp.int32), axis=0)
-        prev_g = jax.lax.ppermute(
-            g_tail, "sp", [(i, i + 1) for i in range(sp_size - 1)])
-        bitmap = _rowwise_gear_bitmap(data, prev_g, table_j, mask_j)
-        state = _sha256_blocks_impl(words, nblocks)
-        n_cand = jax.lax.psum(
-            jax.lax.psum(jnp.sum(bitmap.astype(jnp.int32)), "sp"), "dp")
-        return bitmap, state, n_cand
-
-    shard_fn = jax.shard_map(
-        local_step, mesh=mesh,
-        in_specs=(P("dp", "sp"), P(("dp", "sp")), P(("dp", "sp"))),
-        out_specs=(P("dp", "sp"), P(("dp", "sp")), P()),
-        check_vma=False,
-    )
-    return jax.jit(shard_fn)
-
-
-def make_sharded_bitmap_step(mesh: Mesh, table: np.ndarray, mask: int):
-    """Carry-in Gear bitmap over the mesh — the INGEST-side sharded step
-    (round 10): ``fragmenter/cdc_sharded.py`` plugs it into the streaming
-    chunker as a ``bitmap_fn``, so ``stream.py`` feeds whole regions
-    through the mesh while greedy cut selection stays host-side — chunk
-    boundaries are byte-identical to the single-device path by
-    construction (the same bitmap, computed sharded).
-
-    Differs from :func:`make_sharded_step`'s bitmap in one way: the
-    stream's region-to-region 31-value halo enters as an explicit input
-    (``head``) consumed by the FIRST sp tile instead of zeros, so
-    consecutive regions of one stream chunk exactly like one long
-    buffer (zeros ≡ stream start, the old behavior).
-
-    step(data [B, S] u8 — B over dp, S tiled over sp,
-         head [B, HALO] u32 — per-row carry halo, replicated over sp)
-      -> bitmap [B, S] bool (same sharding as data)
-    """
-    table_j = jnp.asarray(table, dtype=jnp.uint32)
-    mask_j = jnp.uint32(mask)
-    sp_size = mesh.shape["sp"]
-
-    def local_step(data, head):
-        g_tail = jnp.take(table_j, data[:, -HALO:].astype(jnp.int32),
-                          axis=0)
-        prev_g = jax.lax.ppermute(
-            g_tail, "sp", [(i, i + 1) for i in range(sp_size - 1)])
-        # sp-rank 0's halo is the carry from the previous REGION of the
-        # stream, not the ring (which handed it nothing)
-        prev_g = jnp.where(jax.lax.axis_index("sp") == 0, head, prev_g)
-        return _rowwise_gear_bitmap(data, prev_g, table_j, mask_j)
-
-    shard_fn = jax.shard_map(
-        local_step, mesh=mesh,
-        in_specs=(P("dp", "sp"), P("dp", None)),
-        out_specs=P("dp", "sp"),
-        check_vma=False,
-    )
-    return jax.jit(shard_fn)
-
-
-def shard_bitmap_inputs(mesh: Mesh, data: np.ndarray, head: np.ndarray):
-    """device_put the carry-bitmap step inputs with matching shardings."""
-    return (
-        jax.device_put(data, NamedSharding(mesh, P("dp", "sp"))),
-        jax.device_put(head, NamedSharding(mesh, P("dp", None))),
-    )
-
-
-def shard_inputs(mesh: Mesh, data: np.ndarray, words: np.ndarray,
-                 nblocks: np.ndarray):
-    """device_put the step inputs with the matching NamedShardings."""
-    return (
-        jax.device_put(data, NamedSharding(mesh, P("dp", "sp"))),
-        jax.device_put(words, NamedSharding(mesh, P(("dp", "sp")))),
-        jax.device_put(nblocks, NamedSharding(mesh, P(("dp", "sp")))),
-    )
-
-
-def make_aligned_step(mesh: Mesh, params):
-    """Multi-device **aligned CDC v2** step (the flagship pipeline,
-    dfs_tpu.ops.cdc_pipeline, sharded).
-
-    Strips chunk independently (ops.cdc_v2: chunking restarts at strip
-    boundaries), so the strip axis shards over the whole mesh with zero
-    halo traffic — the deliberate v2 contrast with the rolling pipeline
-    above, whose 31-byte window forces a ppermute ring. The only
-    collective is the psum that aggregates global chunk-count telemetry.
-
-    step(words_le [S, bps*16] u32 — strips sharded over ('dp','sp'),
-         real_blocks [S] i32 — same sharding)
-      -> (cutflag [bps, S] i32 (strips sharded on axis 1),
-          states [bps*8, S] u32 (same),
-          n_chunks [] i32 (global psum))
-    """
-    from dfs_tpu.ops.cdc_v2 import (gear_candidates_device,
-                                    select_cuts_device)
-    from dfs_tpu.ops.layout import bswap_transpose
-    from dfs_tpu.ops.sha256_strip import strip_states, strip_states_xla
-
-    on_tpu = all(d.platform == "tpu" for d in mesh.devices.flat)
-
-    def local_step(words_le, real_blocks):
-        words_t = bswap_transpose(words_le)           # local [bps*16, S/n]
-        cand = gear_candidates_device(words_t, params)
-        cutflag, _ = select_cuts_device(cand, real_blocks, params)
-        cf32 = cutflag.astype(jnp.int32)
-        # Pallas wants a 128-multiple lane dim; shapes are static at trace
-        # time, so the local strip count decides per-compile.
-        use_pallas = on_tpu and words_t.shape[1] % 128 == 0
-        states = (strip_states if use_pallas else strip_states_xla)(
-            words_t, cf32)
-        n = jax.lax.psum(
-            jax.lax.psum(jnp.sum(cf32), "sp"), "dp")
-        return cf32, states, n
-
-    shard_fn = jax.shard_map(
-        local_step, mesh=mesh,
-        in_specs=(P(("dp", "sp")), P(("dp", "sp"))),
-        out_specs=(P(None, ("dp", "sp")), P(None, ("dp", "sp")), P()),
-        check_vma=False,
-    )
-    return jax.jit(shard_fn)
-
-
-def shard_aligned_inputs(mesh: Mesh, words_le: np.ndarray,
-                         real_blocks: np.ndarray):
-    """device_put aligned-step inputs with strip-axis sharding."""
-    return (
-        jax.device_put(words_le, NamedSharding(mesh, P(("dp", "sp")))),
-        jax.device_put(real_blocks, NamedSharding(mesh, P(("dp", "sp")))),
-    )
-
 
 # ---------------------------------------------------------------------------
-# anchored v3, sharded — the flagship's multi-device step
+# the anchored chunker, sharded
 # ---------------------------------------------------------------------------
 
 def make_anchored_anchor_step(mesh: Mesh, params, m_local: int):
@@ -204,7 +45,7 @@ def make_anchored_anchor_step(mesh: Mesh, params, m_local: int):
     the whole mesh as overlapping word spans with a 2-word (8-byte)
     lookback halo — prepared host-side by :func:`shard_anchor_inputs`, so
     no collective is needed at all (the halo is baked into each device's
-    span, the anchored analogue of the rolling pipeline's ppermute ring).
+    span).
 
     step(spans [n_dev, 2 + m_local] u32) -> tiles
     [3, n_dev * tiles_local] i32 (per TILE_BYTES tile the first two
@@ -255,8 +96,7 @@ def make_anchored_step(mesh: Mesh, params):
     """Sharded **pass B** of the anchored pipeline: segments are fully
     independent lanes (the 64-byte chunk grid restarts at each segment
     start), so the segment axis shards over the whole mesh with zero halo
-    traffic — same contrast with the rolling ppermute ring as the aligned
-    step above. The region words stay replicated (every device repacks its
+    traffic. The region words stay replicated (every device repacks its
     own lanes by dynamic_slice; on a real pod the region would ride dp and
     only lane descriptors shard). The only collective is the chunk-count
     psum.

@@ -124,8 +124,7 @@ class SimSketcher(_StagingMeter):
         # single-device MESH kernel, so the scaling claim compares the
         # device axis, not kernel-vs-oracle (production never sets it:
         # one device means the oracle is the kernel)
-        self._steps = ShardedSteps(self.devices, self._build,
-                                   dp=self.devices) \
+        self._steps = ShardedSteps(self.devices, self._build) \
             if (self.devices > 1 or force_sharded) else None
         self._init_staging(overlap_min_bw)
 

@@ -1,7 +1,9 @@
 """Anchored two-level CDC (v3): oracle properties, device parity, and the
-shift-resilience the aligned v2 grid lacks."""
+shift-resilience a grid anchored at stream offset 0 lacks."""
 
 import hashlib
+import io
+import tarfile
 
 import numpy as np
 import pytest
@@ -124,7 +126,8 @@ def test_spans_tile_stream_and_match_hashlib():
 
 def test_shift_resilience_vs_aligned():
     """The defining property: after an unaligned insertion, most chunks
-    must still dedup (the v2 aligned grid loses everything downstream)."""
+    must still dedup (a 64-byte grid anchored at stream offset 0 loses
+    everything downstream)."""
     base = corpus(300000, seed=4)
     edited = np.concatenate(
         [base[:50001], corpus(77, seed=5), base[50001:]])
@@ -326,6 +329,99 @@ def test_cuts_resynchronise_after_an_insert():
     assert ok >= 61, f"cuts re-synchronised in only {ok} of 64 seeds"
 
 
+# A source tree as a tar and its next release — BASELINE.json configs[3]'s
+# workload shape: thousands of small files, edits that INSERT and DELETE
+# lines (every edited file shifts all later tar content by an unaligned
+# delta), whole-file adds and removes, renames (whole 512-byte record runs
+# shift). The generator PR 37 pinned its thresholds on (it lived in
+# bench_dedup_tree.py until PR 46).
+
+_WORDS = None
+
+
+def _tree_line(rng, width: int = 60) -> bytes:
+    """Source-ish text line: identifier-shaped tokens, stable dictionary
+    so repeated lines across files/versions dedup like real code."""
+    global _WORDS
+    if _WORDS is None:
+        wrng = np.random.default_rng(99)
+        _WORDS = [bytes(wrng.integers(97, 123, size=int(n)).tolist())
+                  for n in wrng.integers(3, 12, size=4096)]
+    k = rng.integers(2, 9)
+    toks = [
+        _WORDS[int(i)] for i in rng.integers(0, len(_WORDS), size=int(k))]
+    return b" ".join(toks)[:width] + b"\n"
+
+
+def make_tree(rng, n_files: int, mean_file_bytes: int):
+    """{path: list-of-lines} — a synthetic source tree."""
+    tree = {}
+    for i in range(n_files):
+        nbytes = max(256, int(rng.exponential(mean_file_bytes)))
+        lines = []
+        sz = 0
+        while sz < nbytes:
+            ln = _tree_line(rng)
+            lines.append(ln)
+            sz += len(ln)
+        d1, d2 = int(rng.integers(0, 12)), int(rng.integers(0, 8))
+        tree[f"src/d{d1:02d}/m{d2}/f{i:05d}.c"] = lines
+    return tree
+
+
+def evolve(rng, tree: dict, churn: float = 0.04) -> dict:
+    """One 'release': edit ~churn of files (insert AND delete lines),
+    add/remove a few files, rename a few (content unchanged)."""
+    out = dict(tree)
+    paths = list(out.keys())
+    n_edit = max(1, int(len(paths) * churn))
+    for p in rng.choice(paths, size=n_edit, replace=False):
+        lines = list(out[p])
+        for _ in range(int(rng.integers(1, 6))):
+            at = int(rng.integers(0, max(1, len(lines))))
+            op = int(rng.integers(0, 3))
+            if op == 0:                          # insert a few lines
+                for j in range(int(rng.integers(1, 4))):
+                    lines.insert(at + j, _tree_line(rng))
+            elif op == 1 and len(lines) > 3:     # delete a few lines
+                del lines[at:at + int(rng.integers(1, 4))]
+            else:                                # modify one line
+                if lines:
+                    lines[at % len(lines)] = _tree_line(rng)
+        out[p] = lines
+    # whole-file adds and removes (~churn/4 each)
+    for p in rng.choice(paths, size=max(1, n_edit // 4), replace=False):
+        out.pop(p, None)
+    base = max(int(p.split("f")[-1].split(".")[0])
+               for p in out if "f" in p) + 1
+    for j in range(max(1, n_edit // 4)):
+        d1, d2 = int(rng.integers(0, 12)), int(rng.integers(0, 8))
+        nf = make_tree(rng, 1, 4096)
+        out[f"src/d{d1:02d}/m{d2}/f{base + j:05d}.c"] = \
+            next(iter(nf.values()))
+    # renames (content identical — pure path shift in the tar)
+    paths = list(out.keys())
+    for p in rng.choice(paths, size=max(1, n_edit // 6), replace=False):
+        if p in out:
+            out[p.replace("/m", "/r")] = out.pop(p)
+    return out
+
+
+def tar_bytes(tree: dict) -> bytes:
+    """Deterministic uncompressed tar (sorted paths, zeroed metadata) —
+    the 'snapshot' artifact each version uploads."""
+    buf = io.BytesIO()
+    with tarfile.open(fileobj=buf, mode="w", format=tarfile.GNU_FORMAT) \
+            as tf:
+        for p in sorted(tree):
+            body = b"".join(tree[p])
+            info = tarfile.TarInfo(name=p)
+            info.size = len(body)
+            info.mtime = 0
+            tf.addfile(info, io.BytesIO(body))
+    return buf.getvalue()
+
+
 def test_tree_snapshot_with_2pct_of_files_edited_is_refound():
     """A source tree of 300 files as a tar, and its next version (2 % of
     the files edited, some added, removed and renamed): at most a quarter
@@ -333,14 +429,12 @@ def test_tree_snapshot_with_2pct_of_files_edited_is_refound():
     32 KiB a file, where the edits lie further apart than the old rule's
     walk took to agree again (read at PR 37: 0.153 and 0.074; the
     last-anchor rule alone: 0.178 and 0.213)."""
-    import bench_dedup_tree as T
-
     params = AnchoredCdcParams()
     for mean_file_bytes, at_most in ((12 * 1024, 0.25), (32 * 1024, 0.12)):
         rng = np.random.default_rng(17)
-        tree = T.make_tree(rng, 300, mean_file_bytes)
-        versions = [np.frombuffer(T.tar_bytes(t), np.uint8)
-                    for t in (tree, T.evolve(rng, tree, churn=0.02))]
+        tree = make_tree(rng, 300, mean_file_bytes)
+        versions = [np.frombuffer(tar_bytes(t), np.uint8)
+                    for t in (tree, evolve(rng, tree, churn=0.02))]
         tables = []
         for v in versions:
             ends = _cut_ends(v, params)
@@ -354,25 +448,45 @@ def test_tree_snapshot_with_2pct_of_files_edited_is_refound():
 
 # ---------------------------------------------------------- device parity --
 
-@pytest.mark.parametrize("n", [1, 63, 4096, 5000, 100001, 300000])
-def test_device_matches_oracle(n):
-    data = corpus(n, seed=n + 100)
-    got = batch_chunks_anchored(data, SMALL, lane_multiple=8)
-    want = chunk_file_anchored_np(data, SMALL)
-    assert got == want
+def _sparse(n: int = 100000) -> np.ndarray:
+    """Low entropy: zeros with one random byte every 997 — few anchors
+    and few chunk candidates, so most cuts are forced ones."""
+    data = np.zeros((n,), dtype=np.uint8)
+    data[::997] = corpus(len(data[::997]), seed=8)
+    return data
 
 
-def test_device_low_entropy():
-    # all-zeros: anchor hash is constant; whatever it decides, device and
-    # oracle must agree, max-size forcing must bound segments
-    data = np.zeros((100000,), dtype=np.uint8)
+# every size class of the block grid and of the segment rule: empty, under
+# / at / over one block, one segment, ragged tails, whole multiples of a
+# lane (4096) — and the inputs where content decides nothing
+PARITY_INPUTS = {
+    **{str(n): (lambda n=n: corpus(n, seed=n + 100))
+       for n in (0, 1, 63, 64, 65, 4096, 4097, 5000, 3 * 4096, 40000,
+                 100001, 64 * 4096, 300000, 300001)},
+    "zeros": lambda: np.zeros((100000,), dtype=np.uint8),
+    "repeat": lambda: np.tile(corpus(256, seed=6), 400),
+    "sparse": _sparse,
+}
+
+
+@pytest.mark.parametrize("name", list(PARITY_INPUTS))
+def test_device_matches_oracle(name):
+    """The device chain, the CPU engine and the NumPy oracle give one
+    chunk table — spans and digests — and it tiles the input in chunks of
+    at most ``max_blocks``, each named by its sha256."""
+    from dfs_tpu.fragmenter.cdc_anchored import AnchoredCpuFragmenter
+
+    data = PARITY_INPUTS[name]()
     got = batch_chunks_anchored(data, SMALL, lane_multiple=8)
-    want = chunk_file_anchored_np(data, SMALL)
-    assert got == want
-    # repeating pattern (anchor-dense)
-    data = np.tile(corpus(256, seed=6), 400)
-    assert batch_chunks_anchored(data, SMALL, lane_multiple=8) == \
-        chunk_file_anchored_np(data, SMALL)
+    assert got == chunk_file_anchored_np(data, SMALL)
+    cpu = AnchoredCpuFragmenter(SMALL).chunk(data.tobytes())
+    assert [(c.offset, c.length, c.digest) for c in cpu] == got
+    end = 0
+    for o, ln, dg in got:
+        assert o == end and 0 < ln <= SMALL.chunk.max_blocks * 64
+        assert dg == hashlib.sha256(data[o:o + ln].tobytes()).hexdigest()
+        end = o + ln
+    assert end == data.shape[0]
 
 
 def test_device_tail_digests():
@@ -589,15 +703,20 @@ def test_factory_auto_honors_chunk_params(monkeypatch):
     assert f.params.seg_max == f.params.chunk.strip_blocks * 64
 
 
-def test_cdc_tpu_v1_deprecation_warning():
-    import warnings
-
+def test_factory_grows_the_lane_for_a_large_max_chunk():
+    """CLI values legal for ``cdc`` must not crash node start-up on an
+    anchored kind: a ``--max-chunk`` beyond the default lane grows the
+    lane (and the segment window pinned to it) instead of failing
+    AlignedCdcParams' ``max <= strip`` check."""
+    from dfs_tpu.config import CDCParams
     from dfs_tpu.fragmenter.base import get_fragmenter
 
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        get_fragmenter("cdc-tpu")
-    assert any(issubclass(x.category, DeprecationWarning) for x in w)
+    big = get_fragmenter("cdc-anchored", cdc_params=CDCParams(
+        min_size=2048, avg_size=8192, max_size=256 * 1024)).params
+    assert big.chunk.max_blocks == 4096
+    assert big.chunk.strip_blocks >= big.chunk.max_blocks
+    assert big.seg_max == big.chunk.strip_blocks * 64
+    assert TILE_BYTES <= big.seg_min < big.seg_max
 
 
 # ---------------------------------------------------------------------------

@@ -1,21 +1,17 @@
-"""Aligned CDC v2: oracle semantics + device parity (CPU backend).
-
-Mirrors the reference's only self-checks — replication hash echo and
-download hash-vs-fileId (StorageNode.java:248-257, 453-458) — as property
-tests: chunk spans tile the stream exactly, digests match hashlib, and the
-device kernels agree bit-for-bit with the NumPy oracle.
+"""The block-grid Gear candidate math of the anchored chunker
+(ops/cdc_v2.py): the NumPy oracle's semantics, and the plain-XLA device
+functions bit-for-bit against it, strip by strip (CPU backend). What a
+whole stream's chunks owe their caller is held in test_cdc_anchored.py
+and test_fragmenter_contract.py.
 """
-
-import hashlib
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dfs_tpu.ops.cdc_v2 import (BLOCK, AlignedCdcParams, block_hashes_np,
-                                candidates_np, chunk_file_np, chunk_spans_np,
-                                gear_candidates_device, g_table,
-                                host_to_strips, select_cuts_blocks,
+from dfs_tpu.ops.cdc_v2 import (BLOCK, AlignedCdcParams, candidates_np,
+                                cut_capacity, g_table,
+                                gear_candidates_device, select_cuts_blocks,
                                 select_cuts_device)
 
 SMALL = AlignedCdcParams(min_blocks=2, avg_blocks=4, max_blocks=16,
@@ -26,72 +22,55 @@ def corpus(n, seed=0):
     return np.random.default_rng(seed).integers(0, 256, size=n, dtype=np.uint8)
 
 
+def strips(data, params, lane_multiple=8):
+    """[N] uint8 -> (words_t [strip_blocks*16, S] uint32, S): the resident
+    layout of ops/cdc_v2.py, a strip every ``strip_len`` bytes, zero-padded
+    to whole strips and S to ``lane_multiple``."""
+    sl = params.strip_len
+    s = -(-max(1, -(-data.shape[0] // sl)) // lane_multiple) * lane_multiple
+    buf = np.zeros((s * sl,), dtype=np.uint8)
+    buf[:data.shape[0]] = data
+    words = buf.view(">u4").astype(np.uint32)
+    return np.ascontiguousarray(
+        words.reshape(s, params.strip_blocks * 16).T), s
+
+
+def strip_spans(data, params):
+    """The oracle, strip by strip: [(offset, length)] of the chunks when
+    chunking restarts every ``strip_len`` bytes."""
+    n, sl = data.shape[0], params.strip_len
+    spans = []
+    for s0 in range(0, n, sl):
+        seg = data[s0:s0 + sl]
+        cuts = select_cuts_blocks(
+            np.flatnonzero(candidates_np(seg, params)),
+            -(-seg.shape[0] // BLOCK), params)
+        prev = 0
+        for c in cuts.tolist():
+            end = min(c * BLOCK, seg.shape[0])
+            spans.append((s0 + prev * BLOCK, end - prev * BLOCK))
+            prev = c
+    return spans
+
+
 # ---------------------------------------------------------------- oracle --
-
-def test_spans_tile_stream_exactly():
-    for n in (0, 1, 63, 64, 65, 4096, 40000, 300000):
-        data = corpus(n)
-        spans = chunk_spans_np(data, SMALL)
-        if n == 0:
-            assert spans == []
-            continue
-        assert spans[0][0] == 0
-        assert sum(ln for _, ln in spans) == n
-        for (o1, l1), (o2, _) in zip(spans, spans[1:]):
-            assert o1 + l1 == o2
-        # every non-final chunk is block-aligned and within min/max
-        for o, ln in spans[:-1]:
-            assert o % BLOCK == 0 and ln % BLOCK == 0
-            assert ln <= SMALL.max_blocks * BLOCK
-
-
-def test_min_max_block_bounds():
-    data = corpus(500000, seed=3)
-    spans = chunk_spans_np(data, SMALL)
-    sl = SMALL.strip_len
-    for o, ln in spans:
-        at_strip_end = (o + ln) % sl == 0 or (o + ln) == data.shape[0]
-        if not at_strip_end:
-            assert ln >= SMALL.min_blocks * BLOCK
-        assert ln <= SMALL.max_blocks * BLOCK
-
-
-def test_digests_match_hashlib():
-    data = corpus(100000, seed=1)
-    for o, ln, dg in chunk_file_np(data, SMALL):
-        assert dg == hashlib.sha256(data[o:o + ln].tobytes()).hexdigest()
-
-
-def test_chunking_is_content_defined():
-    """Same content at the same strip-aligned offset chunks identically."""
-    p = SMALL
-    a = corpus(p.strip_len * 3, seed=5)
-    b = np.concatenate([corpus(p.strip_len, seed=6), a[:p.strip_len * 2]])
-    sa = {(o % p.strip_len, ln) for o, ln in chunk_spans_np(a, p)
-          if o < p.strip_len}
-    sb = {(o % p.strip_len, ln) for o, ln in chunk_spans_np(b, p)
-          if p.strip_len <= o < 2 * p.strip_len}
-    assert sa == sb  # strip 0 of `a` == strip 1 of `b`, chunked identically
-
-
-def test_dedup_across_versions():
-    """Appending data leaves earlier whole strips' chunks unchanged."""
-    p = SMALL
-    v1 = corpus(p.strip_len * 2 + 100, seed=7)
-    v2 = np.concatenate([v1[:p.strip_len * 2], corpus(p.strip_len, seed=8)])
-    d1 = {d for _, _, d in chunk_file_np(v1, p)}
-    d2 = {d for _, _, d in chunk_file_np(v2, p)}
-    shared = d1 & d2
-    # all chunks of the first two (identical) strips dedup
-    n_shared_expected = sum(1 for o, ln, _ in chunk_file_np(v1, p)
-                            if o + ln <= p.strip_len * 2)
-    assert len(shared) >= n_shared_expected
-
 
 def test_select_cuts_blocks_forced_max():
     # no candidates at all -> cuts every max_blocks, tail remainder
     cuts = select_cuts_blocks(np.array([], dtype=np.int64), 40, SMALL)
     assert cuts.tolist() == [16, 32, 40]
+
+
+def test_cut_capacity_bounds_real_cut_count():
+    """The chain provisions its cut table from this bound: random bytes
+    stay under it, and bytes that cut at every ``min_blocks`` (the worst
+    case it is written for) do too."""
+    dense = next(v for v in range(256) if candidates_np(
+        np.full(BLOCK, v, np.uint8), SMALL).any())
+    for data in (corpus(300000, seed=5),
+                 np.full(300000, dense, np.uint8)):
+        s = -(-data.shape[0] // SMALL.strip_len)
+        assert len(strip_spans(data, SMALL)) <= cut_capacity(s, SMALL)
 
 
 def test_g_table_matches_arithmetic():
@@ -105,7 +84,7 @@ def test_g_table_matches_arithmetic():
 @pytest.mark.parametrize("n", [4096 * 3, 300000, 64 * 4096])
 def test_device_candidates_match_oracle(n):
     data = corpus(n, seed=11)
-    words_t, s, _ = host_to_strips(data, SMALL, lane_multiple=8)
+    words_t, s = strips(data, SMALL)
     cand_dev = np.asarray(gear_candidates_device(jnp.asarray(words_t), SMALL))
     want = candidates_np(data, SMALL)
     nb_total = n // BLOCK
@@ -119,7 +98,7 @@ def test_device_selection_matches_oracle():
     n = 300000
     data = corpus(n, seed=12)
     p = SMALL
-    words_t, s, _ = host_to_strips(data, p, lane_multiple=8)
+    words_t, s = strips(data, p)
     cand = gear_candidates_device(jnp.asarray(words_t), p)
     nb_real = -(-n // BLOCK)
     real = np.clip(nb_real - np.arange(s) * p.strip_blocks, 0, p.strip_blocks)
@@ -135,16 +114,4 @@ def test_device_selection_matches_oracle():
             spans.append((off, end - off))
             prev = t + 1
     spans.sort()
-    assert spans == chunk_spans_np(data, p)
-
-
-def test_host_to_strips_roundtrip():
-    data = corpus(100000, seed=13)
-    p = SMALL
-    words_t, s, n = host_to_strips(data, p, lane_multiple=8)
-    assert n == 100000
-    # words_t[t*16+w, s] == BE word of the original bytes
-    flat = words_t.T.reshape(-1)  # [S * bps * 16] strip-major words
-    back = flat.astype(">u4").view(np.uint8) if False else \
-        np.ascontiguousarray(flat, dtype=np.uint32).astype(">u4").tobytes()
-    assert np.frombuffer(back, dtype=np.uint8)[:n].tobytes() == data.tobytes()
+    assert spans == strip_spans(data, p)

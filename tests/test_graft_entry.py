@@ -18,8 +18,7 @@ def test_entry_compiles_and_runs():
     import hashlib
 
     from dfs_tpu.ops.cdc_anchored import AnchoredCdcParams
-    from dfs_tpu.ops.cdc_pipeline import digests_to_hex
-    from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
+    from dfs_tpu.ops.cdc_v2 import AlignedCdcParams, digests_to_hex
     from dfs_tpu.ops.cdc_anchored import chunk_file_anchored_np
 
     fn, args = __graft_entry__.entry()

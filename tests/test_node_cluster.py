@@ -433,6 +433,61 @@ def test_delete_survives_node_downtime(tmp_path, rng):
     asyncio.run(run())
 
 
+@pytest.mark.parametrize("retired",
+                         ["cdc-tpu", "cdc-aligned", "cdc-aligned-tpu"])
+def test_manifest_of_a_retired_kind_still_reads(tmp_path, rng, retired):
+    """Stores in the wild hold manifests whose ``fragmenter`` field names
+    a kind this tree no longer builds (retired at PR 46). The field is a
+    record, never a dispatch: such a file is listed, adopted over the
+    wire by a node that missed it, downloaded byte-identical, walked by
+    the repair cycle (which restores a lost chunk of it) and deleted."""
+    import json
+
+    data = rng.integers(0, 256, size=60_000, dtype=np.uint8).tobytes()
+
+    async def run():
+        cluster = make_cluster_cfg(3)
+        nodes = await start_nodes(cluster, tmp_path)
+        manifest, _ = await nodes[1].upload(data, "old.bin")
+        fid = manifest.file_id
+        paths = {i: n.store.manifests._path(fid) for i, n in nodes.items()}
+        await stop_nodes(nodes)
+        # what such a store holds on disk: the same table under the
+        # retired name — on two nodes; the third never got the announce
+        for i in (1, 2):
+            d = json.loads(paths[i].read_text())
+            assert d["fragmenter"] == "cdc"
+            d["fragmenter"] = retired
+            paths[i].write_text(json.dumps(d))
+        paths[3].unlink()
+
+        nodes = await start_nodes(cluster, tmp_path)
+        try:
+            assert [(f["fileId"], f["name"])
+                    for f in nodes[1].list_files()] == [(fid, "old.bin")]
+            await nodes[3].repair_once()         # adopts it from a peer
+            adopted = nodes[3].store.manifests.load(fid)
+            assert adopted is not None and adopted.fragmenter == retired
+            m, got = await nodes[3].download(fid)
+            assert bytes(got) == data and m.fragmenter == retired
+            # the repair cycle walks it: a chunk lost behind node 2's
+            # back comes back
+            lost = next(c.digest for c in manifest.chunks
+                        if nodes[2].store.chunks.has(c.digest))
+            nodes[2].store.chunks.delete(lost)
+            assert await nodes[1].repair_once() \
+                + await nodes[2].repair_once() >= 1
+            assert nodes[2].store.chunks.has(lost)
+            assert await nodes[2].delete(fid)
+            for n in nodes.values():
+                assert n.store.manifests.load(fid) is None
+                assert n.list_files() == []
+        finally:
+            await stop_nodes(nodes)
+
+    asyncio.run(run())
+
+
 def test_streaming_upload_matches_regular(tmp_path, rng):
     """Chunked-transfer upload must produce the same file id and chunk
     table as a whole-body upload of identical bytes, be visible

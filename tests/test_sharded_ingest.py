@@ -1,37 +1,25 @@
 """Sharded streaming CDC as an INGEST option: the
-``FragmenterConfig.devices`` knob routes the ROLLING ``cdc`` strategy's
-``stream.py`` regions through ``make_sharded_bitmap_step`` (round 10)
-and the flagship ANCHORED strategy's region walk through the sharded
-anchor/region passes with double-buffered staging (round 15), and the
-resulting chunk boundaries and digests must be BYTE-IDENTICAL to the
-single-device path — on smooth streams, ragged tails, carries crossing
-region and device borders, empty and one-chunk streams, and through a
-real node's streaming upload."""
+``FragmenterConfig.devices`` knob routes the ANCHORED chunker's region
+walk through the window-batched anchor/region passes with
+double-buffered staging (round 15), and the resulting chunk boundaries
+and digests must be BYTE-IDENTICAL to the single-device path — on
+smooth streams, ragged tails, carries crossing region and device
+borders, empty and one-chunk streams, and through a real node's
+streaming upload."""
 
 import asyncio
 
 import numpy as np
 import pytest
 
-from dfs_tpu.config import CDCParams, FragmenterConfig
+from dfs_tpu.config import FragmenterConfig
 from dfs_tpu.fragmenter.base import get_fragmenter
 from dfs_tpu.fragmenter.cdc_anchored import AnchoredCpuFragmenter
 from dfs_tpu.fragmenter.cdc_anchored_sharded import \
     ShardedAnchoredCdcFragmenter
-from dfs_tpu.fragmenter.cdc_cpu import CpuCdcFragmenter, gear_bitmap_numpy
-from dfs_tpu.fragmenter.cdc_sharded import ShardedCdcFragmenter
 from dfs_tpu.ops.cdc_anchored import AnchoredCdcParams
 from dfs_tpu.ops.cdc_v2 import AlignedCdcParams
-from dfs_tpu.parallel.mesh import make_mesh
-from dfs_tpu.parallel.sharded_cdc import (make_sharded_bitmap_step,
-                                          shard_bitmap_inputs)
-from dfs_tpu.utils.hashing import gear_table
 from tests.test_cdc_anchored import CASES
-
-PARAMS = CDCParams(min_size=64, avg_size=256, max_size=1024)
-# tiny regions so the sharded step compiles fast on the CI host; still a
-# multiple of the device count and >> the 31-byte halo
-REGION = 4 * 4096
 
 # anchored geometry: the anchored_sharded_parity_check shapes — 4 KiB
 # lanes, 2-4 KiB segments; region = 4 device spans of one seg_max each
@@ -42,144 +30,13 @@ APARAMS = AnchoredCdcParams(
 AREGION = 4 * 4096
 
 
-def _frag(devices: int = 4) -> ShardedCdcFragmenter:
-    return ShardedCdcFragmenter(
-        PARAMS, FragmenterConfig(devices=devices, region_bytes=REGION))
-
-
 def _blocks(data: bytes, n: int):
     for off in range(0, len(data), n):
         yield data[off:off + n]
 
 
-def test_carry_bitmap_step_matches_oracle(rng):
-    """The carry-in sharded bitmap == the whole-stream NumPy bitmap,
-    region by region — including a NONZERO halo entering region 2."""
-    table = gear_table(PARAMS.seed)
-    mesh = make_mesh(4, dp=1)
-    step = make_sharded_bitmap_step(mesh, table, PARAMS.mask)
-    data = rng.integers(0, 256, size=2 * REGION, dtype=np.uint8)
-    whole = gear_bitmap_numpy(data, table, PARAMS.mask)
-    head = np.zeros((1, 31), dtype=np.uint32)
-    for r in range(2):
-        region = data[r * REGION:(r + 1) * REGION]
-        bitmap = np.asarray(step(*shard_bitmap_inputs(
-            mesh, region[None, :], head)))[0]
-        assert np.array_equal(bitmap, whole[r * REGION:(r + 1) * REGION]), \
-            f"region {r} bitmap diverged"
-        head = table[region[-31:]].astype(np.uint32)[None, :]
-
-
-@pytest.mark.parametrize("size", [0, 1, 5000, REGION, REGION + 1,
-                                  3 * REGION - 7, 4 * REGION])
-def test_sharded_stream_boundaries_byte_identical(rng, size):
-    """manifest_stream through the sharded fragmenter == the CPU oracle:
-    same spans, same digests, same file id — for empty, sub-region,
-    exact-region, and ragged-tail stream lengths."""
-    data = rng.integers(0, 256, size=size, dtype=np.uint8).tobytes()
-    cpu = CpuCdcFragmenter(PARAMS).manifest_stream(
-        _blocks(data, 1 << 14), name="x")
-    shd = _frag().manifest_stream(_blocks(data, 1 << 14), name="x")
-    assert [(c.offset, c.length, c.digest) for c in shd.chunks] \
-        == [(c.offset, c.length, c.digest) for c in cpu.chunks]
-    assert shd.file_id == cpu.file_id and shd.size == cpu.size
-
-
-def test_sharded_stream_stores_identical_payloads(rng):
-    data = rng.integers(0, 256, size=2 * REGION + 333,
-                        dtype=np.uint8).tobytes()
-    got: dict[str, bytes] = {}
-    m = _frag().manifest_stream(_blocks(data, 8192), name="x",
-                                store=lambda d, b: got.setdefault(d, b))
-    assert b"".join(got[c.digest] for c in m.chunks) == data
-
-
-def test_factory_returns_sharded_only_when_asked():
-    frag = get_fragmenter("cdc", cdc_params=PARAMS,
-                          frag=FragmenterConfig(devices=4,
-                                                region_bytes=REGION))
-    assert isinstance(frag, ShardedCdcFragmenter)
-    # describe() (the resume protocol) is the CPU engine's — boundaries
-    # are the same strategy, so a resuming client needs no new kind
-    assert frag.describe()["kind"] == "cdc"
-    single = get_fragmenter("cdc", cdc_params=PARAMS,
-                            frag=FragmenterConfig())
-    assert isinstance(single, CpuCdcFragmenter)
-    assert not isinstance(single, ShardedCdcFragmenter)
-
-
-def test_degraded_environment_falls_back(rng):
-    """More devices configured than visible: ingest must still work,
-    through the single-device kernel, with identical output."""
-    frag = ShardedCdcFragmenter(
-        PARAMS, FragmenterConfig(devices=64, region_bytes=64 * 124))
-    data = rng.integers(0, 256, size=40_000, dtype=np.uint8).tobytes()
-    cpu = CpuCdcFragmenter(PARAMS).manifest_stream(
-        _blocks(data, 8192), name="x")
-    shd = frag.manifest_stream(_blocks(data, 8192), name="x")
-    assert frag._unavailable
-    assert [(c.offset, c.length) for c in shd.chunks] \
-        == [(c.offset, c.length) for c in cpu.chunks]
-
-
-def test_node_streaming_upload_via_sharded_cdc(tmp_path, rng):
-    """End to end: a single-node cluster configured with
-    frag.devices=4 ingests a chunked-transfer stream through the sharded
-    step and serves it back byte-identical."""
-    from dfs_tpu.config import ClusterConfig, NodeConfig
-    from dfs_tpu.node.runtime import StorageNodeServer
-
-    data = rng.integers(0, 256, size=3 * REGION + 123,
-                        dtype=np.uint8).tobytes()
-
-    async def run():
-        cluster = ClusterConfig.localhost(1, base_port=0,
-                                          base_internal_port=0,
-                                          replication_factor=1)
-        import socket
-
-        socks = [socket.socket() for _ in range(2)]
-        for s in socks:
-            s.bind(("127.0.0.1", 0))
-        ports = [s.getsockname()[1] for s in socks]
-        for s in socks:
-            s.close()
-        from dfs_tpu.config import PeerAddr
-        cluster = ClusterConfig(
-            peers=(PeerAddr(node_id=1, host="127.0.0.1", port=ports[0],
-                            internal_port=ports[1]),),
-            replication_factor=1)
-        cfg = NodeConfig(
-            node_id=1, cluster=cluster, data_root=tmp_path,
-            fragmenter="cdc", cdc=PARAMS,
-            frag=FragmenterConfig(devices=4, region_bytes=REGION),
-            health_probe_s=0)
-        node = StorageNodeServer(cfg)
-        assert isinstance(node.fragmenter, ShardedCdcFragmenter)
-        await node.start()
-        try:
-            async def blocks():
-                for off in range(0, len(data), 8192):
-                    yield data[off:off + 8192]
-
-            manifest, _ = await node.upload_stream(blocks(), "s.bin")
-            # boundaries equal the single-device oracle
-            oracle = CpuCdcFragmenter(PARAMS).manifest_stream(
-                _blocks(data, 8192), name="s.bin")
-            assert [(c.offset, c.length, c.digest)
-                    for c in manifest.chunks] \
-                == [(c.offset, c.length, c.digest)
-                    for c in oracle.chunks]
-            _, got = await node.download(manifest.file_id)
-            assert got == data
-        finally:
-            await node.stop()
-
-    asyncio.run(run())
-
-
 # ------------------------------------------------------------------ #
-# ANCHORED sharded walk (round 15): the flagship pipeline's streaming
+# the sharded walk (round 15): the anchored chunker's streaming
 # region walk over the mesh — sharded pass A, host select with the
 # threaded carry, sharded region step (repack/scan/digest per lane
 # shard), double-buffered staging

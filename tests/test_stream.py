@@ -12,9 +12,8 @@ import pytest
 from dfs_tpu.config import (CDCParams, ClusterConfig, IngestConfig,
                             NodeConfig)
 from dfs_tpu.fragmenter.cdc_cpu import CpuCdcFragmenter
-from dfs_tpu.fragmenter.cdc_tpu import TpuCdcFragmenter
 from dfs_tpu.fragmenter.fixed import FixedFragmenter
-from dfs_tpu.fragmenter.stream import StreamChunker, reblock
+from dfs_tpu.fragmenter.stream import StreamChunker
 from dfs_tpu.utils.hashing import sha256_hex
 
 PARAMS = CDCParams(min_size=64, avg_size=256, max_size=1024)
@@ -61,41 +60,11 @@ def test_cpu_manifest_stream_matches(rng, tmp_path):
     assert rebuilt == data
 
 
-def test_tpu_manifest_stream_matches(rng):
-    cpu = CpuCdcFragmenter(PARAMS)
-    tpu = TpuCdcFragmenter(PARAMS, tile_size=8_192, hash_batch=16)
-    data = rng.integers(0, 256, size=60_000, dtype=np.uint8).tobytes()
-    m = tpu.manifest_stream(_blocks(data, [10_000, 321]), "t.bin")
-    want = cpu.manifest(data, "t.bin")
-    assert m.fragmenter == "cdc-tpu"  # only the label differs
-    assert (m.file_id, m.size, m.chunks) == (want.file_id, want.size,
-                                             want.chunks)
-
-
 def test_fixed_manifest_stream_fallback(rng):
     frag = FixedFragmenter(parts=5)
     data = rng.integers(0, 256, size=1_000, dtype=np.uint8).tobytes()
     m = frag.manifest_stream(_blocks(data, [100]), "f.bin")
     assert m == frag.manifest(data, "f.bin")
-
-
-def test_chunk_falls_back_to_streaming_beyond_offset_range(rng):
-    """Streams past the int32 device-offset ceiling must route through the
-    streaming path (offset-free) and still match the CPU oracle. The ceiling
-    is shrunk here to keep the test small."""
-    tpu = TpuCdcFragmenter(PARAMS, tile_size=4_096, hash_batch=16)
-    tpu._max_resident = 20_000
-    data = rng.integers(0, 256, size=50_000, dtype=np.uint8).tobytes()
-    got = tpu.chunk(data)
-    want = CpuCdcFragmenter(PARAMS).chunk(data)
-    assert got == want
-
-
-def test_reblock_exact_tiles(rng):
-    data = rng.integers(0, 256, size=10_000, dtype=np.uint8).tobytes()
-    tiles = list(reblock(_blocks(data, [999]), 4096))
-    assert [t.shape[0] for t in tiles] == [4096, 4096, 1808]
-    assert b"".join(t.tobytes() for t in tiles) == data
 
 
 def test_bounded_state(rng):

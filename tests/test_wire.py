@@ -401,7 +401,7 @@ def test_bench_wire_tiny(tmp_path):
     assert proc.returncode == 0, proc.stderr[-2000:]
     art = json.loads(out_path.read_text())
     # schema: the keys WIRE_r10.json (full mode) commits to
-    for key in ("metric", "round", "mode", "wire", "cdc",
+    for key in ("metric", "round", "mode", "wire",
                 "byte_identical", "ok"):
         assert key in art, f"artifact missing {key!r}"
     assert art["metric"] == "zero_copy_data_plane" and art["mode"] == "tiny"
